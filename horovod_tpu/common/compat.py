@@ -1,10 +1,9 @@
-"""Version compatibility shims for the jax API surface we depend on.
+"""The one home of the jax API spellings the ``compat-discipline`` lint
+(``tools/hvdlint``) keeps out of the rest of the package.
 
-The codebase targets the modern jax spelling (``jax.shard_map`` with
-``check_vma=``); older installed versions (< 0.6) expose the same
-machinery as ``jax.experimental.shard_map.shard_map`` with the replication
-check spelled ``check_rep=``. Every internal call site goes through
-:func:`shard_map` here so the framework runs unmodified on both.
+One installation is supported (jax 0.9, docs/install.md), so every
+function here is a plain forward; the indirection stays so a future jax
+rename is absorbed in this file alone.
 """
 
 from __future__ import annotations
@@ -12,88 +11,27 @@ from __future__ import annotations
 import jax
 from jax import lax as _lax
 
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-
-else:  # pre-0.6 jax: experimental module, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
+axis_size = _lax.axis_size
 
 
-if hasattr(_lax, "axis_size"):
-    axis_size = _lax.axis_size
-else:
-
-    def axis_size(axis_name):
-        """Size of a bound mesh axis. Pre-0.5 jax has no ``lax.axis_size``;
-        ``psum`` of a Python literal folds to the static size (no
-        collective is emitted)."""
-        return _lax.psum(1, axis_name)
+def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def ensure_cpu_devices(n: int) -> None:
     """Size the host-CPU backend to ``n`` virtual devices (test meshes,
-    virtual-mesh demos). Must run before the first device query. Uses the
-    ``jax_num_cpu_devices`` config option where it exists (jax >= 0.5),
-    else the ``XLA_FLAGS`` fallback; a no-op if the backend already
-    initialized (same contract as the config option's RuntimeError).
-
-    On the ``XLA_FLAGS`` path the env var stays exported for the life of
-    the process — subprocesses inherit the forced count. Callers that
-    spawn real one-device-per-process worker worlds must strip/restore
-    it around the spawn (tests/conftest.py forces backend init and then
-    restores the var for exactly this reason)."""
-    import os
-
-    try:
-        jax.config.update("jax_num_cpu_devices", int(n))
-        return
-    except AttributeError:
-        pass  # pre-0.5 jax: fall through to the XLA flag
-    except RuntimeError:
-        return  # backend already initialized; too late either way
-    flag = f"--xla_force_host_platform_device_count={int(n)}"
-    flags = os.environ.get("XLA_FLAGS", "")
-    # Append even when a different count is already present — XLA takes
-    # the LAST occurrence of a repeated flag, so the request wins. Whole-
-    # token comparison: "count=8" is a substring of "count=80".
-    if flag not in flags.split():
-        os.environ["XLA_FLAGS"] = (flags + " " + flag).strip()
+    virtual-mesh demos). Must run before the first device query; jax
+    raises RuntimeError once the backend is initialized."""
+    jax.config.update("jax_num_cpu_devices", int(n))
 
 
 def pallas_tpu_compiler_params(**kwargs):
-    """Build a Mosaic compiler-params object under either name
-    (``CompilerParams`` today; ``TPUCompilerParams`` before the rename)."""
+    """Build a Mosaic compiler-params object."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def distributed_is_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` with a pre-0.5 fallback (the
-    accessor was added later; older jax only exposes the client on the
-    private distributed state)."""
-    if hasattr(jax.distributed, "is_initialized"):
-        return bool(jax.distributed.is_initialized())
-    from jax._src import distributed as _dist
-
-    return getattr(_dist.global_state, "client", None) is not None
-
-
-def install() -> None:
-    """Give old jax the modern ``jax.shard_map`` spelling.
-
-    Code written against the current API (tests, user scripts) calls
-    ``jax.shard_map(..., check_vma=...)``; on installs that predate the
-    promotion out of ``jax.experimental`` this plants the compat wrapper
-    under the modern name. No-op when jax already provides it.
-    """
-    if not hasattr(jax, "shard_map"):
-        jax.shard_map = shard_map
+    return bool(jax.distributed.is_initialized())

@@ -67,17 +67,47 @@ _DONE_CB_TYPE = ctypes.CFUNCTYPE(None, ctypes.c_void_p, ctypes.c_longlong,
                                  ctypes.c_int, ctypes.c_char_p)
 
 
-def _build_library() -> bool:
-    try:
-        san = _config.native_sanitize()
-        cmd = ["make", "-C", _CSRC_DIR] + ([san] if san else [])
-        subprocess.run(cmd, check=True, capture_output=True, timeout=300)
-        return os.path.exists(_lib_path())
-    # hvdlint: ignore[exception-discipline] -- build probe: the native
-    # core is optional and no collective exists before it loads
-    except Exception as e:  # compiler missing etc.
-        _log.warning(f"native runtime build failed: {e}")
-        return False
+def _build_library() -> None:
+    """Bring the artifact up to date with ``make``. csrc/Makefile lists
+    every source and header as a prerequisite of the .so, so ``make -q``
+    is the freshness check: a library left over from other sources is
+    rebuilt, never loaded. The check and the rebuild run under one
+    exclusive file lock, and the Makefile links beside the target and
+    renames, so concurrent ranks on one host never take a half-written
+    object for an up-to-date one, let alone dlopen it. Where nothing can
+    be built — no ``make``, no sources, or a read-only install — the
+    library the package shipped (``lib/*.so`` in a wheel) is loaded as it
+    is. Raises with the compiler's output when the build fails."""
+    import fcntl
+    import shutil
+
+    lib_dir = (_LIB_DIR if os.path.isdir(_LIB_DIR)
+               else os.path.dirname(_LIB_DIR))
+    if not (shutil.which("make")
+            and os.path.exists(os.path.join(_CSRC_DIR, "Makefile"))
+            and os.access(lib_dir, os.W_OK)):
+        if os.path.exists(_lib_path()):
+            return
+        raise RuntimeError(
+            f"native runtime: {_lib_path()} is not there and cannot be "
+            f"built (needs `make`, a C++17 compiler, the sources in "
+            f"{_CSRC_DIR} and a writable {_LIB_DIR}); set HOROVOD_NATIVE=0 "
+            f"to run without it")
+    san = _config.native_sanitize()
+    cmd = ["make", "-C", _CSRC_DIR] + ([san] if san else [])
+    os.makedirs(_LIB_DIR, exist_ok=True)
+    with open(os.path.join(_LIB_DIR, "build.lock"), "w") as lockf:
+        fcntl.flock(lockf, fcntl.LOCK_EX)
+        if subprocess.run(cmd + ["-q"], capture_output=True,
+                          timeout=60).returncode == 0:
+            return
+        r = subprocess.run(cmd, capture_output=True, text=True,
+                           timeout=600)
+    if r.returncode != 0:
+        raise RuntimeError(
+            f"native runtime build failed ({' '.join(cmd)}, rc="
+            f"{r.returncode}); set HOROVOD_NATIVE=0 to run without it:\n"
+            f"{r.stderr[-2000:]}")
 
 
 _lib = None
@@ -89,36 +119,18 @@ _keepalive_cbs = []
 
 
 def load_library():
-    """Load (building if necessary) the native library; None on failure
-    or when disabled. The HOROVOD_NATIVE gate is checked before the cache
-    so disabling it mid-process (tests, a re-init after a bad native
-    world) is honored even after an earlier load."""
-    global _lib
+    """Build (if out of date) and load the native library; None only
+    when disabled. A library that does not build, load or bind raises —
+    running without the native core is a choice (HOROVOD_NATIVE=0), not
+    a fallback. The HOROVOD_NATIVE gate is checked before the cache so
+    disabling it mid-process (tests, a re-init after a bad native world)
+    is honored even after an earlier load."""
     if not _config.native_enabled():
         return None
     if _lib is not None:
         return _lib
-    lib_path = _lib_path()
-    if not os.path.exists(lib_path) and not _build_library():
-        return None
-    try:
-        lib = ctypes.CDLL(lib_path)
-        return _bind_prototypes(lib)
-    except (OSError, AttributeError) as e:
-        # A stale .so from an older build (missing symbols) or a
-        # corrupt/wrong-arch one: rebuild once, then either bind the
-        # fresh library or degrade to direct mode — never crash init.
-        _log.warning(f"native library unusable ({e}); rebuilding")
-        if not _build_library():
-            return None
-        try:
-            _lib = None
-            lib = ctypes.CDLL(lib_path)
-            return _bind_prototypes(lib)
-        except (OSError, AttributeError) as e2:
-            _log.warning(f"native library still unusable after rebuild "
-                         f"({e2}); using direct mode")
-            return None
+    _build_library()
+    return _bind_prototypes(ctypes.CDLL(_lib_path()))
 
 
 def _bind_prototypes(lib):

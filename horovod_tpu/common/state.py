@@ -440,9 +440,9 @@ def xla_built() -> bool:
 
 
 def tpu_available() -> bool:
+    """Whether the default backend drives TPU devices. A backend that
+    fails to initialize raises (jax's own error) instead of reading as
+    "no TPU": a chip that is present but unusable is not a CPU host."""
     import jax
 
-    try:
-        return any(d.platform == "tpu" for d in jax.devices())
-    except RuntimeError:
-        return False
+    return any(d.platform == "tpu" for d in jax.devices())

@@ -17,8 +17,9 @@ ring attention's backward ring pass (``parallel.ring_attention``).
 
 Block offsets ride in as prefetched scalars, so the same kernel serves
 ring attention's rotating K/V blocks (global causal masking between
-sequence blocks) and the plain single-block case. On CPU the kernel runs
-in interpreter mode (tests); on TPU it compiles through Mosaic.
+sequence blocks) and the plain single-block case. On TPU the kernels
+compile through Mosaic; tests interpret them on CPU
+(``_resolve_dispatch``).
 """
 
 from __future__ import annotations
@@ -34,12 +35,43 @@ from ..common.compat import pallas_tpu_compiler_params as _compiler_params
 
 NEG_INF = -1e30
 
-# Tile sizes: multiples of the fp32 (8, 128) tile, sized by an on-chip
-# sweep (v5e, T=2048 D=128 causal): 512x512 runs 1.18x faster than XLA's
-# fused attention; 128x128 pays too much per-step overhead. VMEM use at
-# D=128 stays ~1 MB per pipeline stage.
+# Tile sizes: multiples of the fp32 (8, 128) tile. 512x512 came from a
+# sweep on an older stack that left no record; ROADMAP S7 re-tunes it
+# against a measured roofline share. VMEM use at D=128 stays ~1 MB per
+# pipeline stage.
 BLOCK_Q = 512
 BLOCK_K = 512
+
+
+def _mxu_dot(a, b, contract):
+    """``a . b`` contracting ``contract`` = ((a dims), (b dims)), float32
+    accumulation. The MXU takes bf16 operands natively. float32 operands
+    follow ``jax_default_matmul_precision``, as every other float32
+    matmul of a model does (``_xla_flash`` included): by default they are
+    rounded to bf16 on their way in, one pass (on a v5e chip 1e-2 from
+    the float32 reference); under
+    ``jax.default_matmul_precision("highest")`` they take the multi-pass
+    float32 product (6e-7). Mosaic implements those two settings."""
+    precision = None if a.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+    return jax.lax.dot_general(
+        a, b, dimension_numbers=(contract, ((), ())),
+        precision=precision, preferred_element_type=jnp.float32)
+
+
+def row_lse(m, l):
+    """Per-row log-sum-exp ``m + log(l)`` from the online-softmax state,
+    for the backward's ``P = exp(S - lse)``; rows with no visible key get
+    a huge POSITIVE lse so that P underflows to exactly zero for them.
+
+    One Newton step on ``exp(y) = l`` follows the log: a v5e chip's
+    float32 log is off by up to 1e-4 (its exp by 1e-5 relative), and an
+    error in lse scales every P of the row — it left float32 gradients
+    5e-4 from the reference where the interpreter gave 2e-5. Works on
+    values inside a kernel and on arrays outside one (ring attention)."""
+    safe = jnp.maximum(l, 1e-30)
+    log_l = jnp.log(safe)
+    log_l = log_l + (safe * jnp.exp(-log_l) - 1.0)
+    return jnp.where(l > 0.0, m + log_l, -NEG_INF)
 
 
 def _attn_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
@@ -91,9 +123,7 @@ def _attn_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         q = q_ref[0]
         k = k_ref[0]
         scale = 1.0 / (q.shape[-1] ** 0.5)
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
+        s = _mxu_dot(q, k, ((1,), (1,))) * scale  # [bq, bk]
 
         if causal:
             q_pos = (q_base +
@@ -115,10 +145,7 @@ def _attn_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         l_new = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
         # P rides the MXU in the V dtype (f32 accumulation preserved by
         # preferred_element_type) — the standard TPU flash-kernel trade.
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        pv = _mxu_dot(p.astype(v_ref.dtype), v_ref[0], ((1,), (0,)))
         acc_ref[:] = acc_ref[:] * corr + pv
         m_ref[:] = m_new
         l_ref[:] = l_new
@@ -136,13 +163,7 @@ def _attn_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
             o_ref[0] = (acc_ref[:] /
                         jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
             if lse_ref is not None:
-                # Rows with no visible key get +NEG_INF's negation so the
-                # backward's exp(s - lse) underflows to exactly zero
-                # instead of exploding on lse = -inf.
-                m = m_ref[:]
-                l = l_ref[:]
-                lse_ref[0] = jnp.where(l > 0.0, m + jnp.log(
-                    jnp.maximum(l, 1e-30)), -NEG_INF)
+                lse_ref[0] = row_lse(m_ref[:], l_ref[:])
 
 
 def _attn_kernel_state(offs_ref, q_ref, k_ref, v_ref, o_ref, mo_ref,
@@ -208,9 +229,7 @@ def _attn_bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         q = q_ref[0]
         k = k_ref[0]
         scale = 1.0 / (q.shape[-1] ** 0.5)
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # [bq, bk]
+        s = _mxu_dot(q, k, ((1,), (1,))) * scale  # [bq, bk]
         p = jnp.exp(s - lse_ref[0])                          # [bq, bk]
         if causal:
             q_pos = q_base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -220,14 +239,9 @@ def _attn_bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 p = jnp.where(q_pos - k_pos < window, p, 0.0)
         if qs_ref is not None:
             p = jnp.where(qs_ref[0] == ks_ref[0].reshape(1, -1), p, 0.0)
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bq, bk]
-        ds = p * (dp - delta_ref[0]) * scale                 # [bq, bk]
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bq, D]
+        dp = _mxu_dot(do_ref[0], v_ref[0], ((1,), (1,)))  # [bq, bk]
+        ds = p * (dp - delta_ref[0]) * scale  # [bq, bk]
+        dq_acc[:] += _mxu_dot(ds.astype(k.dtype), k, ((1,), (0,)))  # [bq, D]
 
     @pl.when(ki == num_k_tiles - 1)
     def _finalize():
@@ -273,9 +287,7 @@ def _attn_bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         k = k_ref[0]
         do = do_ref[0]
         scale = 1.0 / (q.shape[-1] ** 0.5)
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # [bq, bk]
+        s = _mxu_dot(q, k, ((1,), (1,))) * scale  # [bq, bk]
         p = jnp.exp(s - lse_ref[0])
         if causal:
             q_pos = q_base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -285,18 +297,10 @@ def _attn_bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 p = jnp.where(q_pos - k_pos < window, p, 0.0)
         if qs_ref is not None:
             p = jnp.where(qs_ref[0] == ks_ref[0].reshape(1, -1), p, 0.0)
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bk, D]
-        dp = jax.lax.dot_general(
-            do, v_ref[0], dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bq, bk]
+        dv_acc[:] += _mxu_dot(p.astype(do.dtype), do, ((0,), (0,)))  # [bk, D]
+        dp = _mxu_dot(do, v_ref[0], ((1,), (1,)))  # [bq, bk]
         ds = p * (dp - delta_ref[0]) * scale
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bk, D]
+        dk_acc[:] += _mxu_dot(ds.astype(q.dtype), q, ((0,), (0,)))  # [bk, D]
 
     @pl.when(qi == num_q_tiles - 1)
     def _finalize():
@@ -487,20 +491,23 @@ _block_state_core.defvjp(_block_state_fwd, _block_state_bwd)
 
 
 def _resolve_dispatch(use_pallas: Optional[bool]):
-    """Shared backend policy: (use_pallas, interpret). Mosaic on TPU,
-    interpreter under HVD_PALLAS_INTERPRET=1 (tests), XLA elsewhere."""
+    """Shared backend policy: (use_pallas, interpret). Mosaic on TPU.
+    Off the chip the kernels run only interpreted, and only where a test
+    asked for that with HVD_PALLAS_INTERPRET=1: ``None`` otherwise takes
+    the XLA path and an explicit ``True`` raises — a kernel never runs
+    interpreted without anyone asking."""
     import os
 
+    on_tpu = jax.default_backend() == "tpu"
+    interpret = not on_tpu and bool(os.environ.get("HVD_PALLAS_INTERPRET"))
     if use_pallas is None:
-        platform = jax.default_backend()
-        if platform == "tpu":
-            return True, False
-        if os.environ.get("HVD_PALLAS_INTERPRET"):
-            return True, True
-        return False, False
-    if use_pallas:
-        return True, jax.default_backend() != "tpu"
-    return False, False
+        use_pallas = on_tpu or interpret
+    elif use_pallas and not (on_tpu or interpret):
+        raise RuntimeError(
+            f"use_pallas=True on the {jax.default_backend()!r} backend: "
+            "the Mosaic kernels compile for TPU only (tests interpret "
+            "them under HVD_PALLAS_INTERPRET=1)")
+    return use_pallas, use_pallas and interpret
 
 
 def _merge_heads(x):

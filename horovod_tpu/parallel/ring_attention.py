@@ -115,9 +115,9 @@ def _ring_fwd_pass(q, k, v, seg, axis_name: str, causal: bool,
     (m, l, o, _, _, _), _ = lax.scan(
         body, (m, l, o, k, v, kseg0), jnp.arange(sp))
     o = o / jnp.maximum(l, 1e-30).transpose(0, 2, 1)[..., None]
-    # Dead rows (no visible key) take a huge POSITIVE lse so the
-    # backward's exp(s - lse) underflows to zero for them.
-    lse = jnp.where(l > 0.0, m + jnp.log(jnp.maximum(l, 1e-30)), -NEG_INF)
+    from ..ops.pallas_attention import row_lse
+
+    lse = row_lse(m, l)
     # Anchor the axis index in the live output dataflow: when the mask
     # path doesn't consume it (causal=False, no window/segments), some
     # XLA versions leave the dead partition-id where the SPMD partitioner

@@ -83,15 +83,13 @@ _PORT_CLASH_MARKERS = (
 
 
 def run_world(tmp_path, script_text, sentinel, size=2, timeout=240,
-              args_for_rank=None, drop_env=(), attempts=3):
+              args_for_rank=None, attempts=3):
     """Write ``script_text`` and run ``size`` ranks of it.
 
     Each rank's argv is ``[rank, *args_for_rank(rank, port)]`` (default:
     ``[rank, port]``). Asserts rc==0 and the sentinel for every rank; on
     any failure or timeout the remaining workers are killed before the
-    assertion propagates. ``drop_env`` names vars stripped from the
-    workers' environment — needed for vars that act at interpreter
-    startup (sitecustomize), before the script body can unset them.
+    assertion propagates.
 
     free_port() has a TOCTOU window (another process can bind the port
     between probe and worker startup); failures that look like a port
@@ -101,8 +99,6 @@ def run_world(tmp_path, script_text, sentinel, size=2, timeout=240,
     script.write_text(script_text)
     env = dict(os.environ)
     env["HVD_REPO"] = REPO
-    for name in drop_env:
-        env.pop(name, None)
     if args_for_rank is None:
         args_for_rank = lambda rank, port: [str(port)]  # noqa: E731
 

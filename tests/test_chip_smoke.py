@@ -1,0 +1,312 @@
+"""chip_smoke.py is the proof every later tree must give on the chip; this
+file keeps it from rotting between chip runs, without the chip:
+
+- its phases, imported and run at toy sizes on the CPU mesh (kernels
+  interpreted) — wrong paths, arguments, meshes and sharding rules;
+- the program refusing to stand the CPU in for the chip (chip_smoke.py,
+  bench.py, the peak table);
+- the compile-cache helper;
+- the attention kernels compiled for a *described* v5e chip at the real
+  widths: what the chip's compiler would refuse fails here.
+
+Nothing here is a chip result.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import bench  # noqa: E402
+import chip_smoke  # noqa: E402
+import horovod_tpu.ops.pallas_attention as pa  # noqa: E402
+from tools import compile_cache  # noqa: E402
+
+
+def _run(script):
+    from conftest import subprocess_cpu_env
+
+    return subprocess.Popen(
+        [sys.executable, os.path.join(REPO, script)], cwd=REPO,
+        env=subprocess_cpu_env(), stdout=subprocess.PIPE,
+        stderr=subprocess.PIPE, text=True)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_chip_runs():
+    """Both programs started on the CPU backend at once, so they run
+    while the other tests do; each test below waits for its own."""
+    procs = {s: _run(s) for s in ("chip_smoke.py", "bench.py")}
+    yield procs
+    for p in procs.values():
+        p.kill()
+        p.wait(timeout=30)
+
+
+# ---- the phases at toy sizes -----------------------------------------------
+
+class TinyNet(nn.Module):
+    """Conv + BatchNorm + Dense: the state shapes of ResNet (parameters
+    and batch statistics) at a size the CPU compiles in a second."""
+
+    @nn.compact
+    def __call__(self, x, train=True):
+        x = nn.Conv(8, (3, 3), use_bias=False)(x)
+        x = nn.BatchNorm(use_running_average=not train, momentum=0.9)(x)
+        x = jnp.mean(nn.relu(x), axis=(1, 2))
+        return nn.Dense(10)(x)
+
+
+@pytest.fixture(scope="module")
+def smoke_world():
+    """hvd.init() over four virtual devices, through the smoke's own
+    native-core phase (it asserts the core loaded and is not direct
+    mode)."""
+    import horovod_tpu as hvd
+
+    hvd.init(devices=jax.devices()[:4])
+    chip_smoke.phase_native_core()
+    yield hvd
+    hvd.shutdown()
+
+
+def test_device_phase_refuses_cpu():
+    device = chip_smoke.read_device()
+    assert device["platform"] == "cpu"
+    with pytest.raises(chip_smoke.SmokeFailure, match="no TPU"):
+        chip_smoke.phase_device(device, chips=1)
+
+
+def test_eager_phase(smoke_world):
+    chip_smoke.phase_eager()
+
+
+def test_trainer_and_zero_phases(smoke_world):
+    """Data-parallel step with the bucketed allreduce (placement over
+    four devices, an all-reduce over four participants, bitwise-equal
+    replicas), then ZeRO 2 and 3 against it."""
+    ref = chip_smoke.phase_resnet(TinyNet(), 2, 8, steps=3,
+                                  bucket_cap_bytes=256, num_classes=10)
+    assert len(ref["losses"]) == 3
+    chip_smoke.phase_zero(2, ref)
+    chip_smoke.phase_zero(3, ref)
+
+
+def test_collective_shapes_read_from_hlo_text():
+    text = (
+        "%ar = f32[8] all-reduce(f32[8] %x), channel_id=1, "
+        "replica_groups=[1,4]<=[4], use_global_device_ids=true\n"
+        "%ar2 = f32[8] all-reduce-start(f32[8] %y), "
+        "replica_groups={{0,1},{2,3}}, to_apply=%add\n"
+        "%done = f32[8] all-reduce-done(f32[8] %ar2)\n"
+        "%cp = bf16[8] collective-permute-start(bf16[8] %z), channel_id=3, "
+        "source_target_pairs={{0,1},{1,2},{2,3},{3,0}}\n"
+        "%cp2 = f32[2] collective-permute(f32[2] %w), "
+        "source_target_pairs={{0,0}}\n")
+    assert chip_smoke._allreduce_group_sizes(text) == [4, 2]
+    assert chip_smoke._permute_ring_sizes(text) == [4, 1]
+
+
+TOY_DECODER = chip_smoke.TransformerConfig(
+    vocab=64, d_model=32, n_heads=4, d_head=8, d_ff=64, n_layers=2,
+    max_seq=32, dtype=jnp.bfloat16)
+
+
+def test_decoder_phase_one_device():
+    chip_smoke.phase_decoder("toy-decoder", TOY_DECODER, 4, 3,
+                             jax.devices()[:1])
+
+
+@pytest.mark.full
+def test_decoder_parallel_phase(monkeypatch):
+    """dp 2 x tp 2 and sp 4 (ring, block kernels interpreted) against one
+    device, on four virtual devices."""
+    monkeypatch.setenv("HVD_PALLAS_INTERPRET", "1")
+    chip_smoke.phase_decoder_parallel(TOY_DECODER, 4, 3, jax.devices()[:4])
+
+
+def test_kernels_phase_interpreted(monkeypatch):
+    monkeypatch.setenv("HVD_PALLAS_INTERPRET", "1")
+    chip_smoke.phase_kernels(
+        [dict(shape=(1, 64, 2, 8), dtype=jnp.float32, segments=True,
+              window=16)])
+
+
+def test_kernels_phase_catches_a_wrong_kernel(monkeypatch):
+    """The comparison has teeth: a kernel that ignores the causal mask
+    fails it."""
+    monkeypatch.setattr(
+        chip_smoke, "flash_attention",
+        lambda q, k, v, causal, **kw: pa.flash_attention(
+            q, k, v, causal=False, use_pallas=False))
+    with pytest.raises(AssertionError):
+        chip_smoke.phase_kernels(
+            [dict(shape=(1, 64, 2, 8), dtype=jnp.float32)])
+
+
+# ---- no CPU stand-in on the device path ------------------------------------
+
+def test_chip_smoke_without_a_chip_fails(no_chip_runs):
+    out, err = no_chip_runs["chip_smoke.py"].communicate(timeout=120)
+    assert no_chip_runs["chip_smoke.py"].returncode != 0, out + err
+    last = json.loads(out.strip().splitlines()[-1])
+    assert last["ok"] is False
+    assert last["device"]["platform"] == "cpu"
+    assert "resnet" not in out  # no phase ran on the CPU
+
+
+def test_bench_without_a_chip_prints_no_rate(no_chip_runs):
+    out, err = no_chip_runs["bench.py"].communicate(timeout=120)
+    assert no_chip_runs["bench.py"].returncode != 0, out + err
+    assert out.strip() == ""  # no JSON line, no throughput
+    assert "no accelerator" in err
+
+
+def test_peak_flops_unknown_device_raises():
+    with pytest.raises(ValueError, match="no peak"):
+        bench._peak_flops("unknown")
+    # What the installed runtime reports for a v5e chip.
+    assert bench._peak_flops("TPU v5 lite") == 197e12
+
+
+# ---- the compile cache helper ----------------------------------------------
+
+@pytest.fixture
+def cache_config():
+    """Leave jax's cache configuration as this test found it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+    compilation_cache.reset_cache()
+
+
+def test_cache_helper_sets_nothing_when_the_variable_is_set(
+        monkeypatch, cache_config, tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_cache_helper_sets_the_fixed_path_otherwise(
+        monkeypatch, cache_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    fixed = os.path.join(REPO, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == fixed
+    assert jax.config.jax_compilation_cache_dir == fixed
+    # Fixed means fixed: a second call names the same directory, and
+    # git ignores it.
+    assert compile_cache.enable_compile_cache() == fixed
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+# ---- compiles for the described chip ---------------------------------------
+
+@pytest.fixture(scope="module")
+def described_chip():
+    """One device of a described (not attached) v5e 2x2, with the
+    persistent cache off around the compiles: such an entry could not be
+    read back without a chip."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _attention(variant):
+    """(function of q, k, v; Mosaic kernels its program must hold)."""
+    def attend(q, k, v):
+        seg = (jnp.zeros(q.shape[:2], jnp.int32)
+               if variant == "segments" else None)
+        return pa.flash_attention(
+            q, k, v, causal=True, q_segment_ids=seg, k_segment_ids=seg,
+            window=512 if variant == "window" else None)
+
+    if variant == "forward":
+        return attend, 1
+    return jax.grad(lambda q, k, v: attend(q, k, v).astype(
+        jnp.float32).sum(), argnums=(0, 1, 2)), 3
+
+
+@pytest.mark.parametrize("shape", [(2, 1024, 12, 64), (1, 2048, 8, 128)],
+                         ids=["D64-T1024", "D128-T2048"])
+@pytest.mark.parametrize("variant",
+                         ["forward", "backward", "segments", "window"])
+def test_attention_kernels_compile_for_v5e(described_chip, monkeypatch,
+                                           variant, shape):
+    """Forward, and the dq / dkv backward kernels plain, with segment ids
+    and with a window, at the smoke's widths."""
+    # default_backend() is the CPU here; steer the dispatch to Mosaic.
+    monkeypatch.setattr(pa, "_resolve_dispatch", lambda up: (True, False))
+    fn, n_kernels = _attention(variant)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=described_chip)
+    text = jax.jit(fn).lower(x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") >= n_kernels
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_kernels_compile_for_v5e_at_highest_precision(
+        described_chip, monkeypatch, dtype):
+    """Under ``jax.default_matmul_precision("highest")`` float32 operands
+    take the multi-pass float32 MXU product (``_mxu_dot``) — its VMEM fit
+    is the chip compiler's to judge — and bf16 operands stay as they
+    are."""
+    monkeypatch.setattr(pa, "_resolve_dispatch", lambda up: (True, False))
+    fn, n_kernels = _attention("backward")
+    x = jax.ShapeDtypeStruct((2, 1024, 12, 64), dtype,
+                             sharding=described_chip)
+    with jax.default_matmul_precision("highest"):
+        jaxpr = str(jax.make_jaxpr(fn)(x, x, x))
+        text = jax.jit(fn).lower(x, x, x).compile().as_text()
+    assert ("Precision.HIGHEST" in jaxpr) == (dtype == jnp.float32)
+    # ... and only there: at jax's default setting nothing asks for it.
+    assert "Precision.HIGHEST" not in str(jax.make_jaxpr(fn)(x, x, x))
+    assert text.count("tpu_custom_call") >= n_kernels
+
+
+@pytest.mark.parametrize("which", ["state", "grads"])
+def test_ring_block_kernels_compile_for_v5e(described_chip, monkeypatch,
+                                            which):
+    """Ring attention's per-block kernels at the decoder's sp=4 shard."""
+    monkeypatch.setattr(pa, "_resolve_dispatch", lambda up: (True, False))
+    B, T, H, D = 8, 256, 12, 64
+    x = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16,
+                             sharding=described_chip)
+    if which == "state":
+        def fn(q, k, v):
+            return pa.flash_attention_block(q, k, v, q_off=0, k_off=0,
+                                            causal=True)
+        args = (x, x, x)
+    else:
+        def fn(q, k, v, do, lse, delta):
+            return pa.flash_attention_block_grads(
+                q, k, v, do, lse, delta, q_off=0, k_off=0, causal=True)
+        stat = jax.ShapeDtypeStruct((B, H, T), jnp.float32,
+                                    sharding=described_chip)
+        args = (x, x, x, x, stat, stat)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text

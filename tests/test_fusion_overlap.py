@@ -8,12 +8,17 @@ operand cone excludes bucket j's), which is exactly the structure XLA's
 latency-hiding scheduler needs to overlap communication with the rest of
 the backward pass. Proven two ways:
 
-- compiled HLO (``jax.jit(...).lower(...).compile().as_text()``): the
-  all-reduce op count goes from 2 (fused grads + loss pmean) to
-  buckets + 1, surviving XLA's optimization pipeline;
 - jaxpr dataflow: pairwise cone analysis shows the gradient psums are
   mutually independent (neither is in the other's transitive operand
-  cone), i.e. their operands do not all depend on the final gradient.
+  cone), i.e. their operands do not all depend on the final gradient;
+- compiled HLO (``jax.jit(...).lower(...).compile().as_text()``): more
+  than one gradient all-reduce *instruction* survives XLA's optimization
+  pipeline. This half does NOT hold on jax 0.9: the all-reduce combiner
+  packs every bucket and the loss pmean back into one tuple all-reduce,
+  on the CPU backend here and on a v5e chip alike (PERF.md section 5),
+  so the bucket plan gives the scheduler nothing to overlap. The test
+  stays, as a strict xfail, until ROADMAP S5 decides what to do about
+  it with a trace.
 
 Plus the regression guarantee: with the cap unset the program keeps the
 v1 monolithic shape, and bucketed numerics match monolithic BITWISE
@@ -28,10 +33,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 import optax
-from jax.core import Var
+from jax.extend.core import Var
 
 import flax.linen as nn
 
+from hlo_text import (
+    collective_instructions, collective_results, find_psums)
 from horovod_tpu.training import (
     init_train_state, make_train_step, replicate_state, shard_batch)
 from horovod_tpu.zero import init_zero_train_state, make_zero_train_step
@@ -74,20 +81,6 @@ def _problem(hvd, bucket_cap, donate=True):
 # ---- jaxpr dataflow analysis helpers ---------------------------------------
 
 
-def _find_psums(jaxpr, acc):
-    """Collect (body, eqn_index) for every psum eqn, recursing through
-    pjit/shard_map/cond bodies."""
-    for i, eqn in enumerate(jaxpr.eqns):
-        if eqn.primitive.name == "psum":
-            acc.append((jaxpr, i))
-        for v in eqn.params.values():
-            for w in (v if isinstance(v, (list, tuple)) else (v,)):
-                sub = getattr(w, "jaxpr", w)
-                if hasattr(sub, "eqns"):
-                    _find_psums(sub, acc)
-    return acc
-
-
 def _cone(body, idx):
     """Transitive operand cone of eqn ``idx``: the set of eqn indices in
     ``body`` whose outputs it (transitively) consumes."""
@@ -111,7 +104,7 @@ def _cone(body, idx):
 def _grad_psums(step, state, imgs, lbls):
     """(body, [eqn indices]) of the non-scalar (gradient) psums."""
     jaxpr = jax.make_jaxpr(step)(state, imgs, lbls)
-    acc = _find_psums(jaxpr.jaxpr, [])
+    acc = find_psums(jaxpr.jaxpr)
     assert acc, "no psum eqns found in the train step"
     body = acc[0][0]
     assert all(b is body for b, _ in acc), \
@@ -124,16 +117,25 @@ def _grad_psums(step, state, imgs, lbls):
 # ---- the structural overlap proof ------------------------------------------
 
 
-def test_bucketed_step_has_independent_allreduces(hvd):
+@pytest.mark.xfail(strict=True, reason=(
+    "jax 0.9 regression, ROADMAP S5: XLA's all-reduce combiner packs the "
+    "gradient buckets and the loss pmean into ONE tuple all-reduce (CPU "
+    "backend and v5e alike), so bucket_cap_bytes leaves no separate "
+    "collectives to overlap"))
+def test_bucketed_allreduces_survive_compilation(hvd):
+    """Compiled HLO: >= 2 gradient all-reduce instructions survive XLA's
+    optimization pipeline (the count includes the scalar loss pmean,
+    hence -1)."""
     step, state, imgs, lbls = _problem(hvd, BUCKET_CAP)
-
-    # Compiled HLO: >= 2 gradient all-reduces survive XLA's optimization
-    # pipeline (the count here includes the scalar loss pmean, hence -1).
     hlo = step.lower(state, imgs, lbls).compile().as_text()
-    n_allreduce = hlo.count(" all-reduce(") + hlo.count(" all-reduce-start(")
+    n_allreduce = len(collective_instructions(hlo))
     assert n_allreduce - 1 >= 2, \
         f"expected >=2 gradient all-reduce ops in compiled HLO, " \
         f"found {n_allreduce} total"
+
+
+def test_bucketed_step_has_independent_allreduces(hvd):
+    step, state, imgs, lbls = _problem(hvd, BUCKET_CAP)
 
     # Dataflow: >= 2 gradient psums, and at least one pair is mutually
     # independent — neither lives in the other's operand cone, so their
@@ -168,8 +170,8 @@ def test_unset_cap_keeps_monolithic_program(hvd):
         f"monolithic path must emit exactly 1 gradient psum, " \
         f"got {len(grad_idxs)}"
     hlo = step.lower(state, imgs, lbls).compile().as_text()
-    n_allreduce = hlo.count(" all-reduce(") + hlo.count(" all-reduce-start(")
-    assert n_allreduce == 2, hlo.count("all-reduce")  # fused grads + loss
+    reduced = collective_results(hlo)
+    assert len(reduced) == 2, reduced  # fused grads + loss
 
 
 def test_bucketed_matches_monolithic_bitwise(hvd):

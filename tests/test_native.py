@@ -29,6 +29,55 @@ def test_library_loads():
     assert hn.load_library() is not None
 
 
+_SLOW_MAKEFILE = """\
+../lib/libhvdtpu.so: src.txt
+\t@mkdir -p ../lib
+\tprintf half > $@; sleep 1; printf whole > $@
+"""
+
+
+def test_concurrent_builds_on_an_empty_lib_wait_for_the_whole_library(
+        tmp_path):
+    """Two ranks start on a tree with no lib/: the one that loses the race
+    must not take the winner's half-linked library for an up-to-date one
+    (``make -q`` would say so). This recipe writes in place, as a linker
+    does, so only the lock around check AND build makes both see the
+    whole file."""
+    import subprocess
+    import sys
+
+    (tmp_path / "csrc").mkdir()
+    (tmp_path / "csrc" / "Makefile").write_text(_SLOW_MAKEFILE)
+    (tmp_path / "csrc" / "src.txt").write_text("source")
+    script = textwrap.dedent(f"""
+        import sys, time
+        from horovod_tpu.common import native as hn
+        hn._CSRC_DIR = {str(tmp_path / "csrc")!r}
+        hn._LIB_DIR = {str(tmp_path / "lib")!r}
+        time.sleep(float(sys.argv[1]))
+        hn._build_library()
+        print(open(hn._lib_path()).read())
+    """)
+    env = dict(os.environ, PYTHONPATH=REPO)
+    env.pop("HOROVOD_NATIVE_SANITIZE", None)
+    procs = [subprocess.Popen([sys.executable, "-c", script, delay], env=env,
+                              stdout=subprocess.PIPE, text=True)
+             for delay in ("0", "0.4")]
+    assert [p.communicate(timeout=60)[0].strip() for p in procs] == \
+        ["whole", "whole"]
+    assert [p.returncode for p in procs] == [0, 0]
+
+
+def test_no_make_loads_the_shipped_library_or_says_why(tmp_path, monkeypatch):
+    """A wheel ships lib/*.so; a machine without ``make`` loads it as it
+    is. With neither, the error names what is missing."""
+    monkeypatch.setenv("PATH", str(tmp_path))  # no make here
+    hn._build_library()  # the tree's library is there: nothing to do
+    monkeypatch.setattr(hn, "_LIB_DIR", str(tmp_path / "lib"))
+    with pytest.raises(RuntimeError, match="cannot be built"):
+        hn._build_library()
+
+
 def test_engine_uses_native_core(hvd):
     from horovod_tpu.common.state import global_state
 

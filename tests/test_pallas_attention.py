@@ -11,6 +11,13 @@ import jax.numpy as jnp
 from horovod_tpu.ops.pallas_attention import flash_attention
 
 
+@pytest.fixture(autouse=True)
+def _interpret(monkeypatch):
+    """Off the chip the kernels run only where interpretation was asked
+    for (``_resolve_dispatch``); every test in this file asks."""
+    monkeypatch.setenv("HVD_PALLAS_INTERPRET", "1")
+
+
 def _dense(q, k, v, causal, q_off=0, k_off=0, window=None, seg=None):
     """The ONE dense oracle: causal/offset/window/segment masks compose
     here exactly as the kernels compose them."""
@@ -262,12 +269,9 @@ def test_block_state_merge_equals_full():
     np.testing.assert_allclose(out, np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
-def test_ring_attention_uses_block_kernel(monkeypatch):
+def test_ring_attention_uses_block_kernel():
     # sp>1 ring attention on a 4-device sp mesh must agree with dense
     # attention with the pallas block path enabled via interpret mode.
-    import os
-
-    monkeypatch.setenv("HVD_PALLAS_INTERPRET", "1")
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
 
@@ -291,12 +295,11 @@ def test_ring_attention_uses_block_kernel(monkeypatch):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_ring_attention_segments_block_kernel(monkeypatch):
+def test_ring_attention_segments_block_kernel():
     # Packed-sequence ring on the Pallas block path (interpret): the
     # segment ids rotate with the K/V blocks and stream into the
     # segment-tiled kernels; forward AND grads vs the dense masked
     # oracle.
-    monkeypatch.setenv("HVD_PALLAS_INTERPRET", "1")
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
 
@@ -336,11 +339,10 @@ def test_ring_attention_segments_block_kernel(monkeypatch):
                                    rtol=2e-4, atol=2e-5)
 
 
-def test_ring_attention_gradients(monkeypatch):
+def test_ring_attention_gradients():
     # Training through sp>1 ring attention: the backward ring pass (flash
     # backward kernels + rotating dK/dV accumulators) must reproduce the
     # dense-attention gradients.
-    monkeypatch.setenv("HVD_PALLAS_INTERPRET", "1")
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
 

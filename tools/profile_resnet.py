@@ -1,12 +1,13 @@
 #!/usr/bin/env python
 """Capture a jax.profiler trace of a model-zoo train step and summarize
-the device-plane op costs (the trace evidence VERDICT r3 asked for: name
-the single-chip MFU ceiling operation-by-operation). --model picks any
-bench.py registry entry (resnet50/resnet101/vgg16/inception3).
+the device-plane op costs (name the single-chip MFU ceiling
+operation-by-operation). --model picks any bench.py registry entry
+(resnet50/resnet101/vgg16/inception3). Run it through the chip tool; the
+default --out is the directory the tool copies back.
 
 Usage: python tools/profile_resnet.py [--model resnet50]
                                       [--batch-size 32] [--steps 5]
-                                      [--out docs/probes]
+                                      [--out chiprun_out/profile]
 
 Writes <out>/<model>_trace_<ts>/ (the raw TB trace dir) and
 <out>/<model>_trace_<ts>_summary.md (top ops by device self-time).
@@ -55,17 +56,17 @@ def capture(args):
                             mesh)
 
     global_batch = args.batch_size * n
-    images = jnp.asarray(np.random.RandomState(0).rand(
-        global_batch, args.image_size, args.image_size, 3).astype(np.float32))
-    labels = jnp.asarray(np.random.RandomState(1).randint(
-        0, 1000, size=(global_batch,)).astype(np.int32))
+    images = np.random.RandomState(0).rand(
+        global_batch, args.image_size, args.image_size, 3).astype(np.float32)
+    labels = np.random.RandomState(1).randint(
+        0, 1000, size=(global_batch,)).astype(np.int32)
     images, labels = shard_batch((images, labels), mesh)
 
     step = make_train_step(model, optimizer, mesh)
 
     for _ in range(3):  # compile + warmup
         state, loss = step(state, images, labels)
-    float(np.asarray(loss))
+    loss.block_until_ready()
 
     ts = time.strftime("%Y%m%dT%H%M%S")
     trace_dir = os.path.join(args.out, f"{args.model}_trace_{ts}")
@@ -73,15 +74,12 @@ def capture(args):
     t0 = time.perf_counter()
     for _ in range(args.steps):
         state, loss = step(state, images, labels)
-    float(np.asarray(loss))
+    loss.block_until_ready()
     dt = time.perf_counter() - t0
     jax.profiler.stop_trace()
 
     img_per_sec = global_batch * args.steps / dt
-    platform = jax.devices()[0].platform
-    kind = getattr(jax.devices()[0], "device_kind", "")
-    return trace_dir, dict(platform=platform, device_kind=kind,
-                           model=args.model,
+    return trace_dir, dict(**_bench.device_identity(), model=args.model,
                            batch_size=args.batch_size, steps=args.steps,
                            img_per_sec=round(img_per_sec, 1),
                            step_ms=round(1e3 * dt / args.steps, 2))
@@ -93,8 +91,8 @@ def summarize(trace_dir, meta, args):
         from tensorflow.tsl.profiler.protobuf import xplane_pb2
     except ImportError as e:
         # TF is an optional front-end (docs/install.md); losing the
-        # summary must not crash the tool AFTER the scarce on-chip
-        # capture succeeded — the raw trace dir is still the artifact.
+        # summary must not crash the tool AFTER the on-chip capture
+        # succeeded — the raw trace dir is still the artifact.
         print(f"summarize skipped (tensorflow unavailable: {e}); "
               f"raw trace kept at {trace_dir}", file=sys.stderr)
         return None
@@ -113,8 +111,8 @@ def summarize(trace_dir, meta, args):
             xspace.ParseFromString(f.read())
         for plane in xspace.planes:
             pn = plane.name.lower()
-            # Device planes only: TPU ("/device:TPU:0" / "TPU:0") or, for
-            # CPU smoke runs, the host XLA plane ("/host:CPU").
+            # Device planes only ("/device:TPU:0" / "TPU:0") unless the
+            # host planes were asked for.
             is_dev = "tpu" in pn or "gpu" in pn
             if not is_dev and not args.include_host:
                 continue
@@ -184,11 +182,16 @@ def main(argv=None):
     p.add_argument("--image-size", type=int, default=None,
                    help="defaults to the model's canonical size")
     p.add_argument("--top", type=int, default=25)
-    p.add_argument("--out", default="docs/probes")
+    p.add_argument("--out", default="chiprun_out/profile")
     p.add_argument("--include-host", action="store_true",
-                   help="also aggregate host-plane events (CPU smoke runs)")
+                   help="also aggregate host-plane events")
     args = p.parse_args(argv)
     os.makedirs(args.out, exist_ok=True)
+    from tools.compile_cache import enable_compile_cache
+
+    print(f"profile: compile cache at {enable_compile_cache()}",
+          file=sys.stderr)
+    _bench.require_accelerator()
 
     trace_dir, meta = capture(args)
     print(json.dumps(meta))

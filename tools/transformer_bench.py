@@ -5,7 +5,9 @@ class decoder on the sharded transformer (models/transformer.py).
 Widens the headline evidence beyond the ResNet protocol (bench.py): the
 same mesh machinery drives a causal LM step — flash attention, Megatron
 tp sharding, sp context parallelism all exercised by flags. One JSON
-line per run, same discipline as bench.py.
+line per run, same discipline as bench.py: it needs the chip (no
+accelerator is a non-zero exit, never a CPU number) and every result
+names the device jax reported in the process that ran the step.
 
     python tools/transformer_bench.py                  # GPT-2-small-ish
     python tools/transformer_bench.py --sp 4 --seq-len 8192   # long-ctx
@@ -27,12 +29,14 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-# One peak-FLOPs table for the whole repo: bench.py owns it (repo root
-# is already on sys.path above).
-from bench import _peak_flops  # noqa: E402
+# One peak-FLOPs table and one accelerator gate for the whole repo:
+# bench.py owns them (repo root is already on sys.path above).
+from bench import (  # noqa: E402
+    _peak_flops, device_identity, require_accelerator)
+from tools.compile_cache import enable_compile_cache  # noqa: E402
 
 
-def main(argv=None):
+def _build_parser():
     p = argparse.ArgumentParser()
     p.add_argument("--d-model", type=int, default=768)
     p.add_argument("--n-heads", type=int, default=12)
@@ -64,8 +68,13 @@ def main(argv=None):
                         "memory knob)")
     p.add_argument("--num-warmup", type=int, default=3)
     p.add_argument("--num-iters", type=int, default=20)
-    args = p.parse_args(argv)
+    return p
 
+
+def run(args):
+    """Run the configured decoder in this process; returns the result
+    dict (``main`` prints it). Tests call this at a toy size on the CPU
+    backend on purpose."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -83,10 +92,8 @@ def main(argv=None):
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     if args.batch_size is None:
         args.batch_size = 8 * sizes["dp"]
-    d = jax.devices()[0]
-    platform = d.platform
-    kind = getattr(d, "device_kind", "")
-    print(f"bench: mesh {sizes} on {platform} ({kind}); "
+    ident = device_identity()
+    print(f"bench: mesh {sizes} on {ident}; "
           f"B={args.batch_size} T={args.seq_len}", file=sys.stderr)
 
     cfg = TransformerConfig(
@@ -118,19 +125,18 @@ def main(argv=None):
     rng = np.random.RandomState(0)
     data_sharding = NamedSharding(mesh, P("dp", "sp"))
     tokens = jax.device_put(
-        jnp.asarray(rng.randint(0, cfg.vocab,
-                                (args.batch_size, args.seq_len)), jnp.int32),
-        data_sharding)
+        rng.randint(0, cfg.vocab, (args.batch_size, args.seq_len)
+                    ).astype(np.int32), data_sharding)
     labels = jnp.roll(tokens, -1, axis=1)
 
     for _ in range(max(1, args.num_warmup)):
         sharded, opt_state, loss = step(sharded, opt_state, tokens, labels)
-    float(np.asarray(loss))  # scalar fetch: the real completion fence
+    loss.block_until_ready()
 
     t0 = time.perf_counter()
     for _ in range(args.num_iters):
         sharded, opt_state, loss = step(sharded, opt_state, tokens, labels)
-    float(np.asarray(loss))
+    loss.block_until_ready()
     dt = time.perf_counter() - t0
 
     n_chips = mesh.devices.size
@@ -150,12 +156,11 @@ def main(argv=None):
                        12 * args.n_layers * attn_span * d_attn)
     model_flops_per_s = tok_per_s * flops_per_token
 
-    result = {
+    return {
         "metric": "transformer_tokens_per_sec_per_chip",
         "value": round(tok_per_s / n_chips, 1),
         "unit": "tokens/sec/chip",
-        "platform": platform,
-        "device_kind": kind,
+        **ident,
         "n_params": n_params,
         "n_matmul_params": n_matmul_params,
         "d_model": args.d_model,
@@ -168,11 +173,17 @@ def main(argv=None):
         "zero": bool(args.zero),
         "loss": round(float(np.asarray(loss)), 4),
         "step_ms": round(1e3 * dt / args.num_iters, 2),
+        "mfu": round(model_flops_per_s
+                     / (n_chips * _peak_flops(ident["device_kind"])), 4),
     }
-    peak = _peak_flops(kind)
-    if peak:
-        result["mfu"] = round(model_flops_per_s / (n_chips * peak), 4)
-    print(json.dumps(result))
+
+
+def main(argv=None):
+    args = _build_parser().parse_args(argv)
+    print(f"bench: compile cache at {enable_compile_cache()}",
+          file=sys.stderr)
+    require_accelerator()
+    print(json.dumps(run(args)))
     return 0
 
 

@@ -236,7 +236,13 @@ def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
     """
 
     def layer(x, lp, seg, gathered_seg):
-        # --- attention (tp-sharded heads, sp ring) --------------------------
+        with jax.named_scope("attention"):
+            x = attention_block(x, lp, seg, gathered_seg)
+        with jax.named_scope("moe" if cfg.use_moe else "mlp"):
+            return feed_forward_block(x, lp)
+
+    def attention_block(x, lp, seg, gathered_seg):
+        # tp-sharded heads, sp ring
         h = _layernorm(x, lp["ln1"])
         if "wqkv" in lp:
             qkv = jnp.einsum("btd,dchk->btchk", h, lp["wqkv"])  # h=H/tp
@@ -261,8 +267,9 @@ def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
             window=cfg.attention_window)
         out = jnp.einsum("bthk,hkd->btd", attn, lp["wo"])
         out = lax.psum(out, "tp")  # combine head shards
-        x = x + out
-        # --- feed-forward ----------------------------------------------------
+        return x + out
+
+    def feed_forward_block(x, lp):
         h = _layernorm(x, lp["ln2"])
         if cfg.use_moe:
             B, T, d = h.shape
@@ -311,13 +318,14 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
     sequences — microbatched alongside the activations so each pipeline
     stage masks attention for the microbatch it is holding."""
     b, t = tokens.shape
-    sp_idx = lax.axis_index("sp")
-    x = params["embed"][tokens]  # [b, t, d]
-    if "pos" in params:  # learned positions; RoPE rotates in the layers
-        pos = lax.dynamic_slice_in_dim(params["pos"], sp_idx * t, t,
-                                       axis=0)
-        x = x + pos[None]
-    x = x.astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        sp_idx = lax.axis_index("sp")
+        x = params["embed"][tokens]  # [b, t, d]
+        if "pos" in params:  # learned positions; RoPE rotates in the layers
+            pos = lax.dynamic_slice_in_dim(params["pos"], sp_idx * t, t,
+                                           axis=0)
+            x = x + pos[None]
+        x = x.astype(cfg.dtype)
 
     # microbatch for the pipeline: [M, mb, t, d]
     M = n_microbatches
@@ -343,9 +351,10 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
         collect_fn=(lambda s: s[0]) if segment_ids is not None else None)
     y = y.reshape(b, t, -1)
 
-    y = _layernorm(y, params["final_ln"])
-    return jnp.einsum("btd,dv->btv", y.astype(jnp.float32),
-                      params["head"].astype(jnp.float32))
+    with jax.named_scope("head"):
+        y = _layernorm(y, params["final_ln"])
+        return jnp.einsum("btd,dv->btv", y.astype(jnp.float32),
+                          params["head"].astype(jnp.float32))
 
 
 def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
@@ -365,16 +374,21 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
     def spmd_loss(params, tokens, labels, segment_ids=None):
         logits = _spmd_forward(cfg, stage_fn, params, tokens,
                                n_microbatches, segment_ids=segment_ids)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-        loss = -jnp.mean(ll)
-        return lax.pmean(loss, ("dp", "sp"))
+        with jax.named_scope("loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            ll = jnp.take_along_axis(logp, labels[..., None],
+                                     axis=-1)[..., 0]
+            loss = -jnp.mean(ll)
+            return lax.pmean(loss, ("dp", "sp"))
 
     data = P("dp", "sp")
     in_specs = ((specs, data, data, data) if packed
                 else (specs, data, data))
-    return _compat_shard_map(spmd_loss, mesh=mesh, in_specs=in_specs,
-                         out_specs=P(), check_vma=False)
+    # Around the shard_map, so that every instruction of the pass carries
+    # jvp(forward), and transpose(jvp(forward)) in the backward pass.
+    return jax.named_scope("forward")(_compat_shard_map(
+        spmd_loss, mesh=mesh, in_specs=in_specs, out_specs=P(),
+        check_vma=False))
 
 
 def make_train_step(cfg: TransformerConfig, optimizer, mesh,
@@ -397,6 +411,7 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
 
     loss_fn = make_loss_fn(cfg, mesh, n_microbatches, packed=packed)
 
+    @jax.named_scope("optimizer")
     def apply(grads, params, opt_state):
         updates, opt_state = optimizer.update(grads, opt_state, params)
         if opt_shardings is not None:
@@ -404,20 +419,23 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
                 opt_state, opt_shardings)
         return optax.apply_updates(params, updates), opt_state
 
+    # The function's name is the module's on the device trace
+    # (docs/diagnostics.md, "Tracing").
     if packed:
-        def step(params, opt_state, tokens, labels, segment_ids):
+        def hvd_decoder_step(params, opt_state, tokens, labels,
+                             segment_ids):
             loss, grads = jax.value_and_grad(loss_fn)(
                 params, tokens, labels, segment_ids)
             params, opt_state = apply(grads, params, opt_state)
             return params, opt_state, loss
     else:
-        def step(params, opt_state, tokens, labels):
+        def hvd_decoder_step(params, opt_state, tokens, labels):
             loss, grads = jax.value_and_grad(loss_fn)(params, tokens,
                                                       labels)
             params, opt_state = apply(grads, params, opt_state)
             return params, opt_state, loss
 
-    return jax.jit(step, donate_argnums=(0, 1))
+    return jax.jit(hvd_decoder_step, donate_argnums=(0, 1))
 
 
 def dense_reference_loss(cfg: TransformerConfig, params, tokens, labels,

@@ -20,6 +20,12 @@ ring attention's rotating K/V blocks (global causal masking between
 sequence blocks) and the plain single-block case. On TPU the kernels
 compile through Mosaic; tests interpret them on CPU
 (``_resolve_dispatch``).
+
+Each ``pallas_call`` carries a ``name`` (``flash_fwd``, ``flash_dq``,
+``flash_dkv``), which jax also writes as a scope into the custom call's
+``op_name``; the XLA twins carry the scope ``flash_xla``. A device trace
+tells the kernels apart, and a fall-back from them, by these names
+(docs/diagnostics.md, "Tracing").
 """
 
 from __future__ import annotations
@@ -403,6 +409,7 @@ def _pallas_block_state(q, k, v, offs, causal: bool, interpret: bool,
         compiler_params=_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
 
 
@@ -428,6 +435,7 @@ def _check_window(window, causal):
         raise ValueError(f"window must be >= 1, got {window}")
 
 
+@jax.named_scope("flash_xla")
 def _xla_block_state(q, k, v, offs, causal, q_seg=None, k_seg=None,
                      window=None):
     """XLA twin of the block-mode kernel (backward recompute + fallback).
@@ -654,6 +662,7 @@ def _pallas_attention_fwd(q, k, v, q_off, k_off, causal: bool,
         compiler_params=_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
 
 
@@ -709,6 +718,7 @@ def _pallas_attention_fwd_train(q, k, v, offs, causal: bool,
         compiler_params=_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
 
 
@@ -755,6 +765,7 @@ def _pallas_bwd(q, k, v, do, lse, delta, offs, causal: bool,
         compiler_params=_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_dq",
     )(*dq_args)
 
     # dK/dV pass: K tiles are the parallel dimension, Q tiles sequential.
@@ -790,10 +801,12 @@ def _pallas_bwd(q, k, v, do, lse, delta, offs, causal: bool,
         compiler_params=_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_dkv",
     )(*kv_args)
     return dq, dk, dv
 
 
+@jax.named_scope("flash_xla")
 def _xla_block_grads(q, k, v, do, lse, delta, offs, causal: bool,
                      out_dtype=None, q_seg=None, k_seg=None, window=None):
     """XLA twin of the backward kernels (fallback for untileable shapes
@@ -835,6 +848,7 @@ def _pick_block(t: int, cap: int) -> Optional[int]:
     return None
 
 
+@jax.named_scope("flash_xla")
 def _xla_flash(q, k, v, q_off, k_off, causal, q_seg=None, k_seg=None,
                window=None):
     """XLA reference path (backward recompute + non-TPU fallback), fp32

@@ -148,12 +148,14 @@ def DistributedOptimizer(
 
         def update_fn(grads, state, params=None, **extra):
             check_residual(state)
-            if ef:
-                grads, new_res = reduce_grads_ef(grads, state.residual)
-            else:
-                grads, new_res = reduce_grads(grads), None
-            updates, inner = optimizer.update(grads, state.inner_state, params,
-                                              **extra)
+            with jax.named_scope("exchange"):
+                if ef:
+                    grads, new_res = reduce_grads_ef(grads, state.residual)
+                else:
+                    grads, new_res = reduce_grads(grads), None
+            with jax.named_scope("optimizer"):
+                updates, inner = optimizer.update(grads, state.inner_state,
+                                                  params, **extra)
             return updates, DistributedState(inner, None, None, new_res)
 
         return optax.GradientTransformation(init_fn, update_fn)
@@ -177,15 +179,17 @@ def DistributedOptimizer(
         def comm_branch(operand):
             accum, inner_state, residual = operand
             mean = jax.tree_util.tree_map(lambda a: a / k, accum)
-            if ef:
-                # Error feedback at communication time: the residual
-                # corrects what actually travels the wire (the k-step
-                # mean), untouched on skipped micro-steps.
-                reduced, new_res = reduce_grads_ef(mean, residual)
-            else:
-                reduced, new_res = reduce_grads(mean), residual
-            updates, inner = optimizer.update(reduced, inner_state, params,
-                                              **extra)
+            with jax.named_scope("exchange"):
+                if ef:
+                    # Error feedback at communication time: the residual
+                    # corrects what actually travels the wire (the k-step
+                    # mean), untouched on skipped micro-steps.
+                    reduced, new_res = reduce_grads_ef(mean, residual)
+                else:
+                    reduced, new_res = reduce_grads(mean), residual
+            with jax.named_scope("optimizer"):
+                updates, inner = optimizer.update(reduced, inner_state,
+                                                  params, **extra)
             zeros = jax.tree_util.tree_map(jnp.zeros_like, accum)
             return (updates, inner, zeros, jnp.zeros((), dtype=jnp.int32),
                     new_res)

@@ -44,9 +44,10 @@ class TrainState(NamedTuple):
 
 
 def cross_entropy_loss(logits, labels):
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    onehot = jax.nn.one_hot(labels, logits.shape[-1], dtype=jnp.float32)
-    return -jnp.mean(jnp.sum(onehot * logp, axis=-1))
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+        onehot = jax.nn.one_hot(labels, logits.shape[-1], dtype=jnp.float32)
+        return -jnp.mean(jnp.sum(onehot * logp, axis=-1))
 
 
 def make_train_step(model, optimizer: optax.GradientTransformation,
@@ -81,7 +82,11 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
                                     bucket_cap_bytes=bucket_cap_bytes,
                                     compression=compression)
 
-    def step_fn(state: TrainState, images, labels):
+    # The compiled step names its own work (docs/diagnostics.md,
+    # "Tracing"): the function's name is the module's on the device
+    # trace, the scopes are every instruction's ``op_name``.
+    def hvd_dp_step(state: TrainState, images, labels):
+        @jax.named_scope("forward")
         def loss_fn(p):
             variables = {"params": p}
             if state.batch_stats is not None:
@@ -96,11 +101,13 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
             loss_fn, has_aux=True)(state.params)
         updates, new_opt_state = dist_opt.update(grads, state.opt_state,
                                                  state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        if new_stats is not None:
-            new_stats = jax.tree_util.tree_map(
-                lambda x: lax.pmean(x, axis_name), new_stats)
-        loss = lax.pmean(loss, axis_name)
+        with jax.named_scope("optimizer"):
+            new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("exchange"):
+            if new_stats is not None:
+                new_stats = jax.tree_util.tree_map(
+                    lambda x: lax.pmean(x, axis_name), new_stats)
+            loss = lax.pmean(loss, axis_name)
         return TrainState(new_params, new_opt_state, new_stats,
                           state.step + 1), loss
 
@@ -109,7 +116,7 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
     batch_spec = P(axis_name)
 
     sharded_step = _shard_map(
-        step_fn, mesh,
+        hvd_dp_step, mesh,
         in_specs=(replicated, batch_spec, batch_spec),
         out_specs=(replicated, replicated),
         check_vma=False,

@@ -550,6 +550,7 @@ def make_zero_train_step(model, optimizer: optax.GradientTransformation,
         def grads_dp(state, images, labels):
             """Stages 1/2: differentiate w.r.t. the replicated params,
             then exchange gradient shards per fusion bucket."""
+            @jax.named_scope("forward")
             def loss_fn(p):
                 variables = {"params": p}
                 if state.batch_stats is not None:
@@ -634,6 +635,7 @@ def make_zero_train_step(model, optimizer: optax.GradientTransformation,
             due (reverse parameter order) instead of holding every
             gathered bucket live across backprop."""
 
+            @jax.named_scope("forward")
             def loss_fn(pshard, residual):
                 gathered = [None] * nb
                 visited = []
@@ -689,7 +691,7 @@ def make_zero_train_step(model, optimizer: optax.GradientTransformation,
             # stage-1/2 paths divide per bucket — same value).
             return loss, new_stats, gsum / d, new_residual
 
-        def step_fn(state: ZeroTrainState, images, labels):
+        def hvd_zero_step(state: ZeroTrainState, images, labels):
             if stage == 3:
                 loss, new_stats, gshard, new_residual = grads_zero3(
                     state, images, labels)
@@ -698,8 +700,10 @@ def make_zero_train_step(model, optimizer: optax.GradientTransformation,
                     state, images, labels)
 
             def apply_update(gshard, opt_shard, pshard):
-                updates, new_opt = optimizer.update(gshard, opt_shard, pshard)
-                new_pshard = optax.apply_updates(pshard, updates)
+                with jax.named_scope("optimizer"):
+                    updates, new_opt = optimizer.update(gshard, opt_shard,
+                                                        pshard)
+                    new_pshard = optax.apply_updates(pshard, updates)
                 if stage == 3:
                     # Parameters stay partitioned: no trailing gather —
                     # the NEXT step's forward gathers the fresh masters
@@ -744,7 +748,7 @@ def make_zero_train_step(model, optimizer: optax.GradientTransformation,
                                   new_stats, step, state.bucket_cap,
                                   new_residual, state.stage), loss
 
-        return step_fn
+        return hvd_zero_step
 
     cache = {}
 

@@ -357,10 +357,42 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
                           params["head"].astype(jnp.float32))
 
 
+@jax.custom_vjp
+def token_nll(logits, labels):
+    """Cross-entropy per token, ``-log_softmax(logits)[label]`` as float32
+    [b, t], from float32 logits [b, t, V] and int labels [b, t], without
+    the [b, t, V] log-probabilities: the forward pass reads the logits
+    (max, then ``log sum exp(logits - max)``, as ``jax.nn.log_softmax``
+    computes them) and picks b*t of them; the backward pass is one
+    elementwise ``softmax - onehot`` that XLA fuses into the head's two
+    matmuls. The log-sum-exp is kept in its two parts, max and log-sum, so
+    logits of any magnitude keep the precision ``log_softmax`` has."""
+    return _token_nll_fwd(logits, labels)[0]
+
+
+def _token_nll_fwd(logits, labels):
+    top = jnp.max(logits, axis=-1)
+    log_sum = jnp.log(jnp.sum(jnp.exp(logits - top[..., None]), axis=-1))
+    picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    return log_sum - (picked - top), (logits, top, log_sum, labels)
+
+
+def _token_nll_bwd(residuals, g):
+    logits, top, log_sum, labels = residuals
+    probs = jnp.exp(logits - top[..., None] - log_sum[..., None])
+    onehot = jax.nn.one_hot(labels, logits.shape[-1], dtype=logits.dtype)
+    return (probs - onehot) * g[..., None], None
+
+
+token_nll.defvjp(_token_nll_fwd, _token_nll_bwd)
+
+
 def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
                  packed: bool = False):
     """Build loss(params, tokens, labels) -> scalar, shard_mapped over the
     full mesh. tokens/labels: [B_global, T_global] sharded P('dp','sp').
+    The ``loss`` scope covers ``token_nll``'s forward pass and its
+    hand-written backward pass.
 
     ``packed=True`` builds loss(params, tokens, labels, segment_ids)
     instead: attention masks within segments (packed sequences). The
@@ -375,10 +407,7 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
         logits = _spmd_forward(cfg, stage_fn, params, tokens,
                                n_microbatches, segment_ids=segment_ids)
         with jax.named_scope("loss"):
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            ll = jnp.take_along_axis(logp, labels[..., None],
-                                     axis=-1)[..., 0]
-            loss = -jnp.mean(ll)
+            loss = jnp.mean(token_nll(logits, labels))
             return lax.pmean(loss, ("dp", "sp"))
 
     data = P("dp", "sp")

@@ -15,8 +15,8 @@ reference's DP-only surface (SURVEY §2.5): every mesh axis of
   by ``TransformerConfig.sp_strategy``.
 - **tp**: Megatron-style tensor parallelism — attention heads and MLP
   hidden dim sharded over ``tp``, partial outputs psum'd.
-- **ep**: MoE experts sharded over the dp axis with all_to_all dispatch
-  (``parallel.moe``), Switch-style.
+- **ep**: MoE experts sharded over the dp axis (``parallel.moe``):
+  dropless top-k routing, sort-by-expert dispatch, grouped matmuls.
 
 Pure-jax pytree params (no flax) so shard_map in_specs map 1:1 onto leaves.
 """
@@ -24,6 +24,7 @@ Pure-jax pytree params (no flax) so shard_map in_specs map 1:1 onto leaves.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -31,6 +32,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..common.compat import axis_size as _axis_size
 from ..common.compat import shard_map as _compat_shard_map
 from ..parallel.moe import moe_layer
 from ..parallel.pipeline import spmd_pipeline
@@ -49,8 +51,22 @@ class TransformerConfig:
     use_moe: bool = False
     n_experts: int = 4
     d_expert: int = 128
-    capacity_factor: float = 2.0
-    moe_top_k: int = 1  # 1 = Switch, 2 = GShard renormalized top-2
+    moe_top_k: int = 1  # experts a token; nothing is dropped
+    # Divide a token's top-k router probabilities by their sum (a
+    # published config's ``norm_topk_prob``); False takes them as they are.
+    norm_topk_prob: bool = False
+    # Weights of the router's two extra loss terms, each a mean over the
+    # MoE layers: load balance (``router_aux_loss_coef``) and the squared
+    # log-sum-exp of the router logits (z-loss).
+    router_aux_loss_coef: float = 0.0
+    router_z_loss_coef: float = 0.0
+    # "layernorm": scale-only LayerNorm, eps 1e-5. "rmsnorm":
+    # v * rsqrt(mean(v^2) + norm_eps) * g, in float32.
+    norm: str = "layernorm"
+    norm_eps: float = 1e-5
+    # RMSNorm on the projected queries and keys, over the whole projected
+    # vector (all heads), before the heads are split and rotated.
+    qk_norm: bool = False
     dtype: Any = jnp.float32
     # Sequence-parallel attention strategy over the sp axis: "ring"
     # (K/V rotation, no head constraint), "ulysses" (all-to-all head
@@ -88,6 +104,9 @@ class TransformerConfig:
                 raise ValueError(
                     f"n_heads ({self.n_heads}) must divide by n_kv_heads "
                     f"({self.n_kv_heads})")
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', got "
+                             f"{self.norm!r}")
         if self.rope and self.d_head % 2 != 0:
             raise ValueError(f"rope needs an even d_head, got "
                              f"{self.d_head}")
@@ -116,11 +135,15 @@ def _param_specs(cfg: TransformerConfig) -> Dict[str, P]:
     else:
         specs["wq"] = P("pp", None, None, "tp")
         specs["wkv"] = P("pp", None, None, None, "tp")
+    if cfg.qk_norm:
+        specs["gq"] = P("pp", None, "tp")
+        specs["gk"] = P("pp", None, "tp")
     if cfg.use_moe:
         specs.update({
-            "gate": P("pp"),
-            "we_in": P("pp", None, "dp"),
-            "we_out": P("pp", None, "dp"),
+            "router": P("pp"),
+            "wg": P("pp", None, "dp"),
+            "wu": P("pp", None, "dp"),
+            "wd": P("pp", None, "dp"),
         })
     else:
         specs.update({
@@ -159,13 +182,17 @@ def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
         params["wq"] = norm(ks[2], (n_stages, lps, d, H, Dh), d ** -0.5)
         params["wkv"] = norm(ks[8], (n_stages, lps, d, 2, Hkv, Dh),
                              d ** -0.5)
+    if cfg.qk_norm:
+        params["gq"] = jnp.ones((n_stages, lps, H, Dh), jnp.float32)
+        params["gk"] = jnp.ones((n_stages, lps, Hkv, Dh), jnp.float32)
     if cfg.use_moe:
         E, Fe = cfg.n_experts, cfg.d_expert
         params.update({
-            "gate": norm(ks[5], (n_stages, lps, d, E), d ** -0.5
-                         ).astype(jnp.float32),
-            "we_in": norm(ks[6], (n_stages, lps, E, d, Fe), d ** -0.5),
-            "we_out": norm(ks[7], (n_stages, lps, E, Fe, d), Fe ** -0.5),
+            "router": (jax.random.normal(ks[5], (n_stages, lps, d, E))
+                       * d ** -0.5),
+            "wg": norm(ks[6], (n_stages, lps, E, d, Fe), d ** -0.5),
+            "wu": norm(ks[9], (n_stages, lps, E, d, Fe), d ** -0.5),
+            "wd": norm(ks[7], (n_stages, lps, E, Fe, d), Fe ** -0.5),
         })
     else:
         params.update({
@@ -202,6 +229,7 @@ def shard_params(params: Dict, cfg: TransformerConfig, mesh) -> Dict:
     }
 
 
+@functools.partial(jax.checkpoint, static_argnums=(2,))
 def _rope(x, positions, theta):
     """Rotary position embeddings (rotate-half convention).
 
@@ -227,13 +255,54 @@ def _layernorm(x, scale):
     return ((xf - mu) * jax.lax.rsqrt(var + 1e-5) * scale).astype(x.dtype)
 
 
+# The new norms keep their operand in its own type for the backward pass
+# and form the float32 values anew (jax.checkpoint): at [b, 4096, 2048]
+# the float32 copies of a layer would otherwise be kept six times over.
+@functools.partial(jax.checkpoint, static_argnums=(2,))
+def _rmsnorm(x, scale, eps):
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(xf), -1, keepdims=True)
+    return (xf * jax.lax.rsqrt(ms + eps) * scale).astype(x.dtype)
+
+
+def _block_norm(cfg: TransformerConfig):
+    """norm(x, scale) of the residual stream, by ``cfg.norm``."""
+    if cfg.norm == "rmsnorm":
+        return lambda x, scale: _rmsnorm(x, scale, cfg.norm_eps)
+    return _layernorm
+
+
+@functools.partial(jax.checkpoint, static_argnums=(2,))
+def _qk_norm(x, scale, eps):
+    """RMSNorm of projected queries or keys x [b, t, h, k] over the whole
+    projected vector: every head of every tp member."""
+    xf = x.astype(jnp.float32)
+    width = x.shape[2] * x.shape[3] * _axis_size("tp")
+    ss = lax.psum(jnp.sum(jnp.square(xf), (2, 3), keepdims=True), "tp")
+    return (xf * jax.lax.rsqrt(ss / width + eps) * scale).astype(x.dtype)
+
+
+def _zero_router_stats(cfg: TransformerConfig, lead):
+    """What the MoE layers add up as the activations pass through them,
+    with leading shape ``lead``: the two loss terms as means over all
+    layers, tokens per expert by layer."""
+    return {"lb": jnp.zeros(lead, jnp.float32),
+            "z": jnp.zeros(lead, jnp.float32),
+            "load": jnp.zeros(lead + (cfg.n_layers, cfg.n_experts),
+                              jnp.int32)}
+
+
 def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
     """stage_fn(stage_params, x) applying this stage's layers.
 
-    x: [mb, t_local, d] (or ``(x, segment_ids)`` with ``packed`` — the
-    ids ride the pipeline ring with the activations and pass through
-    each stage unchanged); runs under the full (dp, pp, sp, tp) mesh.
+    x: [mb, t_local, d], or a tuple that starts with it: then the
+    segment ids with ``packed``, then the router statistics with
+    ``cfg.use_moe`` (``_zero_router_stats``). Both ride the pipeline ring
+    with the activations; the ids pass through each stage unchanged, the
+    statistics gain this stage's layers. Runs under the full (dp, pp, sp,
+    tp) mesh.
     """
+    norm = _block_norm(cfg)
 
     def layer(x, lp, seg, gathered_seg):
         with jax.named_scope("attention"):
@@ -243,7 +312,7 @@ def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
 
     def attention_block(x, lp, seg, gathered_seg):
         # tp-sharded heads, sp ring
-        h = _layernorm(x, lp["ln1"])
+        h = norm(x, lp["ln1"])
         if "wqkv" in lp:
             qkv = jnp.einsum("btd,dchk->btchk", h, lp["wqkv"])  # h=H/tp
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
@@ -251,6 +320,9 @@ def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
             q = jnp.einsum("btd,dhk->bthk", h, lp["wq"])
             kv = jnp.einsum("btd,dchk->btchk", h, lp["wkv"])  # h=Hkv/tp
             k, v = kv[:, :, 0], kv[:, :, 1]
+        if cfg.qk_norm:
+            q = _qk_norm(q, lp["gq"], cfg.norm_eps)
+            k = _qk_norm(k, lp["gk"], cfg.norm_eps)
         if cfg.rope:
             t_local = x.shape[1]
             pos = (lax.axis_index("sp") * t_local
@@ -270,26 +342,25 @@ def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
         return x + out
 
     def feed_forward_block(x, lp):
-        h = _layernorm(x, lp["ln2"])
+        h = norm(x, lp["ln2"])
         if cfg.use_moe:
-            B, T, d = h.shape
-            flat = h.reshape(B * T, d)
-            y = moe_layer(flat, {"gate": lp["gate"], "w_in": lp["we_in"],
-                                 "w_out": lp["we_out"]},
-                          axis_name="dp",
-                          capacity_factor=cfg.capacity_factor,
-                          top_k=cfg.moe_top_k)
-            y = y.reshape(B, T, d)
-        else:
-            y = jax.nn.gelu(jnp.einsum("btd,df->btf", h, lp["w1"]))
-            y = jnp.einsum("btf,fd->btd", y, lp["w2"])
-            y = lax.psum(y, "tp")  # combine hidden-dim shards
+            y, stats = moe_layer(
+                h, {k: lp[k] for k in ("router", "wg", "wu", "wd")},
+                axis_name="dp", top_k=cfg.moe_top_k,
+                norm_topk_prob=cfg.norm_topk_prob, seq_axis_name="sp")
+            return x + y, stats
+        y = jax.nn.gelu(jnp.einsum("btd,df->btf", h, lp["w1"]))
+        y = jnp.einsum("btf,fd->btd", y, lp["w2"])
+        y = lax.psum(y, "tp")  # combine hidden-dim shards
         return x + y
 
     layer_fn = jax.checkpoint(layer) if cfg.remat else layer
 
     def stage_fn(stage_params, x):
-        seg = gathered = None
+        seg = gathered = stats = None
+        if cfg.use_moe:
+            *x, stats = x
+            x = tuple(x) if packed else x[0]
         if packed:
             x, seg = x
             if cfg.sp_strategy in ("ulysses", "auto"):
@@ -301,10 +372,21 @@ def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
                 gathered = gather_segment_ids(seg, "sp")
 
         def body(x, lp):
-            return layer_fn(x, lp, seg, gathered), None
+            out = layer_fn(x, lp, seg, gathered)
+            return out if cfg.use_moe else (out, None)
 
-        x, _ = lax.scan(body, x, stage_params)
-        return (x, seg) if packed else x
+        x, layers = lax.scan(body, x, stage_params)
+        if cfg.use_moe:
+            lps = layers["load"].shape[0]
+            stats = {
+                "lb": stats["lb"] + jnp.sum(layers["lb"]) / cfg.n_layers,
+                "z": stats["z"] + jnp.sum(layers["z"]) / cfg.n_layers,
+                "load": lax.dynamic_update_slice_in_dim(
+                    stats["load"], layers["load"].astype(jnp.int32),
+                    lax.axis_index("pp") * lps, axis=0)}
+        out = (x,) + ((seg,) if packed else ()) + (
+            (stats,) if cfg.use_moe else ())
+        return out if len(out) > 1 else x
 
     return stage_fn
 
@@ -316,7 +398,12 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
     Runs under the (dp, pp, sp, tp) mesh; tokens: local [b, t];
     ``segment_ids`` (int [b, t], sequence-sharded like tokens): packed
     sequences — microbatched alongside the activations so each pipeline
-    stage masks attention for the microbatch it is holding."""
+    stage masks attention for the microbatch it is holding.
+
+    Returns ``(logits, router statistics)``; the statistics
+    (``_zero_router_stats``: the two loss terms as means over this
+    member's sequences, tokens per expert summed over them) are None
+    without ``cfg.use_moe``."""
     b, t = tokens.shape
     with jax.named_scope("embed"):
         sp_idx = lax.axis_index("sp")
@@ -333,6 +420,9 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
     if segment_ids is not None:
         seg_mb = jnp.asarray(segment_ids, jnp.int32).reshape(M, b // M, t)
         x = (x, seg_mb)
+    if cfg.use_moe:
+        x = (x if isinstance(x, tuple) else (x,)) + (
+            _zero_router_stats(cfg, (M,)),)
     # Per-stage params: strip the leading pp dim. The local slice MUST be
     # exactly one stage — if init_params was built with a different stage
     # count than the mesh's pp size, layers would silently be dropped.
@@ -345,16 +435,25 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
             "n_stages must equal the mesh pp size")
         stage_params[k] = v[0]
     # Packed mode: segment ids ride the ring carry for later stages but
-    # are side data, not outputs — collect only the activation leaf.
-    y = spmd_pipeline(
-        stage_fn, stage_params, x, axis_name="pp",
-        collect_fn=(lambda s: s[0]) if segment_ids is not None else None)
+    # are side data, not outputs — collect only the activation leaf (and
+    # the router statistics, which are outputs).
+    collect_fn = None
+    if segment_ids is not None:
+        collect_fn = (lambda s: (s[0], s[2])) if cfg.use_moe else (
+            lambda s: s[0])
+    y = spmd_pipeline(stage_fn, stage_params, x, axis_name="pp",
+                      collect_fn=collect_fn)
+    stats = None
+    if cfg.use_moe:
+        y, per_mb = y
+        stats = {"lb": jnp.mean(per_mb["lb"]), "z": jnp.mean(per_mb["z"]),
+                 "load": jnp.sum(per_mb["load"], axis=0)}
     y = y.reshape(b, t, -1)
 
     with jax.named_scope("head"):
-        y = _layernorm(y, params["final_ln"])
+        y = _block_norm(cfg)(y, params["final_ln"])
         return jnp.einsum("btd,dv->btv", y.astype(jnp.float32),
-                          params["head"].astype(jnp.float32))
+                          params["head"].astype(jnp.float32)), stats
 
 
 @jax.custom_vjp
@@ -394,6 +493,11 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
     The ``loss`` scope covers ``token_nll``'s forward pass and its
     hand-written backward pass.
 
+    With ``cfg.use_moe`` the loss is the mean cross-entropy plus
+    ``router_aux_loss_coef`` times the load-balance term and
+    ``router_z_loss_coef`` times the z-loss, each a mean over layers and
+    sequences (``parallel.moe.moe_layer``).
+
     ``packed=True`` builds loss(params, tokens, labels, segment_ids)
     instead: attention masks within segments (packed sequences). The
     loss itself stays plain mean cross-entropy — mask cross-segment
@@ -404,10 +508,14 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
     specs = _param_specs(cfg)
 
     def spmd_loss(params, tokens, labels, segment_ids=None):
-        logits = _spmd_forward(cfg, stage_fn, params, tokens,
-                               n_microbatches, segment_ids=segment_ids)
+        logits, stats = _spmd_forward(cfg, stage_fn, params, tokens,
+                                      n_microbatches,
+                                      segment_ids=segment_ids)
         with jax.named_scope("loss"):
             loss = jnp.mean(token_nll(logits, labels))
+            if cfg.use_moe:
+                loss = (loss + cfg.router_aux_loss_coef * stats["lb"]
+                        + cfg.router_z_loss_coef * stats["z"])
             return lax.pmean(loss, ("dp", "sp"))
 
     data = P("dp", "sp")
@@ -469,10 +577,14 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
 
 def dense_reference_loss(cfg: TransformerConfig, params, tokens, labels,
                          segment_ids=None):
-    """Unsharded single-device oracle: mathematically identical to the
-    sharded loss (pipeline == sequential layers; ring attention == dense
-    causal attention; MoE exact when capacity is ample). Used by tests to
-    validate sharded loss AND gradients."""
+    """Unsharded single-device oracle of the dense LayerNorm decoder:
+    mathematically identical to the sharded loss (pipeline == sequential
+    layers; ring attention == dense causal attention). Used by tests to
+    validate sharded loss AND gradients. The MoE, RMSNorm and QK-norm
+    variants are held to ``benchmark/reference_moe.py`` instead."""
+    if cfg.use_moe or cfg.norm != "layernorm" or cfg.qk_norm:
+        raise ValueError("dense_reference_loss covers the dense LayerNorm "
+                         "decoder only; see benchmark/reference_moe.py")
     from ..parallel.ring_attention import local_flash_attention
 
     def attend(q, k, v):
@@ -526,28 +638,9 @@ def dense_reference_loss(cfg: TransformerConfig, params, tokens, labels,
             attn = attend(q, k, v)
             x = x + jnp.einsum("bthk,hkd->btd", attn, params["wo"][s, li])
             h = _layernorm(x, params["ln2"][s, li])
-            if cfg.use_moe:
-                d = h.shape[-1]
-                flat = h.reshape(b * t, d).astype(jnp.float32)
-                logits = flat @ params["gate"][s, li]
-                probs = jax.nn.softmax(logits, -1)
-                gates, idxs = lax.top_k(probs, cfg.moe_top_k)
-                if cfg.moe_top_k > 1:
-                    gates = gates / jnp.sum(gates, -1, keepdims=True)
-                y = 0.0
-                for j in range(cfg.moe_top_k):
-                    idx = idxs[:, j]
-                    w_in = params["we_in"][s, li].astype(jnp.float32)[idx]
-                    w_out = params["we_out"][s, li].astype(jnp.float32)[idx]
-                    yj = jax.nn.gelu(jnp.einsum("td,tdf->tf", flat, w_in),
-                                     approximate=False)
-                    yj = jnp.einsum("tf,tfd->td", yj, w_out)
-                    y = y + yj * gates[:, j][:, None]
-                x = x + y.reshape(b, t, d).astype(x.dtype)
-            else:
-                y = jax.nn.gelu(jnp.einsum(
-                    "btd,df->btf", h, params["w1"][s, li]))
-                x = x + jnp.einsum("btf,fd->btd", y, params["w2"][s, li])
+            y = jax.nn.gelu(jnp.einsum(
+                "btd,df->btf", h, params["w1"][s, li]))
+            x = x + jnp.einsum("btf,fd->btd", y, params["w2"][s, li])
 
     x = _layernorm(x, params["final_ln"])
     logits = jnp.einsum("btd,dv->btv", x.astype(jnp.float32),
@@ -563,9 +656,29 @@ def make_forward_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2):
     specs = _param_specs(cfg)
 
     def spmd_fwd(params, tokens):
-        return _spmd_forward(cfg, stage_fn, params, tokens, n_microbatches)
+        return _spmd_forward(cfg, stage_fn, params, tokens,
+                             n_microbatches)[0]
 
     return jax.jit(_compat_shard_map(
         spmd_fwd, mesh=mesh,
         in_specs=(specs, P("dp", "sp")),
         out_specs=P("dp", "sp"), check_vma=False))
+
+
+def make_router_load_fn(cfg: TransformerConfig, mesh,
+                        n_microbatches: int = 2):
+    """Jitted load(params, tokens) -> int32 [n_layers, n_experts]: how
+    many of the global batch's tokens chose each expert in each MoE
+    layer. Every row sums to ``moe_top_k`` times the tokens: nothing is
+    dropped. A program of its own, not an output of the training step."""
+    stage_fn = _make_stage_fn(cfg)
+    specs = _param_specs(cfg)
+
+    def spmd_load(params, tokens):
+        stats = _spmd_forward(cfg, stage_fn, params, tokens,
+                              n_microbatches)[1]
+        return lax.psum(stats["load"], ("dp", "sp"))
+
+    return jax.jit(_compat_shard_map(
+        spmd_load, mesh=mesh, in_specs=(specs, P("dp", "sp")),
+        out_specs=P(), check_vma=False))

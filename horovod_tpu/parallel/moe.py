@@ -1,126 +1,201 @@
-"""Mixture-of-Experts with expert parallelism (top-1 Switch or top-2
-GShard routing).
+"""Dropless mixture-of-experts layer: top-k routing, gated SiLU experts.
 
-Expert parallelism rides the ``dp`` mesh axis (the standard GShard/Switch
-placement): each dp group member owns ``E / ep`` experts; tokens are
-delivered to their expert's owner with a single ``lax.all_to_all`` over the
-axis and returned the same way. Routing uses static capacity
-(``capacity_factor``) so every shape is compile-time constant — the XLA
-requirement that rules out the reference-style dynamic dispatch.
+Every token reaches all ``top_k`` of its experts; nothing has a capacity
+and nothing is dropped. The ``top_k * N`` assignments are sorted by
+expert, the tokens' rows gathered in that order, and the experts run as
+three grouped matmuls over the ragged groups (bf16 operands where the
+parameters are bf16, float32 accumulation on the MXU) whose sizes are
+data, so every shape is static: on the chip jax's Pallas grouped matmul
+(``megablox``) under the scope ``moe_gmm``, elsewhere XLA's
+``lax.ragged_dot``, by the attention kernels' own rule
+(``ops.pallas_attention._resolve_dispatch``). The rows go back to their
+tokens by the inverse permutation, already under the router's weights,
+and are summed. Dispatch and combine are gathers in both directions (the
+transpose of a permutation gather is the gather by its inverse), never a
+one-hot and never a scatter.
 
-``top_k=2`` follows the GShard recipe: gates renormalized over the two
-picks, first choices take capacity priority over every second choice,
-and the optional auxiliary load-balance loss (``return_aux=True``) is
-the Switch formulation E * sum_e(f_e * P_e) — 1.0 at perfect balance.
+Expert parallelism rides ``axis_name`` (the ``dp`` mesh axis): each of
+its ``ep`` members holds ``E / ep`` experts. The same code runs on every
+member over the all-gathered tokens with its own experts' groups first in
+the sort, and the partial results are reduce-scattered back; at ``ep`` 1
+both collectives are the identity.
 """
 
 from __future__ import annotations
+
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ..common.compat import axis_size as _axis_size
+from ..ops import pallas_attention as _pallas_attention
+
+# Largest (rows, contraction, columns) tile of the Pallas grouped matmul
+# by operand item size: what fits the kernel's fast memory on a v5e, and
+# of the tilings tried on the chip at [65536, 2048] x [64, 2048, 1024] the
+# fastest (PERF.md, PR 26).
+_GMM_TILE_CAPS = {2: (512, 1024, 1024), 4: (512, 512, 512)}
 
 
 def init_moe_params(rng, d_model: int, d_ff: int, n_experts: int,
                     dtype=jnp.float32):
-    k1, k2, k3 = jax.random.split(rng, 3)
-    scale_in = 1.0 / jnp.sqrt(d_model)
-    scale_out = 1.0 / jnp.sqrt(d_ff)
+    kr, kg, ku, kd = jax.random.split(rng, 4)
+
+    def normal(key, shape, scale, dt):
+        return (jax.random.normal(key, shape) * scale).astype(dt)
+
     return {
-        "gate": (jax.random.normal(k1, (d_model, n_experts)) * scale_in
-                 ).astype(jnp.float32),
-        "w_in": (jax.random.normal(k2, (n_experts, d_model, d_ff)) * scale_in
-                 ).astype(dtype),
-        "w_out": (jax.random.normal(k3, (n_experts, d_ff, d_model)) * scale_out
-                  ).astype(dtype),
+        "router": normal(kr, (d_model, n_experts), d_model ** -0.5,
+                         jnp.float32),
+        "wg": normal(kg, (n_experts, d_model, d_ff), d_model ** -0.5, dtype),
+        "wu": normal(ku, (n_experts, d_model, d_ff), d_model ** -0.5, dtype),
+        "wd": normal(kd, (n_experts, d_ff, d_model), d_ff ** -0.5, dtype),
     }
 
 
-def moe_layer(x, params, axis_name: str = "dp", capacity_factor: float = 1.25,
-              top_k: int = 1, return_aux: bool = False):
-    """Top-k MoE over tokens. x: [T, d] (local tokens); params['w_in']:
-    [E_local, d, f] — the *local* expert shard when run under shard_map
-    with the expert dim sharded over ``axis_name``.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(x, index, inverse, fan):
+    """``x[index // fan]`` for a permutation ``index`` of ``fan *
+    len(x)`` with inverse ``inverse``. Its transpose is the gather by the
+    inverse, summed over each row's ``fan`` copies."""
+    return x[index // fan]
 
-    ``top_k``: 1 (Switch) or 2 (GShard; gates renormalized over the two
-    picks, first choices win capacity). ``return_aux``: also return the
-    load-balance auxiliary loss (scalar, ~1.0 when balanced) for the
-    caller to weight into the training loss.
 
-    Returns [T, d], or ([T, d], aux) with ``return_aux``.
+def _take_rows_fwd(x, index, inverse, fan):
+    return x[index // fan], inverse
+
+
+def _take_rows_bwd(fan, inverse, g):
+    return g[inverse].reshape(-1, fan, g.shape[-1]).sum(1), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs[rows of group g] @ rhs[g]`` for consecutive groups of
+    ``group_sizes`` rows of lhs [m, k], rhs [g, k, n]; rows past the last
+    group come out zero. Operands as they are, float32 accumulation,
+    result in the operands' type."""
+    use_pallas, interpret = _pallas_attention._resolve_dispatch(None)
+    if not use_pallas:
+        return lax.ragged_dot(lhs, rhs, group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    sizes = (lhs.shape[0], lhs.shape[1], rhs.shape[2])
+    caps = _GMM_TILE_CAPS[lhs.dtype.itemsize]
+    tiling = tuple(math.gcd(size, cap) for size, cap in zip(sizes, caps))
+    with jax.named_scope("moe_gmm"):
+        return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype, tiling, None,
+                            None, False, interpret)
+
+
+@jax.checkpoint
+def _gated(gate, up, weight):
+    """``silu(gate) * up * weight[:, None]`` in float32; the backward
+    pass keeps the operands in their own type and forms the float32
+    values anew."""
+    return (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+            * weight[:, None]).astype(gate.dtype)
+
+
+def moe_layer(x, params, axis_name: str = "dp", top_k: int = 1,
+              norm_topk_prob: bool = False, seq_axis_name=None):
+    """Top-k MoE over the tokens of ``x`` [B, T, d] (local sequences).
+
+    ``params``: ``router`` [d, E] float32 (replicated), and this member's
+    experts ``wg``, ``wu`` [E_local, d, f], ``wd`` [E_local, f, d] — the
+    local shard when run under shard_map with the expert dimension
+    sharded over ``axis_name``. A token's output is ``sum_j p[e_j] *
+    (silu(u Wg[e_j]) * (u Wu[e_j])) Wd[e_j]`` over its ``top_k`` largest
+    router probabilities ``p``, taken as they are unless
+    ``norm_topk_prob`` divides them by their sum.
+
+    Returns ``(y [B, T, d], stats)``. ``stats["lb"]`` is the load-balance
+    term ``E * sum_e f_e P_e`` of each sequence (``f_e`` the share of its
+    tokens with ``e`` among their ``top_k``, ``P_e`` its mean router
+    probability; ``top_k`` at perfect balance), averaged over the local
+    sequences; ``stats["z"]`` the mean squared log-sum-exp of the router
+    logits; ``stats["load"]`` [E] the local tokens per expert.
+    ``seq_axis_name`` names the mesh axis the T axis is sharded over, if
+    any, so that ``f`` and ``P`` are those of whole sequences.
     """
     ep = _axis_size(axis_name)
-    T, d = x.shape
-    e_local = params["w_in"].shape[0]
+    B, T, d = x.shape
+    e_local = params["wg"].shape[0]
     E = e_local * ep
     if not 1 <= top_k <= E:
         raise ValueError(f"top_k={top_k} must be in [1, {E}]")
 
-    # --- routing (fp32) -----------------------------------------------------
-    logits = x.astype(jnp.float32) @ params["gate"]  # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    topg, topi = lax.top_k(probs, top_k)  # [T, k]
-    if top_k > 1:
-        topg = topg / jnp.sum(topg, axis=-1, keepdims=True)
+    with jax.named_scope("moe_route"):
+        # float32 in earnest: at default precision the MXU would round
+        # both operands to bf16 and the top-k with them.
+        logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32),
+                            params["router"],
+                            precision=lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, experts = lax.top_k(probs, top_k)  # [B, T, k]
+        if norm_topk_prob:
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        picked = jnp.sum(
+            experts[..., None] == jnp.arange(E, dtype=experts.dtype),
+            axis=(1, 2))  # [B, E]: tokens of the sequence that chose e
+        f = picked.astype(jnp.float32) / T
+        p_mean = jnp.mean(probs, axis=1)
+        if seq_axis_name is not None:
+            f = lax.pmean(f, seq_axis_name)
+            p_mean = lax.pmean(p_mean, seq_axis_name)
+        stats = {
+            "lb": E * jnp.mean(jnp.sum(f * p_mean, axis=-1)),
+            "z": jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
+            "load": jnp.sum(picked, axis=0),
+        }
 
-    # Virtual-token view, choice-major ([all 1st choices; all 2nd ...]):
-    # the capacity cumsum below then gives every first choice priority
-    # over any second choice (the GShard policy).
-    vidx = topi.T.reshape(-1)   # [k*T]
-    vgate = topg.T.reshape(-1)  # [k*T]
+    with jax.named_scope("moe_dispatch"):
+        rows = x.reshape(B * T, d)
+        experts = experts.reshape(B * T * top_k)  # token-major
+        gates = gates.reshape(B * T * top_k)
+        if ep > 1:
+            rows, experts, gates = (
+                lax.all_gather(a, axis_name, tiled=True)
+                for a in (rows, experts, gates))
+        # This member's experts first, in their order; then the others',
+        # which no group below covers.
+        first = lax.axis_index(axis_name) * e_local
+        local = (experts - first) % E
+        order = jnp.argsort(local)
+        inverse = jnp.argsort(order)
+        group_sizes = jnp.sum(
+            local[:, None] == jnp.arange(e_local, dtype=local.dtype),
+            axis=0, dtype=jnp.int32)
+        rows = _take_rows(rows, order, inverse, top_k)  # [k N, d]
+        if ep > 1:
+            # Rows of other members' experts: no group covers them, and a
+            # grouped matmul may leave anything there, in either pass.
+            mine = (local[order] < e_local)[:, None]
+            rows = jnp.where(mine, rows, jnp.zeros_like(rows))
 
-    capacity = max(1, int(capacity_factor * top_k * T / E))
-    onehot = jax.nn.one_hot(vidx, E, dtype=jnp.float32)  # [kT, E]
-    # position of each virtual token within its expert's queue
-    pos = (jnp.cumsum(onehot, axis=0) - 1.0) * onehot  # [kT, E]
-    keep = (pos < capacity) * onehot  # [kT, E] tokens within capacity
-    pos = jnp.sum(pos * keep, axis=-1).astype(jnp.int32)  # [kT]
-    kept = jnp.sum(keep, axis=-1) > 0  # [kT]
+    with jax.named_scope("moe_experts"):
+        # The router's weight rides the gated product, in float32, into
+        # the third matmul: p (h Wd) = (p h) Wd, and the rows need no
+        # weighting on their way back.
+        weight = _take_rows(gates[:, None], order, inverse, 1)
+        if ep > 1:
+            weight = jnp.where(mine, weight, jnp.zeros_like(weight))
+        hidden = _gated(_grouped_matmul(rows, params["wg"], group_sizes),
+                        _grouped_matmul(rows, params["wu"], group_sizes),
+                        weight[:, 0])
+        out = _grouped_matmul(hidden, params["wd"], group_sizes)
 
-    # dispatch tensor [kT, E, C]
-    dispatch = (keep[:, :, None]
-                * jax.nn.one_hot(pos, capacity, dtype=jnp.float32)[:, None, :])
-    # expert input buffers [E, C, d]; each token's features enter once
-    # per surviving choice
-    x32 = jnp.tile(x.astype(jnp.float32), (top_k, 1))  # [kT, d]
-    buffers = jnp.einsum("tec,td->ecd", dispatch, x32)
-
-    # --- all_to_all: deliver each expert's buffer to its owner --------------
-    # [E, C, d] -> [ep, e_local, C, d]; exchange over axis -> every member
-    # ends with its local experts' tokens from all peers: [ep, e_local, C, d]
-    buffers = buffers.reshape(ep, e_local, capacity, d)
-    recv = lax.all_to_all(buffers, axis_name, split_axis=0, concat_axis=0,
-                          tiled=False)  # [ep, e_local, C, d]
-    # merge peer dim into capacity: [e_local, ep*C, d]
-    recv = recv.transpose(1, 0, 2, 3).reshape(e_local, ep * capacity, d)
-
-    # --- expert FFN ---------------------------------------------------------
-    h = jnp.einsum("ecd,edf->ecf", recv, params["w_in"].astype(jnp.float32))
-    h = jax.nn.gelu(h, approximate=False)
-    out = jnp.einsum("ecf,efd->ecd", h, params["w_out"].astype(jnp.float32))
-
-    # --- return trip --------------------------------------------------------
-    out = out.reshape(e_local, ep, capacity, d).transpose(1, 0, 2, 3)
-    back = lax.all_to_all(out, axis_name, split_axis=0, concat_axis=0,
-                          tiled=False)  # [ep, e_local, C, d]
-    back = back.reshape(E, capacity, d)
-
-    # combine: weight each choice's returned features by its gate, then
-    # sum the k choices per real token: [kT, d] -> [k, T, d] -> [T, d]
-    combined = jnp.einsum("tec,ecd->td", dispatch, back)
-    y = (combined * (vgate * kept)[:, None]).reshape(top_k, T, d).sum(0)
-    y = y.astype(x.dtype)
-    if not return_aux:
-        return y
-    # Switch aux loss: E * sum_e(fraction of tokens whose FIRST choice is
-    # e  *  mean router prob on e). 1.0 at perfect balance; grows as
-    # routing collapses onto few experts. The token means are averaged
-    # over the expert-parallel axis so every member returns the same
-    # (global) scalar.
-    first = jax.nn.one_hot(topi[:, 0], E, dtype=jnp.float32)  # [T, E]
-    f = lax.pmean(jnp.mean(first, axis=0), axis_name)
-    p = lax.pmean(jnp.mean(probs, axis=0), axis_name)
-    aux = E * jnp.sum(f * p)
-    return y, aux
+    with jax.named_scope("moe_combine"):
+        if ep > 1:
+            out = jnp.where(mine, out, jnp.zeros_like(out))
+        out = _take_rows(out, inverse, order, 1)  # token-major again
+        y = jnp.sum(out.reshape(-1, top_k, d), axis=1, dtype=jnp.float32)
+        if ep > 1:
+            y = lax.psum_scatter(y, axis_name, scatter_dimension=0,
+                                 tiled=True)
+        return y.astype(x.dtype).reshape(B, T, d), stats

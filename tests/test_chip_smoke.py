@@ -310,3 +310,42 @@ def test_ring_block_kernels_compile_for_v5e(described_chip, monkeypatch,
         args = (x, x, x, x, stat, stat)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32-highest"])
+def test_expert_layer_compiles_for_v5e(described_chip, monkeypatch, dtype):
+    """``parallel/moe.py``'s layer and its gradient at OLMoE's widths (64
+    experts of 2048 x 1024, 8 a token, 8,192 tokens): the Pallas grouped
+    matmul's tiles fit the chip's fast memory in bf16 as benchmarked and
+    in float32 at ``highest`` as the gradient check runs it; nine Mosaic
+    calls (three matmuls forward, each with its two gradients)."""
+    import numpy as np
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from horovod_tpu.parallel import moe
+
+    monkeypatch.setattr(pa, "_resolve_dispatch", lambda up: (True, False))
+    d, f, E, k = 2048, 1024, 64, 8
+    mesh = Mesh(np.array([next(iter(described_chip.device_set))]), ("dp",))
+    shapes = {"router": ((d, E), jnp.float32), "wg": ((E, d, f), dtype),
+              "wu": ((E, d, f), dtype), "wd": ((E, f, d), dtype)}
+    params = {name: jax.ShapeDtypeStruct(shape, dt, sharding=described_chip)
+              for name, (shape, dt) in shapes.items()}
+    x = jax.ShapeDtypeStruct((2, 4096, d), dtype, sharding=described_chip)
+
+    def loss(x, p):
+        y, stats = moe.moe_layer(x, p, axis_name="dp", top_k=k)
+        return (jnp.sum(jnp.square(y.astype(jnp.float32))) + stats["lb"]
+                + stats["z"])
+
+    fn = jax.jit(jax.grad(jax.shard_map(
+        loss, mesh=mesh, in_specs=(P(), {n: P() for n in shapes}),
+        out_specs=P(), check_vma=False), argnums=(0, 1)))
+    with jax.default_matmul_precision(
+            "highest" if dtype == jnp.float32 else "default"):
+        text = fn.lower(x, params).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 9
+    # Dropless and sparse: no [tokens x experts x capacity] array, no
+    # one-hot contraction over the experts.
+    assert "65536,64," not in text

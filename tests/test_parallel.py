@@ -187,36 +187,48 @@ class TestUlyssesAttention:
                              strategy="spiral")(q, k, v)
 
 
+def _run_moe_layer(x, params, ep=1, **kw):
+    """moe_layer on an ``ep``-member dp axis: x [B, T, d] sharded over its
+    sequences, the experts sharded over the members. Returns (y, stats)."""
+    mesh = Mesh(np.array(jax.devices()[:ep]), ("dp",))
+    param_specs = {"router": P(), "wg": P("dp"), "wu": P("dp"),
+                   "wd": P("dp")}
+    sharded = {k: jax.device_put(v, NamedSharding(mesh, param_specs[k]))
+               for k, v in params.items()}
+    xs = jax.device_put(x, NamedSharding(mesh, P("dp")))
+
+    def layer(x, p):
+        y, stats = moe_layer(x, p, axis_name="dp", **kw)
+        return y, {"lb": jax.lax.pmean(stats["lb"], "dp"),
+                   "z": jax.lax.pmean(stats["z"], "dp"),
+                   "load": jax.lax.psum(stats["load"], "dp")}
+
+    fn = jax.jit(jax.shard_map(
+        layer, mesh=mesh, in_specs=(P("dp"), param_specs),
+        out_specs=(P("dp"), P()), check_vma=False))
+    return fn(xs, sharded)
+
+
 class TestMoE:
     def test_single_axis_identity_routing(self):
-        # ep axis of size 2, 4 experts (2 local each)
+        # ep axis of size 2, 4 experts (2 local each), one expert a token
         ep = 2
-        mesh = Mesh(np.array(jax.devices()[:ep]), ("dp",))
         T, d, f, E = 16, 8, 16, 4
-        rng = jax.random.PRNGKey(0)
-        params = init_moe_params(rng, d, f, E)
-        x = jax.random.normal(jax.random.PRNGKey(1), (ep * T, d), jnp.float32)
-
-        shard_x = NamedSharding(mesh, P("dp"))
-        param_specs = {"gate": P(), "w_in": P("dp"), "w_out": P("dp")}
-        sharded_params = {
-            k: jax.device_put(v, NamedSharding(mesh, param_specs[k]))
-            for k, v in params.items()}
-        xs = jax.device_put(x, shard_x)
-
-        fn = jax.jit(jax.shard_map(
-            lambda x, p: moe_layer(x, p, axis_name="dp",
-                                   capacity_factor=4.0),
-            mesh=mesh, in_specs=(P("dp"), param_specs),
-            out_specs=P("dp"), check_vma=False))
-        out = np.asarray(fn(xs, sharded_params))
-        assert out.shape == (ep * T, d)
+        params = init_moe_params(jax.random.PRNGKey(0), d, f, E)
+        x = jax.random.normal(jax.random.PRNGKey(1), (ep, T, d), jnp.float32)
+        out, stats = _run_moe_layer(x, params, ep)
+        out = np.asarray(out)
+        assert out.shape == (ep, T, d)
         assert np.isfinite(out).all()
+        # Every token reached its expert: nothing has a capacity.
+        assert int(np.asarray(stats["load"]).sum()) == ep * T
 
-        # Oracle: dense computation of top-1 MoE with ample capacity
-        # (the k=1 case of the shared top-k oracle).
-        expected = _dense_moe_oracle(np.asarray(x), params, top_k=1)
-        np.testing.assert_allclose(out, expected, rtol=1e-3, atol=1e-4)
+        # Oracle: dense computation of top-1 MoE (the k=1 case of the
+        # shared top-k oracle).
+        expected = _dense_moe_oracle(np.asarray(x).reshape(ep * T, d),
+                                     params, top_k=1)
+        np.testing.assert_allclose(out.reshape(ep * T, d), expected,
+                                   rtol=1e-3, atol=1e-4)
 
 
 class TestSlidingWindow:
@@ -425,91 +437,86 @@ class TestSegmentIds:
                                        rtol=2e-4, atol=2e-5)
 
 
-def _dense_moe_oracle(x, params, top_k):
-    """Ample-capacity top-k MoE oracle, gates renormalized for k > 1
-    (GShard). Shared by the top-1 and top-2 tests so the two stay in
-    sync by construction."""
-    from scipy.stats import norm as _norm
-
+def _dense_moe_oracle(x, params, top_k, renormalize=False):
+    """Dropless top-k MoE oracle in float64, gated SiLU experts, the
+    router's probabilities as they are unless ``renormalize``. Shared by
+    the top-1 and top-2 tests so the two stay in sync by construction."""
     x64 = np.asarray(x, np.float64)
-    logits = x64 @ np.asarray(params["gate"], np.float64)
+    logits = x64 @ np.asarray(params["router"], np.float64)
     probs = np.exp(logits - logits.max(-1, keepdims=True))
     probs /= probs.sum(-1, keepdims=True)
     order = np.argsort(-probs, axis=-1)[:, :top_k]
     gates = np.take_along_axis(probs, order, axis=-1)
-    if top_k > 1:
+    if renormalize:
         gates = gates / gates.sum(-1, keepdims=True)
-    w_in = np.asarray(params["w_in"], np.float64)
-    w_out = np.asarray(params["w_out"], np.float64)
+    wg, wu, wd = (np.asarray(params[k], np.float64)
+                  for k in ("wg", "wu", "wd"))
     out = np.zeros_like(x64)
     for t in range(x64.shape[0]):
         for j in range(top_k):
             e = order[t, j]
-            h = x64[t] @ w_in[e]
-            h = h * _norm.cdf(h)  # exact gelu
-            out[t] += gates[t, j] * (h @ w_out[e])
+            g = x64[t] @ wg[e]
+            h = g / (1.0 + np.exp(-g)) * (x64[t] @ wu[e])  # silu(g) * up
+            out[t] += gates[t, j] * (h @ wd[e])
     return out
 
 
 class TestMoETop2:
-    def _run_layer(self, x, params, ep, **kw):
-        mesh = Mesh(np.array(jax.devices()[:ep]), ("dp",))
-        param_specs = {"gate": P(), "w_in": P("dp"), "w_out": P("dp")}
-        sharded = {
-            k: jax.device_put(v, NamedSharding(mesh, param_specs[k]))
-            for k, v in params.items()}
-        xs = jax.device_put(x, NamedSharding(mesh, P("dp")))
-        fn = jax.jit(jax.shard_map(
-            lambda x, p: moe_layer(x, p, axis_name="dp", **kw),
-            mesh=mesh, in_specs=(P("dp"), param_specs),
-            out_specs=P("dp") if not kw.get("return_aux") else
-            (P("dp"), P()), check_vma=False))
-        return fn(xs, sharded)
-
-    def test_top2_matches_dense(self):
+    @pytest.mark.parametrize("renormalize", [False, True])
+    def test_top2_matches_dense(self, renormalize):
+        # The gates are the router's probabilities as they are; dividing
+        # them by their sum is a field (norm_topk_prob) and a different
+        # result: each oracle is matched by its own setting only.
         ep, T, d, f, E = 2, 16, 8, 16, 4
         params = init_moe_params(jax.random.PRNGKey(0), d, f, E)
         x = np.asarray(jax.random.normal(jax.random.PRNGKey(1),
-                                         (ep * T, d), jnp.float32))
-        out = np.asarray(self._run_layer(jnp.asarray(x), params, ep,
-                                         capacity_factor=4.0, top_k=2))
-        expected = _dense_moe_oracle(x, params, top_k=2)
+                                         (ep, T, d), jnp.float32))
+        out, _ = _run_moe_layer(jnp.asarray(x), params, ep, top_k=2,
+                                norm_topk_prob=renormalize)
+        out = np.asarray(out).reshape(ep * T, d)
+        flat = x.reshape(ep * T, d)
+        expected = _dense_moe_oracle(flat, params, 2, renormalize)
         np.testing.assert_allclose(out, expected, rtol=1e-3, atol=1e-4)
+        other = _dense_moe_oracle(flat, params, 2, not renormalize)
+        assert np.abs(out - other).max() > 0.05 * np.abs(other).max()
 
     def test_aux_loss_balance(self):
-        # A uniform router (zero gate weights -> equal probs) must score
-        # aux == 1.0 exactly; a collapsed router (huge bias onto expert
-        # 0 via a rigged gate) must score ~E.
+        # A uniform router (zero weights -> equal probs) must score the
+        # load-balance term at top_k exactly; a collapsed router (huge
+        # weight onto expert 0) must score ~E.
         ep, T, d, f, E = 2, 32, 8, 16, 4
         params = init_moe_params(jax.random.PRNGKey(0), d, f, E)
-        x = jax.random.normal(jax.random.PRNGKey(1), (ep * T, d),
+        x = jax.random.normal(jax.random.PRNGKey(1), (ep, T, d),
                               jnp.float32)
 
-        params_uni = dict(params, gate=jnp.zeros((d, E), jnp.float32))
-        _, aux = self._run_layer(x, params_uni, ep, capacity_factor=4.0,
-                                 top_k=1, return_aux=True)
+        params_uni = dict(params, router=jnp.zeros((d, E), jnp.float32))
+        _, stats = _run_moe_layer(x, params_uni, ep, top_k=1)
         # Uniform probs: P_e = 1/E exactly; argmax ties resolve to
-        # expert 0, so f_0 = 1 and aux = E * (1 * 1/E) = 1.0.
-        assert float(aux) == pytest.approx(1.0, rel=1e-5)
+        # expert 0, so f_0 = 1 and lb = E * (1 * 1/E) = 1.0 = top_k.
+        assert float(stats["lb"]) == pytest.approx(1.0, rel=1e-5)
+        # logsumexp of E zeros is log E at every token.
+        assert float(stats["z"]) == pytest.approx(np.log(E) ** 2, rel=1e-5)
 
-        # Collapse: first gate column dominates. The gate is linear (no
-        # bias), so positive features make logits[:, 0] large for every
-        # token.
+        # Collapse: first router column dominates. The router is linear
+        # (no bias), so positive features make logits[:, 0] large for
+        # every token.
         g = np.zeros((d, E), np.float32)
         g[:, 0] = 10.0
         x_pos = jnp.abs(x) + 0.5
-        _, aux = self._run_layer(x_pos, dict(params, gate=jnp.asarray(g)),
-                                 ep, capacity_factor=4.0, top_k=1,
-                                 return_aux=True)
-        assert float(aux) > 0.9 * E
+        _, stats = _run_moe_layer(
+            x_pos, dict(params, router=jnp.asarray(g)), ep, top_k=1)
+        assert float(stats["lb"]) > 0.9 * E
+        # Nothing dropped though every token wants expert 0.
+        np.testing.assert_array_equal(np.asarray(stats["load"]),
+                                      [ep * T, 0, 0, 0])
 
     def test_top_k_validated(self):
         ep, T, d, f, E = 2, 8, 8, 16, 4
         params = init_moe_params(jax.random.PRNGKey(0), d, f, E)
-        x = jax.random.normal(jax.random.PRNGKey(1), (ep * T, d),
+        x = jax.random.normal(jax.random.PRNGKey(1), (ep, T, d),
                               jnp.float32)
         with pytest.raises(ValueError, match="top_k"):
-            self._run_layer(x, params, ep, top_k=0)
+            _run_moe_layer(x, params, ep, top_k=0)
 
 
 class TestPipeline:

@@ -5,6 +5,7 @@ and each flash kernel carries its name. Read from the compiled text and
 the jaxpr on the CPU; the device trace that reads the same names is the
 benchmark's (``benchmark/scope_reduce.py``)."""
 import contextlib
+import dataclasses
 import re
 
 import jax
@@ -20,6 +21,9 @@ BUILDERS = {
     "dp": ("jit_hvd_dp_step", ("exchange", "loss")),
     "decoder": ("jit_hvd_decoder_step",
                 ("embed", "attention", "mlp", "head", "loss")),
+    "decoder_moe": ("jit_hvd_decoder_step",
+                    ("embed", "attention", "moe", "moe_route", "moe_dispatch",
+                     "moe_experts", "moe_combine", "head", "loss")),
     "zero": ("jit_hvd_zero_step", ("loss",)),
 }
 
@@ -61,7 +65,7 @@ def _build(kind, hvd):
     """(run, lower) of one step builder at a size the CPU compiles in a
     second or two: ``run()`` takes one step and returns its outputs,
     ``lower()`` lowers the jitted program."""
-    if kind == "decoder":
+    if kind in ("decoder", "decoder_moe"):
         from horovod_tpu.models.transformer import (
             TransformerConfig, init_params, make_train_step, shard_params)
         from horovod_tpu.parallel.mesh import build_parallel_mesh
@@ -69,6 +73,11 @@ def _build(kind, hvd):
 
         cfg = TransformerConfig(vocab=64, d_model=32, n_heads=2, d_head=16,
                                 d_ff=64, n_layers=2, max_seq=16)
+        if kind == "decoder_moe":  # OLMoE's block, experts over dp 2
+            cfg = dataclasses.replace(
+                cfg, use_moe=True, n_experts=4, d_expert=16, moe_top_k=2,
+                norm="rmsnorm", qk_norm=True, rope=True,
+                router_aux_loss_coef=0.01, router_z_loss_coef=0.001)
         mesh = build_parallel_mesh(jax.devices()[:2], sp=1, tp=1, pp=1)
         opt = optax.adamw(1e-3)
         params = shard_params(init_params(cfg, jax.random.PRNGKey(0), 1),

@@ -333,24 +333,47 @@ def test_remat_matches_dense():
             err_msg=f"remat grad mismatch for {key}")
 
 
-@pytest.mark.full
-def test_moe_grads_match_dense():
-    # Validates the differentiable path through routing, all_to_all
-    # dispatch/return, and gate combination (ample capacity: no drops).
-    cfg = TransformerConfig(vocab=64, d_model=32, n_heads=4, d_head=8,
-                            n_layers=2, max_seq=64, use_moe=True,
-                            n_experts=4, d_expert=64, capacity_factor=8.0)
-    mesh = build_parallel_mesh(jax.devices(), dp=2, pp=2, sp=1, tp=2)
-    params, tokens, labels = _setup(cfg, mesh)
+# The MoE decoder is held to the benchmark's plain float32 reference
+# (benchmark/reference_moe.py: RMSNorm, QK-norm, RoPE, dropless top-k
+# gated SiLU experts, both router loss terms), not to
+# dense_reference_loss.
+def _moe_cfg(**kw):
+    sizes = dict(vocab=64, d_model=32, n_heads=4, d_head=8, n_layers=2,
+                 max_seq=64, use_moe=True, n_experts=4, d_expert=64,
+                 moe_top_k=2, norm="rmsnorm", qk_norm=True, rope=True,
+                 router_aux_loss_coef=0.01, router_z_loss_coef=0.001)
+    return TransformerConfig(**dict(sizes, **kw))
+
+
+def _moe_reference(cfg, params, tokens, labels):
+    from benchmark import reference_moe
+
+    (loss, _), grads = reference_moe.decoder_moe_loss_and_grad(
+        params, tokens, labels, cfg.moe_top_k, cfg.router_aux_loss_coef,
+        cfg.router_z_loss_coef, cfg.norm_eps, cfg.rope_theta)
+    return loss, grads
+
+
+def _moe_loss_and_grads(cfg, mesh, params, tokens, labels):
     loss_fn = make_loss_fn(cfg, mesh, n_microbatches=2)
     sharded = shard_params(params, cfg, mesh)
     data_sharding = NamedSharding(mesh, P("dp", "sp"))
-    grads = jax.jit(jax.grad(loss_fn))(
+    return jax.jit(jax.value_and_grad(loss_fn))(
         sharded, jax.device_put(tokens, data_sharding),
         jax.device_put(labels, data_sharding))
-    ref_grads = jax.grad(
-        lambda p: dense_reference_loss(cfg, p, tokens, labels))(params)
-    for key in ("gate", "we_in", "we_out", "embed", "head"):
+
+
+@pytest.mark.full
+def test_moe_grads_match_dense():
+    # Validates the differentiable path through routing, sort-by-expert
+    # dispatch over the expert-parallel axis, the grouped matmuls and the
+    # combine, under dp x pp x tp.
+    cfg = _moe_cfg()
+    mesh = build_parallel_mesh(jax.devices(), dp=2, pp=2, sp=1, tp=2)
+    params, tokens, labels = _setup(cfg, mesh)
+    _, grads = _moe_loss_and_grads(cfg, mesh, params, tokens, labels)
+    _, ref_grads = _moe_reference(cfg, params, tokens, labels)
+    for key in ("router", "wg", "wu", "wd", "gq", "gk", "embed", "head"):
         got = np.asarray(jax.device_get(grads[key]))
         want = np.asarray(ref_grads[key])
         np.testing.assert_allclose(
@@ -360,20 +383,12 @@ def test_moe_grads_match_dense():
 
 @pytest.mark.parametrize("top_k", [1, 2])
 def test_moe_loss_matches_dense(top_k):
-    cfg = TransformerConfig(vocab=64, d_model=32, n_heads=4, d_head=8,
-                            n_layers=2, max_seq=64, use_moe=True,
-                            n_experts=4, d_expert=64, moe_top_k=top_k,
-                            capacity_factor=8.0)  # ample: no token drops
+    cfg = _moe_cfg(moe_top_k=top_k)
     mesh = build_parallel_mesh(jax.devices(), dp=2, pp=2, sp=1, tp=2)
     params, tokens, labels = _setup(cfg, mesh)
-    loss_fn = make_loss_fn(cfg, mesh, n_microbatches=2)
-    sharded = shard_params(params, cfg, mesh)
-    data_sharding = NamedSharding(mesh, P("dp", "sp"))
-    loss = float(jax.jit(loss_fn)(
-        sharded, jax.device_put(tokens, data_sharding),
-        jax.device_put(labels, data_sharding)))
-    expected = float(dense_reference_loss(cfg, params, tokens, labels))
-    assert loss == pytest.approx(expected, rel=1e-3)
+    loss, _ = _moe_loss_and_grads(cfg, mesh, params, tokens, labels)
+    expected, _ = _moe_reference(cfg, params, tokens, labels)
+    assert float(loss) == pytest.approx(float(expected), rel=1e-5)
 
 
 def test_train_step_improves_loss():
@@ -399,21 +414,15 @@ def test_train_step_improves_loss():
 def test_moe_sp2_grads_match_dense():
     # MoE combined with sequence parallelism (ring attention over sp=2):
     # the exact axis combination the driver's dryrun exercises; gradients
-    # must still match the dense oracle (ample capacity: no drops).
-    cfg = TransformerConfig(vocab=64, d_model=32, n_heads=4, d_head=8,
-                            n_layers=2, max_seq=64, use_moe=True,
-                            n_experts=4, d_expert=64, capacity_factor=8.0)
+    # must still match the reference, whose load-balance term is taken
+    # over whole sequences (f and P are averaged over the sp axis).
+    cfg = _moe_cfg()
     mesh = build_parallel_mesh(jax.devices(), dp=2, pp=2, sp=2, tp=1)
     params, tokens, labels = _setup(cfg, mesh)
-    loss_fn = make_loss_fn(cfg, mesh, n_microbatches=2)
-    sharded = shard_params(params, cfg, mesh)
-    data_sharding = NamedSharding(mesh, P("dp", "sp"))
-    grads = jax.jit(jax.grad(loss_fn))(
-        sharded, jax.device_put(tokens, data_sharding),
-        jax.device_put(labels, data_sharding))
-    ref_grads = jax.grad(
-        lambda p: dense_reference_loss(cfg, p, tokens, labels))(params)
-    for key in ("gate", "we_in", "we_out", "embed", "head", "wqkv"):
+    loss, grads = _moe_loss_and_grads(cfg, mesh, params, tokens, labels)
+    expected, ref_grads = _moe_reference(cfg, params, tokens, labels)
+    assert float(loss) == pytest.approx(float(expected), rel=1e-5)
+    for key in ("router", "wg", "wu", "wd", "embed", "head", "wqkv"):
         got = np.asarray(jax.device_get(grads[key]))
         want = np.asarray(ref_grads[key])
         np.testing.assert_allclose(
@@ -433,7 +442,7 @@ def test_dryrun_config_train_step():
     cfg = TransformerConfig(
         vocab=64, d_model=32, n_heads=4, d_head=8, n_layers=2 * sizes["pp"],
         max_seq=16 * sizes["sp"], use_moe=True,
-        n_experts=2 * sizes["dp"], d_expert=64, capacity_factor=2.0)
+        n_experts=2 * sizes["dp"], d_expert=64)
     params = init_params(cfg, jax.random.PRNGKey(0), n_stages=sizes["pp"])
     sharded = shard_params(params, cfg, mesh)
     optimizer = optax.adam(1e-3)

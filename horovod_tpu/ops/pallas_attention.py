@@ -1,11 +1,22 @@
 """Flash attention as a Pallas TPU kernel.
 
-The hot op of the transformer stack, written for the hardware: Q/K/V tiles
-stream HBM -> VMEM, the S = QK^T and P.V matmuls run on the MXU in fp32,
-and the online-softmax state (running max / normalizer / accumulator)
-lives in VMEM scratch across the innermost K-tile grid dimension, so the
-full attention matrix never materializes (the same streaming-accumulation
-math as ``parallel.ring_attention``).
+The hot op of the transformer stack, written for the hardware: a grid
+step holds several heads and a resident chunk of the sequence on both
+sides in VMEM and walks the chunk's sub-tiles itself. The S = QK^T and
+P.V matmuls run on the MXU with fp32 accumulation, the online-softmax
+state (running max / normalizer / accumulator) lives in VMEM scratch,
+and the full attention matrix never materializes (the same
+streaming-accumulation math as ``parallel.ring_attention``).
+
+What a kernel's time goes with is the tiles it walks and the latency of
+each tile's chain of phases (PERF.md section 5), so the walk visits only
+the sub-tiles the causal structure and the window leave: the loop's
+bounds come from them, tiles above the diagonal or beyond the window are
+never entered. Every visited tile takes the mask, made once a tile for
+all heads: an unmasked second body for tiles wholly under the diagonal
+saved 0-3 % of a kernel and doubled what every start pays to trace and
+lower it (PERF.md section 6, PR 27). ``kernel_plan`` decides heads a
+step, chunk and sub-tile from the shape alone; nothing else does.
 
 Scope: forward AND backward. Training's forward emits the per-row
 log-sum-exp alongside O; the backward is the standard flash backward as
@@ -15,10 +26,10 @@ the saved lse, so neither pass ever writes the attention matrix to HBM.
 ``flash_attention_block_grads`` exposes the same per-block backward for
 ring attention's backward ring pass (``parallel.ring_attention``).
 
-Block offsets ride in as prefetched scalars, so the same kernel serves
-ring attention's rotating K/V blocks (global causal masking between
-sequence blocks) and the plain single-block case. On TPU the kernels
-compile through Mosaic; tests interpret them on CPU
+Block offsets ride in as prefetched scalars and enter the walk's bounds,
+so the same kernel serves ring attention's rotating K/V blocks (global
+causal masking between sequence blocks) and the plain single-block case.
+On TPU the kernels compile through Mosaic; tests interpret them on CPU
 (``_resolve_dispatch``).
 
 Each ``pallas_call`` carries a ``name`` (``flash_fwd``, ``flash_dq``,
@@ -31,22 +42,53 @@ tells the kernels apart, and a fall-back from them, by these names
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ..common import logging as _log
 from ..common.compat import pallas_tpu_compiler_params as _compiler_params
 
 NEG_INF = -1e30
 
-# Tile sizes: multiples of the fp32 (8, 128) tile. 512x512 came from a
-# sweep on an older stack that left no record; ROADMAP S7 re-tunes it
-# against a measured roofline share. VMEM use at D=128 stays ~1 MB per
-# pipeline stage.
-BLOCK_Q = 512
-BLOCK_K = 512
+# What ``kernel_plan`` works from; docs/diagnostics.md ("Tracing") has its
+# decisions at the benchmark's shapes and PERF.md (section 6, PR 27) the
+# chip sweep behind each number.
+# The [tq, tk] score tile one loop iteration computes, both ways. A tile's
+# chain (matmul, row max, exp, row sum, matmul) runs one phase at a time,
+# so its fixed latencies are paid a tile: 256 x 256 tiles visit a sixth
+# fewer elements at T 1024 and take a third longer.
+_TILE_CAP = 512
+# A grid step takes heads until it holds this many score elements (2 at
+# T 1024, 16 at T 128), so that its fixed cost (0.35-0.5 us of DMA issue
+# and pipeline bookkeeping) is buried.
+_STEP_ELEMS = 1 << 21
+_MAX_HEADS = 16
+# Heads that share one loop body, so that one's matmul runs under
+# another's ``exp``: until the body holds this many score elements, at
+# most four (4 heads of 128 x 128, 2 of 512 x 512). Every head of a body
+# is traced and lowered to Mosaic on the host at every start, compile
+# cache or not: at T 128 four add 0.95 s to a 21 s set-up, and eight
+# would add about 1.9 s (over set-up's bound of 10 %) for 35 us a
+# forward call (PERF.md section 6, PR 27).
+_BODY_ELEMS = 1 << 19
+_MAX_UNROLL = 4
+# The longest resident chunk (``_pick_block``'s largest tile): nothing
+# longer fits ``VMEM_BUDGET`` at any head width.
+_CHUNK_CAP = 8192
+# Lanes the forward's running max and sum occupy in scratch, the same
+# value in each: a [tq, 1] float32 takes 128 lanes of VMEM either way,
+# and kept one lane wide its stores are masked and every use a lane
+# broadcast (the forward took 652 us a call at T 1024 so, 473 this way).
+_STAT_LANES = 128
+# VMEM a plan may count: every pipelined block twice, the scratch, and
+# one sub-tile's float32 temporaries. Row vectors ([T, 1] float32: lse,
+# delta, m, l, segment ids) occupy T x 128 lanes there.
+VMEM_BUDGET = 40 << 20
+_VMEM_DEFAULT_LIMIT = 16 << 20
 
 
 def _mxu_dot(a, b, contract):
@@ -80,265 +122,648 @@ def row_lse(m, l):
     return jnp.where(l > 0.0, m + log_l, -NEG_INF)
 
 
-def _attn_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                 acc_ref, *, causal: bool, block_q: int, block_k: int,
-                 num_k_tiles: int, return_state: bool = False,
-                 mo_ref=None, lo_ref=None, lse_ref=None,
-                 qs_ref=None, ks_ref=None, window=None):
-    """One (batch*head, q-tile, k-tile) grid step.
+# ---------------------------------------------------------------------------
+# The plan: heads a grid step, resident chunk, sub-tile, and the walk.
+# ---------------------------------------------------------------------------
 
-    Refs: q (1, block_q, D), k/v (1, block_k, D), o (1, block_q, D);
-    scratch m/l (block_q, 1) and acc (block_q, D) carry the online-softmax
-    state across the sequential k dimension. offs = [q_off, k_off] global
-    token offsets of sequence block 0 (ring attention rotates k blocks).
-    qs/ks (1, block, 1) int32: optional packed-sequence segment ids —
-    the mask composes with causal at trace time, so the segment-free
-    path compiles identically to before.
-    """
-    ki = pl.program_id(2)
 
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+class KernelPlan(NamedTuple):
+    """What one ``pallas_call`` of a pass does, all of it static."""
+    heads: int           # rows of the merged [B*H, T, D] arrays a grid step
+    chunk_q: int         # resident rows of Q (and dO, lse, delta, O)
+    chunk_k: int         # resident rows of K and V
+    tile_q: int          # the in-kernel loop's sub-tile, [tile_q, tile_k]
+    tile_k: int
+    unroll: int          # heads that share one loop body
+    grid: tuple          # (B*H / heads, outer chunks, inner chunks)
+    tiles_visited: int   # sub-tiles a head's walk enters (q_off == k_off)
+    vmem_bytes: int      # counted VMEM; ``vmem_limit_bytes`` is set from it
 
-    # program_id must be read OUTSIDE pl.when bodies (the predicated
-    # sub-jaxpr escapes the interpreter's program_id rewrite).
-    qi = pl.program_id(1)
-    q_base = offs_ref[0] + qi * block_q
-    k_base = offs_ref[1] + ki * block_k
-    if causal:
-        # Causal tile culling: a K tile strictly in this Q tile's future
-        # contributes nothing — predicate the whole update away (halves
-        # the causal FLOPs; the reference flash kernels do the same).
-        visible = q_base + block_q - 1 >= k_base
-        if window is not None:
-            # Sliding-window culling: a K tile entirely beyond the
-            # window into this Q tile's past is dead too — for
-            # T >> window most tiles skip, the real SWA saving.
-            visible = jnp.logical_and(
-                visible, k_base + block_k - 1 >= q_base - (window - 1))
+
+def _is_static(*xs):
+    return all(isinstance(x, int) for x in xs)
+
+
+def _imin(a, b):
+    return min(a, b) if _is_static(a, b) else jnp.minimum(a, b)
+
+
+def _imax(a, b):
+    return max(a, b) if _is_static(a, b) else jnp.maximum(a, b)
+
+
+def _clip_div(x, t, n):
+    """``floor(x / t)`` clamped into [0, n], on Python ints (the plan's
+    counts) and on the kernel's int32 scalars alike."""
+    if _is_static(x):
+        return min(max(x, 0) // t, n)
+    return jnp.minimum(jax.lax.div(jnp.maximum(x, 0), t), n)
+
+
+def _k_bounds(q_lo, tq, k_base, tk, n, causal, window):
+    """The K sub-tiles (of ``n``, ``tk`` wide, the first at global column
+    ``k_base``) that the Q sub-tile of global rows ``q_lo .. q_lo+tq-1``
+    walks, as ``(first, end)``: tiles wholly above the diagonal or beyond
+    the window lie outside."""
+    if not causal:
+        return 0, n
+    end = _clip_div(q_lo + tq - 1 - k_base + tk, tk, n)   # starting <= q_hi
+    if window is None:
+        return 0, end
+    # Visible: k_hi >= q_lo - window + 1.
+    return _imin(_clip_div(q_lo - window + 1 - k_base, tk, n), end), end
+
+
+def _q_bounds(k_lo, tk, q_base, tq, n, causal, window):
+    """The transposed walk of the dK/dV pass: the Q sub-tiles (of ``n``,
+    ``tq`` tall, the first at global row ``q_base``) that see the K
+    sub-tile of global columns ``k_lo .. k_lo+tk-1``."""
+    if not causal:
+        return 0, n
+    first = _clip_div(k_lo - q_base, tq, n)        # first with q_hi >= k_lo
+    if window is None:
+        return first, n
+    # Visible: q_lo - k_hi < window.
+    return first, _imax(
+        _clip_div(k_lo + tk - 1 + window - q_base + tq - 1, tq, n), first)
+
+
+def _pick_block(t: int, cap: int) -> Optional[int]:
+    """Largest MXU-friendly tile (multiple of the fp32 sublane count, up
+    to ``cap``) that divides ``t``; None when ``t`` isn't tileable
+    (callers fall back to the XLA path rather than reason about
+    padded-position masking)."""
+    for c in (8192, 4096, 2048, 1024, 512, 256, 128, 64, 32, 16, 8):
+        if c <= cap and t % c == 0:
+            return c
+    return None
+
+
+def _chunk(t: int, cap: int) -> int:
+    """The resident chunk of a sequence of ``t``: all of it where that is
+    at most ``cap``, else the largest power of two up to ``cap`` that
+    divides it."""
+    return t if t <= cap else (_pick_block(t, cap) or t)
+
+
+def _lanes(d: int) -> int:
+    return -(-d // 128) * 128
+
+
+def _vmem_bytes(kind, heads, unroll, cq, ck, tq, tk, d, itemsize,
+                out_itemsize, segments, state):
+    """VMEM one grid step of pass ``kind`` holds, counted as Mosaic lays
+    it out: the last dimension padded to 128 lanes (a [T, 1] float32 row
+    vector is T x 512 bytes), pipelined blocks double-buffered, and six
+    float32 [tq, tk] temporaries for every head of a loop body."""
+    dp = _lanes(d)
+    row = 128 * 4
+    q_side = heads * cq * dp
+    k_side = heads * ck * dp
+    blocks = (q_side + 2 * k_side) * itemsize              # q, k, v
+    if segments:
+        blocks += heads * (cq + ck) * row
+    if kind == "fwd":
+        blocks += q_side * out_itemsize                    # o (acc)
+        blocks += heads * cq * row * (2 if state else 1)   # lse | m, l
+        scratch = q_side * 4 + 2 * heads * cq * row        # acc, m, l
     else:
-        visible = True
-
-    @pl.when(visible)
-    def _update():
-        # Feed the MXU its native input dtype (bf16 x bf16 -> f32
-        # accumulate); pre-casting to f32 would halve matmul throughput.
-        q = q_ref[0]
-        k = k_ref[0]
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-        s = _mxu_dot(q, k, ((1,), (1,))) * scale  # [bq, bk]
-
-        if causal:
-            q_pos = (q_base +
-                     jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
-            k_pos = (k_base +
-                     jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-            if window is not None:
-                s = jnp.where(q_pos - k_pos < window, s, NEG_INF)
-        if qs_ref is not None:
-            s = jnp.where(qs_ref[0] == ks_ref[0].reshape(1, -1),
-                          s, NEG_INF)
-
-        m_prev = m_ref[:]                      # [block_q, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alive = m_new > NEG_INF / 2
-        corr = jnp.where(alive, jnp.exp(m_prev - m_new), 1.0)
-        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
-        l_new = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        # P rides the MXU in the V dtype (f32 accumulation preserved by
-        # preferred_element_type) — the standard TPU flash-kernel trade.
-        pv = _mxu_dot(p.astype(v_ref.dtype), v_ref[0], ((1,), (0,)))
-        acc_ref[:] = acc_ref[:] * corr + pv
-        m_ref[:] = m_new
-        l_ref[:] = l_new
-
-    @pl.when(ki == num_k_tiles - 1)
-    def _finalize():
-        if return_state:
-            # Block mode (ring attention): emit the UNnormalized
-            # accumulator plus (m, l) so the caller merges blocks with the
-            # standard online-softmax combine.
-            o_ref[0] = acc_ref[:].astype(o_ref.dtype)
-            mo_ref[0] = m_ref[:]
-            lo_ref[0] = l_ref[:]
+        blocks += q_side * itemsize + 2 * heads * cq * row  # do, lse, delta
+        if kind == "dq":
+            blocks += q_side * out_itemsize
+            scratch = q_side * 4
         else:
-            o_ref[0] = (acc_ref[:] /
-                        jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
-            if lse_ref is not None:
-                lse_ref[0] = row_lse(m_ref[:], l_ref[:])
+            blocks += 2 * k_side * out_itemsize
+            scratch = 2 * k_side * 4
+    return 2 * blocks + scratch + 6 * unroll * tq * tk * 4
 
 
-def _attn_kernel_state(offs_ref, q_ref, k_ref, v_ref, o_ref, mo_ref,
-                       lo_ref, m_ref, l_ref, acc_ref, **kw):
-    """Block-mode positional adapter: pallas passes outputs before
-    scratch, so the three outputs (acc, m, l) precede the scratch refs."""
-    _attn_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                 acc_ref, return_state=True, mo_ref=mo_ref, lo_ref=lo_ref,
-                 **kw)
+def kernel_plan(BH, Tq, Tk, D, dtype, causal, window=None, *,
+                segments=False, kind="fwd", out_dtype=None, state=False):
+    """The grid step of pass ``kind`` ("fwd", "dq" or "dkv") for merged
+    ``[BH, T, D]`` operands of ``dtype``: a pure function of the shape.
+    None where the kernels do not take the shape and the XLA twins do: a
+    sequence no tile divides, or a head so wide that no chunk fits
+    ``VMEM_BUDGET``.
+
+    Heads a step: enough that a step holds ``_STEP_ELEMS`` score elements
+    (16 at T 128, 2 at T 1024, 1 from T 2048 on), a divisor of ``BH``,
+    fewer where that many do not fit;
+    of them ``_BODY_ELEMS`` score elements' worth, at most
+    ``_MAX_UNROLL``, share a loop body. Chunk: the whole sequence on both
+    sides while the counted VMEM stays under ``VMEM_BUDGET``, else the
+    largest power-of-two chunk that does — the chunks are then grid
+    dimensions, the inner one sequential. Sub-tile:
+    the largest divisor of the chunk up to ``_TILE_CAP``. The count is
+    that of a block on the diagonal (``q_off == k_off``), per head."""
+    if _pick_block(Tq, 8) is None or _pick_block(Tk, 8) is None:
+        return None
+    itemsize = jnp.dtype(dtype).itemsize
+    out_itemsize = jnp.dtype(out_dtype or (jnp.float32 if state
+                                           else dtype)).itemsize
+    cap = min(max(Tq, Tk), _CHUNK_CAP)
+    while True:
+        cq, ck = _chunk(Tq, cap), _chunk(Tk, cap)
+        tq, tk = _pick_block(cq, _TILE_CAP), _pick_block(ck, _TILE_CAP)
+        body = max(1, min(_MAX_UNROLL, _BODY_ELEMS // (tq * tk)))
+        want = max(1, min(_MAX_HEADS, _STEP_ELEMS // (cq * ck)))
+        fits = None
+        # The most heads, up to the wanted, that divide BH and fit.
+        for heads in range(want, 0, -1):
+            if BH % heads:
+                continue
+            unroll = max(u for u in range(1, body + 1) if heads % u == 0)
+            vmem = _vmem_bytes(kind, heads, unroll, cq, ck, tq, tk, D,
+                               itemsize, out_itemsize, segments, state)
+            if vmem <= VMEM_BUDGET:
+                fits = heads, unroll, vmem
+                break
+        if fits or cap <= max(_TILE_CAP, 128):
+            break
+        cap = max(c for c in (1 << s for s in range(3, 24)) if c < cap)
+    if fits is None:
+        return None
+    heads, unroll, vmem = fits
+    n_qc, n_kc = Tq // cq, Tk // ck
+    visited = 0
+    for qc in range(n_qc):
+        for kc in range(n_kc):
+            if kind == "dkv":
+                walks = (_q_bounds(kc * ck + j * tk, tk, qc * cq, tq,
+                                   cq // tq, causal, window)
+                         for j in range(ck // tk))
+            else:
+                walks = (_k_bounds(qc * cq + i * tq, tq, kc * ck, tk,
+                                   ck // tk, causal, window)
+                         for i in range(cq // tq))
+            visited += sum(end - first for first, end in walks)
+    grid = ((BH // heads, n_kc, n_qc) if kind == "dkv"
+            else (BH // heads, n_qc, n_kc))
+    return KernelPlan(heads, cq, ck, tq, tk, unroll, grid, visited, vmem)
 
 
-def _attn_kernel_state_seg(offs_ref, q_ref, k_ref, v_ref, qs_ref, ks_ref,
-                           o_ref, mo_ref, lo_ref, m_ref, l_ref, acc_ref,
-                           **kw):
-    """Block-mode adapter with segment-id tiles (inputs ride after v)."""
-    _attn_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                 acc_ref, return_state=True, mo_ref=mo_ref, lo_ref=lo_ref,
-                 qs_ref=qs_ref, ks_ref=ks_ref, **kw)
+def _kernels_take(kinds, q, k, causal, window, segments, **out) -> bool:
+    """Whether ``kernel_plan`` has a grid step for every pass of ``kinds``
+    on merged ``q`` and ``k``; where it has not, the callers take the XLA
+    twins."""
+    BH, Tq, D = q.shape
+    return all(
+        kernel_plan(BH, Tq, k.shape[1], D, q.dtype, causal, window,
+                    segments=segments, kind=kind, **out) is not None
+        for kind in kinds)
 
 
-def _attn_kernel_train(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                       m_ref, l_ref, acc_ref, **kw):
-    """Training-forward adapter: normalized O plus the per-row lse
-    residual the flash backward re-materializes P from."""
-    _attn_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                 acc_ref, lse_ref=lse_ref, **kw)
+def _log_plan(kind, shape, dtype, causal, window, plan):
+    """Everything a plan decides is static, so it is logged once, when
+    the call is traced (``HOROVOD_LOG_LEVEL=debug``)."""
+    _log.debug(
+        f"flash_{kind} {tuple(shape)} {jnp.dtype(dtype).name} "
+        f"causal={causal} window={window}: {plan.heads} heads a step "
+        f"({plan.unroll} a loop body), chunk "
+        f"{plan.chunk_q}x{plan.chunk_k}, sub-tile "
+        f"{plan.tile_q}x{plan.tile_k}, grid {plan.grid}, "
+        f"{plan.tiles_visited} tiles a head, VMEM {plan.vmem_bytes} B")
 
 
-def _attn_kernel_train_seg(offs_ref, q_ref, k_ref, v_ref, qs_ref, ks_ref,
-                           o_ref, lse_ref, m_ref, l_ref, acc_ref, **kw):
-    """Training-forward adapter with segment-id tiles."""
-    _attn_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                 acc_ref, lse_ref=lse_ref, qs_ref=qs_ref, ks_ref=ks_ref,
-                 **kw)
+# ---------------------------------------------------------------------------
+# The kernels: one body a pass.
+# ---------------------------------------------------------------------------
 
 
-def _attn_bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                        delta_ref, dq_ref, dq_acc, *, causal: bool,
-                        block_q: int, block_k: int, num_k_tiles: int,
-                        qs_ref=None, ks_ref=None, window=None):
-    """dQ pass: grid (batch*head, q-tile, k-tile), sequential over K tiles.
-
-    P = exp(S - lse) is rebuilt on-chip from the saved lse;
-    dS = P * (dO.V^T - delta); dQ accumulates dS.K in VMEM across the K
-    dimension. delta = rowsum(dO * O), precomputed by the caller.
-    """
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    qi = pl.program_id(1)
-    q_base = offs_ref[0] + qi * block_q
-    k_base = offs_ref[1] + ki * block_k
-    visible = (q_base + block_q - 1 >= k_base) if causal else True
-    if causal and window is not None:
-        visible = jnp.logical_and(
-            visible, k_base + block_k - 1 >= q_base - (window - 1))
-
-    @pl.when(visible)
-    def _update():
-        q = q_ref[0]
-        k = k_ref[0]
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-        s = _mxu_dot(q, k, ((1,), (1,))) * scale  # [bq, bk]
-        p = jnp.exp(s - lse_ref[0])                          # [bq, bk]
-        if causal:
-            q_pos = q_base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = k_base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            p = jnp.where(q_pos >= k_pos, p, 0.0)
-            if window is not None:
-                p = jnp.where(q_pos - k_pos < window, p, 0.0)
-        if qs_ref is not None:
-            p = jnp.where(qs_ref[0] == ks_ref[0].reshape(1, -1), p, 0.0)
-        dp = _mxu_dot(do_ref[0], v_ref[0], ((1,), (1,)))  # [bq, bk]
-        ds = p * (dp - delta_ref[0]) * scale  # [bq, bk]
-        dq_acc[:] += _mxu_dot(ds.astype(k.dtype), k, ((1,), (0,)))  # [bq, D]
-
-    @pl.when(ki == num_k_tiles - 1)
-    def _finalize():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+def _for_each(n, fn):
+    """``fn(i)`` for i in [0, n): inline where n is 1, else an in-kernel
+    loop (never a Python unroll over tiles: compile time)."""
+    if n == 1:
+        fn(0)
+    else:
+        jax.lax.fori_loop(0, n, lambda i, c: (fn(i), c)[1], None)
 
 
-def _attn_bwd_dq_kernel_seg(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                            delta_ref, qs_ref, ks_ref, dq_ref, dq_acc,
-                            **kw):
-    """dQ adapter with segment-id tiles (inputs ride after delta)."""
-    _attn_bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                        delta_ref, dq_ref, dq_acc, qs_ref=qs_ref,
-                        ks_ref=ks_ref, **kw)
+def _for_heads(n, compute, commit=None, unroll=1):
+    """The heads of a grid step are the innermost loop: every head walks
+    the same tiles, so the bounds and the mask are made once a tile.
+    ``unroll`` heads share a loop body (unrolled by hand: Mosaic takes a
+    loop whole or not at all), and every head of a body computes before
+    any ``commit``s its result to the scratch, so that no store of one
+    head stands between another's loads and the scheduler can run one
+    head's matmul under another's ``exp``."""
+    def group(i):
+        heads = [i * unroll + r for r in range(unroll)]
+        results = [compute(g) for g in heads]
+        if commit is not None:
+            for g, result in zip(heads, results):
+                commit(g, result)
+
+    _for_each(n // unroll, group)
 
 
-def _attn_bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                         delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                         causal: bool, block_q: int, block_k: int,
-                         num_q_tiles: int, qs_ref=None, ks_ref=None,
-                         window=None):
-    """dK/dV pass: grid (batch*head, k-tile, q-tile), sequential over Q
-    tiles. Same [bq, bk] orientation as the dQ pass; the transposed
+def _walk(bounds, tile, chunk, n_chunks, init, finish):
+    """Run ``tile(index)`` over the walk's ``(first, end)``, between
+    ``init`` in the first grid step of the sequential chunk dimension and
+    ``finish`` in the last."""
+    if n_chunks == 1:
+        init()
+    else:
+        pl.when(chunk == 0)(init)
+    jax.lax.fori_loop(*bounds, lambda t, c: (tile(t), c)[1], None)
+    if n_chunks == 1:
+        finish()
+    else:
+        pl.when(chunk == n_chunks - 1)(finish)
+
+
+def _rows(i, t):
+    return pl.ds(i * t if isinstance(i, int) else pl.multiple_of(i * t, t),
+                 t)
+
+
+def _tile_mask(q_lo, k_lo, tq, tk, causal, window):
+    """Which elements of the [tq, tk] tile at global (q_lo, k_lo) the
+    causal structure and the window keep (None: all). One per tile, for
+    all heads of a loop body."""
+    if not causal:
+        return None
+    rel = (q_lo - k_lo) + (jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0) -
+                           jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1))
+    keep = rel >= 0
+    if window is not None:
+        keep = jnp.logical_and(keep, rel < window)
+    return keep
+
+
+def _across(x, n):
+    """A row statistic, kept ``_STAT_LANES`` lanes wide with the same
+    value in every lane, against an ``n``-lane operand: no lane broadcast
+    where ``n`` is a multiple of the width."""
+    w = x.shape[-1]
+    if n <= w:
+        return x[:, :n]
+    if n % w:
+        return x[:, :1]
+    return pltpu.repeat(x, n // w, axis=1)
+
+
+def _keep(mask, qs_ref, ks_ref, g, rows, cols):
+    """The tile's mask composed with head ``g``'s segment ids (either may
+    be absent; None means every element is kept)."""
+    if qs_ref is None:
+        return mask
+    same = qs_ref[g, rows, :] == ks_ref[g, cols, :].reshape(1, -1)
+    return same if mask is None else jnp.logical_and(mask, same)
+
+
+def _fwd_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
+                mode: str, segments: bool):
+    """One grid step of the forward: ``plan.heads`` heads x a resident
+    [chunk_q, D] of Q x a resident [chunk_k, D] of K and V.
+
+    Refs: q (G, chunk_q, D), k/v (G, chunk_k, D), optional segment ids
+    qs/ks (G, chunk, 1) int32, then the outputs of ``mode`` — "plain": o;
+    "train": o and the per-row lse (G, chunk_q, 1); "state" (ring
+    attention): the UNnormalized accumulator plus (m, l), which the
+    caller merges with the online-softmax combine — then scratch m/l
+    (G, chunk_q, _STAT_LANES) and acc (G, chunk_q, D), which carry the
+    state along the walk and across the sequential K-chunk grid dimension.
+    offs = [q_off, k_off], global token offsets of sequence block 0."""
+    n_in = 5 if segments else 3
+    q_ref, k_ref, v_ref = refs[:3]
+    qs_ref, ks_ref = refs[3:5] if segments else (None, None)
+    outs = refs[n_in:-3]
+    m_ref, l_ref, acc_ref = refs[-3:]
+    G, tq, tk = plan.heads, plan.tile_q, plan.tile_k
+    n_k = plan.chunk_k // tk
+    n_kc = plan.grid[2]
+    # program_id is read here, outside every predicated or looped body.
+    kc = pl.program_id(2)
+    q_base = offs_ref[0] + pl.program_id(1) * plan.chunk_q
+    k_base = offs_ref[1] + kc * plan.chunk_k
+    scale = 1.0 / (q_ref.shape[-1] ** 0.5)
+
+    def q_tile(i):
+        rows = _rows(i, tq)
+        q_lo = q_base + i * tq
+
+        def init():
+            W = m_ref.shape[-1]
+            m_ref[:, rows, :] = jnp.full((G, tq, W), NEG_INF, jnp.float32)
+            l_ref[:, rows, :] = jnp.zeros((G, tq, W), jnp.float32)
+            acc_ref[:, rows, :] = jnp.zeros((G, tq, acc_ref.shape[-1]),
+                                            jnp.float32)
+
+        def tile(j):
+            cols = _rows(j, tk)
+            mask = _tile_mask(q_lo, k_base + j * tk, tq, tk, causal, window)
+
+            def head(g):
+                # Feed the MXU its native input dtype (bf16 x bf16 -> f32
+                # accumulate); pre-casting to f32 would halve throughput.
+                s = _mxu_dot(q_ref[g, rows, :], k_ref[g, cols, :],
+                             ((1,), (1,))) * scale            # [tq, tk]
+                keep = _keep(mask, qs_ref, ks_ref, g, rows, cols)
+                if keep is not None:
+                    s = jnp.where(keep, s, NEG_INF)
+                m_prev = m_ref[g, rows, :]                    # [tq, W]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                # exp(NEG_INF - NEG_INF) is 1 and the state it scales 0.
+                corr = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - _across(m_new, tk))
+                if keep is not None:
+                    # A row with no key yet has m_new == NEG_INF and
+                    # exp(0) in every place.
+                    p = jnp.where(keep, p, 0.0)
+                l_new = (l_ref[g, rows, :] * corr +
+                         jnp.sum(p, axis=-1, keepdims=True))
+                # P rides the MXU in the V dtype (f32 accumulation
+                # preserved) — the standard TPU flash-kernel trade.
+                v = v_ref[g, cols, :]
+                acc = (acc_ref[g, rows, :] * _across(corr, v.shape[-1]) +
+                       _mxu_dot(p.astype(v.dtype), v, ((1,), (0,))))
+                return m_new, l_new, acc
+
+            def commit(g, state):
+                m_ref[g, rows, :], l_ref[g, rows, :], acc_ref[g, rows, :] = \
+                    state
+
+            _for_heads(G, head, commit, plan.unroll)
+
+        def finish():
+            def head(g):
+                m, l = m_ref[g, rows, :1], l_ref[g, rows, :1]
+                acc = acc_ref[g, rows, :]
+                if mode == "state":
+                    outs[0][g, rows, :] = acc.astype(outs[0].dtype)
+                    outs[1][g, rows, :] = m
+                    outs[2][g, rows, :] = l
+                else:
+                    outs[0][g, rows, :] = (
+                        acc / jnp.maximum(l, 1e-30)).astype(outs[0].dtype)
+                    if mode == "train":
+                        outs[1][g, rows, :] = row_lse(m, l)
+
+            _for_heads(G, head)
+
+        _walk(_k_bounds(q_lo, tq, k_base, tk, n_k, causal, window), tile,
+              kc, n_kc, init, finish)
+
+    _for_each(plan.chunk_q // tq, q_tile)
+
+
+def _dq_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
+               segments: bool):
+    """dQ pass: the forward's walk. P = exp(S - lse) is rebuilt on-chip
+    from the saved lse; dS = P * (dO.V^T - delta); dQ accumulates dS.K in
+    VMEM along the walk and across the sequential K-chunk grid dimension;
+    the softmax scale multiplies the [tq, D] accumulator once, at the
+    end. delta = rowsum(dO * O), precomputed by the caller."""
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    qs_ref, ks_ref = refs[6:8] if segments else (None, None)
+    dq_ref, dq_acc = refs[-2:]
+    G, tq, tk = plan.heads, plan.tile_q, plan.tile_k
+    n_k = plan.chunk_k // tk
+    n_kc = plan.grid[2]
+    kc = pl.program_id(2)
+    q_base = offs_ref[0] + pl.program_id(1) * plan.chunk_q
+    k_base = offs_ref[1] + kc * plan.chunk_k
+    scale = 1.0 / (q_ref.shape[-1] ** 0.5)
+
+    def q_tile(i):
+        rows = _rows(i, tq)
+        q_lo = q_base + i * tq
+
+        def init():
+            dq_acc[:, rows, :] = jnp.zeros((G, tq, dq_acc.shape[-1]),
+                                           jnp.float32)
+
+        def tile(j):
+            cols = _rows(j, tk)
+            mask = _tile_mask(q_lo, k_base + j * tk, tq, tk, causal, window)
+
+            def head(g):
+                k = k_ref[g, cols, :]
+                s = _mxu_dot(q_ref[g, rows, :], k,
+                             ((1,), (1,))) * scale                # [tq, tk]
+                p = jnp.exp(s - lse_ref[g, rows, :])
+                keep = _keep(mask, qs_ref, ks_ref, g, rows, cols)
+                if keep is not None:
+                    p = jnp.where(keep, p, 0.0)
+                dp = _mxu_dot(do_ref[g, rows, :], v_ref[g, cols, :],
+                              ((1,), (1,)))                       # [tq, tk]
+                ds = p * (dp - delta_ref[g, rows, :])
+                return dq_acc[g, rows, :] + _mxu_dot(
+                    ds.astype(k.dtype), k, ((1,), (0,)))          # [tq, D]
+
+            def commit(g, dq):
+                dq_acc[g, rows, :] = dq
+
+            _for_heads(G, head, commit, plan.unroll)
+
+        def finish():
+            def head(g):
+                dq_ref[g, rows, :] = (dq_acc[g, rows, :] *
+                                      scale).astype(dq_ref.dtype)
+
+            _for_heads(G, head)
+
+        _walk(_k_bounds(q_lo, tq, k_base, tk, n_k, causal, window), tile,
+              kc, n_kc, init, finish)
+
+    _for_each(plan.chunk_q // tq, q_tile)
+
+
+def _dkv_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
+                segments: bool):
+    """dK/dV pass: the transposed walk — for each K sub-tile, the Q
+    sub-tiles that see it; grid (heads, K chunk, Q chunk), sequential over
+    Q chunks. Same [tq, tk] orientation as the dQ pass; the transposed
     contractions (P^T.dO, dS^T.Q) ride dot_general dimension numbers so
-    no tile is ever explicitly transposed."""
-    qi = pl.program_id(2)
+    no tile is ever explicitly transposed. The softmax scale multiplies
+    dK's accumulator once, at the end, as it does dQ's."""
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    qs_ref, ks_ref = refs[6:8] if segments else (None, None)
+    dk_ref, dv_ref, dk_acc, dv_acc = refs[-4:]
+    G, tq, tk = plan.heads, plan.tile_q, plan.tile_k
+    n_q = plan.chunk_q // tq
+    n_qc = plan.grid[2]
+    qc = pl.program_id(2)
+    q_base = offs_ref[0] + qc * plan.chunk_q
+    k_base = offs_ref[1] + pl.program_id(1) * plan.chunk_k
+    scale = 1.0 / (q_ref.shape[-1] ** 0.5)
 
-    @pl.when(qi == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+    def k_tile(j):
+        cols = _rows(j, tk)
+        k_lo = k_base + j * tk
 
-    ki = pl.program_id(1)
-    q_base = offs_ref[0] + qi * block_q
-    k_base = offs_ref[1] + ki * block_k
-    visible = (q_base + block_q - 1 >= k_base) if causal else True
-    if causal and window is not None:
-        visible = jnp.logical_and(
-            visible, k_base + block_k - 1 >= q_base - (window - 1))
+        def init():
+            zeros = jnp.zeros((G, tk, dk_acc.shape[-1]), jnp.float32)
+            dk_acc[:, cols, :] = zeros
+            dv_acc[:, cols, :] = zeros
 
-    @pl.when(visible)
-    def _update():
-        q = q_ref[0]
-        k = k_ref[0]
-        do = do_ref[0]
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-        s = _mxu_dot(q, k, ((1,), (1,))) * scale  # [bq, bk]
-        p = jnp.exp(s - lse_ref[0])
-        if causal:
-            q_pos = q_base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = k_base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            p = jnp.where(q_pos >= k_pos, p, 0.0)
-            if window is not None:
-                p = jnp.where(q_pos - k_pos < window, p, 0.0)
-        if qs_ref is not None:
-            p = jnp.where(qs_ref[0] == ks_ref[0].reshape(1, -1), p, 0.0)
-        dv_acc[:] += _mxu_dot(p.astype(do.dtype), do, ((0,), (0,)))  # [bk, D]
-        dp = _mxu_dot(do, v_ref[0], ((1,), (1,)))  # [bq, bk]
-        ds = p * (dp - delta_ref[0]) * scale
-        dk_acc[:] += _mxu_dot(ds.astype(q.dtype), q, ((0,), (0,)))  # [bk, D]
+        def tile(i):
+            rows = _rows(i, tq)
+            mask = _tile_mask(q_base + i * tq, k_lo, tq, tk, causal, window)
 
-    @pl.when(qi == num_q_tiles - 1)
-    def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+            def head(g):
+                q = q_ref[g, rows, :]
+                do = do_ref[g, rows, :]
+                s = _mxu_dot(q, k_ref[g, cols, :],
+                             ((1,), (1,))) * scale                # [tq, tk]
+                p = jnp.exp(s - lse_ref[g, rows, :])
+                keep = _keep(mask, qs_ref, ks_ref, g, rows, cols)
+                if keep is not None:
+                    p = jnp.where(keep, p, 0.0)
+                dv = dv_acc[g, cols, :] + _mxu_dot(
+                    p.astype(do.dtype), do, ((0,), (0,)))         # [tk, D]
+                dp = _mxu_dot(do, v_ref[g, cols, :], ((1,), (1,)))
+                ds = p * (dp - delta_ref[g, rows, :])
+                dk = dk_acc[g, cols, :] + _mxu_dot(
+                    ds.astype(q.dtype), q, ((0,), (0,)))          # [tk, D]
+                return dk, dv
 
+            def commit(g, grads):
+                dk_acc[g, cols, :], dv_acc[g, cols, :] = grads
 
-def _attn_bwd_dkv_kernel_seg(offs_ref, q_ref, k_ref, v_ref, do_ref,
-                             lse_ref, delta_ref, qs_ref, ks_ref, dk_ref,
-                             dv_ref, dk_acc, dv_acc, **kw):
-    """dK/dV adapter with segment-id tiles (inputs ride after delta)."""
-    _attn_bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                         delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                         qs_ref=qs_ref, ks_ref=ks_ref, **kw)
+            _for_heads(G, head, commit, plan.unroll)
 
+        def finish():
+            def head(g):
+                dk_ref[g, cols, :] = (dk_acc[g, cols, :] *
+                                      scale).astype(dk_ref.dtype)
+                dv_ref[g, cols, :] = dv_acc[g, cols, :].astype(dv_ref.dtype)
 
-def _seg3(seg):
-    """[BH, T] int32 -> [BH, T, 1]: the row-oriented layout the lse/delta
-    tiles already use. Mosaic requires the last two block dims be
-    (8, 128)-divisible or full-extent; a (1, block, 1) tile satisfies
-    that for EVERY _pick_block size (block >= 8 on the sublane dim, the
-    lane dim full at 1) — the lane-major (1, 1, block) layout fails for
-    blocks < 128."""
-    return seg[:, :, None]
+            _for_heads(G, head)
+
+        _walk(_q_bounds(k_lo, tk, q_base, tq, n_q, causal, window), tile,
+              qc, n_qc, init, finish)
+
+    _for_each(plan.chunk_k // tk, k_tile)
 
 
-def _seg_specs(bq, bk):
-    """BlockSpecs for the (1, block, 1) int32 segment-id tiles."""
-    return [
-        pl.BlockSpec((1, bq, 1), lambda bh, qi, ki, offs: (bh, qi, 0)),
-        pl.BlockSpec((1, bk, 1), lambda bh, qi, ki, offs: (bh, ki, 0)),
-    ]
+def _flash_call(kind, kernel, plan, args, out_shapes, out_sides, scratch,
+                interpret):
+    """The ``pallas_call`` of one pass. ``args`` are (array, side) pairs,
+    side "q" or "k" saying which chunk the block follows; the outputs'
+    sides are ``out_sides``. The last grid dimension is the sequential
+    one: K chunks for "fwd" and "dq", Q chunks for "dkv"."""
+    G = plan.heads
+    if kind == "dkv":
+        maps = {"q": lambda b, kc, qc, offs: (b, qc, 0),
+                "k": lambda b, kc, qc, offs: (b, kc, 0)}
+    else:
+        maps = {"q": lambda b, qc, kc, offs: (b, qc, 0),
+                "k": lambda b, qc, kc, offs: (b, kc, 0)}
+    chunk = {"q": plan.chunk_q, "k": plan.chunk_k}
+
+    def spec(shape, side):
+        return pl.BlockSpec((G, chunk[side], shape[-1]), maps[side])
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=plan.grid,
+            in_specs=[spec(a.shape, side) for a, side in args],
+            out_specs=[spec(o.shape, side)
+                       for o, side in zip(out_shapes, out_sides)],
+            scratch_shapes=scratch,
+        ),
+        out_shape=out_shapes,
+        compiler_params=_compiler_params(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=max(_VMEM_DEFAULT_LIMIT,
+                                 plan.vmem_bytes + (8 << 20))),
+        interpret=interpret,
+        name=f"flash_{kind}",
+    )
+
+
+def _seg_args(q_seg, k_seg):
+    """[BH, T] int32 -> [BH, T, 1] blocks, the row-oriented layout the
+    lse/delta blocks already use. Mosaic requires the last two block dims
+    be (8, 128)-divisible or full-extent; a (G, chunk, 1) block satisfies
+    that for EVERY chunk (>= 8 on the sublane dim, the lane dim full at
+    1) — the lane-major (G, 1, chunk) layout fails for sub-tiles < 128."""
+    if q_seg is None:
+        return []
+    return [(q_seg[:, :, None], "q"), (k_seg[:, :, None], "k")]
+
+
+def _flash_forward(q, k, v, offs, causal: bool, interpret: bool, mode: str,
+                   q_seg=None, k_seg=None, window=None):
+    """The forward pass on merged [BH, T, D] operands. ``mode`` "plain":
+    o in q.dtype; "train": (o, lse f32 [BH, Tq, 1]); "state": (acc f32,
+    m, l), the unmerged online-softmax state of this K block."""
+    BH, Tq, D = q.shape
+    Tk = k.shape[1]
+    segments = q_seg is not None
+    plan = kernel_plan(BH, Tq, Tk, D, q.dtype, causal, window,
+                       segments=segments, kind="fwd",
+                       state=mode == "state")
+    _log_plan("fwd", q.shape, q.dtype, causal, window, plan)
+    row = jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32)
+    out_shapes = {
+        "plain": [jax.ShapeDtypeStruct(q.shape, q.dtype)],
+        "train": [jax.ShapeDtypeStruct(q.shape, q.dtype), row],
+        "state": [jax.ShapeDtypeStruct(q.shape, jnp.float32), row, row],
+    }[mode]
+    G, cq = plan.heads, plan.chunk_q
+    operands = [(q, "q"), (k, "k"), (v, "k")] + _seg_args(q_seg, k_seg)
+    out = _flash_call(
+        "fwd",
+        functools.partial(_fwd_kernel, plan=plan, causal=causal,
+                          window=window, mode=mode, segments=segments),
+        plan, operands, out_shapes, ["q"] * len(out_shapes),
+        [pltpu.VMEM((G, cq, _STAT_LANES), jnp.float32),
+         pltpu.VMEM((G, cq, _STAT_LANES), jnp.float32),
+         pltpu.VMEM((G, cq, D), jnp.float32)],
+        interpret,
+    )(offs, *(a for a, _ in operands))
+    return out[0] if mode == "plain" else tuple(out)
+
+
+def _pallas_bwd(q, k, v, do, lse, delta, offs, causal: bool,
+                interpret: bool, out_dtype=None, q_seg=None, k_seg=None,
+                window=None):
+    """The two flash-backward kernels; returns (dq, dk, dv) in the input
+    dtypes (or ``out_dtype`` when given — ring accumulation wants f32).
+    lse/delta: f32 [BH, T, 1]."""
+    BH, Tq, D = q.shape
+    Tk = k.shape[1]
+    segments = q_seg is not None
+    operands = [(q, "q"), (k, "k"), (v, "k"), (do, "q"), (lse, "q"),
+                (delta, "q")] + _seg_args(q_seg, k_seg)
+    outs = {}
+    for kind, kernel in (("dq", _dq_kernel), ("dkv", _dkv_kernel)):
+        plan = kernel_plan(BH, Tq, Tk, D, q.dtype, causal, window,
+                           segments=segments, kind=kind,
+                           out_dtype=out_dtype)
+        _log_plan(kind, q.shape, q.dtype, causal, window, plan)
+        G, cq, ck = plan.heads, plan.chunk_q, plan.chunk_k
+        if kind == "dq":
+            shapes = [jax.ShapeDtypeStruct((BH, Tq, D),
+                                           out_dtype or q.dtype)]
+            sides = ["q"]
+            scratch = [pltpu.VMEM((G, cq, D), jnp.float32)]
+        else:
+            shapes = [jax.ShapeDtypeStruct((BH, Tk, D),
+                                           out_dtype or k.dtype),
+                      jax.ShapeDtypeStruct((BH, Tk, D),
+                                           out_dtype or v.dtype)]
+            sides = ["k", "k"]
+            scratch = [pltpu.VMEM((G, ck, D), jnp.float32),
+                       pltpu.VMEM((G, ck, D), jnp.float32)]
+        outs[kind] = _flash_call(
+            kind,
+            functools.partial(kernel, plan=plan, causal=causal,
+                              window=window, segments=segments),
+            plan, operands, shapes, sides, scratch, interpret,
+        )(offs, *(a for a, _ in operands))
+    return (outs["dq"][0], *outs["dkv"])
+
+
+# ---------------------------------------------------------------------------
+# XLA twins, dispatch and the public entry points.
+# ---------------------------------------------------------------------------
 
 
 def int_cotangent(x):
@@ -348,69 +773,6 @@ def int_cotangent(x):
 
     return None if x is None else np.zeros(x.shape,
                                            dtype=jax.dtypes.float0)
-
-
-def _pallas_block_state(q, k, v, offs, causal: bool, interpret: bool,
-                        q_seg=None, k_seg=None, window=None):
-    """q/k/v: [BH, T, D]. Returns (acc f32 [BH,Tq,D], m f32 [BH,Tq,1],
-    l f32 [BH,Tq,1]) — the unmerged online-softmax state of this K block
-    (ring attention merges blocks as they rotate). ``q_seg``/``k_seg``:
-    optional int32 [BH, T] segment ids (streamed as extra tiles)."""
-    BH, Tq, D = q.shape
-    Tk = k.shape[1]
-    bq = _pick_block(Tq, BLOCK_Q)
-    bk = _pick_block(Tk, BLOCK_K)
-    num_q = Tq // bq
-    num_k = Tk // bk
-
-    from jax.experimental.pallas import tpu as pltpu
-
-    in_specs = [
-        pl.BlockSpec((1, bq, D), lambda bh, qi, ki, offs: (bh, qi, 0)),
-        pl.BlockSpec((1, bk, D), lambda bh, qi, ki, offs: (bh, ki, 0)),
-        pl.BlockSpec((1, bk, D), lambda bh, qi, ki, offs: (bh, ki, 0)),
-    ]
-    args = [offs, q, k, v]
-    if q_seg is not None:
-        in_specs += _seg_specs(bq, bk)
-        args += [_seg3(q_seg), _seg3(k_seg)]
-        kernel_fn = _attn_kernel_state_seg
-    else:
-        kernel_fn = _attn_kernel_state
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(BH, num_q, num_k),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, bq, D),
-                         lambda bh, qi, ki, offs: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, 1),
-                         lambda bh, qi, ki, offs: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, 1),
-                         lambda bh, qi, ki, offs: (bh, qi, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        kernel_fn, causal=causal, block_q=bq, block_k=bk,
-        num_k_tiles=num_k, window=window)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, Tq, D), jnp.float32),
-            jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32),
-            jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32),
-        ],
-        compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="flash_fwd",
-    )(*args)
 
 
 def _apply_segment_mask(x, q_seg, k_seg, fill):
@@ -464,12 +826,12 @@ def _xla_block_state(q, k, v, offs, causal, q_seg=None, k_seg=None,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
 def _block_state_core(q, k, v, offs, q_seg, k_seg, causal, interpret,
                       window):
-    if _pick_block(q.shape[1], BLOCK_Q) is None or \
-            _pick_block(k.shape[1], BLOCK_K) is None:
+    if not _kernels_take(("fwd",), q, k, causal, window, q_seg is not None,
+                         state=True):
         return _xla_block_state(q, k, v, offs, causal, q_seg=q_seg,
                                 k_seg=k_seg, window=window)
-    return _pallas_block_state(q, k, v, offs, causal, interpret,
-                               q_seg=q_seg, k_seg=k_seg, window=window)
+    return _flash_forward(q, k, v, offs, causal, interpret, "state",
+                          q_seg=q_seg, k_seg=k_seg, window=window)
 
 
 def _block_state_fwd(q, k, v, offs, q_seg, k_seg, causal, interpret,
@@ -534,7 +896,7 @@ def flash_attention_block(q, k, v, q_off, k_off, causal: bool = True,
     (unnormalized P.V), m/l f32 [B, H, T] — merge across blocks with the
     online-softmax combine. Dispatch rules match ``flash_attention``
     (shared ``_resolve_dispatch``); segment ids stream into the same
-    kernels as extra id tiles (packed sequences).
+    kernels as extra id blocks (packed sequences).
     """
     B, Tq, H, D = q.shape
 
@@ -590,8 +952,9 @@ def flash_attention_block_grads(q, k, v, do, lse, delta, q_off, k_off,
         q_seg = _tile_seg(q_segment_ids, H)
         k_seg = _tile_seg(k_segment_ids, H)
     _check_window(window, causal)
-    if use_pallas and _pick_block(Tq, BLOCK_Q) is not None and \
-            _pick_block(Tk, BLOCK_K) is not None:
+    if use_pallas and _kernels_take(("dq", "dkv"), qm, km, causal, window,
+                                    q_seg is not None,
+                                    out_dtype=jnp.float32):
         dq, dk, dv = _pallas_bwd(qm, km, vm, dom, lse_m, delta_m, offs,
                                  causal, interpret, out_dtype=jnp.float32,
                                  q_seg=q_seg, k_seg=k_seg, window=window)
@@ -605,205 +968,6 @@ def flash_attention_block_grads(q, k, v, do, lse, delta, q_off, k_off,
         return x.reshape(B, H, t, D).transpose(0, 2, 1, 3)
 
     return split(dq, Tq), split(dk, Tk), split(dv, Tk)
-
-
-def _attn_kernel_seg(offs_ref, q_ref, k_ref, v_ref, qs_ref, ks_ref,
-                     o_ref, m_ref, l_ref, acc_ref, **kw):
-    """Plain-forward adapter with segment-id tiles (no lse residual)."""
-    _attn_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                 acc_ref, qs_ref=qs_ref, ks_ref=ks_ref, **kw)
-
-
-def _pallas_attention_fwd(q, k, v, q_off, k_off, causal: bool,
-                          interpret: bool, q_seg=None, k_seg=None,
-                          window=None):
-    """q/k/v: [BH, T, D] (already merged batch*heads, padded to tiles)."""
-    BH, Tq, D = q.shape
-    Tk = k.shape[1]
-    bq = _pick_block(Tq, BLOCK_Q)
-    bk = _pick_block(Tk, BLOCK_K)
-    num_q = Tq // bq
-    num_k = Tk // bk
-
-    from jax.experimental.pallas import tpu as pltpu
-
-    in_specs = [
-        pl.BlockSpec((1, bq, D), lambda bh, qi, ki, offs: (bh, qi, 0)),
-        pl.BlockSpec((1, bk, D), lambda bh, qi, ki, offs: (bh, ki, 0)),
-        pl.BlockSpec((1, bk, D), lambda bh, qi, ki, offs: (bh, ki, 0)),
-    ]
-    offs = jnp.asarray([q_off, k_off], jnp.int32)
-    args = [offs, q, k, v]
-    if q_seg is not None:
-        in_specs += _seg_specs(bq, bk)
-        args += [_seg3(q_seg), _seg3(k_seg)]
-        kernel_fn = _attn_kernel_seg
-    else:
-        kernel_fn = _attn_kernel
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(BH, num_q, num_k),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bq, D),
-                               lambda bh, qi, ki, offs: (bh, qi, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        kernel_fn, causal=causal, block_q=bq, block_k=bk,
-        num_k_tiles=num_k, window=window)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="flash_fwd",
-    )(*args)
-
-
-def _pallas_attention_fwd_train(q, k, v, offs, causal: bool,
-                                interpret: bool, q_seg=None, k_seg=None,
-                                window=None):
-    """Forward with residuals: (o [BH,T,D] in q.dtype, lse f32 [BH,T,1])."""
-    BH, Tq, D = q.shape
-    Tk = k.shape[1]
-    bq = _pick_block(Tq, BLOCK_Q)
-    bk = _pick_block(Tk, BLOCK_K)
-    num_q = Tq // bq
-    num_k = Tk // bk
-
-    from jax.experimental.pallas import tpu as pltpu
-
-    in_specs = [
-        pl.BlockSpec((1, bq, D), lambda bh, qi, ki, offs: (bh, qi, 0)),
-        pl.BlockSpec((1, bk, D), lambda bh, qi, ki, offs: (bh, ki, 0)),
-        pl.BlockSpec((1, bk, D), lambda bh, qi, ki, offs: (bh, ki, 0)),
-    ]
-    args = [offs, q, k, v]
-    if q_seg is not None:
-        in_specs += _seg_specs(bq, bk)
-        args += [_seg3(q_seg), _seg3(k_seg)]
-        kernel_fn = _attn_kernel_train_seg
-    else:
-        kernel_fn = _attn_kernel_train
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(BH, num_q, num_k),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, bq, D), lambda bh, qi, ki, offs: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, qi, ki, offs: (bh, qi, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        kernel_fn, causal=causal, block_q=bq, block_k=bk,
-        num_k_tiles=num_k, window=window)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32),
-        ],
-        compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="flash_fwd",
-    )(*args)
-
-
-def _pallas_bwd(q, k, v, do, lse, delta, offs, causal: bool,
-                interpret: bool, out_dtype=None, q_seg=None, k_seg=None,
-                window=None):
-    """The two flash-backward kernels; returns (dq, dk, dv) in the input
-    dtypes (or ``out_dtype`` when given — ring accumulation wants f32).
-    lse/delta: f32 [BH, T, 1]."""
-    dq_dt = out_dtype or q.dtype
-    dk_dt = out_dtype or k.dtype
-    dv_dt = out_dtype or v.dtype
-    BH, Tq, D = q.shape
-    Tk = k.shape[1]
-    bq = _pick_block(Tq, BLOCK_Q)
-    bk = _pick_block(Tk, BLOCK_K)
-    num_q = Tq // bq
-    num_k = Tk // bk
-
-    from jax.experimental.pallas import tpu as pltpu
-
-    q_spec = pl.BlockSpec((1, bq, D), lambda bh, qi, ki, offs: (bh, qi, 0))
-    k_spec = pl.BlockSpec((1, bk, D), lambda bh, qi, ki, offs: (bh, ki, 0))
-    row_spec = pl.BlockSpec((1, bq, 1), lambda bh, qi, ki, offs: (bh, qi, 0))
-    dq_in_specs = [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec]
-    dq_args = [offs, q, k, v, do, lse, delta]
-    if q_seg is not None:
-        dq_in_specs += _seg_specs(bq, bk)
-        dq_args += [_seg3(q_seg), _seg3(k_seg)]
-        dq_kernel = _attn_bwd_dq_kernel_seg
-    else:
-        dq_kernel = _attn_bwd_dq_kernel
-    dq = pl.pallas_call(
-        functools.partial(dq_kernel, causal=causal, block_q=bq,
-                          block_k=bk, num_k_tiles=num_k, window=window),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(BH, num_q, num_k),
-            in_specs=dq_in_specs,
-            out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((BH, Tq, D), dq_dt),
-        compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="flash_dq",
-    )(*dq_args)
-
-    # dK/dV pass: K tiles are the parallel dimension, Q tiles sequential.
-    qkv_spec = pl.BlockSpec((1, bq, D), lambda bh, ki, qi, offs: (bh, qi, 0))
-    kkv_spec = pl.BlockSpec((1, bk, D), lambda bh, ki, qi, offs: (bh, ki, 0))
-    rowkv_spec = pl.BlockSpec((1, bq, 1),
-                              lambda bh, ki, qi, offs: (bh, qi, 0))
-    kv_in_specs = [qkv_spec, kkv_spec, kkv_spec, qkv_spec, rowkv_spec,
-                   rowkv_spec]
-    kv_args = [offs, q, k, v, do, lse, delta]
-    if q_seg is not None:
-        kv_in_specs += [
-            pl.BlockSpec((1, bq, 1), lambda bh, ki, qi, offs: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, 1), lambda bh, ki, qi, offs: (bh, ki, 0)),
-        ]
-        kv_args += [_seg3(q_seg), _seg3(k_seg)]
-        kv_kernel = _attn_bwd_dkv_kernel_seg
-    else:
-        kv_kernel = _attn_bwd_dkv_kernel
-    dk, dv = pl.pallas_call(
-        functools.partial(kv_kernel, causal=causal, block_q=bq,
-                          block_k=bk, num_q_tiles=num_q, window=window),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(BH, num_k, num_q),
-            in_specs=kv_in_specs,
-            out_specs=[kkv_spec, kkv_spec],
-            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                            pltpu.VMEM((bk, D), jnp.float32)],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((BH, Tk, D), dk_dt),
-                   jax.ShapeDtypeStruct((BH, Tk, D), dv_dt)],
-        compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="flash_dkv",
-    )(*kv_args)
-    return dq, dk, dv
 
 
 @jax.named_scope("flash_xla")
@@ -833,19 +997,6 @@ def _xla_block_grads(q, k, v, do, lse, delta, offs, causal: bool,
     dq = jnp.einsum("bts,bsd->btd", ds, k.astype(jnp.float32))
     dk = jnp.einsum("bts,btd->bsd", ds, q.astype(jnp.float32))
     return dq.astype(dq_dt), dk.astype(dk_dt), dv.astype(dv_dt)
-
-
-def _pick_block(t: int, cap: int) -> Optional[int]:
-    """Largest MXU-friendly tile (multiple of the fp32 sublane count, up
-    to ``cap``) that divides ``t``; None when ``t`` isn't tileable
-    (callers fall back to the XLA path rather than reason about
-    padded-position masking). Candidates extend above the 512 default so
-    a BLOCK_Q/BLOCK_K override (tools/pallas_bench.py --sweep-blocks)
-    genuinely changes the tiling."""
-    for c in (2048, 1024, 512, 256, 128, 64, 32, 16, 8):
-        if c <= cap and t % c == 0:
-            return c
-    return None
 
 
 @jax.named_scope("flash_xla")
@@ -878,25 +1029,24 @@ def _xla_flash(q, k, v, q_off, k_off, causal, q_seg=None, k_seg=None,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def _flash_core(q, k, v, q_seg, k_seg, q_off, k_off, causal, interpret,
                 window):
-    if _pick_block(q.shape[1], BLOCK_Q) is None or \
-            _pick_block(k.shape[1], BLOCK_K) is None:
+    if not _kernels_take(("fwd",), q, k, causal, window, q_seg is not None):
         return _xla_flash(q, k, v, q_off, k_off, causal, q_seg=q_seg,
                           k_seg=k_seg, window=window)
-    return _pallas_attention_fwd(q, k, v, q_off, k_off, causal, interpret,
-                                 q_seg=q_seg, k_seg=k_seg, window=window)
+    return _flash_forward(q, k, v, jnp.asarray([q_off, k_off], jnp.int32),
+                          causal, interpret, "plain", q_seg=q_seg,
+                          k_seg=k_seg, window=window)
 
 
 def _flash_fwd(q, k, v, q_seg, k_seg, q_off, k_off, causal, interpret,
                window):
-    if _pick_block(q.shape[1], BLOCK_Q) is None or \
-            _pick_block(k.shape[1], BLOCK_K) is None:
+    if not _kernels_take(("fwd", "dq", "dkv"), q, k, causal, window,
+                         q_seg is not None):
         return _xla_flash(q, k, v, q_off, k_off, causal, q_seg=q_seg,
                           k_seg=k_seg, window=window), \
             (q, k, v, q_seg, k_seg, None, None)
     offs = jnp.asarray([q_off, k_off], jnp.int32)
-    o, lse = _pallas_attention_fwd_train(q, k, v, offs, causal, interpret,
-                                         q_seg=q_seg, k_seg=k_seg,
-                                         window=window)
+    o, lse = _flash_forward(q, k, v, offs, causal, interpret, "train",
+                            q_seg=q_seg, k_seg=k_seg, window=window)
     return o, (q, k, v, q_seg, k_seg, o, lse)
 
 
@@ -942,8 +1092,8 @@ def flash_attention(q, k, v, causal: bool = True, q_off: int = 0,
     ``q_segment_ids``/``k_segment_ids`` (int [B, T]): packed-sequence
     masking — a token attends only to keys with its segment id (composed
     with the causal mask). The Mosaic kernels stream the ids as extra
-    (1, block) int32 tiles; the mask composes at trace time so the
-    segment-free path compiles unchanged.
+    (heads, chunk, 1) int32 blocks; the mask composes at trace time so
+    the segment-free path compiles unchanged.
     """
     B, Tq, H, D = q.shape
 

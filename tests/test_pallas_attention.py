@@ -43,6 +43,17 @@ def _qkv(B=2, T=32, H=4, D=16, seed=0):
     return mk(seed), mk(seed + 1), mk(seed + 2)
 
 
+def _force_plan(monkeypatch, tile_cap=None, heads_cap=None, chunk_cap=None):
+    """Lower the constants ``kernel_plan`` works from: a sub-tile forced
+    small makes a short sequence walk many tiles."""
+    from horovod_tpu.ops import pallas_attention as pa
+
+    for name, cap in (("_TILE_CAP", tile_cap), ("_MAX_HEADS", heads_cap),
+                      ("_CHUNK_CAP", chunk_cap)):
+        if cap is not None:
+            monkeypatch.setattr(pa, name, cap)
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_matches_dense_oracle(causal):
     q, k, v = _qkv()
@@ -73,9 +84,16 @@ def test_sliding_window_matches_dense(window):
 
 
 def test_sliding_window_tile_culling():
-    # T=1536 -> three 512-wide K tiles with window=64 << 512: whole
-    # out-of-window K tiles hit the cull predicate (a sign/off-by-one
-    # error there drops a LIVE tile and this comparison catches it).
+    # T=1536 with window=64 << the sub-tile: whole out-of-window K
+    # sub-tiles lie outside the walk's bounds (a sign/off-by-one error
+    # there drops a LIVE tile and this comparison catches it).
+    from horovod_tpu.ops import pallas_attention as pa
+
+    plan = pa.kernel_plan(1, 1536, 1536, 8, jnp.float32, True, 64)
+    n_q, n_k = 1536 // plan.tile_q, 1536 // plan.tile_k
+    causal_tiles = pa.kernel_plan(1, 1536, 1536, 8, jnp.float32,
+                                  True).tiles_visited
+    assert n_k >= 3 and plan.tiles_visited <= 2 * n_q - 1 < causal_tiles
     q, k, v = _qkv(B=1, T=1536, H=1, D=8)
     out = flash_attention(q, k, v, causal=True, use_pallas=True,
                           window=64)
@@ -180,16 +198,20 @@ def test_gradients_match_dense():
                                    rtol=1e-4, atol=1e-4)
 
 
-def test_gradients_multi_tile(monkeypatch):
+@pytest.mark.parametrize("chunk_cap", [None, 128])
+def test_gradients_multi_tile(monkeypatch, chunk_cap):
     # T spans several q/k tiles: the backward kernels' VMEM accumulation
-    # across the sequential grid dimension is exercised (dq over k tiles,
-    # dk/dv over q tiles). Tile caps are shrunk so T=256 genuinely yields
-    # a 4x4 tile grid — at the default 512 cap a 256-token sequence is a
-    # single tile and the accumulation logic would be dead in this test.
+    # along the walk is exercised (dq over k tiles, dk/dv over q tiles),
+    # and with a chunk of 128 across the sequential grid dimension too.
+    # The sub-tile is forced to 64 so T=256 genuinely yields a 4x4 walk —
+    # at the plan's own sub-tile a 256-token sequence is a single tile
+    # and the accumulation logic would be dead in this test.
     from horovod_tpu.ops import pallas_attention as pa
 
-    monkeypatch.setattr(pa, "BLOCK_Q", 64)
-    monkeypatch.setattr(pa, "BLOCK_K", 64)
+    _force_plan(monkeypatch, tile_cap=64, chunk_cap=chunk_cap)
+    plan = pa.kernel_plan(2, 256, 256, 8, jnp.float32, True, kind="dkv")
+    assert plan.tile_q == plan.tile_k == 64
+    assert plan.grid[1:] == ((1, 1) if chunk_cap is None else (2, 2))
     q, k, v = _qkv(B=1, T=256, H=2, D=8)
 
     def loss_flash(q, k, v):
@@ -372,3 +394,238 @@ def test_ring_attention_gradients():
     for name, a, b in zip("qkv", gr, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4, err_msg=name)
+
+
+# ---------------------------------------------------------------------------
+# The grid step of PR 27: several heads and a resident chunk a step, the
+# walk over sub-tiles inside the kernel. Oracle: the XLA twins
+# (``use_pallas=False`` is ``_xla_flash`` and its autodiff).
+# ---------------------------------------------------------------------------
+
+_TOL = {jnp.float32: dict(fwd=dict(rtol=2e-5, atol=2e-5),
+                          grad=dict(rtol=2e-4, atol=1e-4)),
+        jnp.bfloat16: dict(fwd=dict(rtol=2e-2, atol=2e-2),
+                           grad=dict(rtol=1e-1, atol=1e-1))}
+
+
+def _fwd_and_grads(q, k, v, use_pallas, **kw):
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, use_pallas=use_pallas,
+                              **kw)
+        return jnp.sum(out.astype(jnp.float32) ** 2) / out.shape[1], out
+
+    (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                         has_aux=True)(q, k, v)
+    return (out, *grads)
+
+
+def _assert_matches_xla(q, k, v, dtype, **kw):
+    got = _fwd_and_grads(*(x.astype(dtype) for x in (q, k, v)), True, **kw)
+    # The oracle takes the float32 inputs for bf16 too, as
+    # test_gradients_bf16 does.
+    want = _fwd_and_grads(q, k, v, False, **kw)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == dtype, name
+        assert np.abs(np.asarray(a, np.float32)).max() > 0, name
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            err_msg=name, **_TOL[dtype]["fwd" if name == "out" else "grad"])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("B,T,H,D,caps,heads", [
+    # gpt2s-t128: the preferred 16 heads a step do not divide B*H = 24.
+    (2, 128, 12, 64, {}, 12),
+    # ... and 8 heads a step span the batch boundary.
+    (2, 128, 12, 64, {"heads_cap": 8}, 8),
+    # gpt2s-t1024: two heads a step, the whole sequence resident.
+    (1, 1024, 12, 64, {}, 2),
+    # olmoe's head width, several sub-tiles a chunk and two chunks a side.
+    (1, 1024, 16, 128, {"chunk_cap": 512, "tile_cap": 128}, 8),
+])
+def test_cell_shapes_match_xla(monkeypatch, dtype, B, T, H, D, caps,
+                               heads):
+    from horovod_tpu.ops import pallas_attention as pa
+
+    _force_plan(monkeypatch, **caps)
+    for kind in ("fwd", "dq", "dkv"):
+        plan = pa.kernel_plan(B * H, T, T, D, dtype, True, kind=kind)
+        assert plan.heads == heads and plan.grid[0] == B * H // heads
+        if "chunk_cap" in caps:
+            assert plan.grid[1:] == (2, 2) and plan.chunk_q // plan.tile_q > 1
+    q, k, v = _qkv(B=B, T=T, H=H, D=D, seed=11)
+    _assert_matches_xla(q, k, v, dtype)
+
+
+@pytest.mark.parametrize("caps", [{"tile_cap": 64},
+                                  {"tile_cap": 64, "chunk_cap": 128}])
+def test_segment_ids_multi_tile(monkeypatch, caps):
+    # Segment boundaries inside and on sub-tile edges; every visited tile
+    # takes the segment mask, interior ones included.
+    _force_plan(monkeypatch, **caps)
+    q, k, v = _qkv(B=2, T=256, H=2, D=16, seed=5)
+    seg = jnp.asarray(np.repeat([[0, 1, 2, 3]], 2, axis=0).repeat(
+        [40, 88, 64, 64], axis=1), jnp.int32)
+    _assert_matches_xla(q, k, v, jnp.float32, q_segment_ids=seg,
+                        k_segment_ids=seg)
+
+
+@pytest.mark.parametrize("window,caps", [
+    (16, {"tile_cap": 64}),                      # narrower than a sub-tile
+    (100, {"tile_cap": 64}),                     # straddles sub-tiles
+    (200, {"tile_cap": 32, "chunk_cap": 64}),    # wider than a chunk
+])
+def test_window_multi_tile(monkeypatch, window, caps):
+    _force_plan(monkeypatch, **caps)
+    q, k, v = _qkv(B=1, T=256, H=2, D=16, seed=7)
+    _assert_matches_xla(q, k, v, jnp.float32, window=window)
+    ref = _dense(q, k, v, True, window=window)
+    out = flash_attention(q, k, v, causal=True, use_pallas=True,
+                          window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("q_off,k_off", [(128, 0), (64, 64), (0, 128),
+                                         (96, 32), (32, 96)])
+@pytest.mark.parametrize("window", [None, 80])
+def test_ring_block_offsets(monkeypatch, q_off, k_off, window):
+    # A ring block strictly below, on and above the diagonal, and two
+    # that the diagonal cuts off-centre: the offsets enter the walk's
+    # bounds. State, forward and the block gradients against the twins.
+    from horovod_tpu.ops import pallas_attention as pa
+
+    _force_plan(monkeypatch, tile_cap=32)
+    q, k, v = _qkv(B=1, T=128, H=2, D=16, seed=9)
+    for a, b in zip(
+            pa.flash_attention_block(q, k, v, q_off, k_off, use_pallas=True,
+                                     window=window),
+            pa.flash_attention_block(q, k, v, q_off, k_off,
+                                     use_pallas=False, window=window)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-5, atol=2e-5)
+    out = flash_attention(q, k, v, causal=True, q_off=q_off, k_off=k_off,
+                          use_pallas=True, window=window)
+    ref = flash_attention(q, k, v, causal=True, q_off=q_off, k_off=k_off,
+                          use_pallas=False, window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    rng = np.random.RandomState(3)
+    do = jnp.asarray(rng.randn(*q.shape), jnp.float32)
+    lse = jnp.asarray(rng.rand(1, 2, 128) + 3.0, jnp.float32)
+    delta = jnp.asarray(rng.randn(1, 2, 128) * 0.1, jnp.float32)
+    for name, a, b in zip(
+            ("dq", "dk", "dv"),
+            pa.flash_attention_block_grads(q, k, v, do, lse, delta, q_off,
+                                           k_off, use_pallas=True,
+                                           window=window),
+            pa.flash_attention_block_grads(q, k, v, do, lse, delta, q_off,
+                                           k_off, use_pallas=False,
+                                           window=window)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+def _live_tiles(Tq, Tk, tq, tk, window):
+    """Brute force: the [tq, tk] sub-tiles in which the causal mask and
+    the window keep any element."""
+    iq = np.arange(Tq)[:, None]
+    ik = np.arange(Tk)[None, :]
+    keep = iq >= ik
+    if window is not None:
+        keep &= iq - ik < window
+    return int(keep.reshape(Tq // tq, tq, Tk // tk, tk).any(axis=(1, 3)).sum())
+
+
+@pytest.mark.parametrize("kind", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("BH,T,D,window,heads", [
+    (768, 128, 64, None, 16),      # gpt2s-t128
+    (96, 1024, 64, None, 2),       # gpt2s-t1024
+    (32, 4096, 128, None, 1),      # olmoe-t4096
+    (32, 4096, 128, 1000, 1),
+    (96, 1024, 64, 100, 2),
+])
+def test_kernel_plan_at_cell_shapes(kind, BH, T, D, window, heads):
+    from horovod_tpu.ops import pallas_attention as pa
+
+    plan = pa.kernel_plan(BH, T, T, D, jnp.bfloat16, True, window,
+                          kind=kind)
+    assert plan.heads == heads
+    # The whole sequence is resident at all three cells' shapes.
+    assert (plan.chunk_q, plan.chunk_k) == (T, T)
+    assert plan.grid == (BH // heads, 1, 1)
+    assert T % plan.tile_q == 0 and T % plan.tile_k == 0
+    assert plan.vmem_bytes <= pa.VMEM_BUDGET
+    # Exactly the tiles the mask leaves, with segment ids too.
+    assert plan.tiles_visited == _live_tiles(T, T, plan.tile_q, plan.tile_k,
+                                             window)
+    seg = pa.kernel_plan(BH, T, T, D, jnp.bfloat16, True, window, kind=kind,
+                         segments=True)
+    assert seg.tiles_visited == plan.tiles_visited
+
+
+def test_kernel_plan_long_sequence_keeps_chunk_grid(monkeypatch):
+    from horovod_tpu.ops import pallas_attention as pa
+
+    for kind in ("fwd", "dq", "dkv"):
+        plan = pa.kernel_plan(8, 65536, 65536, 128, jnp.bfloat16, True,
+                              kind=kind)
+        assert plan.vmem_bytes <= pa.VMEM_BUDGET
+        assert plan.chunk_q < 65536 and plan.chunk_q % plan.tile_q == 0
+        n = 65536 // plan.chunk_q
+        assert plan.grid == (8 // plan.heads, n, n)
+    # Non-causal: every tile.
+    plan = pa.kernel_plan(4, 512, 1024, 64, jnp.float32, False)
+    assert plan.tiles_visited == (512 // plan.tile_q) * (1024 // plan.tile_k)
+    # Counted chunk by chunk, the walk is still the mask's.
+    _force_plan(monkeypatch, chunk_cap=1024)
+    for kind in ("fwd", "dq", "dkv"):
+        short = pa.kernel_plan(8, 4096, 4096, 128, jnp.bfloat16, True,
+                               kind=kind)
+        assert short.grid[1:] == (4, 4)
+        assert short.tiles_visited == _live_tiles(4096, 4096, short.tile_q,
+                                                  short.tile_k, None)
+
+
+@pytest.mark.parametrize("kind", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("segments", [False, True])
+@pytest.mark.parametrize("BH,T,D,dtype", [
+    (8, 2048, 256, jnp.bfloat16),
+    (4, 1024, 1024, jnp.float32),
+    (2, 8192, 512, jnp.float32),
+    (2, 512, 8192, jnp.float32),     # no chunk of this width fits
+    (2, 4104, 64, jnp.float32),      # 8 x 513: 8-wide sub-tiles only
+])
+def test_kernel_plan_holds_its_budget_or_declines(kind, segments, BH, T, D,
+                                                  dtype):
+    # Wide heads: a plan either counts itself under the budget it states
+    # (and ``vmem_limit_bytes`` follows the count), or there is none and
+    # the callers take the XLA twins. Never a plan over the budget.
+    from horovod_tpu.ops import pallas_attention as pa
+
+    plan = pa.kernel_plan(BH, T, T, D, dtype, True, segments=segments,
+                          kind=kind)
+    if D == 8192:
+        assert plan is None
+    else:
+        assert plan.vmem_bytes <= pa.VMEM_BUDGET
+        assert T % plan.chunk_q == 0 and plan.chunk_q % plan.tile_q == 0
+
+
+def test_untileable_and_too_wide_fall_back_to_xla(monkeypatch):
+    # No plan (T with no 8-divisor; a budget nothing fits): forward and
+    # gradients are the XLA twins', and no kernel is traced.
+    from horovod_tpu.ops import pallas_attention as pa
+
+    assert pa.kernel_plan(4, 100, 100, 16, jnp.float32, True) is None
+    monkeypatch.setattr(pa, "VMEM_BUDGET", 1 << 16)
+    monkeypatch.setattr(
+        pa, "_flash_call",
+        lambda *a, **kw: pytest.fail("a kernel was traced without a plan"))
+    q, k, v = _qkv(B=1, T=256, H=2, D=16, seed=3)
+    assert pa.kernel_plan(2, 256, 256, 16, jnp.float32, True) is None
+    _assert_matches_xla(q, k, v, jnp.float32)
+    for a, b in zip(
+            pa.flash_attention_block(q, k, v, 0, 0, use_pallas=True),
+            pa.flash_attention_block(q, k, v, 0, 0, use_pallas=False)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

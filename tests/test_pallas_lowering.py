@@ -113,3 +113,33 @@ def test_segment_id_kernels_lower_with_small_blocks(mosaic):
 
     txt = _export_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
     assert txt.count("tpu_custom_call") >= 2
+
+
+# The benchmark's three decoder cells, batch cut: (B, T, H, D).
+_CELL_SHAPES = {"gpt2s-t128": (4, 128, 12, 64),
+                "gpt2s-t1024": (1, 1024, 12, 64),
+                "olmoe-t4096": (1, 4096, 16, 128)}
+
+
+@pytest.mark.parametrize("segments", [False, True])
+@pytest.mark.parametrize("cell", sorted(_CELL_SHAPES))
+def test_cell_shapes_lower_to_mosaic(mosaic, cell, segments):
+    """The grid step ``kernel_plan`` picks at each cell's shape (several
+    heads a step at T 128, the whole sequence resident at T 4,096, the
+    in-kernel walk with its run-time bounds) lowers in all three passes: a
+    step Mosaic refuses fails here, off the chip."""
+    B, T, H, D = _CELL_SHAPES[cell]
+    q, k, v = _qkv(B=B, T=T, H=H, D=D)
+    seg = jnp.zeros((B, T), jnp.int32) if segments else None
+
+    def loss(q, k, v):
+        return pa.flash_attention(
+            q, k, v, causal=True, q_segment_ids=seg,
+            k_segment_ids=seg).astype(jnp.float32).sum()
+
+    txt = _export_tpu(loss, q, k, v)
+    assert txt.count("tpu_custom_call") == 1 and "flash_fwd" in txt
+    txt = _export_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+    assert txt.count("tpu_custom_call") == 3
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert name in txt

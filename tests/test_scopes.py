@@ -184,14 +184,16 @@ _ROW = jax.ShapeDtypeStruct((2, 16, 1), jnp.float32)
 _OFFS = jax.ShapeDtypeStruct((2,), jnp.int32)
 PALLAS_SITES = {
     "block_state": (
-        lambda q, offs: pa._pallas_block_state(q, q, q, offs, True, True),
+        lambda q, offs: pa._flash_forward(q, q, q, offs, True, True,
+                                          "state"),
         (_Q, _OFFS), ["flash_fwd"]),
     "forward": (
-        lambda q: pa._pallas_attention_fwd(q, q, q, 0, 0, True, True),
-        (_Q,), ["flash_fwd"]),
+        lambda q, offs: pa._flash_forward(q, q, q, offs, True, True,
+                                          "plain"),
+        (_Q, _OFFS), ["flash_fwd"]),
     "forward_train": (
-        lambda q, offs: pa._pallas_attention_fwd_train(q, q, q, offs, True,
-                                                       True),
+        lambda q, offs: pa._flash_forward(q, q, q, offs, True, True,
+                                          "train"),
         (_Q, _OFFS), ["flash_fwd"]),
     "backward": (
         lambda q, row, offs: pa._pallas_bwd(q, q, q, q, row, row, offs,

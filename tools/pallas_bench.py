@@ -80,7 +80,7 @@ def _build_xla_cache(T, iters, batch, heads, dim, causal=True,
 
 def bench_one(T, iters, batch, heads, dim, causal=True, xla_cache=None,
               window=None):
-    """Mosaic vs XLA at the current BLOCK_Q/BLOCK_K. ``xla_cache`` — a
+    """Mosaic vs XLA at ``kernel_plan``'s grid step. ``xla_cache`` — a
     dict from :func:`_build_xla_cache` — skips re-running the
     block-size-invariant XLA baseline (timings AND the numerics-oracle
     outputs/grads; the sweep reuses both)."""
@@ -117,33 +117,32 @@ def bench_one(T, iters, batch, heads, dim, causal=True, xla_cache=None,
 
 
 def sweep_blocks(T, iters, batch, heads, dim):
-    """Time the Mosaic kernels across (BLOCK_Q, BLOCK_K) tilings to pick
-    the VMEM-fit sweet spot per chip generation. Fresh jit wrappers per
-    config re-trace with the patched module constants."""
+    """Time the Mosaic kernels across ``kernel_plan``'s sub-tile cap
+    (``_TILE_CAP``: the [t, t] score tile of the in-kernel walk), to
+    check its choice per chip generation. Fresh jit wrappers per config
+    re-trace with the patched cap."""
     import horovod_tpu.ops.pallas_attention as pa
 
-    orig = (pa.BLOCK_Q, pa.BLOCK_K)
+    cap = pa._TILE_CAP
     # Block-size-invariant: built once up front (before any Pallas config
     # can fail), reused across every config.
     xla_cache = _build_xla_cache(T, iters, batch, heads, dim)
     try:
-        for bq in (256, 512, 1024):
-            for bk in (256, 512, 1024):
-                pa.BLOCK_Q, pa.BLOCK_K = bq, bk
-                try:
-                    rows, xla_cache = bench_one(T, iters, batch, heads,
-                                                dim, xla_cache=xla_cache)
-                except Exception as e:  # VMEM overflow etc.: report, go on
-                    print(json.dumps({"seq_len": T, "block_q": bq,
-                                      "block_k": bk,
-                                      "error": str(e)[:200]}))
-                    continue
-                for row in rows:
-                    row["block_q"], row["block_k"] = bq, bk
-                    print(json.dumps(row))
-                    sys.stdout.flush()
+        for tile in (128, 256, 512, 1024):
+            pa._TILE_CAP = tile
+            try:
+                rows, xla_cache = bench_one(T, iters, batch, heads, dim,
+                                            xla_cache=xla_cache)
+            except Exception as e:  # VMEM overflow etc.: report, go on
+                print(json.dumps({"seq_len": T, "tile": tile,
+                                  "error": str(e)[:200]}))
+                continue
+            for row in rows:
+                row["tile"] = tile
+                print(json.dumps(row))
+                sys.stdout.flush()
     finally:
-        pa.BLOCK_Q, pa.BLOCK_K = orig
+        pa._TILE_CAP = cap
 
 
 def main(argv=None):
@@ -157,7 +156,7 @@ def main(argv=None):
                    help="sliding-window width: measures the whole-tile "
                         "culling speedup vs the XLA masked path")
     p.add_argument("--sweep-blocks", action="store_true",
-                   help="sweep (BLOCK_Q, BLOCK_K) tilings per seq len")
+                   help="sweep kernel_plan's sub-tile cap per seq len")
     args = p.parse_args(argv)
 
     from bench import require_accelerator

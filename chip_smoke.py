@@ -53,7 +53,7 @@ from horovod_tpu.zero import (
     gather_params, init_zero_train_state, make_zero_train_step)
 from tools.compile_cache import enable_compile_cache
 
-# tools/transformer_bench.py's default configuration: a GPT-2-small-class
+# The `gpt2s` cells' model (benchmark/configs/gpt2s.json): a GPT-2-small-class
 # decoder at its published widths (d 768, 12 heads, 12 layers, vocabulary
 # 50,304, 1,024 tokens), bf16, AdamW.
 DECODER = dict(vocab=50304, d_model=768, n_heads=12, d_head=64, d_ff=3072,
@@ -269,8 +269,8 @@ def _permute_ring_sizes(hlo_text):
 def phase_resnet(model, batch_per_chip, image_size, steps,
                  bucket_cap_bytes="auto", num_classes=1000):
     """The data-parallel trainer over ``hvd.mesh()``, built as
-    ``bench.py`` builds it (SGD+momentum, synthetic ImageNet-shaped
-    batch). Returns what the ZeRO phase compares with."""
+    the ``resnet50`` cells build it (SGD+momentum, synthetic
+    ImageNet-shaped batch). Returns what the ZeRO phase compares with."""
     mesh, n = hvd.mesh(), hvd.size()
     optimizer = optax.sgd(0.01, momentum=0.9)
     sample = jnp.zeros((1, image_size, image_size, 3), jnp.float32)

@@ -751,7 +751,7 @@ class NativeCore:
     def shm_active(self) -> bool:
         """True when this rank's shm transport is plausibly carrying
         traffic: its segment is live and not every peer attach has
-        failed (the transport choice bench.py records). False with
+        failed (the transport choice ``hvd.ring_traffic()`` reports). False with
         HOROVOD_SHM off, on init failure, in a world with no same-host
         peers, or once all attaches fell back to TCP."""
         return bool(self.lib.hvd_shm_active())
@@ -766,15 +766,15 @@ class NativeCore:
     def ring_cross_ns(self) -> int:
         """Wall-clock nanoseconds this rank spent inside cross-host
         leader-leg exchanges (send + receive + pipelined accumulate,
-        whichever transport carried them) — the leg-local timing the
-        ``--cross-leg`` A/B compares."""
+        whichever transport carried them) — the leg-local timing
+        ``docs/stripe_transport_ab.json`` compared."""
         return int(self.lib.hvd_ring_cross_ns())
 
     def ring_stripe_count(self) -> int:
         """The stripe count in ACTIVE use: K once at least one leader
         pair carries striped traffic, 0 with striping off
         (HOROVOD_STRIPES unset/1) or once every pair fell back to
-        single-socket TCP (the transport choice bench.py records)."""
+        single-socket TCP (the choice ``hvd.ring_traffic()`` reports)."""
         return int(self.lib.hvd_ring_stripe_count())
 
     def set_stripes(self, stripes: int) -> None:

@@ -1291,7 +1291,7 @@ long long hvd_ring_shm_bytes() {
 }
 
 // 1 when this rank's shm segment is live (transport registered and
-// enabled) — the transport choice bench.py records.
+// enabled) — the transport choice hvd.ring_traffic() reports.
 int hvd_shm_active() {
   auto* s = hvd::g();
   hvd::MutexLock lk(s->init_mu);
@@ -1310,8 +1310,7 @@ long long hvd_ring_stripe_bytes() {
 
 // The stripe count in ACTIVE use: K once at least one leader pair
 // carries striped traffic, 0 when striping is off or every pair fell
-// back to single-socket TCP (what hvd.ring_traffic() / bench.py
-// record).
+// back to single-socket TCP (what hvd.ring_traffic() reports).
 int hvd_ring_stripe_count() {
   auto* s = hvd::g();
   hvd::MutexLock lk(s->init_mu);
@@ -1319,7 +1318,7 @@ int hvd_ring_stripe_count() {
 }
 
 // Wall-clock nanoseconds spent inside cross-host leader-leg exchanges —
-// the leg-local timing the --cross-leg A/B compares (end-to-end
+// the leg-local timing docs/stripe_transport_ab.json compared (end-to-end
 // iteration time on an oversubscribed box is dominated by fusion copies
 // and idle members' yield-spins, which the leg never touches).
 long long hvd_ring_cross_ns() {
@@ -1342,7 +1341,7 @@ void hvd_set_stripes(int stripes) {
 // The EFFECTIVE host-plane hierarchical dispatch flags this process would
 // apply right now: the tuner's synced value when present, else the env
 // default (bit0 = allreduce, bit1 = allgather). Observability for
-// hvd.ring_traffic() / bench.py — hvd_get_hier_flags reports only the
+// hvd.ring_traffic() — hvd_get_hier_flags reports only the
 // tuned value (-1 when untuned).
 int hvd_host_hier_flags() {
   auto* s = hvd::g();

@@ -146,7 +146,7 @@ class Ring {
   // True when this rank's shm transport is plausibly carrying traffic:
   // segment live AND not every peer attach failed (a rank riding the
   // TCP fallback for every leg must not report shm as its transport
-  // choice) — what bench.py records.
+  // choice) — what hvd.ring_traffic() reports.
   bool shm_active() const { return shm_ != nullptr && shm_->Active(); }
   // Payload bytes that rode the striped cross-host transport (a subset
   // of cross_bytes_sent — striping changes the carrier, never the
@@ -158,14 +158,14 @@ class Ring {
   // The stripe count in ACTIVE use: K once at least one leader pair
   // carries striped traffic, 0 when striping is off or every pair fell
   // back to single-socket TCP (the transport-choice surface
-  // hvd.ring_traffic() / bench.py record).
+  // hvd.ring_traffic() reports).
   int stripe_count() const {
     return stripe_ ? stripe_->active_stripes() : 0;
   }
   // Wall-clock nanoseconds this rank spent inside cross-host leader-leg
   // exchanges (CrossSendRecv: duplex send+recv+pipelined accumulate,
-  // whichever backend carried it). The leg-local timing bench.py's
-  // --cross-leg A/B compares — end-to-end iteration time on an
+  // whichever backend carried it). The leg-local timing
+  // docs/stripe_transport_ab.json compared — end-to-end iteration time on an
   // oversubscribed box is dominated by fusion copies and idle members'
   // yield-spins, which the leg never touches.
   long long cross_leg_ns() const { return cross_ns_.load(); }

@@ -117,7 +117,7 @@ class StripeTransport : public TransportBackend {
   // Observability (atomics: polled by monitor threads through shutdown
   // — the PR 5/7 getter-race class). `active_stripes` reports K once at
   // least one pair actually carries striped traffic, else 0 — the
-  // transport-choice surface bench.py records must not claim striping
+  // transport-choice surface hvd.ring_traffic() reports must not claim striping
   // when every pair fell back.
   long long bytes_sent() const { return bytes_sent_.load(); }
   int active_stripes() const {

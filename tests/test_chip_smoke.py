@@ -4,7 +4,7 @@ file keeps it from rotting between chip runs, without the chip:
 - its phases, imported and run at toy sizes on the CPU mesh (kernels
   interpreted) — wrong paths, arguments, meshes and sharding rules;
 - the program refusing to stand the CPU in for the chip (chip_smoke.py,
-  bench.py, the peak table);
+  benchmark/run.py, the peak table);
 - the compile-cache helper;
 - the attention kernels compiled for a *described* v5e chip at the real
   widths: what the chip's compiler would refuse fails here.
@@ -25,17 +25,20 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-import bench  # noqa: E402
 import chip_smoke  # noqa: E402
 import horovod_tpu.ops.pallas_attention as pa  # noqa: E402
+from benchmark import flops  # noqa: E402
 from tools import compile_cache  # noqa: E402
 
+BENCHMARK_RUN = ("benchmark/run.py", "--workload", "gpt2s-t128", "--seed",
+                 "1", "--seconds", "1")
 
-def _run(script):
+
+def _run(script, *args):
     from conftest import subprocess_cpu_env
 
     return subprocess.Popen(
-        [sys.executable, os.path.join(REPO, script)], cwd=REPO,
+        [sys.executable, os.path.join(REPO, script), *args], cwd=REPO,
         env=subprocess_cpu_env(), stdout=subprocess.PIPE,
         stderr=subprocess.PIPE, text=True)
 
@@ -44,7 +47,8 @@ def _run(script):
 def no_chip_runs():
     """Both programs started on the CPU backend at once, so they run
     while the other tests do; each test below waits for its own."""
-    procs = {s: _run(s) for s in ("chip_smoke.py", "bench.py")}
+    procs = {cmd[0]: _run(*cmd)
+             for cmd in (("chip_smoke.py",), BENCHMARK_RUN)}
     yield procs
     for p in procs.values():
         p.kill()
@@ -163,18 +167,19 @@ def test_chip_smoke_without_a_chip_fails(no_chip_runs):
     assert "resnet" not in out  # no phase ran on the CPU
 
 
-def test_bench_without_a_chip_prints_no_rate(no_chip_runs):
-    out, err = no_chip_runs["bench.py"].communicate(timeout=120)
-    assert no_chip_runs["bench.py"].returncode != 0, out + err
-    assert out.strip() == ""  # no JSON line, no throughput
-    assert "no accelerator" in err
+def test_benchmark_without_a_chip_prints_no_result(no_chip_runs):
+    run = no_chip_runs["benchmark/run.py"]
+    out, err = run.communicate(timeout=120)
+    assert run.returncode == 3, out + err  # harness.NoChip
+    assert "{" not in out  # no result line
+    assert "no TPU" in err
 
 
-def test_peak_flops_unknown_device_raises():
-    with pytest.raises(ValueError, match="no peak"):
-        bench._peak_flops("unknown")
+def test_peaks_unknown_device_raises():
+    with pytest.raises(ValueError, match="no peaks"):
+        flops.peaks_for("unknown")
     # What the installed runtime reports for a v5e chip.
-    assert bench._peak_flops("TPU v5 lite") == 197e12
+    assert flops.peaks_for("TPU v5 lite")["bf16_flops_per_s"] == 197e12
 
 
 # ---- the compile cache helper ----------------------------------------------

@@ -311,13 +311,26 @@ def test_elastic_keras_end_to_end(tmp_path):
     env["HVD_REPO"] = os.path.dirname(
         os.path.dirname(os.path.abspath(__file__)))
     env["JAX_PLATFORMS"] = "cpu"
-    proc = subprocess.run(
-        [sys.executable, "-m", "horovod_tpu.run",
-         "-np", "2", "--min-np", "2",
-         "--host-discovery-script", str(discover),
-         "--cycle-time-ms", "1.0",
-         sys.executable, str(script)],
-        env=env, capture_output=True, text=True, timeout=300)
+
+    def launch():
+        return subprocess.run(
+            [sys.executable, "-m", "horovod_tpu.run",
+             "-np", "2", "--min-np", "2",
+             "--host-discovery-script", str(discover),
+             "--cycle-time-ms", "1.0",
+             sys.executable, str(script)],
+            env=env, capture_output=True, text=True, timeout=300)
+
+    proc = launch()
+    if proc.returncode != 0 and "failed to listen on port" in (
+            proc.stdout + proc.stderr):
+        # The launcher picks the controller port by binding port 0 and
+        # closing it (run/launch.py::free_port); rank 0 binds it again
+        # only after it has imported TensorFlow, and on a loaded box
+        # another process can be handed the port in between. That race is
+        # the launcher's (ROADMAP D0), not what this test holds: launch
+        # once more.
+        proc = launch()
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "KELASTIC_RANK_0_DONE" in proc.stdout
     assert "KELASTIC_RANK_1_DONE" in proc.stdout

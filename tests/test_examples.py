@@ -157,16 +157,6 @@ def test_elastic_tf2_synthetic_example_single():
     assert "img/sec per worker" in out
 
 
-@pytest.mark.full
-def test_scaling_bench_protocol_runs():
-    out = _run_example(
-        "scaling_bench.py", "--cpu-devices", "4", "--devices", "1", "2",
-        "--batch-size", "2", "--image-size", "32", "--num-classes", "10",
-        "--num-warmup", "1", "--num-iters", "2", timeout=420)
-    assert '"metric": "scaling_efficiency"' in out
-    assert "efficiency vs" in out
-
-
 @pytest.mark.parametrize("strategy", ["ring", "ulysses"])
 def test_long_context_example(strategy):
     out = _run_example(

@@ -6,7 +6,7 @@ only the fusion plan differs). Tier-1 safe: small model, few iterations,
 and NO assertion that bucketed is faster — on 8 *virtual* CPU devices the
 collectives are memcpys and overlap cannot win; the structural win is
 asserted (instruction count), the timing is reported for trend tracking.
-On real ICI the same pair is driven by ``bench.py --bucket-mb``.
+On real ICI such an A/B is a PR judged in the ``resnet50-dp4`` cell.
 
 On jax 0.9 the structural win is gone: XLA's combiner packs the buckets
 back into one all-reduce (strict xfail below; ROADMAP S5).

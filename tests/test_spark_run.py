@@ -8,6 +8,7 @@ allreduces across ranks."""
 
 import sys
 import threading
+import time
 import types
 
 import pytest
@@ -192,8 +193,16 @@ def test_registration_timeout_shuts_down_registered_tasks():
     p = ctx.Process(target=task_main, args=(0, driver.addresses(), key))
     p.start()
     try:
+        # The child has to import and register first; on a loaded box
+        # that alone can outlast the driver's short timeout, and a task
+        # that has not registered is (rightly) sent nothing.
+        deadline = time.monotonic() + 300
+        while not driver.task_addresses_for_driver(0):
+            assert p.is_alive() and time.monotonic() < deadline, \
+                "task 0 never registered"
+            time.sleep(0.1)
         with pytest.raises(TimeoutError):
-            driver.wait_for_initial_registration(5)
+            driver.wait_for_initial_registration(2)
         # The fix: the driver's error path shuts down registered tasks.
         shutdown_registered_tasks(driver, 2, key)
         p.join(timeout=30)

@@ -5,8 +5,8 @@ Byte accounting is over the state's committed device buffers
 (``addressable_shards`` on one device) — the steady-state footprint a
 training loop actually holds between steps. Transients (the gathered
 bucket in flight, the scatter payload) are bounded by the bucket cap and
-are the price of the step, not the residency; the bench
-(``bench.py --workload zero``) tracks the peak including them.
+are the price of the step, not the residency; ``step_mem_GiB`` of a
+benchmark cell would count them (no cell runs ZeRO yet: PERF.md, 7).
 
 The analytic model this pins (plain fp32 SGD, no momentum):
 

@@ -1,8 +1,8 @@
 """Where this checkout keeps jax's persistent compilation cache.
 
-Every entry point that compiles for the chip (``chip_smoke.py``,
-``bench.py``, the ``tools/*_bench.py`` scripts) calls
-:func:`enable_compile_cache` before its first compile, so the runs of one
+``chip_smoke.py`` and ``tools/pallas_bench.py`` call
+:func:`enable_compile_cache` before their first compile (the benchmark
+keeps the same rule in ``harness.enable_compile_cache``), so the runs of one
 command share their compilations: a cold ResNet-50 step alone is most of
 a minute of compile on a v5e chip.
 """

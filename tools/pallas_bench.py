@@ -159,12 +159,13 @@ def main(argv=None):
                    help="sweep kernel_plan's sub-tile cap per seq len")
     args = p.parse_args(argv)
 
-    from bench import require_accelerator
+    from benchmark.harness import claim_devices
     from tools.compile_cache import enable_compile_cache
 
     print(f"bench: compile cache at {enable_compile_cache()}",
           file=sys.stderr)
-    print(json.dumps(require_accelerator()))
+    _, device, _ = claim_devices(1)  # no TPU: exit 3, nothing timed
+    print(json.dumps(device))
     for T in [int(t) for t in args.seq_lens.split(",")]:
         if args.sweep_blocks:
             sweep_blocks(T, args.iters, args.batch, args.heads, args.dim)

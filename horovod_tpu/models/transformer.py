@@ -304,11 +304,11 @@ def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
     """
     norm = _block_norm(cfg)
 
-    def layer(x, lp, seg, gathered_seg):
+    def layer(x, lp, seg, gathered_seg, experts=None):
         with jax.named_scope("attention"):
             x = attention_block(x, lp, seg, gathered_seg)
         with jax.named_scope("moe" if cfg.use_moe else "mlp"):
-            return feed_forward_block(x, lp)
+            return feed_forward_block(x, lp, experts)
 
     def attention_block(x, lp, seg, gathered_seg):
         # tp-sharded heads, sp ring
@@ -341,13 +341,15 @@ def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
         out = lax.psum(out, "tp")  # combine head shards
         return x + out
 
-    def feed_forward_block(x, lp):
+    def feed_forward_block(x, lp, experts):
         h = norm(x, lp["ln2"])
         if cfg.use_moe:
+            stacks, index = experts
             y, stats = moe_layer(
                 h, {k: lp[k] for k in ("router", "wg", "wu", "wd")},
                 axis_name="dp", top_k=cfg.moe_top_k,
-                norm_topk_prob=cfg.norm_topk_prob, seq_axis_name="sp")
+                norm_topk_prob=cfg.norm_topk_prob, seq_axis_name="sp",
+                stacks=stacks, layer=index)
             return x + y, stats
         y = jax.nn.gelu(jnp.einsum("btd,df->btf", h, lp["w1"]))
         y = jnp.einsum("btf,fd->btd", y, lp["w2"])
@@ -371,19 +373,32 @@ def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
 
                 gathered = gather_segment_ids(seg, "sp")
 
-        def body(x, lp):
-            out = layer_fn(x, lp, seg, gathered)
-            return out if cfg.use_moe else (out, None)
+        if not cfg.use_moe:
+            x, _ = lax.scan(
+                lambda x, lp: (layer_fn(x, lp, seg, gathered), None), x,
+                stage_params)
+        else:
+            # The expert kernels read a layer's matrices out of the
+            # stage's stacks, constants of the scan, by the layer's index
+            # (a Mosaic call cannot take the scan's slice without a copy
+            # of it); the slices are still the leaves the scan returns
+            # the weight gradients for, one layer an iteration.
+            stacks = {k: lax.stop_gradient(stage_params[k])
+                      for k in ("wg", "wu", "wd")}
+            n_local = stage_params["wg"].shape[0]
 
-        x, layers = lax.scan(body, x, stage_params)
-        if cfg.use_moe:
-            lps = layers["load"].shape[0]
+            def body(x, scanned):
+                lp, index = scanned
+                return layer_fn(x, lp, seg, gathered, (stacks, index))
+
+            x, layers = lax.scan(
+                body, x, (stage_params, jnp.arange(n_local)))
             stats = {
                 "lb": stats["lb"] + jnp.sum(layers["lb"]) / cfg.n_layers,
                 "z": stats["z"] + jnp.sum(layers["z"]) / cfg.n_layers,
                 "load": lax.dynamic_update_slice_in_dim(
                     stats["load"], layers["load"].astype(jnp.int32),
-                    lax.axis_index("pp") * lps, axis=0)}
+                    lax.axis_index("pp") * n_local, axis=0)}
         out = (x,) + ((seg,) if packed else ()) + (
             (stats,) if cfg.use_moe else ())
         return out if len(out) > 1 else x

@@ -8,7 +8,9 @@ parameters are bf16, float32 accumulation on the MXU) whose sizes are
 data, so every shape is static: on the chip jax's Pallas grouped matmul
 (``megablox``) under the scope ``moe_gmm``, elsewhere XLA's
 ``lax.ragged_dot``, by the attention kernels' own rule
-(``ops.pallas_attention._resolve_dispatch``). The rows go back to their
+(``ops.pallas_attention._resolve_dispatch``). The kernels read a layer's
+matrices where they lie in a stack of layers (``moe_layer``'s ``stacks``),
+so a scan over layers copies none out for them. The rows go back to their
 tokens by the inverse permutation, already under the router's weights,
 and are summed. Dispatch and combine are gathers in both directions (the
 transpose of a permutation gather is the gather by its inverse), never a
@@ -75,22 +77,78 @@ def _take_rows_bwd(fan, inverse, g):
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-def _grouped_matmul(lhs, rhs, group_sizes):
+def _gmm_tiling(lhs, stack):
+    """The kernels' (rows, contraction, columns) tile of ``lhs`` [m, k] by
+    ``stack`` [L, g, k, n]: one tiling for the product and both of its
+    gradients."""
+    sizes = (lhs.shape[0], lhs.shape[1], stack.shape[3])
+    caps = _GMM_TILE_CAPS[lhs.dtype.itemsize]
+    return tuple(math.gcd(size, cap) for size, cap in zip(sizes, caps))
+
+
+def _gmm_of_layer(lhs, stack, layer, group_sizes, tiling, transpose_rhs,
+                  interpret):
+    """The Pallas grouped matmul of ``lhs`` with the matrices
+    ``stack[layer]``, read where they lie: the kernel takes the whole
+    stack as ``L * g`` groups of which only this layer's have rows, and
+    its index map, which skips empty groups, finds a tile's matrix at
+    ``layer * g`` + its group. A slice of the stack would have to be
+    copied out first: a custom call's operand is a buffer of its own."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    n_layers, groups = stack.shape[:2]
+    sizes = lax.dynamic_update_slice(
+        jnp.zeros(n_layers * groups, jnp.int32), group_sizes,
+        (layer * groups,))
+    return gmm(lhs, stack.reshape((-1,) + stack.shape[2:]), sizes, lhs.dtype,
+               tiling, None, None, transpose_rhs, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _stacked_gmm(lhs, rhs, stack, layer, group_sizes, interpret):
+    """``lhs @ stack[layer]`` by groups. ``rhs`` is ``stack[layer]`` as
+    the caller's leaf: never read, it is what the weight gradient is
+    returned for, [g, k, n] from this layer's groups alone, and ``stack``,
+    a constant to the caller, gets none."""
+    return _stacked_gmm_fwd(lhs, rhs, stack, layer, group_sizes,
+                            interpret)[0]
+
+
+def _stacked_gmm_fwd(lhs, rhs, stack, layer, group_sizes, interpret):
+    del rhs
+    out = _gmm_of_layer(lhs, stack, layer, group_sizes,
+                        _gmm_tiling(lhs, stack), False, interpret)
+    return out, (lhs, stack, layer, group_sizes)
+
+
+def _stacked_gmm_bwd(interpret, residuals, grad):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+    lhs, stack, layer, group_sizes = residuals
+    tiling = _gmm_tiling(lhs, stack)
+    grad_lhs = _gmm_of_layer(grad, stack, layer, group_sizes, tiling, True,
+                             interpret)
+    grad_rhs = tgmm(lhs.swapaxes(0, 1), grad, group_sizes, stack.dtype,
+                    tiling, None, stack.shape[1], interpret=interpret)
+    return grad_lhs, grad_rhs, None, None, None
+
+
+_stacked_gmm.defvjp(_stacked_gmm_fwd, _stacked_gmm_bwd)
+
+
+def _grouped_matmul(lhs, rhs, group_sizes, stack=None, layer=0):
     """``lhs[rows of group g] @ rhs[g]`` for consecutive groups of
     ``group_sizes`` rows of lhs [m, k], rhs [g, k, n]; rows past the last
     group come out zero. Operands as they are, float32 accumulation,
-    result in the operands' type."""
+    result in the operands' type. Where ``rhs`` is ``stack[layer]`` of a
+    constant ``stack`` [L, g, k, n], the kernels read it there."""
     use_pallas, interpret = _pallas_attention._resolve_dispatch(None)
     if not use_pallas:
         return lax.ragged_dot(lhs, rhs, group_sizes)
-    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
-
-    sizes = (lhs.shape[0], lhs.shape[1], rhs.shape[2])
-    caps = _GMM_TILE_CAPS[lhs.dtype.itemsize]
-    tiling = tuple(math.gcd(size, cap) for size, cap in zip(sizes, caps))
+    if stack is None:
+        stack = lax.stop_gradient(rhs)[None]
     with jax.named_scope("moe_gmm"):
-        return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype, tiling, None,
-                            None, False, interpret)
+        return _stacked_gmm(lhs, rhs, stack, layer, group_sizes, interpret)
 
 
 @jax.checkpoint
@@ -103,7 +161,8 @@ def _gated(gate, up, weight):
 
 
 def moe_layer(x, params, axis_name: str = "dp", top_k: int = 1,
-              norm_topk_prob: bool = False, seq_axis_name=None):
+              norm_topk_prob: bool = False, seq_axis_name=None,
+              stacks=None, layer=0):
     """Top-k MoE over the tokens of ``x`` [B, T, d] (local sequences).
 
     ``params``: ``router`` [d, E] float32 (replicated), and this member's
@@ -122,6 +181,14 @@ def moe_layer(x, params, axis_name: str = "dp", top_k: int = 1,
     logits; ``stats["load"]`` [E] the local tokens per expert.
     ``seq_axis_name`` names the mesh axis the T axis is sharded over, if
     any, so that ``f`` and ``P`` are those of whole sequences.
+
+    A caller that holds the experts of several layers stacked, ``wg``,
+    ``wu`` [L, E_local, d, f] and ``wd`` [L, E_local, f, d], passes them
+    as ``stacks`` (constants: under ``lax.stop_gradient``) with this
+    layer's index ``layer``, ``params`` holding the same matrices as
+    ``stacks[name][layer]``: the Pallas kernels then read the stack in
+    place and no copy of a layer's matrices is made for them, and the
+    gradient still goes to ``params``. Values are the same either way.
     """
     ep = _axis_size(axis_name)
     B, T, d = x.shape
@@ -185,10 +252,14 @@ def moe_layer(x, params, axis_name: str = "dp", top_k: int = 1,
         weight = _take_rows(gates[:, None], order, inverse, 1)
         if ep > 1:
             weight = jnp.where(mine, weight, jnp.zeros_like(weight))
-        hidden = _gated(_grouped_matmul(rows, params["wg"], group_sizes),
-                        _grouped_matmul(rows, params["wu"], group_sizes),
+
+        def experts(lhs, name):
+            return _grouped_matmul(lhs, params[name], group_sizes,
+                                   stacks[name] if stacks else None, layer)
+
+        hidden = _gated(experts(rows, "wg"), experts(rows, "wu"),
                         weight[:, 0])
-        out = _grouped_matmul(hidden, params["wd"], group_sizes)
+        out = experts(hidden, "wd")
 
     with jax.named_scope("moe_combine"):
         if ep > 1:

@@ -14,6 +14,7 @@ Nothing here is a chip result.
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -354,3 +355,103 @@ def test_expert_layer_compiles_for_v5e(described_chip, monkeypatch, dtype):
     # Dropless and sparse: no [tokens x experts x capacity] array, no
     # one-hot contraction over the experts.
     assert "65536,64," not in text
+
+
+def _expert_operands(text):
+    """Of a compiled program's Mosaic grouped matmuls (``%gmm.<n>``, whose
+    last operand is the experts' matrices): the instruction that makes
+    that operand, by kernel."""
+    made = {}
+    for line in text.splitlines():
+        name, _, rest = line.strip().removeprefix("ROOT ").partition(" = ")
+        made[name] = rest
+    return {name: made[rest.split("custom-call(")[1].split(")")[0]
+                       .split(", ")[-1].split("*/")[-1]]
+            for name, rest in made.items()
+            if name.startswith("%gmm.") and "custom-call(" in rest}
+
+
+def test_layer_scan_copies_no_expert_matrix_for_the_kernels_on_v5e(
+        described_chip, monkeypatch):
+    """The decoder's gradient over a scan of two expert layers: every
+    grouped matmul that needs a layer's matrices takes the stage's whole
+    ``[L * E, ...]`` stack, a bitcast of the parameter, and none a
+    ``dynamic-slice`` of it that XLA would first have to copy out (the
+    Mosaic call's operand is a buffer of its own); handed the slices, as
+    before, all six take such a copy."""
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from horovod_tpu.models import transformer as tr
+    from horovod_tpu.parallel import moe
+    from horovod_tpu.parallel.mesh import build_parallel_mesh
+
+    monkeypatch.setattr(pa, "_resolve_dispatch", lambda up: (True, False))
+    cfg = tr.TransformerConfig(
+        vocab=512, d_model=256, n_heads=2, d_head=128, n_layers=2,
+        max_seq=512, use_moe=True, n_experts=8, d_expert=128, moe_top_k=2,
+        norm="rmsnorm", qk_norm=True, rope=True, dtype=jnp.bfloat16)
+    mesh = build_parallel_mesh(list(described_chip.device_set), sp=1, tp=1,
+                               pp=1)
+    specs = tr._param_specs(cfg)
+    params = {k: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                      sharding=NamedSharding(mesh, specs[k]))
+              for k, v in jax.eval_shape(
+                  lambda: tr.init_params(cfg, jax.random.PRNGKey(0), 1)
+              ).items()}
+    tokens = jax.ShapeDtypeStruct((2, 512), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("dp", "sp")))
+
+    def operands():
+        grad = jax.jit(jax.grad(tr.make_loss_fn(cfg, mesh, 1)))
+        made = _expert_operands(
+            grad.lower(params, tokens, tokens).compile().as_text())
+        assert len(made) == 6, made  # three forward, three row gradients
+        # "bf16[16,256,128]{layout} bitcast(...": shape and opcode.
+        return [" ".join(re.match(r"(\w+\[[\d,]*\])\S* ([\w\-]+)\(",
+                                  rest).groups())
+                for rest in made.values()]
+
+    in_place = operands()
+    # ... as it lies: the parameter's bitcast, or the loop's own operand.
+    assert {made.split()[0] for made in in_place} == {
+        "bf16[16,256,128]", "bf16[16,128,256]"}, in_place
+    assert {made.split()[1] for made in in_place} <= {
+        "bitcast", "get-tuple-element"}, in_place
+    slice_taking = moe._grouped_matmul
+    monkeypatch.setattr(
+        moe, "_grouped_matmul", lambda lhs, rhs, sizes, stack=None, layer=0:
+        slice_taking(lhs, rhs, sizes))
+    copied = operands()
+    assert set(copied) == {"bf16[8,256,128] fusion",
+                           "bf16[8,128,256] fusion"}, copied
+
+
+def test_expert_kernels_take_a_stack_of_the_published_depth_on_v5e(
+        described_chip, monkeypatch):
+    """OLMoE's sixteen layers in one stage: the grouped matmul and its two
+    gradients over a ``[16, 64, 2048, 1024]`` stack, 1,024 groups of which
+    64 have rows; the kernels' tables of 128 + 1,024 - 1 entries fit."""
+    from horovod_tpu.parallel import moe
+
+    monkeypatch.setattr(pa, "_resolve_dispatch", lambda up: (True, False))
+    rows = jax.ShapeDtypeStruct((65536, 2048), jnp.bfloat16,
+                                sharding=described_chip)
+    stack = jax.ShapeDtypeStruct((16, 64, 2048, 1024), jnp.bfloat16,
+                                 sharding=described_chip)
+    sizes = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=described_chip)
+    layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=described_chip)
+
+    def product(rows, stack, sizes, layer):
+        def of(rows, rhs):
+            return jnp.sum(moe._grouped_matmul(
+                rows, rhs, sizes, jax.lax.stop_gradient(stack),
+                layer).astype(jnp.float32))
+        return jax.value_and_grad(of, (0, 1))(rows, stack[layer])
+
+    text = jax.jit(product).lower(rows, stack, sizes, layer).compile(
+        ).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert "s32[1151]" in text
+    assert all(made.startswith("bf16[1024,2048,1024]") and " bitcast(" in made
+               for made in _expert_operands(text).values())

@@ -256,3 +256,133 @@ def test_the_pallas_grouped_matmul_gives_what_ragged_dot_gives(monkeypatch):
     assert loss == pytest.approx(want, rel=1e-6)
     worst = _worst_leaf(grads, want_grads)
     assert max(worst.values()) < 1e-5, worst
+
+
+# ---- the stage's stacked expert matrices, read in place ---------------------
+
+def _slices_only(monkeypatch):
+    """The grouped matmuls take their layer's slice, as if the stage had
+    handed them no stack: the path the in-place reading is held to."""
+    in_place = moe._grouped_matmul
+    monkeypatch.setattr(
+        moe, "_grouped_matmul",
+        lambda lhs, rhs, group_sizes, stack=None, layer=0:
+        in_place(lhs, rhs, group_sizes))
+
+
+def _starved(params, expert=5):
+    """No token picks ``expert`` in either layer: it shares its router
+    column with experts 0, 1 and 2, ties go to the lower index, and a
+    token has three picks. The others are chosen as the data has it."""
+    router = np.array(params["router"])
+    for twin in (1, 2, expert):
+        router[..., twin] = router[..., 0]
+    return dict(params, router=jnp.asarray(router))
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+def test_a_grouped_matmul_reads_its_layer_of_the_stack(monkeypatch, layer):
+    # Bit for bit what the kernels give on the slice, and its gradients:
+    # the rows' through the stack again, the weights' [g, k, n] from this
+    # layer's groups alone, one of them empty.
+    monkeypatch.setenv("HVD_PALLAS_INTERPRET", "1")
+    m, k, n, g = 64, 16, 32, 4
+    keys = jax.random.split(jax.random.PRNGKey(8), 3)
+    lhs = jax.random.normal(keys[0], (m, k))
+    stack = jax.random.normal(keys[1], (2, g, k, n))
+    weight = jax.random.normal(keys[2], (m, n))
+    sizes = jnp.asarray([24, 0, 30, 10], jnp.int32)
+
+    def product(lhs, rhs, *where):
+        return jnp.sum(moe._grouped_matmul(lhs, rhs, sizes, *where) * weight)
+
+    got = jax.value_and_grad(product, (0, 1))(lhs, stack[layer], stack,
+                                               jnp.int32(layer))
+    want = jax.value_and_grad(product, (0, 1))(lhs, stack[layer])
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    ragged = jax.lax.ragged_dot(lhs, stack[layer], sizes)
+    np.testing.assert_allclose(float(got[0]), float(jnp.sum(ragged * weight)),
+                               rtol=1e-5)
+    assert got[1][1].shape == (g, k, n)
+    assert not np.asarray(got[1][1][1]).any()  # the empty group's
+
+
+@pytest.mark.parametrize("ep", [1, 2])
+def test_stacked_experts_read_in_place_give_the_slices_values(monkeypatch,
+                                                              ep):
+    # Two layers in one scan: the kernels read both out of the stage's
+    # stacks by the layer's index. Loss and every gradient leaf, both
+    # layers of it, are those of the slice-taking path to the bit.
+    monkeypatch.setenv("HVD_PALLAS_INTERPRET", "1")
+    params, (tokens, labels) = _starved(_weights(CFG)), _batch()
+    mesh = build_parallel_mesh(jax.devices()[:ep], dp=ep, pp=1, sp=1, tp=1)
+    load = np.asarray(make_router_load_fn(CFG, mesh, n_microbatches=1)(
+        shard_params(params, CFG, mesh),
+        jax.device_put(tokens, NamedSharding(mesh, P("dp", "sp")))))
+    assert (load[:, 5] == 0).all() and (load.sum(1) == 3 * B * T).all()
+    loss, grads = _program(CFG, params, tokens, labels, dp=ep)
+    with monkeypatch.context() as patch:
+        _slices_only(patch)
+        want, want_grads = _program(CFG, params, tokens, labels, dp=ep)
+    assert loss == want
+    assert set(grads) == set(params)
+    for name in params:
+        np.testing.assert_array_equal(grads[name], want_grads[name],
+                                      err_msg=name)
+    for name in ("wg", "wu", "wd"):
+        assert grads[name].shape == params[name].shape
+        assert not grads[name][0, :, 5].any()  # the expert no token chose
+        assert grads[name][0, 0].any() and grads[name][0, 1].any()
+
+
+def _walk(jaxpr, visit):
+    for eqn in jaxpr.eqns:
+        visit(eqn)
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                _walk(sub, visit)
+
+
+def test_no_slice_feeds_an_expert_kernel_and_the_stack_gets_no_cotangent(
+        monkeypatch):
+    # The gradient's program: every kernel that needs a layer's matrices
+    # takes the whole [L * E, d, f] stack, a reshape of the parameter;
+    # nothing else has that shape (a cotangent of the stack would: zeros
+    # broadcast, or a sum carried through the layers' scan, of length L;
+    # the pipeline's scan of one tick carries the stage's gradient); no
+    # kernel reads an [E, d, f] slice. Handed the slices, six would.
+    monkeypatch.setenv("HVD_PALLAS_INTERPRET", "1")
+    params, (tokens, labels) = _weights(CFG), _batch()
+    mesh = build_parallel_mesh(jax.devices()[:1], dp=1, pp=1, sp=1, tp=1)
+    L, E, d, f = CFG.n_layers, CFG.n_experts, CFG.d_model, CFG.d_expert
+    stack, layer = {(L * E, d, f), (L * E, f, d)}, {(E, d, f), (E, f, d)}
+
+    def kernels_and_stack_shaped():
+        taking = {"stack": 0, "slice": 0}
+        others, carried = [], []
+
+        def visit(eqn):
+            shapes = [v.aval.shape for v in eqn.invars]
+            if eqn.primitive.name == "pallas_call":
+                taking["stack"] += bool(stack & set(shapes))
+                taking["slice"] += bool(layer & set(shapes))
+            elif eqn.primitive.name != "reshape":
+                others.extend(v.aval.shape for v in eqn.outvars
+                              if v.aval.shape in stack)
+            if eqn.primitive.name == "scan" and eqn.params["length"] == L:
+                first = eqn.params["num_consts"]
+                carried.extend(
+                    v.aval.shape for v in
+                    eqn.invars[first:first + eqn.params["num_carry"]])
+
+        _walk(jax.make_jaxpr(jax.grad(make_loss_fn(CFG, mesh, 1)))(
+            params, tokens, labels).jaxpr, visit)
+        return taking, others, carried
+
+    taking, others, carried = kernels_and_stack_shaped()
+    assert taking == {"stack": 6, "slice": 0}
+    assert others == []
+    assert not {(L, E, d, f), (L, E, f, d)} & set(carried)
+    _slices_only(monkeypatch)
+    assert kernels_and_stack_shaped()[0] == {"stack": 0, "slice": 6}

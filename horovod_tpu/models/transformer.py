@@ -18,6 +18,32 @@ reference's DP-only surface (SURVEY §2.5): every mesh axis of
 - **ep**: MoE experts sharded over the dp axis (``parallel.moe``):
   dropless top-k routing, sort-by-expert dispatch, grouped matmuls.
 
+The stack of layers is data: ``TransformerConfig.layer_types`` names each
+layer's mixer, ``"attention"`` (softmax attention, above) or ``"mamba"``
+(a Mamba-2 state-space mixer, ``_mamba_mixer``), and every layer ends in
+the same feed-forward block. Parameters are stacked per kind, and a stage
+scans each maximal run of one kind over its slice of the stacks
+(``_make_stage_fn``); a pattern of one kind is one scan.
+
+The Mamba-2 mixer (Dao & Gu, arXiv:2405.21060; HF
+``GraniteMoeHybridMambaLayer``), for one sequence of normed hidden states
+``h`` [T, d], H heads of P channels, state N, one group::
+
+    [z | xBC | dt] = h W_in                 widths H P | H P + 2 N | H, no bias
+    xBC = silu(conv1d_causal_depthwise(xBC, k) + b)
+    x [T, H, P], B [T, N], C [T, N] = split(xBC)
+    dt = softplus(dt + dt_bias);  A = -exp(A_log)                  per head
+    S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t;  y_t = S_t C_t + D x_t
+    y = rmsnorm(y * silu(z)) * g       over all H P channels, gate first
+    out = y W_out
+
+``dt``, ``A``, the decays, their sums, the state and the norm are float32;
+the matmuls take operands in the model's type and accumulate in float32
+(``ops/ssd.py`` has the scan in its chunked form). ``W_in`` is held in
+three parts, ``m_wzx`` [d, 2, H, P], ``m_wbc`` [d, 2, N] and ``m_wdt``
+[d, H], and the convolution in two, so that the heads shard over ``tp``
+while ``B`` and ``C`` stay whole on every member.
+
 Pure-jax pytree params (no flax) so shard_map in_specs map 1:1 onto leaves.
 """
 
@@ -25,18 +51,23 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..common.compat import axis_size as _axis_size
 from ..common.compat import shard_map as _compat_shard_map
+from ..ops.ssd import ssd_chunked
 from ..parallel.moe import moe_layer
 from ..parallel.pipeline import spmd_pipeline
 from ..parallel.ulysses import context_parallel_attention
+
+
+LAYER_KINDS = ("attention", "mamba")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,6 +114,7 @@ class TransformerConfig:
     # (jax.checkpoint): activations are recomputed instead of saved, so
     # activation HBM drops from O(n_layers) to O(1) layers — the
     # standard trade that lets long sequences fit, at ~1/3 extra FLOPs.
+    # A Mamba layer keeps two things all the same (``_REMAT_KEEPS``).
     remat: bool = False
     # Grouped-query attention (Llama/Mistral-style): n_kv_heads < n_heads
     # shares each K/V head across n_heads/n_kv_heads query heads (KV
@@ -94,6 +126,33 @@ class TransformerConfig:
     # RoPE composes with sequence parallelism.
     rope: bool = False
     rope_theta: float = 10000.0
+    # The learned position table of a model that does not rotate. False
+    # with ``rope`` False: no positional signal at all (NoPE).
+    pos_table: bool = True
+    # Each layer's mixer, "attention" or "mamba", in order; None = every
+    # layer attends. A published ``layer_types``.
+    layer_types: Optional[Tuple[str, ...]] = None
+    # The Mamba-2 mixer: heads of ``mamba_d_head`` channels (their product
+    # is the inner width), state size, causal depthwise convolution
+    # width, and the chunk of the scan (ops/ssd.py). One group of B, C.
+    mamba_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_chunk: int = 256
+    # Dense feed-forward W_d (silu(h W_g) * h W_u) of width d_ff instead
+    # of W_2 gelu(h W_1).
+    gated_mlp: bool = False
+    # The head multiplies by the embedding table itself: one parameter,
+    # its gradient the sum of both ends.
+    tie_embeddings: bool = False
+    # Granite's four multipliers: on the embedded tokens, on each
+    # branch before it joins the residual stream, a divisor of the
+    # logits, and the softmax scale in place of d_head^-1/2 (None).
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: Optional[float] = None
 
     def __post_init__(self):
         if self.n_kv_heads is not None:
@@ -110,40 +169,109 @@ class TransformerConfig:
         if self.rope and self.d_head % 2 != 0:
             raise ValueError(f"rope needs an even d_head, got "
                              f"{self.d_head}")
+        if self.layer_types is not None:
+            kinds = tuple(self.layer_types)
+            object.__setattr__(self, "layer_types", kinds)
+            if len(kinds) != self.n_layers or set(kinds) - set(LAYER_KINDS):
+                raise ValueError(
+                    f"layer_types must name {self.n_layers} layers, each "
+                    f"one of {LAYER_KINDS}; got {kinds}")
+            if "mamba" in kinds and not (self.mamba_heads
+                                         and self.mamba_d_head
+                                         and self.mamba_d_state):
+                raise ValueError("a mamba layer needs mamba_heads, "
+                                 "mamba_d_head and mamba_d_state")
 
     @property
     def kv_heads(self) -> int:
         return self.n_heads if self.n_kv_heads is None else self.n_kv_heads
 
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """Each layer's mixer, in order."""
+        return self.layer_types or ("attention",) * self.n_layers
+
+    def stage_kinds(self, n_stages: int) -> Tuple[str, ...]:
+        """The mixers of one pipeline stage. Every stage scans the same
+        pattern (the stages are one SPMD program), so a stage holds whole
+        periods of it."""
+        if self.n_layers % n_stages != 0:
+            raise ValueError(f"n_layers ({self.n_layers}) must divide "
+                             f"into {n_stages} pipeline stages")
+        stage = self.kinds[:self.n_layers // n_stages]
+        if stage * n_stages != self.kinds:
+            raise ValueError(
+                f"{n_stages} pipeline stages must each hold whole periods "
+                f"of the layer pattern; {self.kinds} does not repeat every "
+                f"{len(stage)} layers")
+        return stage
+
+
+# Which layers each stacked leaf has one slice for: every layer, or the
+# layers of one kind. Everything not named here belongs to every layer
+# (the norms and the feed-forward block).
+_ATTENTION_LEAVES = ("wqkv", "wq", "wkv", "wo", "gq", "gk")
+_MODEL_LEAVES = ("embed", "pos", "final_ln", "head")
+
+
+def _leaf_kind(name: str) -> Optional[str]:
+    """The kind of layer whose stack ``name`` is, None for a leaf every
+    layer has."""
+    if name.startswith("m_"):
+        return "mamba"
+    return "attention" if name in _ATTENTION_LEAVES else None
+
 
 def _param_specs(cfg: TransformerConfig) -> Dict[str, P]:
-    """PartitionSpecs for every param leaf (leading dims: [S(tage), L(ayer/
-
-    stage)] on per-layer params)."""
+    """PartitionSpecs for every param leaf (leading dims: [S(tage), L(ayers
+    of the leaf's kind in the stage)] on per-layer params)."""
     specs = {
         "embed": P(),
         "ln1": P("pp"),
-        "wo": P("pp", None, "tp"),
         "ln2": P("pp"),
         "final_ln": P(),
-        "head": P(),
     }
-    if not cfg.rope:
+    if not cfg.tie_embeddings:
+        specs["head"] = P()
+    if cfg.pos_table and not cfg.rope:
         specs["pos"] = P()
-    if cfg.kv_heads == cfg.n_heads:
-        specs["wqkv"] = P("pp", None, None, None, "tp")
-    else:
-        specs["wq"] = P("pp", None, None, "tp")
-        specs["wkv"] = P("pp", None, None, None, "tp")
-    if cfg.qk_norm:
-        specs["gq"] = P("pp", None, "tp")
-        specs["gk"] = P("pp", None, "tp")
+    if "attention" in cfg.kinds:
+        specs["wo"] = P("pp", None, "tp")
+        if cfg.kv_heads == cfg.n_heads:
+            specs["wqkv"] = P("pp", None, None, None, "tp")
+        else:
+            specs["wq"] = P("pp", None, None, "tp")
+            specs["wkv"] = P("pp", None, None, None, "tp")
+        if cfg.qk_norm:
+            specs["gq"] = P("pp", None, "tp")
+            specs["gk"] = P("pp", None, "tp")
+    if "mamba" in cfg.kinds:
+        # Heads over tp; B, C and their convolution channels whole.
+        specs.update({
+            "m_wzx": P("pp", None, None, None, "tp"),
+            "m_wbc": P("pp"),
+            "m_wdt": P("pp", None, None, "tp"),
+            "m_conv_x": P("pp", None, None, "tp"),
+            "m_conv_xb": P("pp", None, "tp"),
+            "m_conv_bc": P("pp"),
+            "m_conv_bcb": P("pp"),
+            "m_dt_bias": P("pp", None, "tp"),
+            "m_A_log": P("pp", None, "tp"),
+            "m_D": P("pp", None, "tp"),
+            "m_g": P("pp", None, "tp"),
+            "m_wo": P("pp", None, "tp"),
+        })
     if cfg.use_moe:
         specs.update({
             "router": P("pp"),
             "wg": P("pp", None, "dp"),
             "wu": P("pp", None, "dp"),
             "wd": P("pp", None, "dp"),
+        })
+    elif cfg.gated_mlp:
+        specs.update({
+            "wgu": P("pp", None, None, None, "tp"),
+            "w2": P("pp", None, "tp"),
         })
     else:
         specs.update({
@@ -155,8 +283,8 @@ def _param_specs(cfg: TransformerConfig) -> Dict[str, P]:
 
 def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
     """Global (unsharded) parameter pytree; shard with ``shard_params``."""
-    assert cfg.n_layers % n_stages == 0, "n_layers must divide into stages"
-    lps = cfg.n_layers // n_stages
+    stage = cfg.stage_kinds(n_stages)
+    lps = len(stage)
     H, Dh, d, F = cfg.n_heads, cfg.d_head, cfg.d_model, cfg.d_ff
     ks = jax.random.split(rng, 12)
     dt = cfg.dtype
@@ -167,24 +295,31 @@ def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
     params = {
         "embed": norm(ks[0], (cfg.vocab, d), 0.02),
         "ln1": jnp.ones((n_stages, lps, d), jnp.float32),
-        "wo": norm(ks[3], (n_stages, lps, H, Dh, d), (H * Dh) ** -0.5),
         "ln2": jnp.ones((n_stages, lps, d), jnp.float32),
         "final_ln": jnp.ones((d,), jnp.float32),
-        "head": norm(ks[4], (d, cfg.vocab), d ** -0.5),
     }
-    if not cfg.rope:
+    if not cfg.tie_embeddings:
+        params["head"] = norm(ks[4], (d, cfg.vocab), d ** -0.5)
+    if cfg.pos_table and not cfg.rope:
         params["pos"] = norm(ks[1], (cfg.max_seq, d), 0.02)
-    Hkv = cfg.kv_heads
-    if Hkv == H:
-        params["wqkv"] = norm(ks[2], (n_stages, lps, d, 3, H, Dh),
-                              d ** -0.5)
-    else:
-        params["wq"] = norm(ks[2], (n_stages, lps, d, H, Dh), d ** -0.5)
-        params["wkv"] = norm(ks[8], (n_stages, lps, d, 2, Hkv, Dh),
-                             d ** -0.5)
-    if cfg.qk_norm:
-        params["gq"] = jnp.ones((n_stages, lps, H, Dh), jnp.float32)
-        params["gk"] = jnp.ones((n_stages, lps, Hkv, Dh), jnp.float32)
+    La = stage.count("attention")
+    if La:
+        Hkv = cfg.kv_heads
+        params["wo"] = norm(ks[3], (n_stages, La, H, Dh, d),
+                            (H * Dh) ** -0.5)
+        if Hkv == H:
+            params["wqkv"] = norm(ks[2], (n_stages, La, d, 3, H, Dh),
+                                  d ** -0.5)
+        else:
+            params["wq"] = norm(ks[2], (n_stages, La, d, H, Dh), d ** -0.5)
+            params["wkv"] = norm(ks[8], (n_stages, La, d, 2, Hkv, Dh),
+                                 d ** -0.5)
+        if cfg.qk_norm:
+            params["gq"] = jnp.ones((n_stages, La, H, Dh), jnp.float32)
+            params["gk"] = jnp.ones((n_stages, La, Hkv, Dh), jnp.float32)
+    Lm = stage.count("mamba")
+    if Lm:
+        params.update(_init_mamba(cfg, ks[10], (n_stages, Lm), norm))
     if cfg.use_moe:
         E, Fe = cfg.n_experts, cfg.d_expert
         params.update({
@@ -194,6 +329,11 @@ def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
             "wu": norm(ks[9], (n_stages, lps, E, d, Fe), d ** -0.5),
             "wd": norm(ks[7], (n_stages, lps, E, Fe, d), Fe ** -0.5),
         })
+    elif cfg.gated_mlp:
+        params.update({
+            "wgu": norm(ks[5], (n_stages, lps, d, 2, F), d ** -0.5),
+            "w2": norm(ks[6], (n_stages, lps, F, d), F ** -0.5),
+        })
     else:
         params.update({
             "w1": norm(ks[5], (n_stages, lps, d, F), d ** -0.5),
@@ -202,13 +342,54 @@ def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
     return params
 
 
+def _init_mamba(cfg: TransformerConfig, rng, lead, norm) -> Dict:
+    """The Mamba-2 mixers' leaves with leading shape ``lead``: matrices
+    normal at fan-in^-1/2, the convolution uniform at k^-1/2 as
+    ``nn.Conv1d``'s, and Mamba-2's own defaults for the rest: ``dt_bias``
+    the inverse softplus of a step log-uniform in [1e-3, 1e-1], ``A``
+    uniform in [1, 16], ``D`` one, the norm's scale one (float32)."""
+    d, H, Pm, N, K = (cfg.d_model, cfg.mamba_heads, cfg.mamba_d_head,
+                      cfg.mamba_d_state, cfg.mamba_d_conv)
+    ks = jax.random.split(rng, 10)
+    f32 = jnp.float32
+
+    def conv(key, shape):
+        return jax.random.uniform(key, lead + shape, f32, -1.0, 1.0) \
+            * K ** -0.5
+
+    step = jnp.exp(jax.random.uniform(ks[7], lead + (H,), f32,
+                                      jnp.log(1e-3), jnp.log(1e-1)))
+    return {
+        "m_wzx": norm(ks[0], lead + (d, 2, H, Pm), d ** -0.5),
+        "m_wbc": norm(ks[1], lead + (d, 2, N), d ** -0.5),
+        "m_wdt": norm(ks[2], lead + (d, H), d ** -0.5),
+        "m_conv_x": conv(ks[3], (K, H, Pm)),
+        "m_conv_xb": conv(ks[4], (H, Pm)),
+        "m_conv_bc": conv(ks[5], (K, 2, N)),
+        "m_conv_bcb": conv(ks[6], (2, N)),
+        "m_dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "m_A_log": jnp.log(jax.random.uniform(ks[8], lead + (H,), f32,
+                                              1.0, 16.0)),
+        "m_D": jnp.ones(lead + (H,), f32),
+        "m_g": jnp.ones(lead + (H, Pm), f32),
+        "m_wo": norm(ks[9], lead + (H, Pm, d), (H * Pm) ** -0.5),
+    }
+
+
+def _pipeline_stages(mesh) -> int:
+    return dict(mesh.shape).get("pp", 1)
+
+
 def _validate_mesh_divisibility(cfg: TransformerConfig, mesh) -> None:
     """Head counts must divide the tp axis: wq/wqkv shard the query-head
     dim and wkv the KV-head dim over 'tp', and an indivisible split only
     surfaces later as an opaque XLA sharding error at compile time.
     Checked here — where the mesh is known — rather than in
-    ``__post_init__``, which never sees it."""
-    tp = dict(mesh.shape).get("tp", 1)
+    ``__post_init__``, which never sees it. Likewise a pipeline whose
+    stages would not hold whole periods of the layer pattern, and what a
+    Mamba layer cannot yet do across ``sp``."""
+    shape = dict(mesh.shape)
+    tp = shape.get("tp", 1)
     if cfg.n_heads % tp != 0:
         raise ValueError(
             f"n_heads ({cfg.n_heads}) must be divisible by the mesh's tp "
@@ -218,6 +399,19 @@ def _validate_mesh_divisibility(cfg: TransformerConfig, mesh) -> None:
             f"kv_heads ({cfg.kv_heads}) must be divisible by the mesh's "
             f"tp axis ({tp}) — wkv shards the KV-head dim over tp; use "
             f"n_kv_heads that is a multiple of tp (or tp <= n_kv_heads)")
+    cfg.stage_kinds(_pipeline_stages(mesh))
+    if "mamba" in cfg.kinds:
+        if cfg.mamba_heads % tp != 0:
+            raise ValueError(
+                f"mamba_heads ({cfg.mamba_heads}) must be divisible by "
+                f"the mesh's tp axis ({tp}) — the mixer shards its heads "
+                f"over tp")
+        if shape.get("sp", 1) > 1:
+            raise ValueError(
+                "a mamba layer cannot run over sp > 1: the scan's state "
+                "at a shard's last token and the convolution's last "
+                f"{cfg.mamba_d_conv - 1} rows are not handed to the next "
+                "sp member")
 
 
 def shard_params(params: Dict, cfg: TransformerConfig, mesh) -> Dict:
@@ -292,7 +486,94 @@ def _zero_router_stats(cfg: TransformerConfig, lead):
                               jnp.int32)}
 
 
-def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
+def _causal_depthwise_conv(x, w, bias):
+    """``y_t = sum_k w[k] * x[t - (K - 1) + k] + bias`` along axis 1 of x
+    [b, T, ...] with w [K, ...]: each channel its own filter, zeros
+    before the sequence's first token."""
+    K, T = w.shape[0], x.shape[1]
+    xp = jnp.pad(x, [(0, 0), (K - 1, 0)] + [(0, 0)] * (x.ndim - 2))
+    y = sum(xp[:, k:k + T].astype(jnp.float32) * w[k] for k in range(K))
+    return (y + bias).astype(x.dtype)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3,))
+def _gated_rmsnorm(y, z, scale, eps):
+    """``rmsnorm(y * silu(z)) * scale`` of y, z [b, t, h, p] over all
+    heads and channels of every tp member (one group), in float32."""
+    v = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    width = y.shape[2] * y.shape[3] * _axis_size("tp")
+    ss = lax.psum(jnp.sum(jnp.square(v), (2, 3), keepdims=True), "tp")
+    return (v * jax.lax.rsqrt(ss / width + eps) * scale).astype(y.dtype)
+
+
+# What a rematerialized layer (``TransformerConfig.remat``) keeps of its
+# forward pass beside its input: the Mamba in-projection's z and x, and
+# the scan's output, so that the layer's second forward leaves out that
+# matmul and the scan (which runs again in its own backward pass). Three
+# [t, H P] arrays a layer in the model's type; in granite-h-t8192 8.7 %
+# more tokens a second for 3.3 GiB (PERF.md section 6, PR 30).
+_REMAT_KEEPS = ("mamba_zx", "ssd_out")
+
+
+def _mamba_mixer(cfg: TransformerConfig, h, lp):
+    """The Mamba-2 mixer of the module's docstring on normed h [b, t, d];
+    heads are this tp member's, the result its partial sum."""
+    with jax.named_scope("mamba_in_proj"):
+        zx = checkpoint_name(
+            jnp.einsum("btd,dchp->btchp", h, lp["m_wzx"]),  # h=H/tp
+            "mamba_zx")
+        z, xs = zx[:, :, 0], zx[:, :, 1]
+        bc = jnp.einsum("btd,dcn->btcn", h, lp["m_wbc"])
+        dt = jnp.einsum("btd,dh->bth", h, lp["m_wdt"])
+    with jax.named_scope("mamba_conv"):
+        xs = jax.nn.silu(_causal_depthwise_conv(
+            xs, lp["m_conv_x"], lp["m_conv_xb"]))
+        bc = jax.nn.silu(_causal_depthwise_conv(
+            bc, lp["m_conv_bc"], lp["m_conv_bcb"]))
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["m_dt_bias"])
+    with jax.named_scope("ssd"):
+        y = checkpoint_name(
+            ssd_chunked(xs, dt, -jnp.exp(lp["m_A_log"]), bc[:, :, 0],
+                        bc[:, :, 1], lp["m_D"], cfg.mamba_chunk), "ssd_out")
+    with jax.named_scope("mamba_gate_norm"):
+        y = _gated_rmsnorm(y, z, lp["m_g"], cfg.norm_eps)
+    with jax.named_scope("mamba_out_proj"):
+        return jnp.einsum("bthp,hpd->btd", y, lp["m_wo"])
+
+
+def _times(x, multiplier):
+    """``x * multiplier``; a multiplier of one is no instruction."""
+    return x if multiplier == 1.0 else x * multiplier
+
+
+def _plus(x, offset: int):
+    """``x + offset``; an offset of zero is no instruction."""
+    return x + offset if offset else x
+
+
+def _runs(kinds):
+    """The maximal runs of one kind in ``kinds``, in order: (kind, the
+    run's first layer, how many earlier layers are of its kind, length)."""
+    runs, seen = [], {}
+    for at, kind in enumerate(kinds):
+        if runs and runs[-1][0] == kind:
+            runs[-1][3] += 1
+        else:
+            runs.append([kind, at, seen.get(kind, 0), 1])
+        seen[kind] = seen.get(kind, 0) + 1
+    return [tuple(run) for run in runs]
+
+
+def _rows(stack, first, n):
+    """Layers ``first .. first + n`` of a stack; the whole stack is
+    itself, no slice."""
+    if first == 0 and n == stack.shape[0]:
+        return stack
+    return lax.slice_in_dim(stack, first, first + n, axis=0)
+
+
+def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
+                   packed: bool = False):
     """stage_fn(stage_params, x) applying this stage's layers.
 
     x: [mb, t_local, d], or a tuple that starts with it: then the
@@ -301,18 +582,36 @@ def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
     with the activations; the ids pass through each stage unchanged, the
     statistics gain this stage's layers. Runs under the full (dp, pp, sp,
     tp) mesh.
+
+    The stage walks the maximal runs of one kind of layer in its pattern
+    (``cfg.stage_kinds``) and scans each over its rows of the stacks:
+    the leaves every layer has by the layer's place in the stage, a
+    kind's own by its place among that kind.
     """
     norm = _block_norm(cfg)
+    runs = _runs(cfg.stage_kinds(n_stages))
+    if packed and "mamba" in cfg.kinds:
+        raise ValueError(
+            "packed sequences cannot pass a mamba layer: the scan's state "
+            "and the convolution are not reset at a segment boundary")
 
-    def layer(x, lp, seg, gathered_seg, experts=None):
-        with jax.named_scope("attention"):
-            x = attention_block(x, lp, seg, gathered_seg)
+    def layer(kind, x, lp, seg, gathered_seg, experts=None):
+        with jax.named_scope(kind):
+            x = mixer_block(kind, x, lp, seg, gathered_seg)
         with jax.named_scope("moe" if cfg.use_moe else "mlp"):
             return feed_forward_block(x, lp, experts)
 
-    def attention_block(x, lp, seg, gathered_seg):
-        # tp-sharded heads, sp ring
+    def mixer_block(kind, x, lp, seg, gathered_seg):
         h = norm(x, lp["ln1"])
+        if kind == "attention":
+            out = attention_mixer(h, lp, seg, gathered_seg)
+        else:
+            out = _mamba_mixer(cfg, h, lp)
+        out = lax.psum(out, "tp")  # combine head shards
+        return x + _times(out, cfg.residual_multiplier)
+
+    def attention_mixer(h, lp, seg, gathered_seg):
+        # tp-sharded heads, sp ring
         if "wqkv" in lp:
             qkv = jnp.einsum("btd,dchk->btchk", h, lp["wqkv"])  # h=H/tp
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
@@ -324,11 +623,14 @@ def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
             q = _qk_norm(q, lp["gq"], cfg.norm_eps)
             k = _qk_norm(k, lp["gk"], cfg.norm_eps)
         if cfg.rope:
-            t_local = x.shape[1]
+            t_local = h.shape[1]
             pos = (lax.axis_index("sp") * t_local
                    + jnp.arange(t_local, dtype=jnp.int32))
             q = _rope(q, pos, cfg.rope_theta)
             k = _rope(k, pos, cfg.rope_theta)
+        if cfg.attention_multiplier is not None:
+            # The kernels keep their d_head^-1/2; the rest goes on q.
+            q = _times(q, cfg.attention_multiplier * cfg.d_head ** 0.5)
         # GQA K/V stay at their reduced head width here — the
         # context-parallel strategies carry them across the sp fabric
         # at that width and expand only at the kernel boundary.
@@ -337,9 +639,7 @@ def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
             strategy=cfg.sp_strategy, segment_ids=seg,
             gathered_segment_ids=gathered_seg,
             window=cfg.attention_window)
-        out = jnp.einsum("bthk,hkd->btd", attn, lp["wo"])
-        out = lax.psum(out, "tp")  # combine head shards
-        return x + out
+        return jnp.einsum("bthk,hkd->btd", attn, lp["wo"])
 
     def feed_forward_block(x, lp, experts):
         h = norm(x, lp["ln2"])
@@ -350,13 +650,20 @@ def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
                 axis_name="dp", top_k=cfg.moe_top_k,
                 norm_topk_prob=cfg.norm_topk_prob, seq_axis_name="sp",
                 stacks=stacks, layer=index)
-            return x + y, stats
-        y = jax.nn.gelu(jnp.einsum("btd,df->btf", h, lp["w1"]))
+            return x + _times(y, cfg.residual_multiplier), stats
+        if cfg.gated_mlp:
+            gu = jnp.einsum("btd,dcf->btcf", h, lp["wgu"])
+            y = jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]
+        else:
+            y = jax.nn.gelu(jnp.einsum("btd,df->btf", h, lp["w1"]))
         y = jnp.einsum("btf,fd->btd", y, lp["w2"])
         y = lax.psum(y, "tp")  # combine hidden-dim shards
-        return x + y
+        return x + _times(y, cfg.residual_multiplier)
 
-    layer_fn = jax.checkpoint(layer) if cfg.remat else layer
+    layer_fn = jax.checkpoint(
+        layer, static_argnums=(0,),
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *_REMAT_KEEPS)) if cfg.remat else layer
 
     def stage_fn(stage_params, x):
         seg = gathered = stats = None
@@ -373,11 +680,7 @@ def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
 
                 gathered = gather_segment_ids(seg, "sp")
 
-        if not cfg.use_moe:
-            x, _ = lax.scan(
-                lambda x, lp: (layer_fn(x, lp, seg, gathered), None), x,
-                stage_params)
-        else:
+        if cfg.use_moe:
             # The expert kernels read a layer's matrices out of the
             # stage's stacks, constants of the scan, by the layer's index
             # (a Mosaic call cannot take the scan's slice without a copy
@@ -385,20 +688,33 @@ def _make_stage_fn(cfg: TransformerConfig, packed: bool = False):
             # the weight gradients for, one layer an iteration.
             stacks = {k: lax.stop_gradient(stage_params[k])
                       for k in ("wg", "wu", "wd")}
-            n_local = stage_params["wg"].shape[0]
+        for kind, first, first_of_kind, n in runs:
+            run_params = {
+                k: _rows(v, first if _leaf_kind(k) is None
+                         else first_of_kind, n)
+                for k, v in stage_params.items()
+                if _leaf_kind(k) in (None, kind)}
+            if not cfg.use_moe:
+                x, _ = lax.scan(
+                    lambda x, lp: (layer_fn(kind, x, lp, seg, gathered),
+                                   None), x, run_params)
+                continue
 
             def body(x, scanned):
                 lp, index = scanned
-                return layer_fn(x, lp, seg, gathered, (stacks, index))
+                return layer_fn(kind, x, lp, seg, gathered,
+                                (stacks, index))
 
+            # The layers' places in the stage, for the expert kernels.
             x, layers = lax.scan(
-                body, x, (stage_params, jnp.arange(n_local)))
+                body, x, (run_params, _plus(jnp.arange(n), first)))
+            at = lax.axis_index("pp") * stage_params["wg"].shape[0]
             stats = {
                 "lb": stats["lb"] + jnp.sum(layers["lb"]) / cfg.n_layers,
                 "z": stats["z"] + jnp.sum(layers["z"]) / cfg.n_layers,
                 "load": lax.dynamic_update_slice_in_dim(
                     stats["load"], layers["load"].astype(jnp.int32),
-                    lax.axis_index("pp") * n_local, axis=0)}
+                    _plus(at, first), axis=0)}
         out = (x,) + ((seg,) if packed else ()) + (
             (stats,) if cfg.use_moe else ())
         return out if len(out) > 1 else x
@@ -422,7 +738,8 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
     b, t = tokens.shape
     with jax.named_scope("embed"):
         sp_idx = lax.axis_index("sp")
-        x = params["embed"][tokens]  # [b, t, d]
+        x = _times(params["embed"][tokens],  # [b, t, d]
+                   cfg.embedding_multiplier)
         if "pos" in params:  # learned positions; RoPE rotates in the layers
             pos = lax.dynamic_slice_in_dim(params["pos"], sp_idx * t, t,
                                            axis=0)
@@ -443,7 +760,7 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
     # count than the mesh's pp size, layers would silently be dropped.
     stage_params = {}
     for k, v in params.items():
-        if k in ("embed", "pos", "final_ln", "head"):
+        if k in _MODEL_LEAVES:
             continue
         assert v.shape[0] == 1, (
             f"param '{k}' has {v.shape[0]} local stages; init_params "
@@ -466,9 +783,14 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
     y = y.reshape(b, t, -1)
 
     with jax.named_scope("head"):
-        y = _block_norm(cfg)(y, params["final_ln"])
-        return jnp.einsum("btd,dv->btv", y.astype(jnp.float32),
-                          params["head"].astype(jnp.float32)), stats
+        y = _block_norm(cfg)(y, params["final_ln"]).astype(jnp.float32)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("btd,vd->btv", y,
+                                params["embed"].astype(jnp.float32))
+        else:
+            logits = jnp.einsum("btd,dv->btv", y,
+                                params["head"].astype(jnp.float32))
+        return _times(logits, 1.0 / cfg.logits_scaling), stats
 
 
 @jax.custom_vjp
@@ -519,7 +841,7 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
     next-token positions through the labels (e.g. weight-zero ids) as
     your data pipeline defines them."""
     _validate_mesh_divisibility(cfg, mesh)
-    stage_fn = _make_stage_fn(cfg, packed=packed)
+    stage_fn = _make_stage_fn(cfg, _pipeline_stages(mesh), packed=packed)
     specs = _param_specs(cfg)
 
     def spmd_loss(params, tokens, labels, segment_ids=None):
@@ -596,10 +918,19 @@ def dense_reference_loss(cfg: TransformerConfig, params, tokens, labels,
     mathematically identical to the sharded loss (pipeline == sequential
     layers; ring attention == dense causal attention). Used by tests to
     validate sharded loss AND gradients. The MoE, RMSNorm and QK-norm
-    variants are held to ``benchmark/reference_moe.py`` instead."""
-    if cfg.use_moe or cfg.norm != "layernorm" or cfg.qk_norm:
+    variants are held to ``benchmark/reference_moe.py`` instead, and
+    everything the Mamba-2 hybrid brought (a layer pattern, the gated
+    MLP, a tied head, no positions, the multipliers) to
+    ``benchmark/reference_hybrid.py``."""
+    if (cfg.use_moe or cfg.norm != "layernorm" or cfg.qk_norm
+            or "mamba" in cfg.kinds or cfg.gated_mlp or cfg.tie_embeddings
+            or not (cfg.pos_table or cfg.rope)
+            or (cfg.embedding_multiplier, cfg.residual_multiplier,
+                cfg.logits_scaling, cfg.attention_multiplier)
+            != (1.0, 1.0, 1.0, None)):
         raise ValueError("dense_reference_loss covers the dense LayerNorm "
-                         "decoder only; see benchmark/reference_moe.py")
+                         "decoder only; see benchmark/reference_moe.py and "
+                         "benchmark/reference_hybrid.py")
     from ..parallel.ring_attention import local_flash_attention
 
     def attend(q, k, v):
@@ -667,7 +998,7 @@ def dense_reference_loss(cfg: TransformerConfig, params, tokens, labels,
 
 def make_forward_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2):
     """Inference forward returning logits, sharded like the loss."""
-    stage_fn = _make_stage_fn(cfg)
+    stage_fn = _make_stage_fn(cfg, _pipeline_stages(mesh))
     specs = _param_specs(cfg)
 
     def spmd_fwd(params, tokens):
@@ -686,7 +1017,7 @@ def make_router_load_fn(cfg: TransformerConfig, mesh,
     many of the global batch's tokens chose each expert in each MoE
     layer. Every row sums to ``moe_top_k`` times the tokens: nothing is
     dropped. A program of its own, not an output of the training step."""
-    stage_fn = _make_stage_fn(cfg)
+    stage_fn = _make_stage_fn(cfg, _pipeline_stages(mesh))
     specs = _param_specs(cfg)
 
     def spmd_load(params, tokens):

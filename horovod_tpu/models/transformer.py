@@ -509,9 +509,12 @@ def _gated_rmsnorm(y, z, scale, eps):
 # What a rematerialized layer (``TransformerConfig.remat``) keeps of its
 # forward pass beside its input: the Mamba in-projection's z and x, and
 # the scan's output, so that the layer's second forward leaves out that
-# matmul and the scan (which runs again in its own backward pass). Three
-# [t, H P] arrays a layer in the model's type; in granite-h-t8192 8.7 %
-# more tokens a second for 3.3 GiB (PERF.md section 6, PR 30).
+# matmul and the scan's forward kernel (``ssd_fwd`` is dead code there:
+# the scan's ``custom_vjp`` keeps its inputs only, and its backward kernel
+# forms the decay and score tiles anew in VMEM; the sums and the chunk
+# states, XLA's, are computed again). Three [t, H P] arrays a layer in the
+# model's type; in granite-h-t8192 8.7 % more tokens a second for 3.3 GiB
+# (PERF.md section 6, PR 30).
 _REMAT_KEEPS = ("mamba_zx", "ssd_out")
 
 

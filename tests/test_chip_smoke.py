@@ -455,3 +455,80 @@ def test_expert_kernels_take_a_stack_of_the_published_depth_on_v5e(
     assert "s32[1151]" in text
     assert all(made.startswith("bf16[1024,2048,1024]") and " bitcast(" in made
                for made in _expert_operands(text).values())
+
+
+def _materialised(text):
+    """(result shape, opcode) of every instruction of a compiled program
+    that writes its result to memory: those of the entry, the loops'
+    bodies and the like, not those inside a fusion."""
+    fused = set(re.findall(r"fusion\([^\n]*calls=(%[\w.\-]+)", text))
+    found = []
+    for computation in re.split(
+            r"\n(?=(?:ENTRY )?%[\w.\-]+ \([^\n]*\) -> [^\n]*\{\n)", text):
+        name = re.match(r"(?:ENTRY )?(%[\w.\-]+)", computation)
+        if not name or name.group(1) in fused:
+            continue
+        found += re.findall(
+            r"\n\s+(?:ROOT )?%[\w.\-]+ = (\w+\[[\d,]*\])\S* ([\w\-]+)\(",
+            computation)
+    return found
+
+
+def _scan_gradient_text(described_chip, heads, T=8192, P=64, N=128, Q=256):
+    """The compiled forward and gradient of ``ops/ssd.py``'s scan at
+    granite-h-t8192's shape (one sequence of 8,192 tokens, heads of 64,
+    state 128, chunk 256, bf16)."""
+    from horovod_tpu.ops import ssd
+
+    def operand(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=described_chip)
+
+    args = (operand((1, T, heads, P), jnp.bfloat16),
+            operand((1, T, heads), jnp.float32),
+            operand((heads,), jnp.float32),
+            operand((1, T, N), jnp.bfloat16), operand((1, T, N), jnp.bfloat16),
+            operand((heads,), jnp.float32))
+
+    def loss(*a):
+        return ssd.ssd_chunked(*a, chunk=Q).astype(jnp.float32).sum()
+
+    return jax.jit(jax.value_and_grad(loss, argnums=range(6))).lower(
+        *args).compile().as_text()
+
+
+def _tiles_and_wide_copies(text, heads, T=8192, P=64, Q=256):
+    """Of what a compiled scan writes to memory: the [.., heads, Q, Q]
+    decay and score arrays, and float32 arrays of ``x``'s shape."""
+    written = _materialised(text)
+    assert len(written) > 20  # the rule reads this compiler's text
+    wide = {f"f32[1,{T},{heads},{P}]", f"f32[1,{heads * P},{T}]",
+            f"f32[1,{T},{heads * P}]", f"f32[1,{T // Q},{Q},{heads},{P}]",
+            f"f32[1,{T // Q},{heads},{P},{Q}]"}
+    return ([w for w in written if w[0].endswith(f"{heads},{Q},{Q}]")],
+            [w for w in written if w[0] in wide])
+
+
+@pytest.mark.parametrize("heads", [64, 32], ids=["the-cell", "a-tp2-member"])
+def test_scan_kernels_compile_for_v5e(described_chip, monkeypatch, heads):
+    """The kernel pair at the cell's shape and with the 32 heads of a
+    ``tp`` 2 member: both Mosaic calls by name, and neither the decay and
+    score arrays nor a float32 copy of ``x`` written to memory."""
+    monkeypatch.setattr(pa, "_resolve_dispatch", lambda up: (True, False))
+    text = _scan_gradient_text(described_chip, heads)
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert len(calls) == 2
+    assert any("ssd_fwd" in c for c in calls)
+    assert any("ssd_bwd" in c for c in calls)
+    assert _tiles_and_wide_copies(text, heads) == ([], [])
+
+
+def test_the_einsum_form_writes_what_the_scan_kernels_keep_in_vmem(
+        described_chip, monkeypatch):
+    """The fall-back at the same shape: no Mosaic call, and the rule above
+    finds the decay and score arrays and the widened ``x`` in memory."""
+    monkeypatch.setattr(pa, "_resolve_dispatch", lambda up: (False, False))
+    text = _scan_gradient_text(described_chip, 64)
+    assert "tpu_custom_call" not in text
+    tiles, wide = _tiles_and_wide_copies(text, 64)
+    assert tiles and wide

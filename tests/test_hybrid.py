@@ -127,12 +127,24 @@ def test_chunked_scan_is_the_recurrence_value_and_every_gradient(T):
             err_msg=f"gradient by {name}")
 
 
-def test_the_decay_and_score_arrays_are_no_residuals_of_the_scan():
-    """The backward pass keeps the scan's arguments, not [H, Q, Q]."""
-    args = _scan_inputs(32, H=4)
-    _, residuals = jax.vjp(lambda *a: ssd.ssd_chunked(*a, chunk=16), *args)
+@pytest.mark.parametrize("path, chunk, sizes", [
+    ("einsums", 16, dict(H=4)), ("kernels", 128, dict(H=4, Pm=64, N=128))])
+def test_the_decay_and_score_arrays_are_no_residuals_of_the_scan(
+        monkeypatch, path, chunk, sizes):
+    """The backward pass keeps the scan's arguments, the chunk states and
+    what is made of them, not [H, Q, Q]: the einsum form by
+    ``jax.checkpoint`` around the chunks' term, the kernel pair (here
+    interpreted, at the least sizes its plan takes) by its
+    ``custom_vjp``."""
+    if path == "kernels":
+        monkeypatch.setenv("HVD_PALLAS_INTERPRET", "1")
+    args = _scan_inputs(2 * chunk, **sizes)
+    scan = lambda *a: ssd.ssd_chunked(*a, chunk=chunk)
+    assert ("pallas_call" in str(jax.make_jaxpr(scan)(*args))) == (
+        path == "kernels")
+    _, residuals = jax.vjp(scan, *args)
     biggest = max(leaf.size for leaf in jax.tree_util.tree_leaves(residuals))
-    assert biggest <= args[0].size < 4 * 16 * 16 * 2
+    assert biggest <= args[0].size < 4 * chunk * chunk * 2
 
 
 @pytest.mark.parametrize("bf16_sums", [False, True],

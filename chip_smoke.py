@@ -43,7 +43,7 @@ from horovod_tpu.common import native as hvd_native
 from horovod_tpu.models.resnet import ResNet50
 from horovod_tpu.models.transformer import (
     TransformerConfig, init_params, make_train_step as make_decoder_step,
-    shard_params)
+    shard_params, trained)
 from horovod_tpu.ops.pallas_attention import flash_attention
 from horovod_tpu.parallel.mesh import build_parallel_mesh
 from horovod_tpu.training import (
@@ -59,6 +59,22 @@ from tools.compile_cache import enable_compile_cache
 DECODER = dict(vocab=50304, d_model=768, n_heads=12, d_head=64, d_ff=3072,
                n_layers=12, max_seq=1024, dtype=jnp.bfloat16)
 DECODER_BATCH = 8
+# The `trinity-mini-t8192` cell's block at a tiny size: sliding-window and
+# full attention layers, gated attention, a leading dense layer, a sigmoid
+# router over 16 experts of which 4 are held, a shared expert, and the
+# balancing bias that the step moves.
+AFMOE = dict(vocab=4096, d_model=256, n_heads=4, d_head=64, n_kv_heads=2,
+             d_ff=512, n_layers=3, max_seq=1024,
+             layer_types=("sliding_attention", "full_attention",
+                          "sliding_attention"), sliding_window=256,
+             pos_table=False, use_moe=True, num_dense_layers=1,
+             n_experts=16, n_experts_held=4, d_expert=256, moe_top_k=4,
+             moe_score_func="sigmoid", norm_topk_prob=True,
+             route_scale=2.826, n_shared_experts=1, expert_bias_rate=1e-3,
+             norm="rmsnorm", qk_norm="head", attn_gate=True,
+             post_norms=True, gated_mlp=True, embedding_multiplier=16.0,
+             remat=True, remat_keeps=("flash_out", "flash_lse"),
+             dtype=jnp.bfloat16)
 # Gradient bucket cap for the four-chip data-parallel step: ResNet-50's
 # 102 MB of fp32 gradients in four buckets.
 BUCKET_CAP_BYTES = 32 << 20
@@ -367,7 +383,7 @@ def phase_decoder(name, cfg, batch, steps, devices, sp=1, tp=1):
     params = shard_params(
         init_params(cfg, jax.random.PRNGKey(0), n_stages=1), cfg, mesh)
     optimizer = optax.adamw(3e-4)
-    opt_state = init_opt_state(optimizer, params, mesh)
+    opt_state = init_opt_state(optimizer, trained(params), mesh)
     step = make_decoder_step(cfg, optimizer, mesh, n_microbatches=1)
     tokens = np.random.RandomState(0).randint(
         0, cfg.vocab, (batch, cfg.max_seq)).astype(np.int32)
@@ -393,7 +409,9 @@ def phase_decoder(name, cfg, batch, steps, devices, sp=1, tp=1):
 
     def step_once():
         nonlocal params, opt_state
-        params, opt_state, loss = step(params, opt_state, tokens, labels)
+        # (a step that moves a balancing bias returns its readings too)
+        params, opt_state, loss, *_ = step(params, opt_state, tokens,
+                                           labels)
         return loss
 
     losses = run_steps(name, step_once, steps)
@@ -506,6 +524,9 @@ def one_chip_phases():
         ("resnet", lambda: phase_resnet(resnet, 32, 224, steps=5)),
         ("decoder", lambda: phase_decoder(
             "decoder", TransformerConfig(**DECODER), DECODER_BATCH, 3,
+            jax.devices())),
+        ("decoder-afmoe", lambda: phase_decoder(
+            "decoder-afmoe", TransformerConfig(**AFMOE), 2, 3,
             jax.devices())),
         ("kernels", lambda: phase_kernels(KERNEL_CASES)),
     ]

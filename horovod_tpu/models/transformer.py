@@ -18,12 +18,20 @@ reference's DP-only surface (SURVEY §2.5): every mesh axis of
 - **ep**: MoE experts sharded over the dp axis (``parallel.moe``):
   dropless top-k routing, sort-by-expert dispatch, grouped matmuls.
 
-The stack of layers is data: ``TransformerConfig.layer_types`` names each
-layer's mixer, ``"attention"`` (softmax attention, above) or ``"mamba"``
-(a Mamba-2 state-space mixer, ``_mamba_mixer``), and every layer ends in
-the same feed-forward block. Parameters are stacked per kind, and a stage
-scans each maximal run of one kind over its slice of the stacks
-(``_make_stage_fn``); a pattern of one kind is one scan.
+The stack of layers is data. ``TransformerConfig.layer_types`` names each
+layer's mixer: ``"attention"`` (softmax attention, above, with the
+model's one ``attention_window`` and ``rope`` switch), ``"mamba"`` (a
+Mamba-2 state-space mixer, ``_mamba_mixer``), ``"sliding_attention"``
+(attention over ``sliding_window`` tokens, rotated) or
+``"full_attention"`` (causal attention over everything, no positions);
+the three attention kinds share one set of leaves. Each layer ends in a
+feed-forward block that is data too: the dense MLP, or with ``use_moe``
+the expert layer in all but the ``num_dense_layers`` leading layers.
+Parameters are stacked per group (the attention mixers, the Mamba mixers,
+the dense blocks, the expert blocks, and what every layer has), and a
+stage scans each maximal run of one (mixer, feed-forward) pair over its
+slice of the stacks (``_make_stage_fn``); a pattern of one pair is one
+scan.
 
 The Mamba-2 mixer (Dao & Gu, arXiv:2405.21060; HF
 ``GraniteMoeHybridMambaLayer``), for one sequence of normed hidden states
@@ -67,7 +75,15 @@ from ..parallel.pipeline import spmd_pipeline
 from ..parallel.ulysses import context_parallel_attention
 
 
-LAYER_KINDS = ("attention", "mamba")
+LAYER_KINDS = ("attention", "mamba", "sliding_attention", "full_attention")
+# What a layer names with ``checkpoint_name``, so that a rematerialized
+# layer can keep it (``TransformerConfig.remat_keeps``): the Mamba
+# in-projection's z and x and the scan's output; an attention mixer's Q,
+# K/V and gate projections, its output projection, and the flash kernels'
+# output and row statistics (ops/pallas_attention.py); the gate-up product
+# of a gated MLP or shared expert.
+REMAT_NAMES = ("mamba_zx", "ssd_out", "attn_q", "attn_kv", "attn_gate",
+               "attn_proj", "flash_out", "flash_lse", "mlp_gu")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,10 +95,34 @@ class TransformerConfig:
     d_ff: int = 256
     n_layers: int = 4
     max_seq: int = 64
+    # The feed-forward block is the dropless expert layer
+    # (parallel/moe.py) in every layer but the ``num_dense_layers``
+    # leading ones, which keep the dense MLP (``d_ff``, ``gated_mlp``).
     use_moe: bool = False
+    num_dense_layers: int = 0
+    # Experts the router scores, and of those the ones this program
+    # holds: ``n_experts_held`` from ``first_expert_held`` (None: all of
+    # them). A program that holds a share computes that share's part of
+    # the layer's result; what the other experts would add is left out.
     n_experts: int = 4
+    n_experts_held: Optional[int] = None
+    first_expert_held: int = 0
     d_expert: int = 128
     moe_top_k: int = 1  # experts a token; nothing is dropped
+    # "softmax": the top-k router probabilities. "sigmoid": scores
+    # sigmoid(logits), times ``route_scale`` (``norm_topk_prob`` divides
+    # by the picked scores' sum first).
+    moe_score_func: str = "softmax"
+    route_scale: float = 1.0
+    # Gated MLPs of width ``n_shared_experts * d_expert`` that every
+    # token passes beside its routed experts.
+    n_shared_experts: int = 0
+    # Aux-loss-free balancing: > 0 gives each expert layer an
+    # ``expert_bias`` [E] that is added to the scores for the top-k
+    # *selection* only. It is no trained parameter: the train step moves
+    # it by ``rate * sign(mean load - load)`` (centred) from the tokens
+    # each expert got, and the optimizer never sees it.
+    expert_bias_rate: float = 0.0
     # Divide a token's top-k router probabilities by their sum (a
     # published config's ``norm_topk_prob``); False takes them as they are.
     norm_topk_prob: bool = False
@@ -95,42 +135,60 @@ class TransformerConfig:
     # v * rsqrt(mean(v^2) + norm_eps) * g, in float32.
     norm: str = "layernorm"
     norm_eps: float = 1e-5
-    # RMSNorm on the projected queries and keys, over the whole projected
-    # vector (all heads), before the heads are split and rotated.
-    qk_norm: bool = False
+    # RMSNorm on the projected queries and keys before they are rotated.
+    # True: over the whole projected vector (all heads of every tp
+    # member), one weight a channel. "head": over each head's ``d_head``
+    # channels, one weight vector [d_head] for all heads.
+    qk_norm: Any = False
+    # The kernels' output times ``sigmoid(h W_gate)`` (W_gate [d, H, Dh])
+    # before the output projection.
+    attn_gate: bool = False
+    # Each branch is normed again before it joins the residual stream:
+    # ``x + norm(mixer(norm(x)))``, ``x + norm(ffn(norm(x)))``.
+    post_norms: bool = False
     dtype: Any = jnp.float32
     # Sequence-parallel attention strategy over the sp axis: "ring"
     # (K/V rotation, no head constraint), "ulysses" (all-to-all head
     # re-shard, needs (n_heads/tp) % sp == 0), or "auto"
     # (parallel/ulysses.py).
     sp_strategy: str = "ring"
-    # Sliding-window attention (Mistral-style SWA): each token attends
-    # to itself plus the `attention_window - 1` preceding tokens
-    # (receptive field = attention_window; mask q_pos - k_pos < W).
-    # None = full causal. Out-of-window K tiles are culled in the
-    # kernels.
+    # Sliding-window attention (Mistral-style SWA) in the layers of kind
+    # "attention": each token attends to itself plus the
+    # `attention_window - 1` preceding tokens (receptive field =
+    # attention_window; mask q_pos - k_pos < W). None = full causal.
+    # Out-of-window K tiles are culled in the kernels.
     attention_window: Optional[int] = None
+    # The same for the layers of kind "sliding_attention", which must
+    # state one; "full_attention" layers have none.
+    sliding_window: Optional[int] = None
     # Rematerialize each decoder layer in the backward pass
     # (jax.checkpoint): activations are recomputed instead of saved, so
     # activation HBM drops from O(n_layers) to O(1) layers — the
     # standard trade that lets long sequences fit, at ~1/3 extra FLOPs.
-    # A Mamba layer keeps two things all the same (``_REMAT_KEEPS``).
+    # A layer keeps what ``remat_keeps`` names all the same, of
+    # ``REMAT_NAMES`` (None: a Mamba layer's ``_REMAT_KEEPS``).
     remat: bool = False
+    remat_keeps: Optional[Tuple[str, ...]] = None
     # Grouped-query attention (Llama/Mistral-style): n_kv_heads < n_heads
     # shares each K/V head across n_heads/n_kv_heads query heads (KV
-    # params cut by that factor; K/V expanded before the kernel — the
-    # training-side GQA formulation). None = multi-head (= n_heads).
+    # params cut by that factor). K/V cross the sp fabric at their own
+    # width and are repeated to n_heads where the kernels are called
+    # (parallel/ring_attention.py ``_expand_kv``; the kernels see
+    # multi-head attention, and the repeat's transpose sums the groups).
+    # None = multi-head (= n_heads).
     n_kv_heads: Optional[int] = None
-    # Rotary position embeddings instead of the learned position table.
-    # Positions are GLOBAL (sp-sharded ranks offset by their shard), so
-    # RoPE composes with sequence parallelism.
+    # Rotary position embeddings in the layers of kind "attention",
+    # instead of the learned position table ("sliding_attention" layers
+    # always rotate, "full_attention" layers never). Positions are GLOBAL
+    # (sp-sharded ranks offset by their shard), so RoPE composes with
+    # sequence parallelism.
     rope: bool = False
     rope_theta: float = 10000.0
     # The learned position table of a model that does not rotate. False
     # with ``rope`` False: no positional signal at all (NoPE).
     pos_table: bool = True
-    # Each layer's mixer, "attention" or "mamba", in order; None = every
-    # layer attends. A published ``layer_types``.
+    # Each layer's mixer, one of LAYER_KINDS, in order; None = every
+    # layer is "attention". A published ``layer_types``.
     layer_types: Optional[Tuple[str, ...]] = None
     # The Mamba-2 mixer: heads of ``mamba_d_head`` channels (their product
     # is the inner width), state size, causal depthwise convolution
@@ -181,25 +239,89 @@ class TransformerConfig:
                                          and self.mamba_d_state):
                 raise ValueError("a mamba layer needs mamba_heads, "
                                  "mamba_d_head and mamba_d_state")
+            if "sliding_attention" in kinds and (
+                    not self.sliding_window or self.d_head % 2 != 0):
+                raise ValueError("a sliding_attention layer needs a "
+                                 "sliding_window and an even d_head")
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError(f"qk_norm must be False, True or 'head', got "
+                             f"{self.qk_norm!r}")
+        if self.moe_score_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_score_func must be 'softmax' or "
+                             f"'sigmoid', got {self.moe_score_func!r}")
+        if not 0 <= self.num_dense_layers <= self.n_layers or (
+                self.num_dense_layers and not self.use_moe):
+            raise ValueError(
+                f"num_dense_layers ({self.num_dense_layers}) counts the "
+                f"leading dense layers of a use_moe model of "
+                f"{self.n_layers} layers")
+        if self.n_experts_held is not None and not (
+                0 <= self.first_expert_held
+                and 1 <= self.n_experts_held
+                and self.first_expert_held + self.n_experts_held
+                <= self.n_experts):
+            raise ValueError(
+                f"the held experts [{self.first_expert_held}, "
+                f"{self.first_expert_held} + {self.n_experts_held}) are "
+                f"not among the {self.n_experts} the router scores")
+        if self.expert_bias_rate and (not self.use_moe
+                                      or self.moe_score_func != "sigmoid"):
+            raise ValueError("expert_bias_rate moves the selection bias of "
+                             "a sigmoid router (use_moe, moe_score_func)")
+        if self.remat_keeps is not None:
+            keeps = tuple(self.remat_keeps)
+            object.__setattr__(self, "remat_keeps", keeps)
+            if set(keeps) - set(REMAT_NAMES):
+                raise ValueError(
+                    f"remat_keeps names what a layer writes under "
+                    f"checkpoint_name, of {REMAT_NAMES}; got {keeps}")
 
     @property
     def kv_heads(self) -> int:
         return self.n_heads if self.n_kv_heads is None else self.n_kv_heads
+
+    def attention_of(self, kind: str) -> Tuple[Optional[int], bool]:
+        """(window, whether queries and keys are rotated) of an attending
+        layer of ``kind``: the model's switches for "attention"; a sliding
+        layer has its window and rotates, a full layer neither."""
+        return {"attention": (self.attention_window, self.rope),
+                "sliding_attention": (self.sliding_window, True),
+                "full_attention": (None, False)}[kind]
+
+    @property
+    def experts_held(self) -> int:
+        return (self.n_experts if self.n_experts_held is None
+                else self.n_experts_held)
 
     @property
     def kinds(self) -> Tuple[str, ...]:
         """Each layer's mixer, in order."""
         return self.layer_types or ("attention",) * self.n_layers
 
-    def stage_kinds(self, n_stages: int) -> Tuple[str, ...]:
-        """The mixers of one pipeline stage. Every stage scans the same
-        pattern (the stages are one SPMD program), so a stage holds whole
-        periods of it."""
+    @property
+    def ffn_kinds(self) -> Tuple[str, ...]:
+        """Each layer's feed-forward block, "mlp" or "moe", in order."""
+        if not self.use_moe:
+            return ("mlp",) * self.n_layers
+        dense = self.num_dense_layers
+        return ("mlp",) * dense + ("moe",) * (self.n_layers - dense)
+
+    def stage_pattern(self, n_stages: int) -> Tuple[Tuple[str, str], ...]:
+        """The (mixer, feed-forward) pairs of one pipeline stage. Every
+        stage scans the same pattern (the stages are one SPMD program),
+        so a stage holds whole periods of it."""
         if self.n_layers % n_stages != 0:
             raise ValueError(f"n_layers ({self.n_layers}) must divide "
                              f"into {n_stages} pipeline stages")
-        stage = self.kinds[:self.n_layers // n_stages]
-        if stage * n_stages != self.kinds:
+        pattern = tuple(zip(self.kinds, self.ffn_kinds))
+        stage = pattern[:self.n_layers // n_stages]
+        if stage * n_stages != pattern:
+            if self.num_dense_layers:
+                raise ValueError(
+                    f"{n_stages} pipeline stages over a pattern with "
+                    f"{self.num_dense_layers} leading dense layer(s) are "
+                    f"not built: the stages are one program and would not "
+                    f"hold the same layers")
             raise ValueError(
                 f"{n_stages} pipeline stages must each hold whole periods "
                 f"of the layer pattern; {self.kinds} does not repeat every "
@@ -207,19 +329,40 @@ class TransformerConfig:
         return stage
 
 
-# Which layers each stacked leaf has one slice for: every layer, or the
-# layers of one kind. Everything not named here belongs to every layer
-# (the norms and the feed-forward block).
-_ATTENTION_LEAVES = ("wqkv", "wq", "wkv", "wo", "gq", "gk")
+# Which layers each stacked leaf has one slice for: every layer (the
+# norms), or the layers of one group: those whose mixer attends (of any
+# of the three kinds), the Mamba layers, those that end in the dense MLP,
+# those that end in the expert layer.
+_ATTENTION_LEAVES = ("wqkv", "wq", "wkv", "wo", "gq", "gk", "wgate")
+_MLP_LEAVES = ("w1", "w2", "wgu")
+_ROUTED_LEAVES = ("router", "wg", "wu", "wd", "expert_bias")
+_MOE_LEAVES = _ROUTED_LEAVES + ("shared_wgu", "shared_w2")
 _MODEL_LEAVES = ("embed", "pos", "final_ln", "head")
+# Leaves the train step carries that are no trained parameter.
+_STATE_LEAVES = ("expert_bias",)
 
 
-def _leaf_kind(name: str) -> Optional[str]:
-    """The kind of layer whose stack ``name`` is, None for a leaf every
+def _mixer_group(kind: str) -> str:
+    """The group of stacks a mixer of ``kind`` reads."""
+    return "mamba" if kind == "mamba" else "attention"
+
+
+def _leaf_group(name: str) -> Optional[str]:
+    """The group of layers whose stack ``name`` is, None for a leaf every
     layer has."""
     if name.startswith("m_"):
         return "mamba"
-    return "attention" if name in _ATTENTION_LEAVES else None
+    for group, leaves in (("attention", _ATTENTION_LEAVES),
+                          ("mlp", _MLP_LEAVES), ("moe", _MOE_LEAVES)):
+        if name in leaves:
+            return group
+    return None
+
+
+def trained(params: Dict) -> Dict:
+    """``params`` without the leaves no optimizer may see (the router's
+    ``expert_bias``): what the optimizer state is made for."""
+    return {k: v for k, v in params.items() if k not in _STATE_LEAVES}
 
 
 def _param_specs(cfg: TransformerConfig) -> Dict[str, P]:
@@ -231,20 +374,28 @@ def _param_specs(cfg: TransformerConfig) -> Dict[str, P]:
         "ln2": P("pp"),
         "final_ln": P(),
     }
+    if cfg.post_norms:
+        specs["ln1_post"] = P("pp")
+        specs["ln2_post"] = P("pp")
     if not cfg.tie_embeddings:
         specs["head"] = P()
     if cfg.pos_table and not cfg.rope:
         specs["pos"] = P()
-    if "attention" in cfg.kinds:
+    if set(cfg.kinds) - {"mamba"}:
         specs["wo"] = P("pp", None, "tp")
         if cfg.kv_heads == cfg.n_heads:
             specs["wqkv"] = P("pp", None, None, None, "tp")
         else:
             specs["wq"] = P("pp", None, None, "tp")
             specs["wkv"] = P("pp", None, None, None, "tp")
-        if cfg.qk_norm:
+        if cfg.qk_norm == "head":  # one [d_head] vector for all heads
+            specs["gq"] = P("pp")
+            specs["gk"] = P("pp")
+        elif cfg.qk_norm:
             specs["gq"] = P("pp", None, "tp")
             specs["gk"] = P("pp", None, "tp")
+        if cfg.attn_gate:
+            specs["wgate"] = P("pp", None, None, "tp")
     if "mamba" in cfg.kinds:
         # Heads over tp; B, C and their convolution channels whole.
         specs.update({
@@ -261,14 +412,21 @@ def _param_specs(cfg: TransformerConfig) -> Dict[str, P]:
             "m_g": P("pp", None, "tp"),
             "m_wo": P("pp", None, "tp"),
         })
-    if cfg.use_moe:
+    if "moe" in cfg.ffn_kinds:
         specs.update({
             "router": P("pp"),
             "wg": P("pp", None, "dp"),
             "wu": P("pp", None, "dp"),
             "wd": P("pp", None, "dp"),
         })
-    elif cfg.gated_mlp:
+        if cfg.n_shared_experts:  # the dense MLP's layout: width over tp
+            specs["shared_wgu"] = P("pp", None, None, None, "tp")
+            specs["shared_w2"] = P("pp", None, "tp")
+        if cfg.expert_bias_rate:
+            specs["expert_bias"] = P("pp")
+    if "mlp" not in cfg.ffn_kinds:
+        return specs
+    if cfg.gated_mlp:
         specs.update({
             "wgu": P("pp", None, None, None, "tp"),
             "w2": P("pp", None, "tp"),
@@ -283,10 +441,15 @@ def _param_specs(cfg: TransformerConfig) -> Dict[str, P]:
 
 def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
     """Global (unsharded) parameter pytree; shard with ``shard_params``."""
-    stage = cfg.stage_kinds(n_stages)
-    lps = len(stage)
+    pattern = cfg.stage_pattern(n_stages)
+    stage = [_mixer_group(mixer) for mixer, _ in pattern]
+    ffns = [ffn for _, ffn in pattern]
+    lps = len(pattern)
     H, Dh, d, F = cfg.n_heads, cfg.d_head, cfg.d_model, cfg.d_ff
     ks = jax.random.split(rng, 12)
+    # The leaves that came after the twelve were dealt out.
+    k_gate, k_shared_gu, k_shared_2, k_dense_gu, k_dense_2 = \
+        jax.random.split(ks[11], 5)
     dt = cfg.dtype
 
     def norm(key, shape, scale):
@@ -298,6 +461,9 @@ def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
         "ln2": jnp.ones((n_stages, lps, d), jnp.float32),
         "final_ln": jnp.ones((d,), jnp.float32),
     }
+    if cfg.post_norms:
+        params["ln1_post"] = jnp.ones((n_stages, lps, d), jnp.float32)
+        params["ln2_post"] = jnp.ones((n_stages, lps, d), jnp.float32)
     if not cfg.tie_embeddings:
         params["head"] = norm(ks[4], (d, cfg.vocab), d ** -0.5)
     if cfg.pos_table and not cfg.rope:
@@ -314,30 +480,48 @@ def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
             params["wq"] = norm(ks[2], (n_stages, La, d, H, Dh), d ** -0.5)
             params["wkv"] = norm(ks[8], (n_stages, La, d, 2, Hkv, Dh),
                                  d ** -0.5)
-        if cfg.qk_norm:
+        if cfg.qk_norm == "head":
+            params["gq"] = jnp.ones((n_stages, La, Dh), jnp.float32)
+            params["gk"] = jnp.ones((n_stages, La, Dh), jnp.float32)
+        elif cfg.qk_norm:
             params["gq"] = jnp.ones((n_stages, La, H, Dh), jnp.float32)
             params["gk"] = jnp.ones((n_stages, La, Hkv, Dh), jnp.float32)
+        if cfg.attn_gate:
+            params["wgate"] = norm(k_gate, (n_stages, La, d, H, Dh),
+                                   d ** -0.5)
     Lm = stage.count("mamba")
     if Lm:
         params.update(_init_mamba(cfg, ks[10], (n_stages, Lm), norm))
-    if cfg.use_moe:
-        E, Fe = cfg.n_experts, cfg.d_expert
+    Le, Ld = ffns.count("moe"), ffns.count("mlp")
+    if Le:
+        E, Eh, Fe = cfg.n_experts, cfg.experts_held, cfg.d_expert
+        Fs = cfg.n_shared_experts * Fe
         params.update({
-            "router": (jax.random.normal(ks[5], (n_stages, lps, d, E))
+            "router": (jax.random.normal(ks[5], (n_stages, Le, d, E))
                        * d ** -0.5),
-            "wg": norm(ks[6], (n_stages, lps, E, d, Fe), d ** -0.5),
-            "wu": norm(ks[9], (n_stages, lps, E, d, Fe), d ** -0.5),
-            "wd": norm(ks[7], (n_stages, lps, E, Fe, d), Fe ** -0.5),
+            "wg": norm(ks[6], (n_stages, Le, Eh, d, Fe), d ** -0.5),
+            "wu": norm(ks[9], (n_stages, Le, Eh, d, Fe), d ** -0.5),
+            "wd": norm(ks[7], (n_stages, Le, Eh, Fe, d), Fe ** -0.5),
         })
-    elif cfg.gated_mlp:
+        if Fs:
+            params["shared_wgu"] = norm(
+                k_shared_gu, (n_stages, Le, d, 2, Fs), d ** -0.5)
+            params["shared_w2"] = norm(
+                k_shared_2, (n_stages, Le, Fs, d), Fs ** -0.5)
+        if cfg.expert_bias_rate:
+            params["expert_bias"] = jnp.zeros((n_stages, Le, E),
+                                              jnp.float32)
+    # A model of dense layers alone takes the keys it always took.
+    k_gu, k_2 = (k_dense_gu, k_dense_2) if Le else (ks[5], ks[6])
+    if Ld and cfg.gated_mlp:
         params.update({
-            "wgu": norm(ks[5], (n_stages, lps, d, 2, F), d ** -0.5),
-            "w2": norm(ks[6], (n_stages, lps, F, d), F ** -0.5),
+            "wgu": norm(k_gu, (n_stages, Ld, d, 2, F), d ** -0.5),
+            "w2": norm(k_2, (n_stages, Ld, F, d), F ** -0.5),
         })
-    else:
+    elif Ld:
         params.update({
-            "w1": norm(ks[5], (n_stages, lps, d, F), d ** -0.5),
-            "w2": norm(ks[6], (n_stages, lps, F, d), F ** -0.5),
+            "w1": norm(k_gu, (n_stages, Ld, d, F), d ** -0.5),
+            "w2": norm(k_2, (n_stages, Ld, F, d), F ** -0.5),
         })
     return params
 
@@ -387,7 +571,7 @@ def _validate_mesh_divisibility(cfg: TransformerConfig, mesh) -> None:
     Checked here — where the mesh is known — rather than in
     ``__post_init__``, which never sees it. Likewise a pipeline whose
     stages would not hold whole periods of the layer pattern, and what a
-    Mamba layer cannot yet do across ``sp``."""
+    Mamba layer or the balancing bias cannot yet do across ``sp``."""
     shape = dict(mesh.shape)
     tp = shape.get("tp", 1)
     if cfg.n_heads % tp != 0:
@@ -399,7 +583,11 @@ def _validate_mesh_divisibility(cfg: TransformerConfig, mesh) -> None:
             f"kv_heads ({cfg.kv_heads}) must be divisible by the mesh's "
             f"tp axis ({tp}) — wkv shards the KV-head dim over tp; use "
             f"n_kv_heads that is a multiple of tp (or tp <= n_kv_heads)")
-    cfg.stage_kinds(_pipeline_stages(mesh))
+    cfg.stage_pattern(_pipeline_stages(mesh))
+    if cfg.expert_bias_rate and shape.get("sp", 1) > 1:
+        raise ValueError(
+            "the router's balancing bias is not built over sp > 1: no "
+            "test holds the counts of a sequence's shards")
     if "mamba" in cfg.kinds:
         if cfg.mamba_heads % tp != 0:
             raise ValueError(
@@ -476,6 +664,14 @@ def _qk_norm(x, scale, eps):
     return (xf * jax.lax.rsqrt(ss / width + eps) * scale).astype(x.dtype)
 
 
+@jax.checkpoint
+def _sigmoid_gated(attn, gate):
+    """``attn * sigmoid(gate)`` in float32; the backward pass keeps the
+    operands in their own type."""
+    return (attn.astype(jnp.float32)
+            * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(attn.dtype)
+
+
 def _zero_router_stats(cfg: TransformerConfig, lead):
     """What the MoE layers add up as the activations pass through them,
     with leading shape ``lead``: the two loss terms as means over all
@@ -507,7 +703,8 @@ def _gated_rmsnorm(y, z, scale, eps):
 
 
 # What a rematerialized layer (``TransformerConfig.remat``) keeps of its
-# forward pass beside its input: the Mamba in-projection's z and x, and
+# forward pass beside its input unless ``remat_keeps`` names others of
+# ``REMAT_NAMES``: the Mamba in-projection's z and x, and
 # the scan's output, so that the layer's second forward leaves out that
 # matmul and the scan's forward kernel (``ssd_fwd`` is dead code there:
 # the scan's ``custom_vjp`` keeps its inputs only, and its backward kernel
@@ -554,16 +751,22 @@ def _plus(x, offset: int):
     return x + offset if offset else x
 
 
-def _runs(kinds):
-    """The maximal runs of one kind in ``kinds``, in order: (kind, the
-    run's first layer, how many earlier layers are of its kind, length)."""
+def _runs(pattern):
+    """The maximal runs of one (mixer, feed-forward) pair in ``pattern``,
+    in order: (mixer, feed-forward, the run's first row in each group of
+    stacks, length). The rows are by ``_leaf_group``: None the run's first
+    layer, the mixer's and the feed-forward's group how many earlier
+    layers read that group."""
     runs, seen = [], {}
-    for at, kind in enumerate(kinds):
-        if runs and runs[-1][0] == kind:
+    for at, (mixer, ffn) in enumerate(pattern):
+        groups = (_mixer_group(mixer), ffn)
+        if runs and runs[-1][:2] == [mixer, ffn]:
             runs[-1][3] += 1
         else:
-            runs.append([kind, at, seen.get(kind, 0), 1])
-        seen[kind] = seen.get(kind, 0) + 1
+            runs.append([mixer, ffn, {None: at, **{
+                g: seen.get(g, 0) for g in groups}}, 1])
+        for g in groups:
+            seen[g] = seen.get(g, 0) + 1
     return [tuple(run) for run in runs]
 
 
@@ -586,46 +789,61 @@ def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
     statistics gain this stage's layers. Runs under the full (dp, pp, sp,
     tp) mesh.
 
-    The stage walks the maximal runs of one kind of layer in its pattern
-    (``cfg.stage_kinds``) and scans each over its rows of the stacks:
-    the leaves every layer has by the layer's place in the stage, a
-    kind's own by its place among that kind.
+    The stage walks the maximal runs of one (mixer, feed-forward) pair in
+    its pattern (``cfg.stage_pattern``) and scans each over its rows of
+    the stacks: the leaves every layer has by the layer's place in the
+    stage, a group's own by its place among the layers of that group.
     """
     norm = _block_norm(cfg)
-    runs = _runs(cfg.stage_kinds(n_stages))
+    pattern = cfg.stage_pattern(n_stages)
+    runs = _runs(pattern)
     if packed and "mamba" in cfg.kinds:
         raise ValueError(
             "packed sequences cannot pass a mamba layer: the scan's state "
             "and the convolution are not reset at a segment boundary")
+    if packed and set(cfg.kinds) & {"sliding_attention", "full_attention"}:
+        raise ValueError(
+            "packed documents through sliding_attention / full_attention "
+            "layers are not built: no test holds their masks together "
+            "with a segment's")
 
-    def layer(kind, x, lp, seg, gathered_seg, experts=None):
+    def layer(kind, ffn, x, lp, seg, gathered_seg, experts=None):
         with jax.named_scope(kind):
             x = mixer_block(kind, x, lp, seg, gathered_seg)
-        with jax.named_scope("moe" if cfg.use_moe else "mlp"):
-            return feed_forward_block(x, lp, experts)
+        with jax.named_scope(ffn):
+            return feed_forward_block(ffn, x, lp, experts)
 
     def mixer_block(kind, x, lp, seg, gathered_seg):
         h = norm(x, lp["ln1"])
-        if kind == "attention":
-            out = attention_mixer(h, lp, seg, gathered_seg)
-        else:
+        if kind == "mamba":
             out = _mamba_mixer(cfg, h, lp)
+        else:
+            out = attention_mixer(kind, h, lp, seg, gathered_seg)
         out = lax.psum(out, "tp")  # combine head shards
+        if cfg.post_norms:
+            out = norm(out, lp["ln1_post"])
         return x + _times(out, cfg.residual_multiplier)
 
-    def attention_mixer(h, lp, seg, gathered_seg):
+    def attention_mixer(kind, h, lp, seg, gathered_seg):
+        window, rope = cfg.attention_of(kind)
         # tp-sharded heads, sp ring
         if "wqkv" in lp:
             qkv = jnp.einsum("btd,dchk->btchk", h, lp["wqkv"])  # h=H/tp
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         else:  # GQA: separate q and (fewer-headed) kv projections
-            q = jnp.einsum("btd,dhk->bthk", h, lp["wq"])
-            kv = jnp.einsum("btd,dchk->btchk", h, lp["wkv"])  # h=Hkv/tp
+            q = checkpoint_name(
+                jnp.einsum("btd,dhk->bthk", h, lp["wq"]), "attn_q")
+            kv = checkpoint_name(
+                jnp.einsum("btd,dchk->btchk", h, lp["wkv"]),  # h=Hkv/tp
+                "attn_kv")
             k, v = kv[:, :, 0], kv[:, :, 1]
-        if cfg.qk_norm:
+        if cfg.qk_norm == "head":  # [b, t, h, k] over k, one scale [k]
+            q = _rmsnorm(q, lp["gq"], cfg.norm_eps)
+            k = _rmsnorm(k, lp["gk"], cfg.norm_eps)
+        elif cfg.qk_norm:
             q = _qk_norm(q, lp["gq"], cfg.norm_eps)
             k = _qk_norm(k, lp["gk"], cfg.norm_eps)
-        if cfg.rope:
+        if rope:
             t_local = h.shape[1]
             pos = (lax.axis_index("sp") * t_local
                    + jnp.arange(t_local, dtype=jnp.int32))
@@ -640,33 +858,55 @@ def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
         attn = context_parallel_attention(
             q, k, v, axis_name="sp", causal=True,
             strategy=cfg.sp_strategy, segment_ids=seg,
-            gathered_segment_ids=gathered_seg,
-            window=cfg.attention_window)
-        return jnp.einsum("bthk,hkd->btd", attn, lp["wo"])
+            gathered_segment_ids=gathered_seg, window=window)
+        if cfg.attn_gate:
+            with jax.named_scope("attn_gate"):
+                attn = _sigmoid_gated(attn, checkpoint_name(jnp.einsum(
+                    "btd,dhk->bthk", h, lp["wgate"]), "attn_gate"))
+        return checkpoint_name(
+            jnp.einsum("bthk,hkd->btd", attn, lp["wo"]), "attn_proj")
 
-    def feed_forward_block(x, lp, experts):
+    def gated_mlp(h, wgu, w2):
+        gu = checkpoint_name(jnp.einsum("btd,dcf->btcf", h, wgu), "mlp_gu")
+        y = jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]
+        return jnp.einsum("btf,fd->btd", y, w2)
+
+    def feed_forward_block(ffn, x, lp, experts):
         h = norm(x, lp["ln2"])
-        if cfg.use_moe:
+        if ffn == "moe":
             stacks, index = experts
+            first = None if cfg.n_experts_held is None else _plus(
+                lax.axis_index("dp") * lp["wg"].shape[0],
+                cfg.first_expert_held)
             y, stats = moe_layer(
-                h, {k: lp[k] for k in ("router", "wg", "wu", "wd")},
-                axis_name="dp", top_k=cfg.moe_top_k,
+                h, {k: lp[k] for k in _ROUTED_LEAVES if k in lp},
+                cfg.n_experts, first, axis_name="dp", top_k=cfg.moe_top_k,
                 norm_topk_prob=cfg.norm_topk_prob, seq_axis_name="sp",
-                stacks=stacks, layer=index)
+                stacks=stacks, layer=index,
+                score_func=cfg.moe_score_func,
+                route_scale=cfg.route_scale)
+            if cfg.n_shared_experts:
+                with jax.named_scope("moe_shared"):
+                    y = y + lax.psum(gated_mlp(
+                        h, lp["shared_wgu"], lp["shared_w2"]), "tp")
+            if cfg.post_norms:
+                y = norm(y, lp["ln2_post"])
             return x + _times(y, cfg.residual_multiplier), stats
         if cfg.gated_mlp:
-            gu = jnp.einsum("btd,dcf->btcf", h, lp["wgu"])
-            y = jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]
+            y = gated_mlp(h, lp["wgu"], lp["w2"])
         else:
             y = jax.nn.gelu(jnp.einsum("btd,df->btf", h, lp["w1"]))
-        y = jnp.einsum("btf,fd->btd", y, lp["w2"])
+            y = jnp.einsum("btf,fd->btd", y, lp["w2"])
         y = lax.psum(y, "tp")  # combine hidden-dim shards
+        if cfg.post_norms:
+            y = norm(y, lp["ln2_post"])
         return x + _times(y, cfg.residual_multiplier)
 
+    keeps = _REMAT_KEEPS if cfg.remat_keeps is None else cfg.remat_keeps
     layer_fn = jax.checkpoint(
-        layer, static_argnums=(0,),
+        layer, static_argnums=(0, 1),
         policy=jax.checkpoint_policies.save_only_these_names(
-            *_REMAT_KEEPS)) if cfg.remat else layer
+            *keeps)) if cfg.remat else layer
 
     def stage_fn(stage_params, x):
         seg = gathered = stats = None
@@ -691,33 +931,33 @@ def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
             # the weight gradients for, one layer an iteration.
             stacks = {k: lax.stop_gradient(stage_params[k])
                       for k in ("wg", "wu", "wd")}
-        for kind, first, first_of_kind, n in runs:
+        for kind, ffn, rows, n in runs:
             run_params = {
-                k: _rows(v, first if _leaf_kind(k) is None
-                         else first_of_kind, n)
+                k: _rows(v, rows[_leaf_group(k)], n)
                 for k, v in stage_params.items()
-                if _leaf_kind(k) in (None, kind)}
-            if not cfg.use_moe:
+                if _leaf_group(k) in rows}
+            if ffn != "moe":
                 x, _ = lax.scan(
-                    lambda x, lp: (layer_fn(kind, x, lp, seg, gathered),
-                                   None), x, run_params)
+                    lambda x, lp: (layer_fn(kind, ffn, x, lp, seg,
+                                            gathered), None), x, run_params)
                 continue
 
             def body(x, scanned):
                 lp, index = scanned
-                return layer_fn(kind, x, lp, seg, gathered,
+                return layer_fn(kind, ffn, x, lp, seg, gathered,
                                 (stacks, index))
 
-            # The layers' places in the stage, for the expert kernels.
+            # The layers' places among the stage's expert layers, for
+            # the expert kernels.
             x, layers = lax.scan(
-                body, x, (run_params, _plus(jnp.arange(n), first)))
-            at = lax.axis_index("pp") * stage_params["wg"].shape[0]
+                body, x, (run_params, _plus(jnp.arange(n), rows["moe"])))
+            at = lax.axis_index("pp") * len(pattern)
             stats = {
                 "lb": stats["lb"] + jnp.sum(layers["lb"]) / cfg.n_layers,
                 "z": stats["z"] + jnp.sum(layers["z"]) / cfg.n_layers,
                 "load": lax.dynamic_update_slice_in_dim(
                     stats["load"], layers["load"].astype(jnp.int32),
-                    _plus(at, first), axis=0)}
+                    _plus(at, rows[None]), axis=0)}
         out = (x,) + ((seg,) if packed else ()) + (
             (stats,) if cfg.use_moe else ())
         return out if len(out) > 1 else x
@@ -827,11 +1067,18 @@ token_nll.defvjp(_token_nll_fwd, _token_nll_bwd)
 
 
 def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
-                 packed: bool = False):
+                 packed: bool = False, with_readings: bool = False):
     """Build loss(params, tokens, labels) -> scalar, shard_mapped over the
     full mesh. tokens/labels: [B_global, T_global] sharded P('dp','sp').
     The ``loss`` scope covers ``token_nll``'s forward pass and its
     hand-written backward pass.
+
+    ``with_readings`` (a ``cfg.use_moe`` model) returns ``(loss,
+    readings)`` instead: ``readings["load"]`` int32 [n_layers, n_experts]
+    the tokens of the global batch that chose each expert in each layer
+    (a dense layer's row is zero), what the train step moves the
+    balancing bias by, and ``readings["token_nll"]`` float32 [B, T] every
+    token's cross-entropy, sharded as the tokens, whose mean the loss is.
 
     With ``cfg.use_moe`` the loss is the mean cross-entropy plus
     ``router_aux_loss_coef`` times the load-balance term and
@@ -852,11 +1099,16 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
                                       n_microbatches,
                                       segment_ids=segment_ids)
         with jax.named_scope("loss"):
-            loss = jnp.mean(token_nll(logits, labels))
+            nll = token_nll(logits, labels)
+            loss = jnp.mean(nll)
             if cfg.use_moe:
                 loss = (loss + cfg.router_aux_loss_coef * stats["lb"]
                         + cfg.router_z_loss_coef * stats["z"])
-            return lax.pmean(loss, ("dp", "sp"))
+            loss = lax.pmean(loss, ("dp", "sp"))
+        if with_readings:
+            return loss, {"load": lax.psum(stats["load"], ("dp", "sp")),
+                          "token_nll": nll}
+        return loss
 
     data = P("dp", "sp")
     in_specs = ((specs, data, data, data) if packed
@@ -864,8 +1116,9 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
     # Around the shard_map, so that every instruction of the pass carries
     # jvp(forward), and transpose(jvp(forward)) in the backward pass.
     return jax.named_scope("forward")(_compat_shard_map(
-        spmd_loss, mesh=mesh, in_specs=in_specs, out_specs=P(),
-        check_vma=False))
+        spmd_loss, mesh=mesh, in_specs=in_specs,
+        out_specs=(P(), {"load": P(), "token_nll": data})
+        if with_readings else P(), check_vma=False))
 
 
 def make_train_step(cfg: TransformerConfig, optimizer, mesh,
@@ -883,10 +1136,20 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
     slice/gather collectives around the elementwise update.
 
     ``packed=True`` builds step(params, opt_state, tokens, labels,
-    segment_ids) for packed-sequence training (``make_loss_fn``)."""
+    segment_ids) for packed-sequence training (``make_loss_fn``).
+
+    With ``cfg.expert_bias_rate`` the step carries state that is no
+    trained parameter: ``params["expert_bias"]`` takes no gradient and
+    the optimizer never sees it (``opt_state`` is made for
+    ``trained(params)``). The step moves it from the tokens each expert
+    got in this very step, under the scope ``router_bias``, and returns
+    ``(params, opt_state, loss, readings)``, ``readings`` the loss
+    function's: that ``load`` [n_layers, n_experts] and every token's
+    cross-entropy ``token_nll`` [B, T] of this step's forward pass."""
     import optax
 
-    loss_fn = make_loss_fn(cfg, mesh, n_microbatches, packed=packed)
+    loss_fn = make_loss_fn(cfg, mesh, n_microbatches, packed=packed,
+                           with_readings=bool(cfg.expert_bias_rate))
 
     @jax.named_scope("optimizer")
     def apply(grads, params, opt_state):
@@ -896,6 +1159,12 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
                 opt_state, opt_shardings)
         return optax.apply_updates(params, updates), opt_state
 
+    if cfg.expert_bias_rate:
+        if packed:
+            raise ValueError("packed documents with the router's balancing "
+                             "bias are not built")
+        return jax.jit(_biased_step(cfg, loss_fn, apply),
+                       donate_argnums=(0, 1))
     # The function's name is the module's on the device trace
     # (docs/diagnostics.md, "Tracing").
     if packed:
@@ -915,6 +1184,40 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
     return jax.jit(hvd_decoder_step, donate_argnums=(0, 1))
 
 
+def update_expert_bias(bias, load, rate: float):
+    """Aux-loss-free balancing (Wang et al., arXiv:2408.15664), centred:
+    ``bias + delta - mean(delta)`` with ``delta = rate * sign(mean(load) -
+    load)`` over each layer's experts; bias float32 [..., E], load the
+    tokens each expert got, same shape."""
+    load = load.astype(jnp.float32)
+    delta = rate * jnp.sign(jnp.mean(load, -1, keepdims=True) - load)
+    return bias + delta - jnp.mean(delta, -1, keepdims=True)
+
+
+def _biased_step(cfg: TransformerConfig, loss_fn, apply):
+    """``make_train_step``'s step of a model whose router has a balancing
+    bias; ``loss_fn`` returns its readings beside the loss."""
+    @jax.named_scope("router_bias")
+    def move(bias, load):
+        # The expert layers' rows (they follow the dense ones), as the
+        # bias is stacked: [S, L, E].
+        load = load[cfg.num_dense_layers:].reshape(bias.shape)
+        return update_expert_bias(bias, load, cfg.expert_bias_rate)
+
+    # A name of its own on the device trace: a module of this name has
+    # always carried the scopes this step writes.
+    def hvd_decoder_bias_step(params, opt_state, tokens, labels):
+        bias, weights = params["expert_bias"], trained(params)
+        (loss, readings), grads = jax.value_and_grad(
+            lambda weights: loss_fn({**weights, "expert_bias": bias},
+                                    tokens, labels), has_aux=True)(weights)
+        weights, opt_state = apply(grads, weights, opt_state)
+        return ({**weights, "expert_bias": move(bias, readings["load"])},
+                opt_state, loss, readings)
+
+    return hvd_decoder_bias_step
+
+
 def dense_reference_loss(cfg: TransformerConfig, params, tokens, labels,
                          segment_ids=None):
     """Unsharded single-device oracle of the dense LayerNorm decoder:
@@ -924,16 +1227,21 @@ def dense_reference_loss(cfg: TransformerConfig, params, tokens, labels,
     variants are held to ``benchmark/reference_moe.py`` instead, and
     everything the Mamba-2 hybrid brought (a layer pattern, the gated
     MLP, a tied head, no positions, the multipliers) to
-    ``benchmark/reference_hybrid.py``."""
+    ``benchmark/reference_hybrid.py``, and sliding and full attention
+    layers, the gate, per-head QK-norm, post-norms, the sigmoid router
+    with its bias, held experts and the shared expert to
+    ``benchmark/reference_afmoe.py``."""
     if (cfg.use_moe or cfg.norm != "layernorm" or cfg.qk_norm
-            or "mamba" in cfg.kinds or cfg.gated_mlp or cfg.tie_embeddings
+            or set(cfg.kinds) != {"attention"} or cfg.attn_gate
+            or cfg.post_norms or cfg.gated_mlp or cfg.tie_embeddings
             or not (cfg.pos_table or cfg.rope)
             or (cfg.embedding_multiplier, cfg.residual_multiplier,
                 cfg.logits_scaling, cfg.attention_multiplier)
             != (1.0, 1.0, 1.0, None)):
         raise ValueError("dense_reference_loss covers the dense LayerNorm "
-                         "decoder only; see benchmark/reference_moe.py and "
-                         "benchmark/reference_hybrid.py")
+                         "decoder only; see benchmark/reference_moe.py, "
+                         "benchmark/reference_hybrid.py and "
+                         "benchmark/reference_afmoe.py")
     from ..parallel.ring_attention import local_flash_attention
 
     def attend(q, k, v):
@@ -1018,8 +1326,10 @@ def make_router_load_fn(cfg: TransformerConfig, mesh,
                         n_microbatches: int = 2):
     """Jitted load(params, tokens) -> int32 [n_layers, n_experts]: how
     many of the global batch's tokens chose each expert in each MoE
-    layer. Every row sums to ``moe_top_k`` times the tokens: nothing is
-    dropped. A program of its own, not an output of the training step."""
+    layer, held here or not. Every expert layer's row sums to
+    ``moe_top_k`` times the tokens: nothing is dropped; a dense layer's
+    row is zero. A program of its own, not an output of the training step
+    (the step of a model with a balancing bias returns its own counts)."""
     stage_fn = _make_stage_fn(cfg, _pipeline_stages(mesh))
     specs = _param_specs(cfg)
 

@@ -46,6 +46,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -1047,6 +1048,10 @@ def _flash_fwd(q, k, v, q_seg, k_seg, q_off, k_off, causal, interpret,
     offs = jnp.asarray([q_off, k_off], jnp.int32)
     o, lse = _flash_forward(q, k, v, offs, causal, interpret, "train",
                             q_seg=q_seg, k_seg=k_seg, window=window)
+    # Named for a caller's ``jax.checkpoint`` policy: a layer that keeps
+    # both runs no forward kernel when it is rematerialized.
+    o = checkpoint_name(o, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
     return o, (q, k, v, q_seg, k_seg, o, lse)
 
 

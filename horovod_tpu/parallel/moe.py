@@ -16,11 +16,24 @@ and are summed. Dispatch and combine are gathers in both directions (the
 transpose of a permutation gather is the gather by its inverse), never a
 one-hot and never a scatter.
 
+The layer is told how many experts the router scores (``n_experts``) and
+which of them it holds (``first`` and the leading size of its matrices).
+It routes over all of them and computes the part of the result its own
+experts give: the held experts' groups come first in the sort, the
+assignments that fell on other experts after them, which no group
+covers, and their rows are zeroed. A chip that holds a share of a
+deployment's experts and runs without its fellows gives that partial
+result as it is; nothing stands in for the absent ones.
+
 Expert parallelism rides ``axis_name`` (the ``dp`` mesh axis): each of
 its ``ep`` members holds ``E / ep`` experts. The same code runs on every
-member over the all-gathered tokens with its own experts' groups first in
-the sort, and the partial results are reduce-scattered back; at ``ep`` 1
-both collectives are the identity.
+member over the all-gathered tokens, and the partial results are
+reduce-scattered back; at ``ep`` 1 both collectives are the identity.
+
+The router scores by softmax (the loss terms ``lb`` and ``z`` are its) or
+by sigmoid; an ``expert_bias`` moves which experts a token picks and
+never their weights (aux-loss-free balancing: the caller moves it by the
+``load`` this layer returns).
 """
 
 from __future__ import annotations
@@ -160,30 +173,43 @@ def _gated(gate, up, weight):
             * weight[:, None]).astype(gate.dtype)
 
 
-def moe_layer(x, params, axis_name: str = "dp", top_k: int = 1,
-              norm_topk_prob: bool = False, seq_axis_name=None,
-              stacks=None, layer=0):
+def moe_layer(x, params, n_experts: int, first=None, axis_name: str = "dp",
+              top_k: int = 1, norm_topk_prob: bool = False,
+              seq_axis_name=None, stacks=None, layer=0,
+              score_func: str = "softmax", route_scale: float = 1.0):
     """Top-k MoE over the tokens of ``x`` [B, T, d] (local sequences).
 
-    ``params``: ``router`` [d, E] float32 (replicated), and this member's
-    experts ``wg``, ``wu`` [E_local, d, f], ``wd`` [E_local, f, d] — the
-    local shard when run under shard_map with the expert dimension
-    sharded over ``axis_name``. A token's output is ``sum_j p[e_j] *
-    (silu(u Wg[e_j]) * (u Wu[e_j])) Wd[e_j]`` over its ``top_k`` largest
-    router probabilities ``p``, taken as they are unless
-    ``norm_topk_prob`` divides them by their sum.
+    ``params``: ``router`` [d, E] float32 (replicated) over all ``E =
+    n_experts``, and the experts held here ``wg``, ``wu`` [E_held, d, f],
+    ``wd`` [E_held, f, d], which are experts ``first .. first + E_held``.
+    ``first`` None is the share of a member of ``axis_name`` under
+    shard_map with the expert dimension sharded over it: member ``m`` of
+    ``ep`` holds ``E / ep`` experts from ``m * E / ep``. A token's output
+    is ``sum_j w[e_j] * (silu(u Wg[e_j]) * (u Wu[e_j])) Wd[e_j]`` over
+    those of its ``top_k`` experts that are held (all of them over the
+    members of ``axis_name`` together).
+
+    ``score_func`` ``"softmax"``: the ``top_k`` largest router
+    probabilities ``p``, and ``w = p`` as they are unless
+    ``norm_topk_prob`` divides them by their sum. ``"sigmoid"``: scores
+    ``s = sigmoid(logits)``; the ``top_k`` largest of ``s +
+    params["expert_bias"]`` [E] (float32, no gradient; absent: zero) are
+    picked, and ``w = route_scale * s`` of the picked, with
+    ``norm_topk_prob`` over their sum (+ 1e-20) first. The sum is over
+    all ``top_k`` picked experts, held or not.
 
     Returns ``(y [B, T, d], stats)``. ``stats["lb"]`` is the load-balance
     term ``E * sum_e f_e P_e`` of each sequence (``f_e`` the share of its
     tokens with ``e`` among their ``top_k``, ``P_e`` its mean router
     probability; ``top_k`` at perfect balance), averaged over the local
     sequences; ``stats["z"]`` the mean squared log-sum-exp of the router
-    logits; ``stats["load"]`` [E] the local tokens per expert.
+    logits (both zero under ``"sigmoid"``, which has no such terms);
+    ``stats["load"]`` [E] the local tokens per expert, held or not.
     ``seq_axis_name`` names the mesh axis the T axis is sharded over, if
     any, so that ``f`` and ``P`` are those of whole sequences.
 
     A caller that holds the experts of several layers stacked, ``wg``,
-    ``wu`` [L, E_local, d, f] and ``wd`` [L, E_local, f, d], passes them
+    ``wu`` [L, E_held, d, f] and ``wd`` [L, E_held, f, d], passes them
     as ``stacks`` (constants: under ``lax.stop_gradient``) with this
     layer's index ``layer``, ``params`` holding the same matrices as
     ``stacks[name][layer]``: the Pallas kernels then read the stack in
@@ -193,9 +219,20 @@ def moe_layer(x, params, axis_name: str = "dp", top_k: int = 1,
     ep = _axis_size(axis_name)
     B, T, d = x.shape
     e_local = params["wg"].shape[0]
-    E = e_local * ep
+    E = n_experts
+    if first is None and e_local * ep != E:
+        raise ValueError(
+            f"{ep} members of {axis_name!r} holding {e_local} experts each "
+            f"are not the {E} the router scores; say which are held "
+            f"(first)")
     if not 1 <= top_k <= E:
         raise ValueError(f"top_k={top_k} must be in [1, {E}]")
+    if score_func not in ("softmax", "sigmoid"):
+        raise ValueError(f"score_func must be 'softmax' or 'sigmoid', got "
+                         f"{score_func!r}")
+    # Assignments on experts that are not held here exist: their rows
+    # are sorted after the held groups and zeroed.
+    elsewhere = e_local < E
 
     with jax.named_scope("moe_route"):
         # float32 in earnest: at default precision the MXU would round
@@ -203,23 +240,40 @@ def moe_layer(x, params, axis_name: str = "dp", top_k: int = 1,
         logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32),
                             params["router"],
                             precision=lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gates, experts = lax.top_k(probs, top_k)  # [B, T, k]
-        if norm_topk_prob:
-            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        if score_func == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            biased = scores
+            if "expert_bias" in params:
+                biased = scores + lax.stop_gradient(params["expert_bias"])
+            experts = lax.top_k(biased, top_k)[1]  # [B, T, k]
+            gates = jnp.take_along_axis(scores, experts, axis=-1)
+            if norm_topk_prob:
+                gates = gates / (jnp.sum(gates, axis=-1, keepdims=True)
+                                 + 1e-20)
+            gates = gates * route_scale
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
+            gates, experts = lax.top_k(probs, top_k)  # [B, T, k]
+            if norm_topk_prob:
+                gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
         picked = jnp.sum(
             experts[..., None] == jnp.arange(E, dtype=experts.dtype),
             axis=(1, 2))  # [B, E]: tokens of the sequence that chose e
-        f = picked.astype(jnp.float32) / T
-        p_mean = jnp.mean(probs, axis=1)
-        if seq_axis_name is not None:
-            f = lax.pmean(f, seq_axis_name)
-            p_mean = lax.pmean(p_mean, seq_axis_name)
-        stats = {
-            "lb": E * jnp.mean(jnp.sum(f * p_mean, axis=-1)),
-            "z": jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
-            "load": jnp.sum(picked, axis=0),
-        }
+        if score_func == "sigmoid":
+            zero = jnp.zeros((), jnp.float32)
+            stats = {"lb": zero, "z": zero, "load": jnp.sum(picked, axis=0)}
+        else:
+            f = picked.astype(jnp.float32) / T
+            p_mean = jnp.mean(probs, axis=1)
+            if seq_axis_name is not None:
+                f = lax.pmean(f, seq_axis_name)
+                p_mean = lax.pmean(p_mean, seq_axis_name)
+            stats = {
+                "lb": E * jnp.mean(jnp.sum(f * p_mean, axis=-1)),
+                "z": jnp.mean(jnp.square(jax.nn.logsumexp(logits,
+                                                           axis=-1))),
+                "load": jnp.sum(picked, axis=0),
+            }
 
     with jax.named_scope("moe_dispatch"):
         rows = x.reshape(B * T, d)
@@ -229,9 +283,10 @@ def moe_layer(x, params, axis_name: str = "dp", top_k: int = 1,
             rows, experts, gates = (
                 lax.all_gather(a, axis_name, tiled=True)
                 for a in (rows, experts, gates))
-        # This member's experts first, in their order; then the others',
-        # which no group below covers.
-        first = lax.axis_index(axis_name) * e_local
+        # The held experts first, in their order; then the others', which
+        # no group below covers.
+        if first is None:
+            first = lax.axis_index(axis_name) * e_local
         local = (experts - first) % E
         order = jnp.argsort(local)
         inverse = jnp.argsort(order)
@@ -239,8 +294,8 @@ def moe_layer(x, params, axis_name: str = "dp", top_k: int = 1,
             local[:, None] == jnp.arange(e_local, dtype=local.dtype),
             axis=0, dtype=jnp.int32)
         rows = _take_rows(rows, order, inverse, top_k)  # [k N, d]
-        if ep > 1:
-            # Rows of other members' experts: no group covers them, and a
+        if elsewhere:
+            # Rows of experts held elsewhere: no group covers them, and a
             # grouped matmul may leave anything there, in either pass.
             mine = (local[order] < e_local)[:, None]
             rows = jnp.where(mine, rows, jnp.zeros_like(rows))
@@ -250,7 +305,7 @@ def moe_layer(x, params, axis_name: str = "dp", top_k: int = 1,
         # the third matmul: p (h Wd) = (p h) Wd, and the rows need no
         # weighting on their way back.
         weight = _take_rows(gates[:, None], order, inverse, 1)
-        if ep > 1:
+        if elsewhere:
             weight = jnp.where(mine, weight, jnp.zeros_like(weight))
 
         def experts(lhs, name):
@@ -262,7 +317,7 @@ def moe_layer(x, params, axis_name: str = "dp", top_k: int = 1,
         out = experts(hidden, "wd")
 
     with jax.named_scope("moe_combine"):
-        if ep > 1:
+        if elsewhere:
             out = jnp.where(mine, out, jnp.zeros_like(out))
         out = _take_rows(out, inverse, order, 1)  # token-major again
         y = jnp.sum(out.reshape(-1, top_k, d), axis=1, dtype=jnp.float32)

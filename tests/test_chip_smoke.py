@@ -130,6 +130,21 @@ def test_decoder_phase_one_device():
                              jax.devices()[:1])
 
 
+def test_decoder_phase_with_the_afmoe_block():
+    """The smoke's `decoder-afmoe` phase at a toy size: the step that
+    moves a balancing bias returns four results, and its optimizer state
+    is made for the trained leaves alone."""
+    import dataclasses
+
+    toy = dataclasses.replace(
+        chip_smoke.TransformerConfig(**chip_smoke.AFMOE), vocab=64,
+        d_model=32, n_heads=4, d_head=8, d_ff=64, d_expert=16, max_seq=32,
+        sliding_window=8, embedding_multiplier=32 ** 0.5)
+    losses = chip_smoke.phase_decoder("toy-afmoe", toy, 2, 3,
+                                      jax.devices()[:1])
+    assert len(losses) == 3 and losses[-1] < losses[0]
+
+
 @pytest.mark.full
 def test_decoder_parallel_phase(monkeypatch):
     """dp 2 x tp 2 and sp 4 (ring, block kernels interpreted) against one
@@ -341,7 +356,7 @@ def test_expert_layer_compiles_for_v5e(described_chip, monkeypatch, dtype):
     x = jax.ShapeDtypeStruct((2, 4096, d), dtype, sharding=described_chip)
 
     def loss(x, p):
-        y, stats = moe.moe_layer(x, p, axis_name="dp", top_k=k)
+        y, stats = moe.moe_layer(x, p, E, axis_name="dp", top_k=k)
         return (jnp.sum(jnp.square(y.astype(jnp.float32))) + stats["lb"]
                 + stats["z"])
 

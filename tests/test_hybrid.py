@@ -298,7 +298,13 @@ def test_no_position_table_and_no_head_leaf():
     (("mamba",) * 5 + ("attention",) + ("mamba",) * 4,
      [("mamba", 0, 0, 5), ("attention", 5, 0, 1), ("mamba", 6, 5, 4)])])
 def test_the_stage_walks_the_patterns_maximal_runs(kinds, want):
-    assert transformer._runs(kinds) == want
+    """(mixer, first layer, earlier layers of its kind, length); every
+    layer ends in the dense MLP, whose stack has a row a layer."""
+    runs = transformer._runs([(kind, "mlp") for kind in kinds])
+    assert [(kind, rows[None], rows[kind], n)
+            for kind, _, rows, n in runs] == want
+    assert all(ffn == "mlp" and rows["mlp"] == rows[None]
+               for _, ffn, rows, _ in runs)
 
 
 @pytest.mark.parametrize("base", [

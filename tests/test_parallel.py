@@ -198,7 +198,8 @@ def _run_moe_layer(x, params, ep=1, **kw):
     xs = jax.device_put(x, NamedSharding(mesh, P("dp")))
 
     def layer(x, p):
-        y, stats = moe_layer(x, p, axis_name="dp", **kw)
+        y, stats = moe_layer(x, p, p["router"].shape[-1], axis_name="dp",
+                             **kw)
         return y, {"lb": jax.lax.pmean(stats["lb"], "dp"),
                    "z": jax.lax.pmean(stats["z"], "dp"),
                    "load": jax.lax.psum(stats["load"], "dp")}

@@ -675,11 +675,13 @@ def _sigmoid_gated(attn, gate):
 def _zero_router_stats(cfg: TransformerConfig, lead):
     """What the MoE layers add up as the activations pass through them,
     with leading shape ``lead``: the two loss terms as means over all
-    layers, tokens per expert by layer."""
+    layers, tokens per expert and the windows of the sorted assignments
+    taken (``parallel.moe``) by layer."""
     return {"lb": jnp.zeros(lead, jnp.float32),
             "z": jnp.zeros(lead, jnp.float32),
             "load": jnp.zeros(lead + (cfg.n_layers, cfg.n_experts),
-                              jnp.int32)}
+                              jnp.int32),
+            "windows": jnp.zeros(lead + (cfg.n_layers,), jnp.int32)}
 
 
 def _causal_depthwise_conv(x, w, bias):
@@ -951,13 +953,13 @@ def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
             # the expert kernels.
             x, layers = lax.scan(
                 body, x, (run_params, _plus(jnp.arange(n), rows["moe"])))
-            at = lax.axis_index("pp") * len(pattern)
+            at = _plus(lax.axis_index("pp") * len(pattern), rows[None])
             stats = {
                 "lb": stats["lb"] + jnp.sum(layers["lb"]) / cfg.n_layers,
                 "z": stats["z"] + jnp.sum(layers["z"]) / cfg.n_layers,
-                "load": lax.dynamic_update_slice_in_dim(
-                    stats["load"], layers["load"].astype(jnp.int32),
-                    _plus(at, rows[None]), axis=0)}
+                **{k: lax.dynamic_update_slice_in_dim(
+                    stats[k], layers[k].astype(jnp.int32), at, axis=0)
+                   for k in ("load", "windows")}}
         out = (x,) + ((seg,) if packed else ()) + (
             (stats,) if cfg.use_moe else ())
         return out if len(out) > 1 else x
@@ -976,8 +978,8 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
 
     Returns ``(logits, router statistics)``; the statistics
     (``_zero_router_stats``: the two loss terms as means over this
-    member's sequences, tokens per expert summed over them) are None
-    without ``cfg.use_moe``."""
+    member's sequences, tokens per expert summed over them, the most
+    windows a microbatch took) are None without ``cfg.use_moe``."""
     b, t = tokens.shape
     with jax.named_scope("embed"):
         sp_idx = lax.axis_index("sp")
@@ -1022,7 +1024,8 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
     if cfg.use_moe:
         y, per_mb = y
         stats = {"lb": jnp.mean(per_mb["lb"]), "z": jnp.mean(per_mb["z"]),
-                 "load": jnp.sum(per_mb["load"], axis=0)}
+                 "load": jnp.sum(per_mb["load"], axis=0),
+                 "windows": jnp.max(per_mb["windows"], axis=0)}
     y = y.reshape(b, t, -1)
 
     with jax.named_scope("head"):
@@ -1077,8 +1080,12 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
     readings)`` instead: ``readings["load"]`` int32 [n_layers, n_experts]
     the tokens of the global batch that chose each expert in each layer
     (a dense layer's row is zero), what the train step moves the
-    balancing bias by, and ``readings["token_nll"]`` float32 [B, T] every
-    token's cross-entropy, sharded as the tokens, whose mean the loss is.
+    balancing bias by, ``readings["windows"]`` int32 [n_layers] the most
+    windows of the sorted assignments an expert layer took on any member
+    (``parallel.moe``: 1 where the held experts' rows fit one; a dense
+    layer's entry is zero), and ``readings["token_nll"]`` float32 [B, T]
+    every token's cross-entropy, sharded as the tokens, whose mean the
+    loss is.
 
     With ``cfg.use_moe`` the loss is the mean cross-entropy plus
     ``router_aux_loss_coef`` times the load-balance term and
@@ -1107,6 +1114,8 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
             loss = lax.pmean(loss, ("dp", "sp"))
         if with_readings:
             return loss, {"load": lax.psum(stats["load"], ("dp", "sp")),
+                          "windows": lax.pmax(stats["windows"],
+                                              ("dp", "sp")),
                           "token_nll": nll}
         return loss
 
@@ -1117,7 +1126,7 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
     # jvp(forward), and transpose(jvp(forward)) in the backward pass.
     return jax.named_scope("forward")(_compat_shard_map(
         spmd_loss, mesh=mesh, in_specs=in_specs,
-        out_specs=(P(), {"load": P(), "token_nll": data})
+        out_specs=(P(), {"load": P(), "windows": P(), "token_nll": data})
         if with_readings else P(), check_vma=False))
 
 
@@ -1144,8 +1153,9 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
     ``trained(params)``). The step moves it from the tokens each expert
     got in this very step, under the scope ``router_bias``, and returns
     ``(params, opt_state, loss, readings)``, ``readings`` the loss
-    function's: that ``load`` [n_layers, n_experts] and every token's
-    cross-entropy ``token_nll`` [B, T] of this step's forward pass."""
+    function's: that ``load`` [n_layers, n_experts], the ``windows``
+    [n_layers] its expert layers took and every token's cross-entropy
+    ``token_nll`` [B, T] of this step's forward pass."""
     import optax
 
     loss_fn = make_loss_fn(cfg, mesh, n_microbatches, packed=packed,

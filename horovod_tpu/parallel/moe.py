@@ -14,16 +14,46 @@ so a scan over layers copies none out for them. The rows go back to their
 tokens by the inverse permutation, already under the router's weights,
 and are summed. Dispatch and combine are gathers in both directions (the
 transpose of a permutation gather is the gather by its inverse), never a
-one-hot and never a scatter.
+one-hot over the tokens or the experts and never a scatter.
 
 The layer is told how many experts the router scores (``n_experts``) and
 which of them it holds (``first`` and the leading size of its matrices).
 It routes over all of them and computes the part of the result its own
 experts give: the held experts' groups come first in the sort, the
 assignments that fell on other experts after them, which no group
-covers, and their rows are zeroed. A chip that holds a share of a
-deployment's experts and runs without its fellows gives that partial
-result as it is; nothing stands in for the absent ones.
+covers. A chip that holds a share of a deployment's experts and runs
+without its fellows gives that partial result as it is; nothing stands in
+for the absent ones.
+
+Only the rows the held experts use are moved. Everything from the gather
+of the rows to the sum over a token's picks (``_window``) works on a
+window of ``C`` consecutive positions of the sort: the rows, the two
+products, the gated product, the result and their gradients are ``[C,
+.]``. ``C`` is derived, not set (``_window_rows``): the least multiple of
+the grouped matmul's row tile that is twice the held experts' share of
+the assignments at balance, ``2 * (E_held / E) * top_k * N`` (``N`` after
+the all-gather); twice, because a seeded or a trained router does not
+balance (``_WINDOW_OVER_BALANCE``). The layer takes as many windows as
+the held rows need, ``ceil(held rows / C)``, which is data (``stats
+["windows"]``), in a ``lax.while_loop``. A routing that puts
+every pick on held experts takes ``top_k * N / C`` windows and gives the
+same result, slower. Where ``C`` is ``top_k * N`` or more (all experts
+held, or ``ep`` up to 2) the layer is the window's body called once on
+the whole sort, with no loop around it. The trip count being data,
+reverse mode is written out (``_windows``): it keeps the section's inputs
+alone and takes each window's ``jax.vjp`` in a second loop.
+
+A window moves rows by gathers in both directions and never by a
+scatter. Rows to their sorted positions and back (``_take_rows``, and
+the gradient of the sum) are gathers of ``C`` rows. The two sums over a
+token's rows in a window (the combine, and the row gradient of the
+dispatch: ``_placed_sums``) are a sorted segment sum: the rows gathered
+token-major, then added up, on the chip by the same Pallas ``tgmm`` with
+blocks of 256 tokens as its groups and an indicator of the row's token
+in its block as the other operand (``[256, C]``, never ``[N, C]``),
+elsewhere by a lookup of every pick with those outside the window
+masked. What a grouped matmul leaves in the rows no group covers is never
+read: the sums count the held rows alone.
 
 Expert parallelism rides ``axis_name`` (the ``dp`` mesh axis): each of
 its ``ep`` members holds ``E / ep`` experts. The same code runs on every
@@ -71,32 +101,116 @@ def init_moe_params(rng, d_model: int, d_ff: int, n_experts: int,
     }
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_rows(x, index, inverse, fan):
-    """``x[index // fan]`` for a permutation ``index`` of ``fan *
-    len(x)`` with inverse ``inverse``. Its transpose is the gather by the
-    inverse, summed over each row's ``fan`` copies."""
+# Tokens a group of the token-side sum's grouped matmul holds: its
+# indicator is [_TOKEN_BLOCK, rows], one MXU pass high.
+_TOKEN_BLOCK = 256
+
+
+def _placed_sums(table, place, index, count, fan, dtype):
+    """[len(place) / fan, d]: for each token, a run of ``fan`` entries of
+    ``place``, the sum in ``dtype`` of the rows of ``table`` its entries
+    name. Only the first ``count`` rows count: an entry that names another
+    adds nothing, whatever lies there. ``index`` is the other way round:
+    the entry that names each row. ``count`` None: every row counts and
+    ``place`` is a permutation of them.
+
+    With a ``count`` the sums are a sorted segment sum. On the chip: the
+    rows that count gathered token-major, then jax's Pallas ``tgmm`` with
+    blocks of ``_TOKEN_BLOCK`` tokens as its groups and, as its other
+    operand, which of its block's tokens each row belongs to; float32
+    accumulation on the MXU, every product a row's own value. Elsewhere a
+    lookup of every entry with those that add nothing masked."""
+    rows, d = table.shape
+    tokens = len(place) // fan
+    if count is None:
+        return table[place].reshape(tokens, fan, d).sum(1, dtype=dtype)
+    use_pallas, interpret = _pallas_attention._resolve_dispatch(None)
+    if use_pallas and d % 128 == 0 and tokens % _TOKEN_BLOCK == 0:
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+        # The rows that do not count last, past every token.
+        at = jnp.arange(rows)
+        entry, by_token = lax.sort_key_val(
+            jnp.where(at < count, index, len(place)), at)
+        token = entry // fan
+        blocks = tokens // _TOKEN_BLOCK
+        which = (token % _TOKEN_BLOCK
+                 == jnp.arange(_TOKEN_BLOCK)[:, None]).astype(table.dtype)
+        sizes = jnp.sum(token[:, None] // _TOKEN_BLOCK == jnp.arange(blocks),
+                        axis=0, dtype=jnp.int32)
+        with jax.named_scope("moe_token_sums"):
+            sums = tgmm(which, table[by_token], sizes, dtype,
+                        _gmm_tiling((rows, _TOKEN_BLOCK, d), table.dtype),
+                        None, blocks, interpret=interpret)
+        return sums.reshape(tokens, d)
+    picked = jnp.where(((place >= 0) & (place < count))[:, None],
+                       table[jnp.clip(place, 0, rows - 1)],
+                       jnp.zeros((), table.dtype))
+    return picked.reshape(tokens, fan, d).sum(1, dtype=dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _take_rows(x, index, place, count, fan):
+    """``x[index // fan]``: of the ``fan * len(x)`` copies of ``x``'s
+    rows, those ``index`` names, which is a window of a permutation of
+    them; ``place`` says for every copy where in ``index`` it is named, or
+    a number outside it. Of what comes back for the rows only the first
+    ``count`` count (None: all), and the transpose is no scatter: each
+    row's copies among them, summed (``_placed_sums``)."""
     return x[index // fan]
 
 
-def _take_rows_fwd(x, index, inverse, fan):
-    return x[index // fan], inverse
+def _take_rows_fwd(x, index, place, count, fan):
+    # Without a count the transpose reads ``place`` alone.
+    return x[index // fan], (place, None if count is None else index, count)
 
 
-def _take_rows_bwd(fan, inverse, g):
-    return g[inverse].reshape(-1, fan, g.shape[-1]).sum(1), None, None
+def _take_rows_bwd(fan, residuals, g):
+    return _placed_sums(g, *residuals, fan, g.dtype), None, None, None
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-def _gmm_tiling(lhs, stack):
-    """The kernels' (rows, contraction, columns) tile of ``lhs`` [m, k] by
-    ``stack`` [L, g, k, n]: one tiling for the product and both of its
-    gradients."""
-    sizes = (lhs.shape[0], lhs.shape[1], stack.shape[3])
-    caps = _GMM_TILE_CAPS[lhs.dtype.itemsize]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _sum_rows(table, place, index, count, fan):
+    """The transpose of ``_take_rows``, in float32: ``_placed_sums`` of
+    ``table``, whose own transpose is the gather ``g[index // fan]``."""
+    return _placed_sums(table, place, index, count, fan, jnp.float32)
+
+
+def _sum_rows_fwd(table, place, index, count, fan):
+    # The empty slice carries the table's type to the backward pass.
+    return (_placed_sums(table, place, index, count, fan, jnp.float32),
+            (index, count, table[:0]))
+
+
+def _sum_rows_bwd(fan, residuals, g):
+    index, count, like = residuals
+    g = g.astype(like.dtype)
+    if count is None:
+        # Every row's copies written out and gathered, as reverse mode
+        # writes the sum's transpose, which keeps the layer that holds
+        # every expert the program it was before there were windows.
+        copies = jnp.broadcast_to(g[:, None], (len(g), fan, g.shape[-1]))
+        return copies.reshape(len(index), -1)[index], None, None, None
+    return g[index // fan], None, None, None
+
+
+_sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
+
+
+def _gmm_tiling(sizes, dtype):
+    """The kernels' (rows, contraction, columns) tile of a product of
+    those ``sizes`` with operands of ``dtype``: one tiling for the product
+    and both of its gradients."""
+    caps = _GMM_TILE_CAPS[jnp.dtype(dtype).itemsize]
     return tuple(math.gcd(size, cap) for size, cap in zip(sizes, caps))
+
+
+def _stack_tiling(lhs, stack):
+    """``_gmm_tiling`` of ``lhs`` [m, k] by ``stack`` [L, g, k, n]."""
+    return _gmm_tiling((*lhs.shape, stack.shape[3]), lhs.dtype)
 
 
 def _gmm_of_layer(lhs, stack, layer, group_sizes, tiling, transpose_rhs,
@@ -130,7 +244,7 @@ def _stacked_gmm(lhs, rhs, stack, layer, group_sizes, interpret):
 def _stacked_gmm_fwd(lhs, rhs, stack, layer, group_sizes, interpret):
     del rhs
     out = _gmm_of_layer(lhs, stack, layer, group_sizes,
-                        _gmm_tiling(lhs, stack), False, interpret)
+                        _stack_tiling(lhs, stack), False, interpret)
     return out, (lhs, stack, layer, group_sizes)
 
 
@@ -138,7 +252,7 @@ def _stacked_gmm_bwd(interpret, residuals, grad):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
 
     lhs, stack, layer, group_sizes = residuals
-    tiling = _gmm_tiling(lhs, stack)
+    tiling = _stack_tiling(lhs, stack)
     grad_lhs = _gmm_of_layer(grad, stack, layer, group_sizes, tiling, True,
                              interpret)
     grad_rhs = tgmm(lhs.swapaxes(0, 1), grad, group_sizes, stack.dtype,
@@ -173,6 +287,120 @@ def _gated(gate, up, weight):
             * weight[:, None]).astype(gate.dtype)
 
 
+# A window of the sorted assignments is twice the rows the held experts
+# get at balance: trinity-mini-t8192's seeded routers send 10.3 to 14.0 % of
+# the assignments to an eighth of the experts (PERF.md section 7), so one
+# window of a quarter holds them with room to spare, and the step's
+# ``windows`` says when training moves that.
+_WINDOW_OVER_BALANCE = 2
+
+
+def _window_rows(assignments: int, e_local: int, n_experts: int) -> int:
+    """Positions of the sorted assignments a window holds: the least
+    multiple of the grouped matmul's row tile that is ``_WINDOW_OVER_
+    BALANCE`` times ``assignments * e_local / n_experts`` or more."""
+    tile = _GMM_TILE_CAPS[2][0]
+    return -(-_WINDOW_OVER_BALANCE * e_local * assignments
+             // (n_experts * tile)) * tile
+
+
+def _window(x, gates, held, index, place, sizes, stacks, layer, top_k,
+            elsewhere):
+    """What the held experts give the tokens ``x`` [N, d] for the
+    assignments at consecutive positions of the sort, float32 [N, d]:
+    ``index`` the assignments there, ``place`` every assignment's
+    position counted from the first of them, ``sizes`` the held groups'
+    rows among them, from the first position on. ``elsewhere``: the
+    positions after the groups hold assignments that no group covers; a
+    grouped matmul may leave anything in their rows, in either pass, and
+    the sums over a token's rows leave them out."""
+    count = jnp.sum(sizes) if elsewhere else None
+    with jax.named_scope("moe_dispatch"):
+        rows = _take_rows(x, index, place, count, top_k)
+
+    with jax.named_scope("moe_experts"):
+        # The router's weight rides the gated product, in float32, into
+        # the third matmul: p (h Wd) = (p h) Wd, and the rows need no
+        # weighting on their way back.
+        weight = _take_rows(gates[:, None], index, place, count, 1)
+
+        def experts(lhs, name):
+            return _grouped_matmul(lhs, held[name], sizes,
+                                   stacks[name] if stacks else None, layer)
+
+        hidden = _gated(experts(rows, "wg"), experts(rows, "wu"),
+                        weight[:, 0])
+        out = experts(hidden, "wd")
+
+    with jax.named_scope("moe_combine"):
+        return _sum_rows(out, place, index, count, top_k)  # by token again
+
+
+@functools.partial(jax.jit, static_argnames=("top_k", "rows"))
+def _window_from(start, x, gates, held, order, inverse, group_sizes, stacks,
+                 layer, *, top_k, rows):
+    """``_window`` over the ``rows`` positions of the sort from ``start``
+    (``order`` reaches that far): the groups clipped to them. Under
+    ``jax.jit`` for what that does to tracing: every layer's window of the
+    same shapes is traced once, and the scopes inside stay the path
+    segments they are when reverse mode goes through (a scope opened
+    under ``jax.vjp`` itself would read ``transpose(jvp(scope))``)."""
+    ends = jnp.clip(jnp.cumsum(group_sizes) - start, 0, rows)
+    return _window(x, gates, held, lax.dynamic_slice(order, (start,), (rows,)),
+                   inverse - start, jnp.diff(ends, prepend=0), stacks, layer,
+                   top_k, True)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
+def _windows(x, gates, held, order, inverse, group_sizes, windows, stacks,
+             layer, top_k, rows):
+    """The sum of ``_window_from`` over the first ``windows`` (data)
+    windows of ``rows`` positions, in a ``lax.while_loop``. Reverse mode
+    is written out because the trip count is data: it keeps the inputs
+    alone, takes each window's ``jax.vjp`` in a second loop and adds up
+    what they give ``x``, ``gates`` and ``held``, each in its own type;
+    ``stacks``, a constant, gets nothing."""
+    return _windows_fwd(x, gates, held, order, inverse, group_sizes, windows,
+                        stacks, layer, top_k, rows)[0]
+
+
+def _over_windows(windows, one):
+    """``one(0) + one(1) + ... + one(windows - 1)``, trees added leaf by
+    leaf to zeros of their own types."""
+    def turn(carry):
+        at, total = carry
+        return at + 1, jax.tree.map(jnp.add, total, one(at))
+
+    zeros = jax.tree.map(lambda leaf: jnp.zeros(leaf.shape, leaf.dtype),
+                         jax.eval_shape(one, jnp.int32(0)))
+    return lax.while_loop(lambda carry: carry[0] < windows, turn,
+                          (jnp.int32(0), zeros))[1]
+
+
+def _windows_fwd(x, gates, held, order, inverse, group_sizes, windows,
+                 stacks, layer, top_k, rows):
+    y = _over_windows(windows, lambda at: _window_from(
+        at * rows, x, gates, held, order, inverse, group_sizes, stacks,
+        layer, top_k=top_k, rows=rows))
+    return y, (x, gates, held, order, inverse, group_sizes, windows, stacks,
+               layer)
+
+
+def _windows_bwd(top_k, rows, residuals, g):
+    x, gates, held, order, inverse, group_sizes, windows, stacks, layer = \
+        residuals
+
+    def pull(at):
+        return jax.vjp(lambda x, gates, held: _window_from(
+            at * rows, x, gates, held, order, inverse, group_sizes, stacks,
+            layer, top_k=top_k, rows=rows), x, gates, held)[1](g)
+
+    return _over_windows(windows, pull) + (None,) * 6
+
+
+_windows.defvjp(_windows_fwd, _windows_bwd)
+
+
 def moe_layer(x, params, n_experts: int, first=None, axis_name: str = "dp",
               top_k: int = 1, norm_topk_prob: bool = False,
               seq_axis_name=None, stacks=None, layer=0,
@@ -204,7 +432,10 @@ def moe_layer(x, params, n_experts: int, first=None, axis_name: str = "dp",
     probability; ``top_k`` at perfect balance), averaged over the local
     sequences; ``stats["z"]`` the mean squared log-sum-exp of the router
     logits (both zero under ``"sigmoid"``, which has no such terms);
-    ``stats["load"]`` [E] the local tokens per expert, held or not.
+    ``stats["load"]`` [E] the local tokens per expert, held or not;
+    ``stats["windows"]`` the windows of the sorted assignments this layer
+    took (int32; the module's docstring): 1 where the held experts' rows
+    fit one, and where one window holds every assignment.
     ``seq_axis_name`` names the mesh axis the T axis is sharded over, if
     any, so that ``f`` and ``P`` are those of whole sequences.
 
@@ -230,10 +461,6 @@ def moe_layer(x, params, n_experts: int, first=None, axis_name: str = "dp",
     if score_func not in ("softmax", "sigmoid"):
         raise ValueError(f"score_func must be 'softmax' or 'sigmoid', got "
                          f"{score_func!r}")
-    # Assignments on experts that are not held here exist: their rows
-    # are sorted after the held groups and zeroed.
-    elsewhere = e_local < E
-
     with jax.named_scope("moe_route"):
         # float32 in earnest: at default precision the MXU would round
         # both operands to bf16 and the top-k with them.
@@ -293,34 +520,24 @@ def moe_layer(x, params, n_experts: int, first=None, axis_name: str = "dp",
         group_sizes = jnp.sum(
             local[:, None] == jnp.arange(e_local, dtype=local.dtype),
             axis=0, dtype=jnp.int32)
-        rows = _take_rows(rows, order, inverse, top_k)  # [k N, d]
-        if elsewhere:
-            # Rows of experts held elsewhere: no group covers them, and a
-            # grouped matmul may leave anything there, in either pass.
-            mine = (local[order] < e_local)[:, None]
-            rows = jnp.where(mine, rows, jnp.zeros_like(rows))
 
-    with jax.named_scope("moe_experts"):
-        # The router's weight rides the gated product, in float32, into
-        # the third matmul: p (h Wd) = (p h) Wd, and the rows need no
-        # weighting on their way back.
-        weight = _take_rows(gates[:, None], order, inverse, 1)
-        if elsewhere:
-            weight = jnp.where(mine, weight, jnp.zeros_like(weight))
-
-        def experts(lhs, name):
-            return _grouped_matmul(lhs, params[name], group_sizes,
-                                   stacks[name] if stacks else None, layer)
-
-        hidden = _gated(experts(rows, "wg"), experts(rows, "wu"),
-                        weight[:, 0])
-        out = experts(hidden, "wd")
+    held = {name: params[name] for name in ("wg", "wu", "wd")}
+    window = _window_rows(len(order), e_local, E)
+    if window >= len(order):
+        # One window holds every assignment: its body as it is, no loop.
+        y = _window(rows, gates, held, order, inverse, group_sizes, stacks,
+                    layer, top_k, e_local < E)
+        stats["windows"] = jnp.ones((), jnp.int32)
+    else:
+        stats["windows"] = jnp.maximum(
+            1, -(-jnp.sum(group_sizes) // window))
+        with jax.named_scope("moe_window"):
+            y = _windows(rows, gates, held,
+                         jnp.pad(order, (0, -len(order) % window)), inverse,
+                         group_sizes, stats["windows"], stacks, layer, top_k,
+                         window)
 
     with jax.named_scope("moe_combine"):
-        if elsewhere:
-            out = jnp.where(mine, out, jnp.zeros_like(out))
-        out = _take_rows(out, inverse, order, 1)  # token-major again
-        y = jnp.sum(out.reshape(-1, top_k, d), axis=1, dtype=jnp.float32)
         if ep > 1:
             y = lax.psum_scatter(y, axis_name, scatter_dimension=0,
                                  tiled=True)

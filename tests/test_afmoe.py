@@ -17,6 +17,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from benchmark import reference_afmoe
 from horovod_tpu.models import transformer
+from horovod_tpu.parallel import moe
 from horovod_tpu.models.transformer import (
     TransformerConfig, init_params, make_loss_fn, make_train_step,
     shard_params)
@@ -316,19 +317,24 @@ def _moe_params(E=8, held=None, d=16, f=8, seed=0):
             "wd": jax.random.normal(ks[3], (held, f, d)) * f ** -0.5}
 
 
-def _layer(x, params, **kw):
-    """The program's expert layer on one device; ``params`` may hold an
-    ``expert_bias``."""
+def _sharded_layer(params, reads=("load",), **kw):
+    """The program's expert layer on one device as a function of (x,
+    params), which may hold an ``expert_bias``; it returns the result and
+    the ``reads`` of its statistics."""
     def run(x, p):
         y, stats = transformer.moe_layer(
             x, p, p["router"].shape[-1], axis_name="dp",
             score_func="sigmoid", **kw)
-        return y, stats["load"]
+        return (y,) + tuple(stats[k] for k in reads)
 
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("dp",))
-    return jax.jit(jax.shard_map(
+    return jax.shard_map(
         run, mesh=mesh, in_specs=(P(), {k: P() for k in params}),
-        out_specs=(P(), P()), check_vma=False))(x, params)
+        out_specs=(P(),) * (1 + len(reads)), check_vma=False)
+
+
+def _layer(x, params, **kw):
+    return jax.jit(_sharded_layer(params, **kw))(x, params)
 
 
 def test_the_bias_picks_and_does_not_weigh():
@@ -435,6 +441,205 @@ def test_a_softmax_router_over_members_still_takes_its_share_from_the_mesh():
         _run_moe_layer(x, short, 1, top_k=2)
     with pytest.raises(ValueError, match="score_func"):
         _run_moe_layer(x, params, 1, top_k=2, score_func="tanh")
+
+
+# ---- windows of the sorted assignments --------------------------------------
+
+# 32 experts of which 8..11 are held, 4 a token, 1,024 tokens: 4,096
+# assignments, and a window of twice the held share at balance is 1,024.
+W_E, W_FIRST, W_HELD, W_K, W_D, W_TOKENS, W_ROWS = 32, 8, 4, 4, 16, 1024, 1024
+W_MODEL = dict(num_experts_per_tok=W_K, route_norm=True, route_scale=2.0,
+               first_expert_held=W_FIRST)
+
+
+def _held_share(seed=0):
+    whole = _moe_params(E=W_E, d=W_D, seed=seed)
+    return {k: (v if k == "router" else v[W_FIRST:W_FIRST + W_HELD])
+            for k, v in whole.items()}
+
+
+def _kinds_of_token(all_held, one_held):
+    """Tokens and a router that leave no pick to chance: the first
+    ``all_held`` tokens pick the four held experts, the next ``one_held``
+    one held expert and three others, the rest four others. The first
+    three coordinates say which; the others are noise that no pick reads."""
+    kind = np.full(W_TOKENS, 2)
+    kind[:all_held], kind[all_held:all_held + one_held] = 0, 1
+    x = np.array(jax.random.normal(jax.random.PRNGKey(5),
+                                   (2, W_TOKENS // 2, W_D)))
+    x[..., :3] = 10.0 * np.eye(3)[np.random.RandomState(0).permutation(
+        kind)].reshape(2, -1, 3)
+    params = _held_share()
+    router = np.array(params["router"])
+    router[:3] = 0.0
+    router[0, W_FIRST:W_FIRST + W_HELD] = 1.0
+    router[1, [W_FIRST + 1, 0, 1, 2]] = 1.0
+    router[2, 3:7] = 1.0
+    return jnp.asarray(x), dict(params, router=jnp.asarray(router))
+
+
+def _bias_on_held(size):
+    return jnp.zeros(W_E).at[W_FIRST:W_FIRST + W_HELD].set(size)
+
+
+def _windows_case(case):
+    """(x, params, held rows, windows) of a case of the test below."""
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, W_TOKENS // 2, W_D))
+    if case == "fits":
+        return x, _held_share(), None, 1
+    if case == "every-pick-held":
+        return (x, dict(_held_share(), expert_bias=_bias_on_held(5.0)),
+                W_K * W_TOKENS, W_K * W_TOKENS // W_ROWS)
+    if case == "no-pick-held":
+        return x, dict(_held_share(), expert_bias=_bias_on_held(-5.0)), 0, 1
+    all_held, one_held, windows = {
+        "ends-on-the-edge": (W_ROWS // W_K, 0, 1),
+        "one-row-past-the-edge": (W_ROWS // W_K, 1, 2),
+        "one-row-short-of-the-edge": (W_ROWS // W_K - 1, 3, 1),
+        "two-windows-to-the-edge": (2 * W_ROWS // W_K, 0, 2)}[case]
+    return (*_kinds_of_token(all_held, one_held),
+            W_K * all_held + one_held, windows)
+
+
+@pytest.mark.parametrize("case", [
+    "fits", "every-pick-held", "no-pick-held", "ends-on-the-edge",
+    "one-row-past-the-edge", "one-row-short-of-the-edge",
+    "two-windows-to-the-edge"])
+def test_windows_of_the_sort_give_the_reference_whatever_the_routing(case):
+    """The held experts' part is computed a window of the sorted
+    assignments at a time, as many windows as the held rows need: values,
+    counts and the gradients of the tokens, the router and the three held
+    matrices are the reference's when the rows fit one window, when every
+    pick is held (all ``top_k N / C`` windows: nothing has a capacity),
+    when none is (zero, and zero gradients for the matrices), and when
+    the rows end on a window's edge or one row to either side of it."""
+    x, params, held_rows, windows = _windows_case(case)
+    assert moe._window_rows(W_K * W_TOKENS, W_HELD, W_E) == W_ROWS
+    layer = _sharded_layer(params, reads=("load", "windows"), top_k=W_K,
+                           norm_topk_prob=True, route_scale=2.0,
+                           first=W_FIRST)
+
+    def program(x, params):
+        y, load, taken = layer(x, params)
+        return jnp.sum(jnp.sin(y)), (y, load, taken)
+
+    def reference(x, params):
+        y, _, load = reference_afmoe.expert_layer(x, params, W_MODEL)
+        return jnp.sum(jnp.sin(y)), (y, load)
+
+    (_, (y, load, taken)), grads = jax.jit(jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True))(x, params)
+    (_, (want_y, want_load)), want = jax.jit(jax.value_and_grad(
+        reference, argnums=(0, 1), has_aux=True))(x, params)
+    assert int(taken) == windows
+    np.testing.assert_array_equal(np.asarray(load), np.asarray(want_load))
+    assert int(load.sum()) == W_K * W_TOKENS
+    if held_rows is not None:
+        assert int(load[W_FIRST:W_FIRST + W_HELD].sum()) == held_rows
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), atol=5e-6)
+    for got, ref in [(grads[0], want[0])] + [
+            (grads[1][k], want[1][k]) for k in ("router", "wg", "wu", "wd")]:
+        got, ref = np.asarray(got), np.asarray(ref)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(
+            got, ref, atol=5e-6 * max(1.0, float(np.abs(ref).max())))
+    if held_rows == 0:
+        assert not np.asarray(y).any()
+        assert not any(np.asarray(grads[1][k]).any()
+                       for k in ("wg", "wu", "wd"))
+    else:
+        assert float(np.abs(np.asarray(want_y)).max()) > 0.1
+
+
+@pytest.mark.parametrize("count", [0, 1, 700, 1024])
+def test_the_chips_sums_over_a_tokens_rows_are_the_lookups(count,
+                                                           monkeypatch):
+    """A window's sums over a token's rows as the chip computes them (the
+    rows gathered token-major, a grouped matmul over blocks of 256 tokens;
+    here in the Pallas interpreter) against the masked lookup of every
+    pick that runs elsewhere: a window of 1,024 positions of 4,096
+    sorted assignments of which the first ``count`` count, the rest
+    holding what must not be read."""
+    rows, fan, d, start = 1024, 4, 128, 1024
+    order = jax.random.permutation(jax.random.PRNGKey(0), fan * W_TOKENS)
+    index = order[start:start + rows]
+    place = jnp.argsort(order) - start
+    table = jax.random.normal(jax.random.PRNGKey(1), (rows, d))
+    table = jnp.where(jnp.arange(rows)[:, None] < count, table, jnp.nan)
+    want = moe._placed_sums(table, place, index, count, fan, jnp.float32)
+    monkeypatch.setenv("HVD_PALLAS_INTERPRET", "1")
+    got = moe._placed_sums(table, place, index, count, fan, jnp.float32)
+    assert got.shape == (W_TOKENS, d) and np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+    by_hand = np.zeros((W_TOKENS, d), np.float32)
+    np.add.at(by_hand, np.asarray(index[:count]) // fan,
+              np.asarray(table[:count]))
+    np.testing.assert_allclose(np.asarray(want), by_hand, atol=1e-6)
+
+
+def _control_flow(jaxpr):
+    return sorted({eqn.primitive.name for eqn, _ in _eqns(jaxpr)}
+                  & {"while", "cond", "scan"})
+
+
+@pytest.mark.parametrize("ep, held", [(1, W_E), (2, W_E // 2), (1, W_HELD)],
+                         ids=["all-held", "ep-2", "an-eighth-held"])
+def test_the_plain_layer_is_the_windows_body_called_once(ep, held):
+    """All experts held, or half of them as a member of two: a window of
+    twice the held share holds every assignment, and the layer is its
+    body once, with no loop and no conditional around it, forward or
+    backward. An eighth held: the loop over windows, in both."""
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:ep]), ("dp",))
+    params = _moe_params(E=W_E, held=held * ep, d=W_D)
+    x = jnp.zeros((2, W_TOKENS // 2, W_D))
+
+    def loss(x, p):
+        return jnp.sum(transformer.moe_layer(
+            x, p, W_E, None if held * ep == W_E else W_FIRST, top_k=W_K,
+            axis_name="dp")[0])
+
+    specs = {k: P() if k == "router" or ep == 1 else P("dp") for k in params}
+    traced = jax.make_jaxpr(jax.grad(jax.shard_map(
+        loss, mesh=mesh, in_specs=(P("dp"), specs), out_specs=P(),
+        check_vma=False), argnums=(0, 1)))(x, params)
+    assert _control_flow(traced.jaxpr) == (
+        ["while"] if held == W_HELD else [])
+
+
+def test_the_members_windows_add_up_to_the_uncut_layer():
+    """Four members holding two of eight experts each, 1,024 tokens, two
+    a token: a member's window is half the 2,048 gathered assignments, so
+    each runs the loop over windows between the all-gather and the
+    reduce-scatter, and together they give what one device that holds
+    all eight gives, and the dense oracle."""
+    from test_parallel import _dense_moe_oracle
+
+    E, d, top_k = 8, 16, 2
+    params = _moe_params(E=E, d=d)
+    x = jax.random.normal(jax.random.PRNGKey(3), (4, 256, d))
+    assert moe._window_rows(top_k * 1024, E // 4, E) == 1024
+    uncut, _ = _run_moe_layer(x, params, 1, top_k=top_k)
+    shared, stats = _run_moe_layer(x, params, 4, top_k=top_k)
+    assert 1 <= int(stats["windows"]) <= 2
+    np.testing.assert_allclose(np.asarray(shared), np.asarray(uncut),
+                               atol=2e-6)
+    np.testing.assert_allclose(
+        np.asarray(shared).reshape(-1, d),
+        _dense_moe_oracle(np.asarray(x).reshape(-1, d), params, top_k),
+        rtol=1e-3, atol=1e-4)
+    assert int(np.asarray(stats["load"]).sum()) == top_k * 1024
+
+
+def test_the_reader_of_the_windows_takes_the_largest_of_the_last_step():
+    from types import SimpleNamespace as Ns
+
+    from benchmark.layer_metrics import moe_share_windows
+
+    assert moe_share_windows.read(Ns(job=Ns())) is None
+    assert moe_share_windows.read(Ns(job=Ns(readings=None))) is None
+    assert moe_share_windows.read(Ns(job=Ns(readings={"load": 1}))) is None
+    assert moe_share_windows.read(Ns(job=Ns(readings={
+        "windows": jnp.asarray([0, 1, 3, 1, 1], jnp.int32)}))) == 3
 
 
 # ---- the pattern ------------------------------------------------------------
@@ -558,10 +763,12 @@ def _not_float32(jaxpr):
     matmul at the highest precision, its scores and its top-k under
     ``moe_route``; every norm's ``rsqrt``; the head's matmul and its
     logits under ``head``; the loss's ``exp`` and ``log`` under ``loss``;
-    the bias's rule under ``router_bias``."""
+    the bias's rule under ``router_bias``; the experts' gated product
+    under ``moe_experts`` and the sum over a token's picks under
+    ``moe_combine``, inside the loop over windows where there is one."""
     f32 = jnp.dtype(jnp.float32)
     looked = {part: 0 for part in ("router", "norms", "head", "loss",
-                                   "bias")}
+                                   "bias", "gated", "token_sums")}
     wrong = {part: [] for part in looked}
 
     def hold(part, eqn, ok):
@@ -578,6 +785,10 @@ def _not_float32(jaxpr):
             highest = name != "dot_general" or "HIGHEST" in str(
                 eqn.params["precision"])
             hold("router", eqn, highest and all(t == f32 for t in floats))
+        elif "moe_experts" in path and name == "logistic":
+            hold("gated", eqn, ins == [f32])
+        elif "moe_combine" in path and name == "reduce_sum" and floats:
+            hold("token_sums", eqn, outs == [f32])
         elif name == "rsqrt":
             hold("norms", eqn, ins == [f32])
         elif "/head" in path and name == "dot_general":
@@ -589,9 +800,10 @@ def _not_float32(jaxpr):
     return wrong, looked
 
 
-def _traced_bf16_step():
+def _traced_bf16_step(shape=(B, T)):
     cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16, remat=True,
-                              remat_keeps=("flash_out", "attn_q"))
+                              remat_keeps=("flash_out", "attn_q"),
+                              max_seq=max(shape[1], CFG.max_seq))
     mesh = _mesh()
     params = shard_params(init_params(cfg, jax.random.PRNGKey(0), 1), cfg,
                           mesh)
@@ -600,25 +812,33 @@ def _traced_bf16_step():
         params["ln1"].dtype == jnp.float32
     optimizer = optax.adamw(3e-4)
     opt_state = init_opt_state(optimizer, transformer.trained(params), mesh)
-    tokens, labels = _batch()
+    tokens, labels = _batch(shape=shape)
     step = make_train_step(cfg, optimizer, mesh, n_microbatches=1)
     return jax.make_jaxpr(step)(params, opt_state, tokens, labels).jaxpr
 
 
-@pytest.fixture(scope="module")
-def bf16_step_parts():
-    return _not_float32(_traced_bf16_step())
+# 2 x 24 tokens: one window holds every assignment, no loop. 2 x 256: 1,536
+# assignments on 16 experts of which 4 are held, windows of 1,024.
+STEP_SHAPES = {"plain": (B, T), "windows": (B, 256)}
+
+
+@pytest.fixture(scope="module", params=sorted(STEP_SHAPES))
+def bf16_step_parts(request):
+    jaxpr = _traced_bf16_step(STEP_SHAPES[request.param])
+    assert _control_flow(jaxpr).count("while") == (request.param == "windows")
+    return _not_float32(jaxpr)
 
 
 @pytest.mark.parametrize("part, at_least", [
     ("router", 3 * 4), ("norms", 5 * 6 + 1), ("head", 1), ("loss", 3),
-    ("bias", 1)])
+    ("bias", 1), ("gated", 3 * 3), ("token_sums", 2 * 3)])
 def test_a_bf16_step_computes_its_float32_parts_in_float32(
         bf16_step_parts, part, at_least):
     """What the cell's ``correct`` cannot tell apart on the chip (a
     router, a norm or the head one precision lower moves a token's
     cross-entropy by less than the seeds do) is held here, in the traced
-    step: the part's operations are there, and every one is float32."""
+    step: the part's operations are there, and every one is float32,
+    with and without the loop over windows of the sorted assignments."""
     wrong, looked = bf16_step_parts
     assert looked[part] >= at_least, looked
     assert not wrong[part], wrong[part]

@@ -372,6 +372,48 @@ def test_expert_layer_compiles_for_v5e(described_chip, monkeypatch, dtype):
     assert "65536,64," not in text
 
 
+def test_a_held_share_of_the_experts_compiles_for_v5e_by_windows(
+        described_chip, monkeypatch):
+    """``parallel/moe.py``'s layer and its gradient at Trinity-Mini's
+    share (16 of 128 experts of 2048 x 1024 held, 8 a token, 16,384
+    tokens, bf16): the section works on windows of 32,768 of the 131,072
+    sorted assignments, in a loop whose trip count is data, forward and
+    backward, and no array of all the assignments' rows is made anywhere;
+    nine grouped matmuls and three sums over a token's rows a window."""
+    import numpy as np
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from horovod_tpu.parallel import moe
+
+    monkeypatch.setattr(pa, "_resolve_dispatch", lambda up: (True, False))
+    d, f, E, held, k = 2048, 1024, 128, 16, 8
+    assert moe._window_rows(k * 16384, held, E) == 32768
+    mesh = Mesh(np.array([next(iter(described_chip.device_set))]), ("dp",))
+    shapes = {"router": ((d, E), jnp.float32),
+              "wg": ((held, d, f), jnp.bfloat16),
+              "wu": ((held, d, f), jnp.bfloat16),
+              "wd": ((held, f, d), jnp.bfloat16)}
+    params = {name: jax.ShapeDtypeStruct(shape, dt, sharding=described_chip)
+              for name, (shape, dt) in shapes.items()}
+    x = jax.ShapeDtypeStruct((2, 8192, d), jnp.bfloat16,
+                             sharding=described_chip)
+
+    def loss(x, p):
+        y, stats = moe.moe_layer(x, p, E, 0, axis_name="dp", top_k=k,
+                                 score_func="sigmoid")
+        return jnp.sum(jnp.square(y.astype(jnp.float32))), stats["windows"]
+
+    fn = jax.jit(jax.grad(jax.shard_map(
+        loss, mesh=mesh, in_specs=(P(), {n: P() for n in shapes}),
+        out_specs=(P(), P()), check_vma=False), argnums=(0, 1),
+        has_aux=True))
+    text = fn.lower(x, params).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 12
+    assert text.count(" while(") >= 2
+    assert "[131072,2048]" not in text and "[16384,8,2048]" not in text
+    assert "[32768,2048]" in text
+
+
 def _expert_operands(text):
     """Of a compiled program's Mosaic grouped matmuls (``%gmm.<n>``, whose
     last operand is the experts' matrices): the instruction that makes

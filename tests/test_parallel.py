@@ -202,7 +202,8 @@ def _run_moe_layer(x, params, ep=1, **kw):
                              **kw)
         return y, {"lb": jax.lax.pmean(stats["lb"], "dp"),
                    "z": jax.lax.pmean(stats["z"], "dp"),
-                   "load": jax.lax.psum(stats["load"], "dp")}
+                   "load": jax.lax.psum(stats["load"], "dp"),
+                   "windows": jax.lax.pmax(stats["windows"], "dp")}
 
     fn = jax.jit(jax.shard_map(
         layer, mesh=mesh, in_specs=(P("dp"), param_specs),

@@ -22,7 +22,12 @@ reference).
 
 from typing import List, Optional
 
-from .version import __version__  # noqa: F401
+from .common import metrics as _metrics
+
+# Set-up span (docs/diagnostics.md): this file, first statement to last.
+_import_span = _metrics.span("import:horovod_tpu").__enter__()
+
+from .version import __version__  # noqa: E402,F401
 from .common import exceptions  # noqa: F401
 from .common.exceptions import (  # noqa: F401
     HorovodInternalError,
@@ -200,17 +205,18 @@ def _native_core():
     else the host (process-rank) world's. None in pure-direct mode.
     (One rule, owned by common/metrics.py — every observability surface
     resolves the core identically.)"""
-    from .common import metrics as _metrics
-
     return _metrics.live_native_core()
 
 
 def metrics() -> dict:
     """The unified metrics snapshot (docs/metrics.md):
-    ``{"python": {...}, "native": {...} | None}``.
+    ``{"python": {...}, "native": {...} | None, "spans": [...]}``.
 
     ``python`` holds the Python-plane counters (Retrier retries, fault
-    injections, shm/stripe fallback armings, elastic evictions/drains);
+    injections, shm/stripe fallback armings, elastic evictions/drains,
+    the kernels the host traced); ``spans`` what the job crossed before
+    its first step, on the profiler's clock (docs/diagnostics.md,
+    "Set-up spans");
     ``native`` is the registry snapshot from the single
     ``hvd_metrics_snapshot`` getter — traffic/control counters, the
     log2 latency histograms (enqueue→negotiated→executed per op class,
@@ -221,8 +227,6 @@ def metrics() -> dict:
     ``native["straggler"]["events"]`` and mirrors them as timeline
     instants when a timeline is active; counters and histograms are
     cumulative for the world and unaffected by reads."""
-    from .common import metrics as _metrics
-
     return _metrics.snapshot()
 
 
@@ -231,8 +235,6 @@ def metrics_report() -> str:
     non-empty histogram with approximate p50/p99 (log2 buckets), and
     the straggler state. Empty-safe: always returns a string, with or
     without a native core."""
-    from .common import metrics as _metrics
-
     return _metrics.report_text()
 
 
@@ -356,3 +358,7 @@ class DistributedOptimizer:
         from .opt import DistributedOptimizer as _impl
 
         return _impl(optimizer, **kwargs)
+
+
+_import_span.__exit__(None, None, None)
+del _import_span

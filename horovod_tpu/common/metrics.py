@@ -18,8 +18,12 @@ timestamps.
 
 from __future__ import annotations
 
+import functools
 import os
+import re
+import sys
 import threading
+import time
 from typing import Optional
 
 from . import config as _config
@@ -61,9 +65,229 @@ def counters() -> dict:
 
 
 def reset() -> None:
-    """Zero the Python-plane counters (tests)."""
+    """Zero the Python-plane counters and forget the spans (tests)."""
+    global _spans_refused
     with _lock:
         _counters.clear()
+        del _spans[:]
+        _spans_refused = 0
+    _local.__dict__.clear()
+
+
+# ---- spans -----------------------------------------------------------------
+#
+# What a job crosses before its first step, as intervals on the
+# profiler's clock (docs/diagnostics.md, "Set-up spans"). A span is
+#   {"id", "parent", "name", "start_ns", "end_ns", "thread", "counts"}
+# with ``parent`` the innermost span open on the same thread (None: a
+# root), both times ``time.time_ns()`` (the clock of jax's own phase
+# spans and of the host lines of an ``.xplane.pb``) and ``counts`` a few
+# integers known at the boundary. Kept in one list under the module's
+# lock and written nowhere unless asked: ``spans()``, ``hvd.metrics()``
+# under "spans", ``hvd.metrics_report()`` as a table. jax's compile
+# phases (trace, lowering to MLIR, compile or cache read) are spans too,
+# named ``<phase>:<module>``, opened and closed by ``jax.monitoring``
+# listeners that are registered at the first span that opens or closes
+# with jax already imported.
+
+# Set-up is tens of spans of the program's and three a program jax
+# builds; a loop that opens them by mistake is refused past this many
+# and counted, so it cannot grow a training job's memory.
+SPAN_CAP = 4096
+_PHASE_EVENT = re.compile(r"^/jax/core/compile/(\w+)_duration$")
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+_spans: list = []
+_spans_refused = 0
+_listening = False
+_local = threading.local()
+
+
+def _mine():
+    """This thread's own: ``open``, its open spans, outermost first;
+    ``phases``, jax's phases in progress (None for one not recorded);
+    ``cache``, what the compile cache has said since the last compile."""
+    if not hasattr(_local, "open"):
+        _local.open, _local.phases, _local.cache = [], [], {}
+    return _local
+
+
+def _open(name, start_ns, counts):
+    """A record under the innermost open span of this thread, or None
+    past the cap."""
+    global _spans_refused
+    stack = _mine().open
+    with _lock:
+        if len(_spans) >= SPAN_CAP:
+            _spans_refused += 1
+            return None
+        rec = {"id": len(_spans),
+               "parent": stack[-1]["id"] if stack else None, "name": name,
+               "start_ns": start_ns, "end_ns": None,
+               "thread": threading.get_ident(), "counts": counts}
+        _spans.append(rec)
+    stack.append(rec)
+    return rec
+
+
+def _close(rec, end_ns):
+    if rec is not None:
+        rec["end_ns"] = end_ns
+        _mine().open.remove(rec)
+
+
+def _module_name(fun_name: str) -> str:
+    """The name jax gives the module of ``fun_name``: the trace phase
+    reports the function's (``hvd_decoder_step``), lowering and compile
+    the wrapped one (``jit(hvd_decoder_step)``)."""
+    if "(" not in fun_name:
+        fun_name = f"jit({fun_name})"
+    return re.sub(r"[^\w.-]", "_", fun_name).rstrip("_")
+
+
+def _on_phase_start(event, start, fun_name="", **_):
+    """jax reports where a phase starts as a scalar, the time. A trace
+    inside another phase is not recorded and stays in that phase's time:
+    every ``jnp`` function a trace calls is a ``jit`` of its own, seven
+    thousand of them in a ResNet-50 job."""
+    phase = _PHASE_EVENT.match(event)
+    if phase is None:
+        return
+    mine = _mine()
+    rec = None
+    if not (mine.phases and phase.group(1) == "jaxpr_trace"):
+        rec = _open(f"{phase.group(1)}:{_module_name(fun_name)}",
+                    int(start * 1e9), {})
+    mine.phases.append(rec)
+
+
+def _on_phase_end(event, start, end, **_):
+    mine = _mine()
+    if mine.phases and _PHASE_EVENT.match(event):
+        rec = mine.phases.pop()
+        if rec is not None and rec["name"].startswith("backend_compile:"):
+            rec["counts"].update(mine.cache)
+            mine.cache = {}
+        _close(rec, int(end * 1e9))
+
+
+def _on_event(event, **_):
+    if event in (_CACHE_HIT, _CACHE_MISS):
+        _mine().cache["cache_hit"] = int(event == _CACHE_HIT)
+
+
+def _on_duration(event, duration, **_):
+    if event == _CACHE_READ:
+        _mine().cache["retrieval_ms"] = round(1e3 * duration)
+
+
+def _jax():
+    """jax if the process has imported it, and never imported from here:
+    this module is read before jax is."""
+    global _listening
+    jax = sys.modules.get("jax")
+    monitoring = getattr(jax, "monitoring", None)
+    if monitoring is not None and not _listening:
+        with _lock:
+            if not _listening:
+                _listening = True
+                monitoring.register_scalar_listener(_on_phase_start)
+                monitoring.register_event_time_span_listener(_on_phase_end)
+                monitoring.register_event_listener(_on_event)
+                monitoring.register_event_duration_secs_listener(
+                    _on_duration)
+    return jax
+
+
+class span:
+    """``with metrics.span("state.init", leaves=n) as s: ...; s.add(bytes=b)``
+    records the block as a span and shows it, under the same name, on
+    the host lines of a profile taken with ``jax.profiler.start_trace``
+    (a TraceMe costs nothing measurable while no profiler runs). As a
+    decorator it records every call of the function."""
+
+    def __init__(self, name: str, **counts: int):
+        self.name = name
+        self.counts = counts
+        self._rec = self._annotation = None
+
+    def add(self, **counts: int) -> None:
+        """Add to the span's counts, before it closes."""
+        for key, n in counts.items():
+            self.counts[key] = self.counts.get(key, 0) + int(n)
+
+    def __enter__(self):
+        profiler = getattr(_jax(), "profiler", None)
+        if profiler is not None:
+            self._annotation = profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
+        self._rec = _open(self.name, time.time_ns(), self.counts)
+        return self
+
+    def __exit__(self, *exc):
+        _close(self._rec, time.time_ns())
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        _jax()
+        return False
+
+    def __call__(self, fn):
+        @functools.wraps(fn)
+        def spanned(*args, **kwargs):
+            with span(self.name, **self.counts):
+                return fn(*args, **kwargs)
+        return spanned
+
+
+def tree_counts(tree) -> dict:
+    """``leaves`` and ``bytes`` of a pytree of arrays (or of tracers)."""
+    import jax
+
+    leaves = [x for x in jax.tree_util.tree_leaves(tree)
+              if hasattr(x, "dtype")]
+    return {"leaves": len(leaves),
+            "bytes": sum(x.size * x.dtype.itemsize for x in leaves)}
+
+
+def _covered_ns(intervals) -> int:
+    """Length of the union of ``(start, end)`` intervals."""
+    total, reach = 0, None
+    for start, end in sorted(intervals):
+        if reach is None or start > reach:
+            total, reach = total + end - start, end
+        elif end > reach:
+            total, reach = total + end - reach, end
+    return total
+
+
+def spans() -> list:
+    """A copy of the spans recorded so far, in the order they were
+    admitted, each with ``self_ns``: its duration less the part its
+    children cover. A span still open ends now."""
+    now = time.time_ns()
+    with _lock:
+        out = [dict(r, counts=dict(r["counts"]),
+                    end_ns=now if r["end_ns"] is None else r["end_ns"])
+               for r in _spans]
+    inside = {}
+    for r in out:
+        if r["parent"] is not None:
+            outer = out[r["parent"]]
+            inside.setdefault(r["parent"], []).append(
+                (max(r["start_ns"], outer["start_ns"]),
+                 min(r["end_ns"], outer["end_ns"])))
+    for r in out:
+        r["self_ns"] = max(0, r["end_ns"] - r["start_ns"]
+                           - _covered_ns(inside.get(r["id"], ())))
+    return out
+
+
+def spans_refused() -> int:
+    """Spans that found the list at ``SPAN_CAP`` and were not recorded."""
+    with _lock:
+        return _spans_refused
 
 
 # ---- native snapshot access ------------------------------------------------
@@ -117,8 +341,10 @@ def _emit_straggler_instants(native: Optional[dict]) -> None:
 def snapshot(drain: bool = True) -> dict:
     """The merged metrics view behind ``hvd.metrics()``:
 
-    ``{"python": {counter: value}, "native": {...} | None}``
+    ``{"python": {counter: value}, "native": {...} | None,
+    "spans": [...]}``
 
+    ``spans`` is :func:`spans`, the set-up spans recorded so far;
     ``native`` is the parsed unified snapshot (counters, log2
     histograms, straggler state) or None when no native core is live.
     With ``drain`` (the default), pending straggler warning events are
@@ -132,7 +358,7 @@ def snapshot(drain: bool = True) -> dict:
         native = core.metrics_snapshot(flags) or None
         if drain:
             _emit_straggler_instants(native)
-    return {"python": counters(), "native": native}
+    return {"python": counters(), "native": native, "spans": spans()}
 
 
 # ---- histogram math --------------------------------------------------------
@@ -165,7 +391,8 @@ def percentiles(hist: dict, qs=(50, 90, 99)) -> dict:
 
 def report_text(snap: Optional[dict] = None) -> str:
     """Human-readable rendering of a merged snapshot (the string behind
-    ``hvd.metrics_report()``): counters, then each non-empty histogram
+    ``hvd.metrics_report()``): counters, the set-up spans by name
+    (count, total and self time), then each non-empty histogram
     with count / approximate p50/p99 / max, then straggler state.
     Reads with ``drain=False`` — a human glance must not steal pending
     straggler events from ``hvd.metrics()``, which renders them."""
@@ -177,6 +404,7 @@ def report_text(snap: Optional[dict] = None) -> str:
         lines.append("-- python counters --")
         for k in sorted(py):
             lines.append(f"{k}: {py[k]}")
+    lines += _span_table(snap.get("spans") or ())
     if not native:
         lines.append("native core: absent (pure-XLA direct mode or "
                      "not initialized)")
@@ -197,6 +425,26 @@ def report_text(snap: Optional[dict] = None) -> str:
                  f"last_rank={st.get('last_rank', -1)} "
                  f"last_lag_ms={st.get('last_lag_ms', 0)}")
     return "\n".join(lines) + "\n"
+
+
+def _span_table(records) -> list:
+    """The report's lines for the spans: one a name, in the order each
+    name first opened."""
+    rows = {}
+    for r in records:
+        row = rows.setdefault(r["name"], [0, 0, 0])
+        row[0] += 1
+        row[1] += r["end_ns"] - r["start_ns"]
+        row[2] += r["self_ns"]
+    if not rows:
+        return []
+    lines = ["-- spans (ms) --"]
+    lines += [f"{name}: n={n} total={total / 1e6:.1f} self={own / 1e6:.1f}"
+              for name, (n, total, own) in rows.items()]
+    refused = spans_refused()
+    if refused:
+        lines.append(f"spans refused past {SPAN_CAP}: {refused}")
+    return lines
 
 
 # ---- Prometheus textfile exporter ------------------------------------------

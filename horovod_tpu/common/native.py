@@ -19,6 +19,7 @@ from typing import List, Optional, Tuple
 
 from . import config as _config
 from . import logging as _log
+from . import metrics as _metrics
 
 _LIB_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)), "lib")
 _CSRC_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)), "csrc")
@@ -67,8 +68,9 @@ _DONE_CB_TYPE = ctypes.CFUNCTYPE(None, ctypes.c_void_p, ctypes.c_longlong,
                                  ctypes.c_int, ctypes.c_char_p)
 
 
-def _build_library() -> None:
-    """Bring the artifact up to date with ``make``. csrc/Makefile lists
+def _build_library() -> bool:
+    """Bring the artifact up to date with ``make``; returns whether it
+    had to be built. csrc/Makefile lists
     every source and header as a prerequisite of the .so, so ``make -q``
     is the freshness check: a library left over from other sources is
     rebuilt, never loaded. The check and the rebuild run under one
@@ -87,7 +89,7 @@ def _build_library() -> None:
             and os.path.exists(os.path.join(_CSRC_DIR, "Makefile"))
             and os.access(lib_dir, os.W_OK)):
         if os.path.exists(_lib_path()):
-            return
+            return False
         raise RuntimeError(
             f"native runtime: {_lib_path()} is not there and cannot be "
             f"built (needs `make`, a C++17 compiler, the sources in "
@@ -98,16 +100,20 @@ def _build_library() -> None:
     os.makedirs(_LIB_DIR, exist_ok=True)
     with open(os.path.join(_LIB_DIR, "build.lock"), "w") as lockf:
         fcntl.flock(lockf, fcntl.LOCK_EX)
-        if subprocess.run(cmd + ["-q"], capture_output=True,
-                          timeout=60).returncode == 0:
-            return
-        r = subprocess.run(cmd, capture_output=True, text=True,
-                           timeout=600)
+        with _metrics.span("native.make_q"):
+            fresh = subprocess.run(cmd + ["-q"], capture_output=True,
+                                   timeout=60).returncode == 0
+        if fresh:
+            return False
+        with _metrics.span("native.build"):
+            r = subprocess.run(cmd, capture_output=True, text=True,
+                               timeout=600)
     if r.returncode != 0:
         raise RuntimeError(
             f"native runtime build failed ({' '.join(cmd)}, rc="
             f"{r.returncode}); set HOROVOD_NATIVE=0 to run without it:\n"
             f"{r.stderr[-2000:]}")
+    return True
 
 
 _lib = None
@@ -129,8 +135,9 @@ def load_library():
         return None
     if _lib is not None:
         return _lib
-    _build_library()
-    return _bind_prototypes(ctypes.CDLL(_lib_path()))
+    with _metrics.span("native.load") as setup:
+        setup.add(built=_build_library())
+        return _bind_prototypes(ctypes.CDLL(_lib_path()))
 
 
 def _bind_prototypes(lib):

@@ -27,6 +27,7 @@ import numpy as np
 
 from . import config as _config
 from . import logging as _log
+from . import metrics as _metrics
 from .exceptions import NotInitializedError
 
 # Mesh axis names. "hvd" is the flat data-parallel axis used by the
@@ -115,7 +116,7 @@ def init(comm=None, devices=None):
     import jax
     from jax.sharding import Mesh
 
-    with _state.lock:
+    with _state.lock, _metrics.span("init") as setup:
         if _state.initialized:
             return
 
@@ -154,16 +155,20 @@ def init(comm=None, devices=None):
         for d in all_devices:
             sizes[d.process_index] = sizes.get(d.process_index, 0) + 1
         _state.is_homogeneous = len(set(sizes.values())) <= 1
+        setup.add(size=_state.size)
 
-        mesh_devices = np.array(all_devices, dtype=object)
-        _state.mesh = Mesh(mesh_devices, (AXIS_GLOBAL,))
-        if _state.is_homogeneous and _state.local_size > 0:
-            hier = mesh_devices.reshape(_state.cross_size, _state.local_size)
-            _state.hier_mesh = Mesh(hier, (AXIS_CROSS, AXIS_LOCAL))
+        with _metrics.span("mesh", devices=_state.size):
+            mesh_devices = np.array(all_devices, dtype=object)
+            _state.mesh = Mesh(mesh_devices, (AXIS_GLOBAL,))
+            if _state.is_homogeneous and _state.local_size > 0:
+                hier = mesh_devices.reshape(_state.cross_size,
+                                            _state.local_size)
+                _state.hier_mesh = Mesh(hier, (AXIS_CROSS, AXIS_LOCAL))
 
         from ..ops.eager import EagerEngine
 
-        _state.engine = EagerEngine(_state)
+        with _metrics.span("engine.start"):
+            _state.engine = EagerEngine(_state)
 
         if _state.config.timeline_filename:
             from .timeline import Timeline
@@ -314,8 +319,6 @@ def init(comm=None, devices=None):
         # HOROVOD_METRICS_EXPORT — unset keeps init byte-identical to
         # pre-metrics builds (no thread, no file, no timeline counter
         # events; regression-tested).
-        from . import metrics as _metrics
-
         _metrics.maybe_start_pump()
 
         _log.info(

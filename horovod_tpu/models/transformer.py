@@ -61,18 +61,21 @@ import dataclasses
 import functools
 from typing import Any, Dict, Optional, Tuple
 
-import jax
-import jax.numpy as jnp
-from jax import lax
-from jax.ad_checkpoint import checkpoint_name
-from jax.sharding import NamedSharding, PartitionSpec as P
+from ..common import metrics as _metrics
 
-from ..common.compat import axis_size as _axis_size
-from ..common.compat import shard_map as _compat_shard_map
-from ..ops.ssd import ssd_chunked
-from ..parallel.moe import moe_layer
-from ..parallel.pipeline import spmd_pipeline
-from ..parallel.ulysses import context_parallel_attention
+with _metrics.span("import:horovod_tpu.models.transformer"):
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.ad_checkpoint import checkpoint_name
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ..common.compat import axis_size as _axis_size
+    from ..common.compat import shard_map as _compat_shard_map
+    from ..ops.ssd import ssd_chunked
+    from ..parallel.moe import moe_layer
+    from ..parallel.pipeline import spmd_pipeline
+    from ..parallel.ulysses import context_parallel_attention
 
 
 LAYER_KINDS = ("attention", "mamba", "sliding_attention", "full_attention")
@@ -605,10 +608,11 @@ def _validate_mesh_divisibility(cfg: TransformerConfig, mesh) -> None:
 def shard_params(params: Dict, cfg: TransformerConfig, mesh) -> Dict:
     _validate_mesh_divisibility(cfg, mesh)
     specs = _param_specs(cfg)
-    return {
-        k: jax.device_put(v, NamedSharding(mesh, specs[k]))
-        for k, v in params.items()
-    }
+    with _metrics.span("state.shard", **_metrics.tree_counts(params)):
+        return {
+            k: jax.device_put(v, NamedSharding(mesh, specs[k]))
+            for k, v in params.items()
+        }
 
 
 @functools.partial(jax.checkpoint, static_argnums=(2,))
@@ -1130,6 +1134,7 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
         if with_readings else P(), check_vma=False))
 
 
+@_metrics.span("step.build")
 def make_train_step(cfg: TransformerConfig, optimizer, mesh,
                     n_microbatches: int = 2, opt_shardings=None,
                     packed: bool = False):

@@ -51,6 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..common import logging as _log
+from ..common import metrics as _metrics
 from ..common.compat import pallas_tpu_compiler_params as _compiler_params
 
 NEG_INF = -1e30
@@ -316,7 +317,9 @@ def _kernels_take(kinds, q, k, causal, window, segments, **out) -> bool:
 
 def _log_plan(kind, shape, dtype, causal, window, plan):
     """Everything a plan decides is static, so it is logged once, when
-    the call is traced (``HOROVOD_LOG_LEVEL=debug``)."""
+    the call is traced (``HOROVOD_LOG_LEVEL=debug``), and counted: the
+    host traces this ``pallas_call`` and lowers it to Mosaic."""
+    _metrics.inc(f"kernels.traced.flash_{kind}")
     _log.debug(
         f"flash_{kind} {tuple(shape)} {jnp.dtype(dtype).name} "
         f"causal={causal} window={window}: {plan.heads} heads a step "

@@ -59,16 +59,23 @@ token and layer in float32 at 64 heads and Q 256).
 import functools
 from typing import NamedTuple
 
-import jax
-import jax.numpy as jnp
-from jax import lax
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from ..common import metrics as _metrics
 
-from ..common import logging as _log
-from ..common.compat import pallas_tpu_compiler_params as _compiler_params
-from . import pallas_attention as _pallas_attention
-from .pallas_attention import _across, _mxu_dot
+# The first of a decoder job's imports to reach jax.experimental.pallas,
+# which brings every backend's lowering with it (docs/diagnostics.md,
+# "Set-up spans").
+with _metrics.span("import:horovod_tpu.ops.ssd"):
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ..common import logging as _log
+    from ..common.compat import (
+        pallas_tpu_compiler_params as _compiler_params)
+    from . import pallas_attention as _pallas_attention
+    from .pallas_attention import _across, _mxu_dot
 
 _LANES = 128
 # Heads unrolled into one loop body: the heads of one 128-lane slab of x.
@@ -234,7 +241,9 @@ def kernel_plan(H, P, N, Q, dtype, *, kind="bwd"):
 
 def _log_plan(kind, shape, dtype, plan):
     """Everything a plan decides is static, so it is logged once, when
-    the call is traced (``HOROVOD_LOG_LEVEL=debug``)."""
+    the call is traced (``HOROVOD_LOG_LEVEL=debug``), and counted: the
+    host traces this ``pallas_call`` and lowers it to Mosaic."""
+    _metrics.inc(f"kernels.traced.ssd_{kind}")
     _log.debug(
         f"ssd_{kind} {tuple(shape)} {jnp.dtype(dtype).name}: "
         f"{plan.heads} heads a step ({plan.body} a loop body of "

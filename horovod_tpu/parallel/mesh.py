@@ -25,6 +25,8 @@ from typing import Dict, Optional, Sequence
 
 import numpy as np
 
+from ..common import metrics as _metrics
+
 AXES = ("dp", "pp", "sp", "tp")
 
 
@@ -64,7 +66,8 @@ def build_parallel_mesh(devices: Sequence, tp: Optional[int] = None,
     from jax.sharding import Mesh
 
     n = len(devices)
-    sizes = factor_devices(n, tp=tp, pp=pp, sp=sp, dp=dp)
-    arr = np.array(devices, dtype=object).reshape(
-        sizes["dp"], sizes["pp"], sizes["sp"], sizes["tp"])
-    return Mesh(arr, AXES)
+    with _metrics.span("mesh", devices=n):
+        sizes = factor_devices(n, tp=tp, pp=pp, sp=sp, dp=dp)
+        arr = np.array(devices, dtype=object).reshape(
+            sizes["dp"], sizes["pp"], sizes["sp"], sizes["tp"])
+        return Mesh(arr, AXES)

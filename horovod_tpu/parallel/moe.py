@@ -75,6 +75,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..common import metrics as _metrics
 from ..common.compat import axis_size as _axis_size
 from ..ops import pallas_attention as _pallas_attention
 
@@ -138,6 +139,7 @@ def _placed_sums(table, place, index, count, fan, dtype):
                  == jnp.arange(_TOKEN_BLOCK)[:, None]).astype(table.dtype)
         sizes = jnp.sum(token[:, None] // _TOKEN_BLOCK == jnp.arange(blocks),
                         axis=0, dtype=jnp.int32)
+        _metrics.inc("kernels.traced.tgmm")
         with jax.named_scope("moe_token_sums"):
             sums = tgmm(which, table[by_token], sizes, dtype,
                         _gmm_tiling((rows, _TOKEN_BLOCK, d), table.dtype),
@@ -223,6 +225,7 @@ def _gmm_of_layer(lhs, stack, layer, group_sizes, tiling, transpose_rhs,
     copied out first: a custom call's operand is a buffer of its own."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
+    _metrics.inc("kernels.traced.gmm")
     n_layers, groups = stack.shape[:2]
     sizes = lax.dynamic_update_slice(
         jnp.zeros(n_layers * groups, jnp.int32), group_sizes,
@@ -255,6 +258,7 @@ def _stacked_gmm_bwd(interpret, residuals, grad):
     tiling = _stack_tiling(lhs, stack)
     grad_lhs = _gmm_of_layer(grad, stack, layer, group_sizes, tiling, True,
                              interpret)
+    _metrics.inc("kernels.traced.tgmm")
     grad_rhs = tgmm(lhs.swapaxes(0, 1), grad, group_sizes, stack.dtype,
                     tiling, None, stack.shape[1], interpret=interpret)
     return grad_lhs, grad_rhs, None, None, None

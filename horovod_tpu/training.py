@@ -25,6 +25,7 @@ import optax
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .common import metrics as _metrics
 from .common.compat import shard_map as _shard_map
 from .common.state import AXIS_GLOBAL
 from .opt import DistributedOptimizer
@@ -50,6 +51,7 @@ def cross_entropy_loss(logits, labels):
         return -jnp.mean(jnp.sum(onehot * logp, axis=-1))
 
 
+@_metrics.span("step.build")
 def make_train_step(model, optimizer: optax.GradientTransformation,
                     mesh, axis_name: str = AXIS_GLOBAL,
                     reduce_op: Optional[int] = None,
@@ -134,19 +136,23 @@ def init_train_state(model, optimizer, rng, sample_input,
     state, so init and step have to agree on the state pytree. Both
     default to "auto" (the ``HOROVOD_COMPRESSION`` env), which agrees by
     construction."""
-    variables = model.init(rng, sample_input, train=False)
-    params = variables["params"]
-    batch_stats = variables.get("batch_stats")
-    dist_opt = DistributedOptimizer(optimizer, compression=compression)
-    opt_state = dist_opt.init(params)
-    return TrainState(params, opt_state, batch_stats,
-                      jnp.zeros((), dtype=jnp.int32))
+    with _metrics.span("state.init") as setup:
+        variables = model.init(rng, sample_input, train=False)
+        params = variables["params"]
+        batch_stats = variables.get("batch_stats")
+        dist_opt = DistributedOptimizer(optimizer, compression=compression)
+        opt_state = dist_opt.init(params)
+        state = TrainState(params, opt_state, batch_stats,
+                           jnp.zeros((), dtype=jnp.int32))
+        setup.add(**_metrics.tree_counts(state))
+        return state
 
 
 def replicate_state(state: TrainState, mesh) -> TrainState:
     sharding = NamedSharding(mesh, P())
-    return jax.tree_util.tree_map(
-        lambda x: jax.device_put(x, sharding), state)
+    with _metrics.span("state.replicate", **_metrics.tree_counts(state)):
+        return jax.tree_util.tree_map(
+            lambda x: jax.device_put(x, sharding), state)
 
 
 def init_opt_state(optimizer: optax.GradientTransformation, params, mesh,
@@ -172,7 +178,6 @@ def init_opt_state(optimizer: optax.GradientTransformation, params, mesh,
     helper re-places the remaining scalar leaves (e.g. Adam's ``count``)
     as mesh-replicated, so every leaf is mesh-consistent.
     """
-    state = optimizer.init(params)
     replicated = NamedSharding(mesh, P())
     zero_size = int(mesh.shape[zero_axis]) if zero_axis else 0
 
@@ -193,7 +198,10 @@ def init_opt_state(optimizer: optax.GradientTransformation, params, mesh,
                 return jax.device_put(leaf, NamedSharding(mesh, P(*spec)))
         return leaf  # no divisible dim: this leaf stays un-partitioned
 
-    return jax.tree_util.tree_map(place, state)
+    with _metrics.span("state.opt") as setup:
+        state = jax.tree_util.tree_map(place, optimizer.init(params))
+        setup.add(**_metrics.tree_counts(state))
+        return state
 
 
 def shard_batch(batch, mesh, axis_name: str = AXIS_GLOBAL):

@@ -74,6 +74,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .common import faults as _faults
+from .common import metrics as _metrics
 from .common.compat import shard_map as _shard_map
 from .common.state import AXIS_GLOBAL
 from .ops import xla as _xla
@@ -466,6 +467,7 @@ def gather_params(state: ZeroTrainState, mesh,
     return fn(state.pshard)
 
 
+@_metrics.span("step.build")
 def make_zero_train_step(model, optimizer: optax.GradientTransformation,
                          mesh, axis_name: str = AXIS_GLOBAL,
                          donate: bool = True, accumulate_steps: int = 1,

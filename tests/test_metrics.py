@@ -57,7 +57,7 @@ def test_report_shapes_without_native_are_pinned():
     assert hvd.liveness_report() == ""
     assert isinstance(hvd.liveness_report(), str)
     m = hvd.metrics()
-    assert set(m) == {"python", "native"}
+    assert set(m) == {"python", "native", "spans"}
     assert m["native"] is None
     assert isinstance(m["python"], dict)
     assert isinstance(hvd.metrics_report(), str)

@@ -90,6 +90,12 @@ KERNEL_CASES = [
     dict(shape=(2, 1024, 12, 64), dtype=jnp.bfloat16, segments=True),
     dict(shape=(1, 2048, 8, 128), dtype=jnp.bfloat16, window=512),
     dict(shape=(2, 1024, 12, 64), dtype=jnp.float32),
+    # Grouped K/V ([B, T, kv_heads, D]): two query heads of a grid step on
+    # one K/V head, and four steps of one head and two chunks a side on
+    # one (Trinity-Mini's head width, length and window).
+    dict(shape=(2, 1024, 12, 64), dtype=jnp.float32, kv_heads=6),
+    dict(shape=(1, 8192, 4, 128), dtype=jnp.bfloat16, kv_heads=1,
+         window=2048),
 ]
 KERNEL_TOL = {  # dtype name -> (forward, gradients), rtol == atol
     "bfloat16": (2e-2, 1e-1),
@@ -422,8 +428,11 @@ def phase_decoder(name, cfg, batch, steps, devices, sp=1, tp=1):
 def dense_attention(q, k, v, window=None, seg=None):
     """Causal softmax attention in plain float32 ``jax.numpy``, the
     reference the kernels are held to (the same math as
-    tests/test_pallas_attention.py's oracle). [B, T, H, D] in and out."""
+    tests/test_pallas_attention.py's oracle). [B, T, H, D] in and out;
+    k and v may have fewer heads, each shared by a group of q's."""
     T, D = q.shape[1], q.shape[-1]
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     s = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32),
                    k.astype(jnp.float32)) / np.sqrt(D)
     iq = jnp.arange(T)[:, None]
@@ -444,8 +453,8 @@ def phase_kernels(cases):
         B, T, H, D = case["shape"]
         dtype, window = case["dtype"], case.get("window")
         rng = np.random.RandomState(0)
-        q, k, v = (jnp.asarray(rng.randn(B, T, H, D), dtype)
-                   for _ in range(3))
+        q, k, v = (jnp.asarray(rng.randn(B, T, heads, D), dtype)
+                   for heads in (H, *2 * (case.get("kv_heads", H),)))
         seg = None
         if case.get("segments"):
             # Three packed documents; the boundaries fall inside tiles.
@@ -484,6 +493,7 @@ def phase_kernels(cases):
                 *(x.astype(jnp.float32) for x in (q, k, v)))
         tol_f, tol_g = KERNEL_TOL[jnp.dtype(dtype).name]
         label = (f"{list(case['shape'])} {jnp.dtype(dtype).name}"
+                 + (f" over {k.shape[2]} K/V heads" if k.shape[2] < H else "")
                  + (" segments" if seg is not None else "")
                  + (f" window={window}" if window else ""))
         pairs = [("out", out, ref, tol_f)] + [

@@ -174,11 +174,11 @@ class TransformerConfig:
     remat_keeps: Optional[Tuple[str, ...]] = None
     # Grouped-query attention (Llama/Mistral-style): n_kv_heads < n_heads
     # shares each K/V head across n_heads/n_kv_heads query heads (KV
-    # params cut by that factor). K/V cross the sp fabric at their own
-    # width and are repeated to n_heads where the kernels are called
-    # (parallel/ring_attention.py ``_expand_kv``; the kernels see
-    # multi-head attention, and the repeat's transpose sums the groups).
-    # None = multi-head (= n_heads).
+    # params cut by that factor). K/V cross the sp fabric and enter the
+    # kernels at their own head count: a group's query heads read one K/V
+    # head through the kernels' index maps, and the dK/dV pass sums the
+    # group in VMEM (ops/pallas_attention.py), so no copy of K, V or
+    # their gradients at n_heads exists. None = multi-head (= n_heads).
     n_kv_heads: Optional[int] = None
     # Rotary position embeddings in the layers of kind "attention",
     # instead of the learned position table ("sliding_attention" layers

@@ -26,6 +26,17 @@ the saved lse, so neither pass ever writes the attention matrix to HBM.
 ``flash_attention_block_grads`` exposes the same per-block backward for
 ring attention's backward ring pass (``parallel.ring_attention``).
 
+Head counts: the Q side (q, o, dO, dQ, lse, delta, the Q segment ids) is
+merged as ``[B*H, T, D]`` and the K side (k, v, dK, dV, the K segment
+ids) as ``[B*Hkv, T, D]``, Hkv a divisor of H; the group ``g = H / Hkv``
+is read from the two shapes and nothing else. Query head j reads K/V head
+j // g through the K side's index map, and the dK/dV pass, whose grid is
+over K/V heads, sums a group's query heads into the float32 accumulators
+it holds in VMEM. So K and V are never repeated to H heads and no
+gradient of theirs is written at H heads. With g = 1 every plan, grid,
+index map and kernel body is the multi-head one. The XLA twins take the
+same operands and repeat K and V inside themselves.
+
 Block offsets ride in as prefetched scalars and enter the walk's bounds,
 so the same kernel serves ring attention's rotating K/V blocks (global
 causal masking between sequence blocks) and the plain single-block case.
@@ -131,7 +142,7 @@ def row_lse(m, l):
 
 class KernelPlan(NamedTuple):
     """What one ``pallas_call`` of a pass does, all of it static."""
-    heads: int           # rows of the merged [B*H, T, D] arrays a grid step
+    heads: int           # rows of the merged [B*H, T, D] Q side a grid step
     chunk_q: int         # resident rows of Q (and dO, lse, delta, O)
     chunk_k: int         # resident rows of K and V
     tile_q: int          # the in-kernel loop's sub-tile, [tile_q, tile_k]
@@ -140,6 +151,25 @@ class KernelPlan(NamedTuple):
     grid: tuple          # (B*H / heads, outer chunks, inner chunks)
     tiles_visited: int   # sub-tiles a head's walk enters (q_off == k_off)
     vmem_bytes: int      # counted VMEM; ``vmem_limit_bytes`` is set from it
+    group: int = 1       # query heads that share one K/V head (H / Hkv)
+
+    @property
+    def kv_heads(self) -> int:
+        """Rows of the merged [B*Hkv, T, D] K side a grid step holds: the
+        step's query heads lie in one group or cover whole groups."""
+        return max(1, self.heads // self.group)
+
+    @property
+    def shared(self) -> int:
+        """Query heads of a grid step that read one K/V head."""
+        return self.heads // self.kv_heads
+
+    @property
+    def passes(self) -> int:
+        """Grid steps that a group's query heads take: the forward and the
+        dQ pass send ``passes`` consecutive head blocks to one K/V block,
+        the dK/dV pass visits them in turn on its sequential dimension."""
+        return self.group // self.shared
 
 
 def _is_static(*xs):
@@ -212,19 +242,20 @@ def _lanes(d: int) -> int:
     return -(-d // 128) * 128
 
 
-def _vmem_bytes(kind, heads, unroll, cq, ck, tq, tk, d, itemsize,
+def _vmem_bytes(kind, heads, kv_heads, unroll, cq, ck, tq, tk, d, itemsize,
                 out_itemsize, segments, state):
     """VMEM one grid step of pass ``kind`` holds, counted as Mosaic lays
     it out: the last dimension padded to 128 lanes (a [T, 1] float32 row
     vector is T x 512 bytes), pipelined blocks double-buffered, and six
-    float32 [tq, tk] temporaries for every head of a loop body."""
+    float32 [tq, tk] temporaries for every head of a loop body. The K
+    side (k, v, dk, dv and their accumulators) holds ``kv_heads``."""
     dp = _lanes(d)
     row = 128 * 4
     q_side = heads * cq * dp
-    k_side = heads * ck * dp
+    k_side = kv_heads * ck * dp
     blocks = (q_side + 2 * k_side) * itemsize              # q, k, v
     if segments:
-        blocks += heads * (cq + ck) * row
+        blocks += (heads * cq + kv_heads * ck) * row
     if kind == "fwd":
         blocks += q_side * out_itemsize                    # o (acc)
         blocks += heads * cq * row * (2 if state else 1)   # lse | m, l
@@ -241,23 +272,32 @@ def _vmem_bytes(kind, heads, unroll, cq, ck, tq, tk, d, itemsize,
 
 
 def kernel_plan(BH, Tq, Tk, D, dtype, causal, window=None, *,
-                segments=False, kind="fwd", out_dtype=None, state=False):
+                segments=False, kind="fwd", out_dtype=None, state=False,
+                group=1):
     """The grid step of pass ``kind`` ("fwd", "dq" or "dkv") for merged
-    ``[BH, T, D]`` operands of ``dtype``: a pure function of the shape.
-    None where the kernels do not take the shape and the XLA twins do: a
-    sequence no tile divides, or a head so wide that no chunk fits
-    ``VMEM_BUDGET``.
+    ``[BH, Tq, D]`` Q-side operands of ``dtype`` over ``[BH / group, Tk,
+    D]`` K-side ones: a pure function of the shape. None where the
+    kernels do not take the shape and the XLA twins do: a sequence no
+    tile divides, or a head so wide that no chunk fits ``VMEM_BUDGET``.
 
     Heads a step: enough that a step holds ``_STEP_ELEMS`` score elements
-    (16 at T 128, 2 at T 1024, 1 from T 2048 on), a divisor of ``BH``,
-    fewer where that many do not fit;
+    (16 at T 128, 2 at T 1024, 1 from T 2048 on), a divisor of ``BH``
+    that lies in one group of ``group`` query heads or covers whole
+    groups, fewer where that many do not fit;
     of them ``_BODY_ELEMS`` score elements' worth, at most
     ``_MAX_UNROLL``, share a loop body. Chunk: the whole sequence on both
     sides while the counted VMEM stays under ``VMEM_BUDGET``, else the
     largest power-of-two chunk that does — the chunks are then grid
     dimensions, the inner one sequential. Sub-tile:
     the largest divisor of the chunk up to ``_TILE_CAP``. The count is
-    that of a block on the diagonal (``q_off == k_off``), per head."""
+    that of a block on the diagonal (``q_off == k_off``), per head.
+
+    The K side has ``BH / group`` heads. The forward and the dQ pass keep
+    the grid of the Q side and send a step's heads to their K/V block
+    through the index map. The dK/dV pass has a grid over K/V heads and
+    sums a group into the one accumulator pair a K/V head has: as heads
+    of one step where the step covers whole groups, else as
+    ``plan.passes`` times the Q chunks on the sequential dimension."""
     if _pick_block(Tq, 8) is None or _pick_block(Tk, 8) is None:
         return None
     itemsize = jnp.dtype(dtype).itemsize
@@ -272,11 +312,12 @@ def kernel_plan(BH, Tq, Tk, D, dtype, causal, window=None, *,
         fits = None
         # The most heads, up to the wanted, that divide BH and fit.
         for heads in range(want, 0, -1):
-            if BH % heads:
+            if BH % heads or (group % heads and heads % group):
                 continue
             unroll = max(u for u in range(1, body + 1) if heads % u == 0)
-            vmem = _vmem_bytes(kind, heads, unroll, cq, ck, tq, tk, D,
-                               itemsize, out_itemsize, segments, state)
+            vmem = _vmem_bytes(kind, heads, max(1, heads // group), unroll,
+                               cq, ck, tq, tk, D, itemsize, out_itemsize,
+                               segments, state)
             if vmem <= VMEM_BUDGET:
                 fits = heads, unroll, vmem
                 break
@@ -299,9 +340,11 @@ def kernel_plan(BH, Tq, Tk, D, dtype, causal, window=None, *,
                                    ck // tk, causal, window)
                          for i in range(cq // tq))
             visited += sum(end - first for first, end in walks)
-    grid = ((BH // heads, n_kc, n_qc) if kind == "dkv"
-            else (BH // heads, n_qc, n_kc))
-    return KernelPlan(heads, cq, ck, tq, tk, unroll, grid, visited, vmem)
+    plan = KernelPlan(heads, cq, ck, tq, tk, unroll, (), visited, vmem,
+                      group)
+    grid = ((BH // heads // plan.passes, n_kc, plan.passes * n_qc)
+            if kind == "dkv" else (BH // heads, n_qc, n_kc))
+    return plan._replace(grid=grid)
 
 
 def _kernels_take(kinds, q, k, causal, window, segments, **out) -> bool:
@@ -311,19 +354,25 @@ def _kernels_take(kinds, q, k, causal, window, segments, **out) -> bool:
     BH, Tq, D = q.shape
     return all(
         kernel_plan(BH, Tq, k.shape[1], D, q.dtype, causal, window,
-                    segments=segments, kind=kind, **out) is not None
+                    segments=segments, kind=kind, group=BH // k.shape[0],
+                    **out) is not None
         for kind in kinds)
 
 
 def _log_plan(kind, shape, dtype, causal, window, plan):
     """Everything a plan decides is static, so it is logged once, when
     the call is traced (``HOROVOD_LOG_LEVEL=debug``), and counted: the
-    host traces this ``pallas_call`` and lowers it to Mosaic."""
+    host traces this ``pallas_call`` and lowers it to Mosaic. A call
+    whose K side has fewer heads than its Q side counts a second time,
+    under ``kernels.grouped.``."""
     _metrics.inc(f"kernels.traced.flash_{kind}")
+    if plan.group > 1:
+        _metrics.inc(f"kernels.grouped.flash_{kind}")
     _log.debug(
         f"flash_{kind} {tuple(shape)} {jnp.dtype(dtype).name} "
         f"causal={causal} window={window}: {plan.heads} heads a step "
-        f"({plan.unroll} a loop body), chunk "
+        f"({plan.unroll} a loop body) over {plan.kv_heads} K/V "
+        f"(group {plan.group}), chunk "
         f"{plan.chunk_q}x{plan.chunk_k}, sub-tile "
         f"{plan.tile_q}x{plan.tile_k}, grid {plan.grid}, "
         f"{plan.tiles_visited} tiles a head, VMEM {plan.vmem_bytes} B")
@@ -407,13 +456,26 @@ def _across(x, n):
     return pltpu.repeat(x, n // w, axis=1)
 
 
-def _keep(mask, qs_ref, ks_ref, g, rows, cols):
-    """The tile's mask composed with head ``g``'s segment ids (either may
-    be absent; None means every element is kept)."""
+def _keep(mask, qs_ref, ks_ref, g, c, rows, cols):
+    """The tile's mask composed with the segment ids of head ``g`` and its
+    K/V head ``c`` (either may be absent; None means every element is
+    kept)."""
     if qs_ref is None:
         return mask
-    same = qs_ref[g, rows, :] == ks_ref[g, cols, :].reshape(1, -1)
+    same = qs_ref[g, rows, :] == ks_ref[c, cols, :].reshape(1, -1)
     return same if mask is None else jnp.logical_and(mask, same)
+
+
+def _kv_head(plan):
+    """Head ``g`` of a grid step -> the row of the step's K-side blocks
+    it reads: ``g`` itself without a group (and in a step of one head,
+    whatever the group: the index map has chosen the row)."""
+    n = plan.shared
+    if n == 1:
+        return lambda g: g
+    if plan.kv_heads == 1:
+        return lambda g: 0
+    return lambda g: g // n if isinstance(g, int) else jax.lax.div(g, n)
 
 
 def _fwd_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
@@ -421,8 +483,10 @@ def _fwd_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
     """One grid step of the forward: ``plan.heads`` heads x a resident
     [chunk_q, D] of Q x a resident [chunk_k, D] of K and V.
 
-    Refs: q (G, chunk_q, D), k/v (G, chunk_k, D), optional segment ids
-    qs/ks (G, chunk, 1) int32, then the outputs of ``mode`` — "plain": o;
+    Refs: q (G, chunk_q, D), k/v (Gkv, chunk_k, D), optional segment ids
+    qs (G, chunk_q, 1) and ks (Gkv, chunk_k, 1) int32 (Gkv is
+    ``plan.kv_heads``, G without a group), then the outputs of ``mode``
+    — "plain": o;
     "train": o and the per-row lse (G, chunk_q, 1); "state" (ring
     attention): the UNnormalized accumulator plus (m, l), which the
     caller merges with the online-softmax combine — then scratch m/l
@@ -435,6 +499,7 @@ def _fwd_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
     outs = refs[n_in:-3]
     m_ref, l_ref, acc_ref = refs[-3:]
     G, tq, tk = plan.heads, plan.tile_q, plan.tile_k
+    kv = _kv_head(plan)
     n_k = plan.chunk_k // tk
     n_kc = plan.grid[2]
     # program_id is read here, outside every predicated or looped body.
@@ -461,9 +526,10 @@ def _fwd_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
             def head(g):
                 # Feed the MXU its native input dtype (bf16 x bf16 -> f32
                 # accumulate); pre-casting to f32 would halve throughput.
-                s = _mxu_dot(q_ref[g, rows, :], k_ref[g, cols, :],
+                c = kv(g)
+                s = _mxu_dot(q_ref[g, rows, :], k_ref[c, cols, :],
                              ((1,), (1,))) * scale            # [tq, tk]
-                keep = _keep(mask, qs_ref, ks_ref, g, rows, cols)
+                keep = _keep(mask, qs_ref, ks_ref, g, c, rows, cols)
                 if keep is not None:
                     s = jnp.where(keep, s, NEG_INF)
                 m_prev = m_ref[g, rows, :]                    # [tq, W]
@@ -480,7 +546,7 @@ def _fwd_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
                          jnp.sum(p, axis=-1, keepdims=True))
                 # P rides the MXU in the V dtype (f32 accumulation
                 # preserved) — the standard TPU flash-kernel trade.
-                v = v_ref[g, cols, :]
+                v = v_ref[c, cols, :]
                 acc = (acc_ref[g, rows, :] * _across(corr, v.shape[-1]) +
                        _mxu_dot(p.astype(v.dtype), v, ((1,), (0,))))
                 return m_new, l_new, acc
@@ -524,6 +590,7 @@ def _dq_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
     qs_ref, ks_ref = refs[6:8] if segments else (None, None)
     dq_ref, dq_acc = refs[-2:]
     G, tq, tk = plan.heads, plan.tile_q, plan.tile_k
+    kv = _kv_head(plan)
     n_k = plan.chunk_k // tk
     n_kc = plan.grid[2]
     kc = pl.program_id(2)
@@ -544,14 +611,15 @@ def _dq_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
             mask = _tile_mask(q_lo, k_base + j * tk, tq, tk, causal, window)
 
             def head(g):
-                k = k_ref[g, cols, :]
+                c = kv(g)
+                k = k_ref[c, cols, :]
                 s = _mxu_dot(q_ref[g, rows, :], k,
                              ((1,), (1,))) * scale                # [tq, tk]
                 p = jnp.exp(s - lse_ref[g, rows, :])
-                keep = _keep(mask, qs_ref, ks_ref, g, rows, cols)
+                keep = _keep(mask, qs_ref, ks_ref, g, c, rows, cols)
                 if keep is not None:
                     p = jnp.where(keep, p, 0.0)
-                dp = _mxu_dot(do_ref[g, rows, :], v_ref[g, cols, :],
+                dp = _mxu_dot(do_ref[g, rows, :], v_ref[c, cols, :],
                               ((1,), (1,)))                       # [tq, tk]
                 ds = p * (dp - delta_ref[g, rows, :])
                 return dq_acc[g, rows, :] + _mxu_dot(
@@ -578,18 +646,28 @@ def _dq_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
 def _dkv_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
                 segments: bool):
     """dK/dV pass: the transposed walk — for each K sub-tile, the Q
-    sub-tiles that see it; grid (heads, K chunk, Q chunk), sequential over
-    Q chunks. Same [tq, tk] orientation as the dQ pass; the transposed
+    sub-tiles that see it; grid (K/V heads, K chunk, Q chunk), sequential
+    over Q chunks. Same [tq, tk] orientation as the dQ pass; the transposed
     contractions (P^T.dO, dS^T.Q) ride dot_general dimension numbers so
     no tile is ever explicitly transposed. The softmax scale multiplies
-    dK's accumulator once, at the end, as it does dQ's."""
+    dK's accumulator once, at the end, as it does dQ's.
+
+    A K/V head's accumulators (Gkv, chunk_k, D) take every query head of
+    its group: the step's own that share it (each body's products are
+    added once all of the body are computed), and, where the step holds
+    fewer than the group, those of the ``plan.passes`` steps that walk
+    the Q chunks again on the sequential dimension, a head block each."""
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     qs_ref, ks_ref = refs[6:8] if segments else (None, None)
     dk_ref, dv_ref, dk_acc, dv_acc = refs[-4:]
-    G, tq, tk = plan.heads, plan.tile_q, plan.tile_k
+    G, Gkv, tq, tk = plan.heads, plan.kv_heads, plan.tile_q, plan.tile_k
+    kv = _kv_head(plan)
+    shared = plan.shared > 1
     n_q = plan.chunk_q // tq
-    n_qc = plan.grid[2]
-    qc = pl.program_id(2)
+    n_seq = plan.grid[2]             # the Q chunks, ``passes`` times over
+    step = pl.program_id(2)
+    qc = (step if plan.passes == 1
+          else jax.lax.rem(step, n_seq // plan.passes))
     q_base = offs_ref[0] + qc * plan.chunk_q
     k_base = offs_ref[1] + pl.program_id(1) * plan.chunk_k
     scale = 1.0 / (q_ref.shape[-1] ** 0.5)
@@ -599,7 +677,7 @@ def _dkv_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
         k_lo = k_base + j * tk
 
         def init():
-            zeros = jnp.zeros((G, tk, dk_acc.shape[-1]), jnp.float32)
+            zeros = jnp.zeros((Gkv, tk, dk_acc.shape[-1]), jnp.float32)
             dk_acc[:, cols, :] = zeros
             dv_acc[:, cols, :] = zeros
 
@@ -608,24 +686,35 @@ def _dkv_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
             mask = _tile_mask(q_base + i * tq, k_lo, tq, tk, causal, window)
 
             def head(g):
+                c = kv(g)
+
+                def add(acc, product):
+                    # Heads of a body that share an accumulator hand
+                    # their products to ``commit``.
+                    return product() if shared else acc[c, cols, :] + product()
+
                 q = q_ref[g, rows, :]
                 do = do_ref[g, rows, :]
-                s = _mxu_dot(q, k_ref[g, cols, :],
+                s = _mxu_dot(q, k_ref[c, cols, :],
                              ((1,), (1,))) * scale                # [tq, tk]
                 p = jnp.exp(s - lse_ref[g, rows, :])
-                keep = _keep(mask, qs_ref, ks_ref, g, rows, cols)
+                keep = _keep(mask, qs_ref, ks_ref, g, c, rows, cols)
                 if keep is not None:
                     p = jnp.where(keep, p, 0.0)
-                dv = dv_acc[g, cols, :] + _mxu_dot(
-                    p.astype(do.dtype), do, ((0,), (0,)))         # [tk, D]
-                dp = _mxu_dot(do, v_ref[g, cols, :], ((1,), (1,)))
+                dv = add(dv_acc, lambda: _mxu_dot(
+                    p.astype(do.dtype), do, ((0,), (0,))))        # [tk, D]
+                dp = _mxu_dot(do, v_ref[c, cols, :], ((1,), (1,)))
                 ds = p * (dp - delta_ref[g, rows, :])
-                dk = dk_acc[g, cols, :] + _mxu_dot(
-                    ds.astype(q.dtype), q, ((0,), (0,)))          # [tk, D]
+                dk = add(dk_acc, lambda: _mxu_dot(
+                    ds.astype(q.dtype), q, ((0,), (0,))))         # [tk, D]
                 return dk, dv
 
             def commit(g, grads):
-                dk_acc[g, cols, :], dv_acc[g, cols, :] = grads
+                c = kv(g)
+                if shared:
+                    grads = (dk_acc[c, cols, :] + grads[0],
+                             dv_acc[c, cols, :] + grads[1])
+                dk_acc[c, cols, :], dv_acc[c, cols, :] = grads
 
             _for_heads(G, head, commit, plan.unroll)
 
@@ -635,10 +724,10 @@ def _dkv_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
                                       scale).astype(dk_ref.dtype)
                 dv_ref[g, cols, :] = dv_acc[g, cols, :].astype(dv_ref.dtype)
 
-            _for_heads(G, head)
+            _for_heads(Gkv, head)
 
         _walk(_q_bounds(k_lo, tk, q_base, tq, n_q, causal, window), tile,
-              qc, n_qc, init, finish)
+              step, n_seq, init, finish)
 
     _for_each(plan.chunk_k // tk, k_tile)
 
@@ -646,20 +735,35 @@ def _dkv_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
 def _flash_call(kind, kernel, plan, args, out_shapes, out_sides, scratch,
                 interpret):
     """The ``pallas_call`` of one pass. ``args`` are (array, side) pairs,
-    side "q" or "k" saying which chunk the block follows; the outputs'
-    sides are ``out_sides``. The last grid dimension is the sequential
-    one: K chunks for "fwd" and "dq", Q chunks for "dkv"."""
-    G = plan.heads
+    side "q" or "k" saying which chunk and which head count the block
+    follows; the outputs' sides are ``out_sides``. The last grid
+    dimension is the sequential one: K chunks for "fwd" and "dq", Q
+    chunks for "dkv".
+
+    A group's ``plan.passes`` head blocks share one K-side block: the
+    forward and the dQ pass send grid row ``b`` to K/V block ``b //
+    passes``; the dK/dV pass, whose rows are K/V blocks, takes the group's
+    head blocks one after another on the sequential dimension (head block
+    ``b * passes + i // n_qc``, Q chunk ``i % n_qc``)."""
+    n = plan.passes
     if kind == "dkv":
         maps = {"q": lambda b, kc, qc, offs: (b, qc, 0),
                 "k": lambda b, kc, qc, offs: (b, kc, 0)}
+        if n > 1:
+            n_qc = plan.grid[2] // n
+            maps["q"] = lambda b, kc, i, offs: (
+                b * n + jax.lax.div(i, n_qc), jax.lax.rem(i, n_qc), 0)
     else:
         maps = {"q": lambda b, qc, kc, offs: (b, qc, 0),
                 "k": lambda b, qc, kc, offs: (b, kc, 0)}
+        if n > 1:
+            maps["k"] = lambda b, qc, kc, offs: (jax.lax.div(b, n), kc, 0)
+    heads = {"q": plan.heads, "k": plan.kv_heads}
     chunk = {"q": plan.chunk_q, "k": plan.chunk_k}
 
     def spec(shape, side):
-        return pl.BlockSpec((G, chunk[side], shape[-1]), maps[side])
+        return pl.BlockSpec((heads[side], chunk[side], shape[-1]),
+                            maps[side])
 
     return pl.pallas_call(
         kernel,
@@ -682,11 +786,12 @@ def _flash_call(kind, kernel, plan, args, out_shapes, out_sides, scratch,
 
 
 def _seg_args(q_seg, k_seg):
-    """[BH, T] int32 -> [BH, T, 1] blocks, the row-oriented layout the
-    lse/delta blocks already use. Mosaic requires the last two block dims
-    be (8, 128)-divisible or full-extent; a (G, chunk, 1) block satisfies
-    that for EVERY chunk (>= 8 on the sublane dim, the lane dim full at
-    1) — the lane-major (G, 1, chunk) layout fails for sub-tiles < 128."""
+    """[BH, Tq] and [BHkv, Tk] int32 -> [.., T, 1] blocks, the
+    row-oriented layout the lse/delta blocks already use. Mosaic requires
+    the last two block dims be (8, 128)-divisible or full-extent; a (G,
+    chunk, 1) block satisfies that for EVERY chunk (>= 8 on the sublane
+    dim, the lane dim full at 1) — the lane-major (G, 1, chunk) layout
+    fails for sub-tiles < 128."""
     if q_seg is None:
         return []
     return [(q_seg[:, :, None], "q"), (k_seg[:, :, None], "k")]
@@ -694,15 +799,16 @@ def _seg_args(q_seg, k_seg):
 
 def _flash_forward(q, k, v, offs, causal: bool, interpret: bool, mode: str,
                    q_seg=None, k_seg=None, window=None):
-    """The forward pass on merged [BH, T, D] operands. ``mode`` "plain":
-    o in q.dtype; "train": (o, lse f32 [BH, Tq, 1]); "state": (acc f32,
-    m, l), the unmerged online-softmax state of this K block."""
+    """The forward pass on merged operands, q [BH, Tq, D] over k, v
+    [BHkv, Tk, D]. ``mode`` "plain": o in q.dtype; "train": (o, lse f32
+    [BH, Tq, 1]); "state": (acc f32, m, l), the unmerged online-softmax
+    state of this K block."""
     BH, Tq, D = q.shape
     Tk = k.shape[1]
     segments = q_seg is not None
     plan = kernel_plan(BH, Tq, Tk, D, q.dtype, causal, window,
                        segments=segments, kind="fwd",
-                       state=mode == "state")
+                       state=mode == "state", group=BH // k.shape[0])
     _log_plan("fwd", q.shape, q.dtype, causal, window, plan)
     row = jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32)
     out_shapes = {
@@ -729,10 +835,10 @@ def _pallas_bwd(q, k, v, do, lse, delta, offs, causal: bool,
                 interpret: bool, out_dtype=None, q_seg=None, k_seg=None,
                 window=None):
     """The two flash-backward kernels; returns (dq, dk, dv) in the input
-    dtypes (or ``out_dtype`` when given — ring accumulation wants f32).
-    lse/delta: f32 [BH, T, 1]."""
+    dtypes (or ``out_dtype`` when given — ring accumulation wants f32),
+    dk and dv at the head count of k and v. lse/delta: f32 [BH, T, 1]."""
     BH, Tq, D = q.shape
-    Tk = k.shape[1]
+    BHkv, Tk = k.shape[:2]
     segments = q_seg is not None
     operands = [(q, "q"), (k, "k"), (v, "k"), (do, "q"), (lse, "q"),
                 (delta, "q")] + _seg_args(q_seg, k_seg)
@@ -740,7 +846,7 @@ def _pallas_bwd(q, k, v, do, lse, delta, offs, causal: bool,
     for kind, kernel in (("dq", _dq_kernel), ("dkv", _dkv_kernel)):
         plan = kernel_plan(BH, Tq, Tk, D, q.dtype, causal, window,
                            segments=segments, kind=kind,
-                           out_dtype=out_dtype)
+                           out_dtype=out_dtype, group=BH // BHkv)
         _log_plan(kind, q.shape, q.dtype, causal, window, plan)
         G, cq, ck = plan.heads, plan.chunk_q, plan.chunk_k
         if kind == "dq":
@@ -749,13 +855,13 @@ def _pallas_bwd(q, k, v, do, lse, delta, offs, causal: bool,
             sides = ["q"]
             scratch = [pltpu.VMEM((G, cq, D), jnp.float32)]
         else:
-            shapes = [jax.ShapeDtypeStruct((BH, Tk, D),
+            shapes = [jax.ShapeDtypeStruct((BHkv, Tk, D),
                                            out_dtype or k.dtype),
-                      jax.ShapeDtypeStruct((BH, Tk, D),
+                      jax.ShapeDtypeStruct((BHkv, Tk, D),
                                            out_dtype or v.dtype)]
             sides = ["k", "k"]
-            scratch = [pltpu.VMEM((G, ck, D), jnp.float32),
-                       pltpu.VMEM((G, ck, D), jnp.float32)]
+            scratch = [pltpu.VMEM((plan.kv_heads, ck, D), jnp.float32),
+                       pltpu.VMEM((plan.kv_heads, ck, D), jnp.float32)]
         outs[kind] = _flash_call(
             kind,
             functools.partial(kernel, plan=plan, causal=causal,
@@ -801,13 +907,24 @@ def _check_window(window, causal):
         raise ValueError(f"window must be >= 1, got {window}")
 
 
+def _xla_expand(q, *k_side):
+    """The twins attend at the query heads' count: each merged K-side
+    array ([BHkv, ...], or None) repeated to ``q``'s BH rows, query head j
+    reading K/V head j // g. The repeat's transpose sums the groups."""
+    g = q.shape[0] // k_side[0].shape[0]
+    return [x if x is None or g == 1 else jnp.repeat(x, g, axis=0)
+            for x in k_side]
+
+
 @jax.named_scope("flash_xla")
 def _xla_block_state(q, k, v, offs, causal, q_seg=None, k_seg=None,
                      window=None):
     """XLA twin of the block-mode kernel (backward recompute + fallback).
     ``offs`` = int32[2] (q_off, k_off) — an array, not statics, because
     ring attention traces the rotating block origin. ``q_seg``/``k_seg``:
-    optional int32 [BH, T] per-block segment ids (packed sequences)."""
+    optional int32 [BH, T] / [BHkv, T] per-block segment ids (packed
+    sequences)."""
+    k, v, k_seg = _xla_expand(q, k, v, k_seg)
     scale = 1.0 / (q.shape[-1] ** 0.5)
     s = jnp.einsum("btd,bsd->bts", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
@@ -885,9 +1002,21 @@ def _resolve_dispatch(use_pallas: Optional[bool]):
 
 
 def _merge_heads(x):
-    """[B, T, H, D] -> [B*H, T, D]."""
+    """[B, T, H, D] -> [B*H, T, D], each array at its own head count."""
     B, T, H, D = x.shape
     return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+
+
+def _kv_heads(q, k, v):
+    """Hkv of ``k``, ``v`` [B, T, Hkv, D], which divides the H of ``q``
+    [B, T, H, D]: query head j reads K/V head j // (H / Hkv). The group
+    is read from these shapes and nothing else."""
+    H, Hkv = q.shape[2], k.shape[2]
+    if v.shape[2] != Hkv or H % Hkv:
+        raise ValueError(
+            f"{H} query heads over {Hkv} K and {v.shape[2]} V heads: the "
+            "K/V head count must be one and divide the query heads'")
+    return Hkv
 
 
 def flash_attention_block(q, k, v, q_off, k_off, causal: bool = True,
@@ -896,13 +1025,16 @@ def flash_attention_block(q, k, v, q_off, k_off, causal: bool = True,
                           window: Optional[int] = None):
     """One K/V block's unmerged attention state for ring attention.
 
-    q/k/v: [B, T, H, D]. Returns (acc, m, l) with acc f32 [B, T, H, D]
+    q: [B, T, H, D]; k/v: [B, T, Hkv, D], Hkv a divisor of H (query head
+    j reads K/V head j // (H / Hkv)). Returns (acc, m, l) with acc f32
+    [B, T, H, D]
     (unnormalized P.V), m/l f32 [B, H, T] — merge across blocks with the
     online-softmax combine. Dispatch rules match ``flash_attention``
     (shared ``_resolve_dispatch``); segment ids stream into the same
     kernels as extra id blocks (packed sequences).
     """
     B, Tq, H, D = q.shape
+    Hkv = _kv_heads(q, k, v)
 
     offs = jnp.stack([jnp.asarray(q_off, jnp.int32),
                       jnp.asarray(k_off, jnp.int32)])
@@ -910,7 +1042,7 @@ def flash_attention_block(q, k, v, q_off, k_off, causal: bool = True,
     q_seg = k_seg = None
     if q_segment_ids is not None:
         q_seg = _tile_seg(q_segment_ids, H)
-        k_seg = _tile_seg(k_segment_ids, H)
+        k_seg = _tile_seg(k_segment_ids, Hkv)
     _check_window(window, causal)
     use_pallas, interpret = _resolve_dispatch(use_pallas)
     if use_pallas:
@@ -934,15 +1066,17 @@ def flash_attention_block_grads(q, k, v, do, lse, delta, q_off, k_off,
                                 window: Optional[int] = None):
     """One K/V block's (dq, dk, dv) for ring attention's backward pass.
 
-    q/k/v/do: [B, T, H, D]; lse/delta: f32 [B, H, T] — the GLOBAL row
-    statistics (lse over all keys, delta = rowsum(dO*O)), so each block's
-    P = exp(S - lse) is already globally normalized and the per-block
-    gradients simply sum across the ring. Returns f32 arrays in the
-    [B, T, H, D] layout (f32 so the ring's cross-block accumulation
-    doesn't round at the model dtype each step).
+    q/do: [B, T, H, D]; k/v: [B, T, Hkv, D]; lse/delta: f32 [B, H, T] —
+    the GLOBAL row statistics (lse over all keys, delta = rowsum(dO*O)),
+    so each block's P = exp(S - lse) is already globally normalized and
+    the per-block gradients simply sum across the ring. Returns f32
+    arrays in the layout of q, k and v, dk and dv summed over each group
+    of query heads (f32 so the ring's cross-block accumulation doesn't
+    round at the model dtype each step).
     """
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
+    Hkv = _kv_heads(q, k, v)
     use_pallas, interpret = _resolve_dispatch(use_pallas)
 
     offs = jnp.stack([jnp.asarray(q_off, jnp.int32),
@@ -954,7 +1088,7 @@ def flash_attention_block_grads(q, k, v, do, lse, delta, q_off, k_off,
     q_seg = k_seg = None
     if q_segment_ids is not None:
         q_seg = _tile_seg(q_segment_ids, H)
-        k_seg = _tile_seg(k_segment_ids, H)
+        k_seg = _tile_seg(k_segment_ids, Hkv)
     _check_window(window, causal)
     if use_pallas and _kernels_take(("dq", "dkv"), qm, km, causal, window,
                                     q_seg is not None,
@@ -968,20 +1102,23 @@ def flash_attention_block_grads(q, k, v, do, lse, delta, q_off, k_off,
                                       q_seg=q_seg, k_seg=k_seg,
                                       window=window)
 
-    def split(x, t):
-        return x.reshape(B, H, t, D).transpose(0, 2, 1, 3)
+    def split(x, t, h):
+        return x.reshape(B, h, t, D).transpose(0, 2, 1, 3)
 
-    return split(dq, Tq), split(dk, Tk), split(dv, Tk)
+    return split(dq, Tq, H), split(dk, Tk, Hkv), split(dv, Tk, Hkv)
 
 
 @jax.named_scope("flash_xla")
 def _xla_block_grads(q, k, v, do, lse, delta, offs, causal: bool,
                      out_dtype=None, q_seg=None, k_seg=None, window=None):
     """XLA twin of the backward kernels (fallback for untileable shapes
-    and non-TPU platforms). Same math, same lse/delta residuals."""
+    and non-TPU platforms). Same math, same lse/delta residuals; dk and
+    dv summed over each group in float32, at the head count of k and v."""
     dq_dt = out_dtype or q.dtype
     dk_dt = out_dtype or k.dtype
     dv_dt = out_dtype or v.dtype
+    BHkv = k.shape[0]
+    k, v, k_seg = _xla_expand(q, k, v, k_seg)
     scale = 1.0 / (q.shape[-1] ** 0.5)
     s = jnp.einsum("btd,bsd->bts", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
@@ -1000,6 +1137,8 @@ def _xla_block_grads(q, k, v, do, lse, delta, offs, causal: bool,
     ds = p * (dp - delta) * scale
     dq = jnp.einsum("bts,bsd->btd", ds, k.astype(jnp.float32))
     dk = jnp.einsum("bts,btd->bsd", ds, q.astype(jnp.float32))
+    if BHkv != q.shape[0]:
+        dk, dv = (x.reshape(BHkv, -1, *x.shape[1:]).sum(1) for x in (dk, dv))
     return dq.astype(dq_dt), dk.astype(dk_dt), dv.astype(dv_dt)
 
 
@@ -1008,8 +1147,9 @@ def _xla_flash(q, k, v, q_off, k_off, causal, q_seg=None, k_seg=None,
                window=None):
     """XLA reference path (backward recompute + non-TPU fallback), fp32
     accumulation — the same math as parallel.ring_attention.
-    ``q_seg``/``k_seg``: optional int32 [BH, T] segment ids (packed
-    sequences); tokens attend only within their segment."""
+    ``q_seg``/``k_seg``: optional int32 [BH, T] / [BHkv, T] segment ids
+    (packed sequences); tokens attend only within their segment."""
+    k, v, k_seg = _xla_expand(q, k, v, k_seg)
     scale = 1.0 / (q.shape[-1] ** 0.5)
     s = jnp.einsum("btd,bsd->bts", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
@@ -1090,7 +1230,11 @@ def flash_attention(q, k, v, causal: bool = True, q_off: int = 0,
                     k_off: int = 0, use_pallas: Optional[bool] = None,
                     q_segment_ids=None, k_segment_ids=None,
                     window: Optional[int] = None):
-    """Blocked flash attention. q/k/v: [B, T, H, D].
+    """Blocked flash attention. q: [B, T, H, D]; k/v: [B, T, Hkv, D] with
+    Hkv a divisor of H: query head j reads K/V head j // (H / Hkv)
+    (grouped-query attention; Hkv == H is multi-head). The kernels fetch
+    a K/V head for its group through their index maps and sum a group's
+    dK and dV in VMEM: K, V and their gradients stay at Hkv heads.
 
     ``use_pallas=None`` auto-selects via ``_resolve_dispatch``.
     ``q_off``/``k_off`` are the global token offsets of the blocks — ring
@@ -1104,6 +1248,7 @@ def flash_attention(q, k, v, causal: bool = True, q_off: int = 0,
     the segment-free path compiles unchanged.
     """
     B, Tq, H, D = q.shape
+    Hkv = _kv_heads(q, k, v)
 
     def split(x, t):
         return x.reshape(B, H, t, D).transpose(0, 2, 1, 3)
@@ -1113,7 +1258,7 @@ def flash_attention(q, k, v, causal: bool = True, q_off: int = 0,
     q_seg = k_seg = None
     if q_segment_ids is not None:
         q_seg = _tile_seg(q_segment_ids, H)
-        k_seg = _tile_seg(k_segment_ids, H)
+        k_seg = _tile_seg(k_segment_ids, Hkv)
 
     use_pallas, interpret = _resolve_dispatch(use_pallas)
     if not use_pallas:

@@ -53,16 +53,6 @@ def _block_attend(q, k, v, m, l, o, mask):
     return m_new, l_new, o_new
 
 
-def _expand_kv(k, v, g):
-    """Expand GQA K/V from Hkv to H = g * Hkv query heads (consecutive
-    repeat: query head j reads KV head j // g). The backward adjoint is
-    the matching group-sum, dk.reshape(B, T, Hkv, g, D).sum(3) — keep
-    the two in lockstep."""
-    if g <= 1:
-        return k, v
-    return jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
-
-
 def _ring_fwd_pass(q, k, v, seg, axis_name: str, causal: bool,
                    window=None):
     """The forward ring: flash block kernel per rotating K/V block +
@@ -73,7 +63,6 @@ def _ring_fwd_pass(q, k, v, seg, axis_name: str, causal: bool,
     sp = _axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, Tq, H, D = q.shape
-    g = H // k.shape[2]  # GQA group size (1 = plain multi-head)
     m = jnp.full((B, H, Tq), NEG_INF, dtype=jnp.float32)
     l = jnp.zeros((B, H, Tq), dtype=jnp.float32)
     o = jnp.zeros((B, Tq, H, D), dtype=jnp.float32)
@@ -85,14 +74,14 @@ def _ring_fwd_pass(q, k, v, seg, axis_name: str, causal: bool,
         # k_cur originated at rank (my - step) mod sp. Each block's local
         # attention state comes from the flash kernel (Pallas on TPU, XLA
         # elsewhere); the cross-block merge below is the standard
-        # online-softmax combine. GQA K/V travel the ring at their
-        # reduced head width and expand only for the kernel call.
+        # online-softmax combine. GQA K/V travel the ring at their own
+        # head count, and the kernels read them at it (query head j reads
+        # K/V head j // g through the index map).
         from ..ops.pallas_attention import flash_attention_block
 
         k_blk = (my - step) % sp
-        k_full, v_full = _expand_kv(k_cur, v_cur, g)
         acc_b, m_b, l_b = flash_attention_block(
-            q, k_full, v_full, q_off=my * Tq,
+            q, k_cur, v_cur, q_off=my * Tq,
             k_off=k_blk * k_cur.shape[1],
             causal=causal, q_segment_ids=seg,
             k_segment_ids=None if seg is None else kseg_cur,
@@ -144,7 +133,9 @@ def _ring_vjp_bwd(axis_name, causal, window, res, do):
     through the flash backward kernels with the GLOBAL lse/delta
     residuals, and dK/dV accumulators travel with their blocks — after sp
     rotations every gradient is home. Twice the forward's ppermute bytes
-    (k, v, dk, dv per step), the standard ring-backward cost."""
+    (k, v, dk, dv per step), the standard ring-backward cost. With GQA the
+    kernels return a block's dK/dV already summed over each group, so the
+    accumulators rotate at the K/V head count."""
     from ..ops.pallas_attention import flash_attention_block_grads
 
     q, k, v, seg, o, lse = res
@@ -153,7 +144,6 @@ def _ring_vjp_bwd(axis_name, causal, window, res, do):
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     Hkv = k.shape[2]
-    g = H // Hkv
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1).transpose(0, 2, 1)            # [B, H, Tq]
 
@@ -165,19 +155,12 @@ def _ring_vjp_bwd(axis_name, causal, window, res, do):
     def body(carry, step):
         dq, dk, dv, k_cur, v_cur, kseg_cur = carry
         k_blk = (my - step) % sp
-        k_full, v_full = _expand_kv(k_cur, v_cur, g)
         dq_b, dk_b, dv_b = flash_attention_block_grads(
-            q, k_full, v_full, do, lse, delta,
+            q, k_cur, v_cur, do, lse, delta,
             q_off=my * Tq, k_off=k_blk * Tk, causal=causal,
             q_segment_ids=seg,
             k_segment_ids=None if seg is None else kseg_cur,
             window=window)
-        if g > 1:
-            # repeat's transpose: sum each query-head group back onto
-            # its shared K/V head, so dK/dV accumulate (and rotate) at
-            # the reduced width.
-            dk_b = dk_b.reshape(B, Tk, Hkv, g, D).sum(3)
-            dv_b = dv_b.reshape(B, Tk, Hkv, g, D).sum(3)
         dq = dq + dq_b
         dk = dk + dk_b
         dv = dv + dv_b
@@ -205,7 +188,9 @@ _ring_core.defvjp(_ring_vjp_fwd, _ring_vjp_bwd)
 
 def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
                    segment_ids=None, window=None):
-    """Context-parallel attention. q/k/v: [B, T_local, H, D] per chip.
+    """Context-parallel attention. q: [B, T_local, H, D] per chip, k/v:
+    [B, T_local, Hkv, D] with Hkv a divisor of H (grouped-query
+    attention: the kernels read K/V at Hkv heads).
 
     Every K/V block's local attention runs through the flash kernel
     (Pallas/Mosaic on TPU, XLA elsewhere — ``ops.pallas_attention``):
@@ -225,7 +210,6 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
     if sp == 1:
         from ..ops.pallas_attention import flash_attention
 
-        k, v = _expand_kv(k, v, q.shape[2] // k.shape[2])
         return flash_attention(q, k, v, causal=causal,
                                q_segment_ids=segment_ids,
                                k_segment_ids=segment_ids, window=window)

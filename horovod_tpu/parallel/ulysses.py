@@ -53,8 +53,9 @@ def ulysses_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
                       window=None):
     """Context-parallel attention via head<->sequence all-to-all.
 
-    q/k/v: [B, T_local, H, D] per chip, sequence-sharded over
-    ``axis_name``. Returns [B, T_local, H, D] with the same sharding.
+    q: [B, T_local, H, D], k/v: [B, T_local, Hkv, D] per chip,
+    sequence-sharded over ``axis_name``. Returns [B, T_local, H, D] with
+    the same sharding.
     Requires ``H % axis_size == 0``. ``segment_ids`` (int [B, T_local],
     sequence-sharded like q): packed-sequence masking — after the
     re-shard every chip holds the full sequence, so the ids are simply
@@ -68,11 +69,7 @@ def ulysses_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
     from ..ops.pallas_attention import flash_attention
 
     heads = q.shape[2]
-    g = heads // k.shape[2]  # GQA group size (1 = plain multi-head)
-    from .ring_attention import _expand_kv
-
     if sp == 1:
-        k, v = _expand_kv(k, v, g)
         return flash_attention(q, k, v, causal=causal,
                                q_segment_ids=segment_ids,
                                k_segment_ids=segment_ids, window=window)
@@ -96,11 +93,10 @@ def ulysses_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
     full_seg = gathered_segment_ids
     if full_seg is None and segment_ids is not None:
         full_seg = gather_segment_ids(segment_ids, axis_name)
-    # GQA K/V cross the fabric at their reduced width; the contiguous
+    # GQA K/V cross the fabric at their own head count; the contiguous
     # head split means shard i's query heads use exactly shard i's KV
-    # heads, so the post-exchange expansion is purely local.
-    kf, vf = _expand_kv(seq_to_heads(k), seq_to_heads(v), g)
-    o = flash_attention(seq_to_heads(q), kf, vf,
+    # heads, which the kernels read as they are.
+    o = flash_attention(seq_to_heads(q), seq_to_heads(k), seq_to_heads(v),
                         causal=causal, q_segment_ids=full_seg,
                         k_segment_ids=full_seg, window=window)
     return heads_to_seq(o)

@@ -66,3 +66,74 @@ def find_psums(jaxpr, acc=None):
                 if hasattr(sub, "eqns"):
                     find_psums(sub, acc)
     return acc
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for w in (v if isinstance(v, (list, tuple)) else (v,)):
+            sub = getattr(w, "jaxpr", w)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
+def flash_calls(jaxpr, acc=None):
+    """{kernel: [(operand shapes, result shapes)]} for every
+    ``pallas_call`` of the flash kernels in ``jaxpr`` (``flash_fwd``,
+    ``flash_dq``, ``flash_dkv``: the call's ``name``), recursing through
+    every body but a kernel's own. Operands in the order of the call: the
+    block offsets, q, k, v, then what the pass takes besides."""
+    acc = {} if acc is None else acc
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = str(eqn.params.get("name"))
+            if name.startswith("flash_"):
+                acc.setdefault(name, []).append(
+                    ([tuple(v.aval.shape) for v in eqn.invars],
+                     [tuple(v.aval.shape) for v in eqn.outvars]))
+            continue
+        for sub in _sub_jaxprs(eqn):
+            flash_calls(sub, acc)
+    return acc
+
+
+def repeats_and_group_sums(jaxpr, acc=None):
+    """The result shape of every ``broadcast_in_dim`` (what ``jnp.repeat``
+    is, before its reshape) and the operand shape of every ``reduce_sum``
+    in ``jaxpr``, recursing through every body but a Pallas kernel's own."""
+    acc = set() if acc is None else acc
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "broadcast_in_dim":
+            acc.add(tuple(eqn.outvars[0].aval.shape))
+        elif eqn.primitive.name == "reduce_sum":
+            acc.add(tuple(eqn.invars[0].aval.shape))
+        elif eqn.primitive.name != "pallas_call":
+            for sub in _sub_jaxprs(eqn):
+                repeats_and_group_sums(sub, acc)
+    return acc
+
+
+def grouped_shapes(B, T, H, Hkv, D):
+    """What a repeat of K or V from ``Hkv`` to ``H`` heads is broadcast
+    to, and a sum over the groups reduces: in the [B, T, heads, D] layout
+    or merged."""
+    g = H // Hkv
+    return {(B, T, Hkv, g, D), (B * Hkv, g, T, D), (B, Hkv, g, T, D)}
+
+
+def assert_kv_stay_grouped(jaxpr, B, T, H, Hkv, D):
+    """A traced step whose attention has ``H`` query heads over ``Hkv``
+    K/V heads: every flash kernel takes K and V as ``[B * Hkv, T, D]``,
+    the dK/dV pass returns that shape, and no repeat to ``H`` heads and
+    no sum over a group is anywhere. Returns the calls."""
+    calls = flash_calls(jaxpr)
+    assert set(calls) == {"flash_fwd", "flash_dq", "flash_dkv"}, set(calls)
+    for name, found in calls.items():
+        for operands, results in found:
+            assert operands[1] == (B * H, T, D), (name, operands)
+            assert operands[2] == operands[3] == (B * Hkv, T, D), (
+                name, operands)
+            if name == "flash_dkv":
+                assert results == [(B * Hkv, T, D)] * 2, results
+    found = grouped_shapes(B, T, H, Hkv, D) & repeats_and_group_sums(jaxpr)
+    assert not found, found
+    return calls

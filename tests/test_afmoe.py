@@ -800,8 +800,8 @@ def _not_float32(jaxpr):
     return wrong, looked
 
 
-def _traced_bf16_step(shape=(B, T)):
-    cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16, remat=True,
+def _traced_bf16_step(shape=(B, T), base=CFG):
+    cfg = dataclasses.replace(base, dtype=jnp.bfloat16, remat=True,
                               remat_keeps=("flash_out", "attn_q"),
                               max_seq=max(shape[1], CFG.max_seq))
     mesh = _mesh()
@@ -815,6 +815,25 @@ def _traced_bf16_step(shape=(B, T)):
     tokens, labels = _batch(shape=shape)
     step = make_train_step(cfg, optimizer, mesh, n_microbatches=1)
     return jax.make_jaxpr(step)(params, opt_state, tokens, labels).jaxpr
+
+
+def test_the_kernels_take_k_and_v_at_their_own_head_count(monkeypatch):
+    """Four query heads over one K/V head, sliding and full layers,
+    rematerialized: with the kernels (interpreted) no repeat of K or V to
+    the query heads' count and no sum over a group is in the step; the
+    kernels' XLA twins, which run without them, repeat inside
+    themselves."""
+    import hlo_text
+
+    cfg = dataclasses.replace(CFG, n_kv_heads=1)
+    assert hlo_text.grouped_shapes(B, T, cfg.n_heads, 1, cfg.d_head) & \
+        hlo_text.repeats_and_group_sums(_traced_bf16_step(base=cfg))
+    monkeypatch.setenv("HVD_PALLAS_INTERPRET", "1")
+    calls = hlo_text.assert_kv_stay_grouped(
+        _traced_bf16_step(base=cfg), B, T, cfg.n_heads, 1, cfg.d_head)
+    # Two runs of layers with a window and one without, each forward
+    # and once more where its layer is rematerialized.
+    assert len(calls["flash_dkv"]) == len(calls["flash_dq"]) >= 2
 
 
 # 2 x 24 tokens: one window holds every assignment, no loop. 2 x 256: 1,536

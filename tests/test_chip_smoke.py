@@ -153,11 +153,13 @@ def test_decoder_parallel_phase(monkeypatch):
     chip_smoke.phase_decoder_parallel(TOY_DECODER, 4, 3, jax.devices()[:4])
 
 
-def test_kernels_phase_interpreted(monkeypatch):
+@pytest.mark.parametrize("case", [
+    dict(shape=(1, 64, 2, 8), segments=True, window=16),
+    dict(shape=(2, 64, 4, 8), kv_heads=2, window=16),
+], ids=["multi-head", "grouped"])
+def test_kernels_phase_interpreted(monkeypatch, case):
     monkeypatch.setenv("HVD_PALLAS_INTERPRET", "1")
-    chip_smoke.phase_kernels(
-        [dict(shape=(1, 64, 2, 8), dtype=jnp.float32, segments=True,
-              window=16)])
+    chip_smoke.phase_kernels([dict(case, dtype=jnp.float32)])
 
 
 def test_kernels_phase_catches_a_wrong_kernel(monkeypatch):
@@ -331,6 +333,64 @@ def test_ring_block_kernels_compile_for_v5e(described_chip, monkeypatch,
         args = (x, x, x, x, stat, stat)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def _flash_custom_calls(text):
+    """{kernel: [(result types, operand types)]} of a compiled program's
+    Mosaic flash kernels, each type as (element type, dims) text."""
+    from hlo_text import _ARRAY
+
+    calls = {}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        name, _, rest = line.strip().removeprefix("ROOT ").partition(" = ")
+        kernel = next((k for k in ("flash_fwd", "flash_dq", "flash_dkv")
+                       if k in name), None)
+        if kernel:
+            calls.setdefault(kernel, []).append((
+                _ARRAY.findall(rest.split(" custom-call(")[0]),
+                _ARRAY.findall(rest.split("operand_layout_constraints={")[1]
+                               .split("}}")[0])))
+    return calls
+
+
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "none"])
+def test_grouped_kv_reach_the_kernels_at_their_head_count_on_v5e(
+        described_chip, monkeypatch, window):
+    """Value and gradient of ``flash_attention`` at Trinity-Mini's widths
+    (32 query heads over 4 K/V heads of 128, two sequences of 8,192,
+    bf16), compiled for the described chip: every kernel takes K and V as
+    ``bf16[8,8192,128]``, the dK/dV pass returns them so, and the program
+    holds no repeat of them to 32 heads and no sum over a group of 8."""
+    monkeypatch.setattr(pa, "_resolve_dispatch", lambda up: (True, False))
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16,
+                             sharding=described_chip)
+    kv = jax.ShapeDtypeStruct((2, 8192, 4, 128), jnp.bfloat16,
+                              sharding=described_chip)
+
+    def loss(q, k, v):
+        return pa.flash_attention(q, k, v, causal=True, window=window
+                                  ).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    calls = _flash_custom_calls(text)
+    assert sorted(calls) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    grouped, merged = ("bf16", "8,8192,128"), ("bf16", "64,8192,128")
+    for kernel, found in calls.items():
+        for results, operands in found:
+            # offsets, q, k, v, ...
+            assert operands[1] == merged, (kernel, operands)
+            assert operands[2] == operands[3] == grouped, (kernel, operands)
+            if kernel == "flash_dkv":
+                assert results == [grouped, grouped], results
+    # jnp.repeat's broadcast, in the [B, T, heads, D] layout or merged.
+    for repeated in ("[2,8192,4,8,128]", "[8,8,8192,128]",
+                     "[2,4,8,8192,128]"):
+        assert repeated not in text, repeated
+    # What is left at 32 heads is the Q side: q, o, dO, dQ.
+    assert "bf16[2,8192,4,128]" in text
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],

@@ -325,6 +325,29 @@ def test_a_pattern_of_one_kind_lowers_to_the_text_of_no_pattern(base):
         base, layer_types=("attention",) * base.n_layers))
 
 
+def test_the_kernels_take_k_and_v_at_their_own_head_count(monkeypatch):
+    """The hybrid's one attention layer, four query heads over one K/V
+    head: with the kernels (interpreted) the loss and its gradient make
+    no copy of K or V at the query heads' count; the XLA twins do."""
+    import hlo_text
+
+    mesh = build_parallel_mesh(jax.devices()[:1], dp=1, pp=1, sp=1, tp=1)
+    cfg = dataclasses.replace(CFG, remat=True, n_kv_heads=1)
+    tokens, labels = _batch()
+
+    def traced():
+        return jax.make_jaxpr(jax.value_and_grad(make_loss_fn(
+            cfg, mesh, n_microbatches=1)))(
+            init_params(cfg, jax.random.PRNGKey(0), 1), tokens,
+            labels).jaxpr
+
+    assert hlo_text.grouped_shapes(B, T, cfg.n_heads, 1, cfg.d_head) & \
+        hlo_text.repeats_and_group_sums(traced())
+    monkeypatch.setenv("HVD_PALLAS_INTERPRET", "1")
+    hlo_text.assert_kv_stay_grouped(traced(), B, T, cfg.n_heads,
+                                    cfg.n_kv_heads, cfg.d_head)
+
+
 @pytest.mark.parametrize("axes, kinds", [
     (dict(dp=2, tp=2), PATTERN),
     (dict(pp=2), ("mamba", "attention") * 2)], ids=["dp2-tp2", "pp2"])

@@ -629,3 +629,198 @@ def test_untileable_and_too_wide_fall_back_to_xla(monkeypatch):
             pa.flash_attention_block(q, k, v, 0, 0, use_pallas=True),
             pa.flash_attention_block(q, k, v, 0, 0, use_pallas=False)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
+# Grouped K/V: the K side at its own head count.
+# ---------------------------------------------------------------------------
+
+# layout -> (T, the constants forced, what the three plans must then be as
+# a function of the group: heads a step, K/V heads a step, passes).
+_GROUP_LAYOUTS = {
+    # Short T, four heads a step: whole groups (g <= 4) or a part of one.
+    "several-heads": (32, dict(heads_cap=4),
+                      lambda g: (4, max(1, 4 // g), max(1, g // 4))),
+    "one-head": (64, dict(heads_cap=1, tile_cap=32), lambda g: (1, 1, g)),
+    # Two chunks a side, two heads a step.
+    "chunks": (128, dict(heads_cap=2, chunk_cap=64, tile_cap=32),
+               lambda g: (2, max(1, 2 // g), max(1, g // 2))),
+}
+
+
+def _grouped_operands(T, g, mask, seed):
+    B, H, D = 2, 8, 16
+    rng = np.random.RandomState(seed)
+    mk = lambda h: jnp.asarray(rng.randn(B, T, h, D), jnp.float32)  # noqa
+    q, k, v, do = mk(H), mk(H // g), mk(H // g), mk(H)
+    kw = {}
+    if mask == "window":
+        kw["window"] = T // 2 - 3
+    if mask == "segments":
+        # Three documents; the boundaries fall inside sub-tiles.
+        seg = jnp.asarray(np.tile(np.searchsorted(
+            [int(0.3 * T), int(0.7 * T)], np.arange(T), side="right"),
+            (B, 1)), jnp.int32)
+        kw.update(q_segment_ids=seg, k_segment_ids=seg)
+    return q, k, v, do, kw
+
+
+@pytest.mark.parametrize("mask", ["causal", "window", "segments"])
+@pytest.mark.parametrize("layout", list(_GROUP_LAYOUTS))
+@pytest.mark.parametrize("g", [1, 2, 4, 8])
+def test_grouped_kv_matches_expand_then_attend(monkeypatch, g, layout, mask):
+    """The three entry points with K and V at H / g heads, interpreted,
+    against the XLA twin on K and V repeated to H heads: output, lse and
+    the three gradients, dK and dV summed over each group."""
+    from horovod_tpu.ops import pallas_attention as pa
+
+    T, caps, want = _GROUP_LAYOUTS[layout]
+    _force_plan(monkeypatch, **caps)
+    q, k, v, do, kw = _grouped_operands(T, g, mask, seed=17 + g)
+    B, _, H, D = q.shape
+    window = kw.get("window")
+    for kind in ("fwd", "dq", "dkv"):
+        plan = pa.kernel_plan(B * H, T, T, D, q.dtype, True, window,
+                              segments=mask == "segments", kind=kind,
+                              group=g)
+        assert (plan.heads, plan.kv_heads, plan.passes) == want(g), kind
+        n_c = T // plan.chunk_q
+        assert plan.grid == (
+            (B * H // g // plan.kv_heads, n_c, plan.passes * n_c)
+            if kind == "dkv" else (B * H // plan.heads, n_c, n_c)), kind
+    expand = lambda x: jnp.repeat(x, g, axis=2)                     # noqa
+    fold = lambda x: x.reshape(B, T, H // g, g, D).sum(3)           # noqa
+    tol, gtol = _TOL[jnp.float32]["fwd"], _TOL[jnp.float32]["grad"]
+
+    def close(a, b, name, **t):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   err_msg=name, **t)
+
+    # flash_attention: output and jax.grad; the twin differentiates
+    # through the repeat, whose transpose is the group sum.
+    got = _fwd_and_grads(q, k, v, True, **kw)
+    ref = _fwd_and_grads(q, expand(k), expand(v), False, **kw)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got,
+                          (*ref[:2], fold(ref[2]), fold(ref[3]))):
+        close(a, b, name, **(tol if name == "out" else gtol))
+    assert got[2].shape == k.shape and got[3].shape == v.shape
+
+    # flash_attention_block: the block's state, and lse from it.
+    state = pa.flash_attention_block(q, k, v, T // 4, 0, use_pallas=True,
+                                     **kw)
+    ref_state = pa.flash_attention_block(q, expand(k), expand(v), T // 4, 0,
+                                         use_pallas=False, **kw)
+    for name, a, b in zip(("acc", "m", "l"), state, ref_state):
+        close(a, b, name, **tol)
+    lse = pa.row_lse(*ref_state[1:])
+    close(pa.row_lse(*state[1:]), lse, "lse", **tol)
+
+    # flash_attention_block_grads on that block, with its own lse.
+    delta = jnp.sum(do * ref_state[0] / jnp.maximum(
+        ref_state[2], 1e-30).transpose(0, 2, 1)[..., None],
+        axis=-1).transpose(0, 2, 1)
+    grads = pa.flash_attention_block_grads(
+        q, k, v, do, lse, delta, T // 4, 0, use_pallas=True, **kw)
+    ref_grads = pa.flash_attention_block_grads(
+        q, expand(k), expand(v), do, lse, delta, T // 4, 0,
+        use_pallas=False, **kw)
+    for name, a, b in zip(("dq", "dk", "dv"), grads,
+                          (ref_grads[0], fold(ref_grads[1]),
+                           fold(ref_grads[2]))):
+        close(a, b, "block " + name, **gtol)
+
+
+def test_grouped_kv_twins_and_errors():
+    # Off the kernels the same operands go through the XLA twins, which
+    # repeat inside themselves; a head count that does not divide raises.
+    q, k, v, do, kw = _grouped_operands(32, 4, "segments", seed=2)
+    expand = lambda x: jnp.repeat(x, 4, axis=2)                     # noqa
+    got = _fwd_and_grads(q, k, v, False, **kw)
+    ref = _fwd_and_grads(q, expand(k), expand(v), False, **kw)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]),
+                               rtol=1e-6, atol=1e-6)
+    assert got[2].shape == k.shape
+    np.testing.assert_allclose(
+        np.asarray(got[2]),
+        np.asarray(ref[2].reshape(2, 32, 2, 4, 16).sum(3)),
+        rtol=1e-5, atol=1e-5)
+    with pytest.raises(ValueError, match="query heads over"):
+        flash_attention(q, k[:, :, :1].repeat(3, axis=2),
+                        v[:, :, :1].repeat(3, axis=2), use_pallas=False)
+    with pytest.raises(ValueError, match="query heads over"):
+        flash_attention(q, k, v[:, :, :1], use_pallas=False)
+
+
+# ``kernel_plan`` before the K side had a head count of its own, at every
+# shape of docs/diagnostics.md's table (bf16, causal): (heads, chunk_q,
+# chunk_k, tile_q, tile_k, unroll, grid, tiles_visited) and the VMEM
+# counted for fwd, dq and dkv. A plan is a pure function of the shape.
+_PLANS_WITHOUT_A_GROUP = {
+    (768, 128, 64, None): ((16, 128, 128, 128, 128, 4, (48, 1, 1), 1),
+                           (11010048, 12058624, 14155776)),
+    (96, 1024, 64, None): ((2, 1024, 1024, 512, 512, 2, (48, 1, 1), 3),
+                           (22020096, 23068672, 25165824)),
+    (32, 4096, 128, None): ((1, 4096, 4096, 512, 512, 1, (32, 1, 1), 36),
+                            (25165824, 27262976, 31457280)),
+    (64, 8192, 128, None): ((1, 4096, 4096, 512, 512, 1, (64, 2, 2), 136),
+                            (25165824, 27262976, 31457280)),
+    (64, 8192, 128, 2048): ((1, 4096, 4096, 512, 512, 1, (64, 2, 2), 70),
+                            (25165824, 27262976, 31457280)),
+    (32, 8192, 64, None): ((1, 4096, 4096, 512, 512, 1, (32, 2, 2), 136),
+                           (25165824, 27262976, 31457280)),
+}
+
+
+@pytest.mark.parametrize("shape", list(_PLANS_WITHOUT_A_GROUP),
+                         ids=lambda s: "-".join(map(str, s)))
+def test_kernel_plan_without_a_group_is_unchanged(shape):
+    from horovod_tpu.ops import pallas_attention as pa
+
+    BH, T, D, window = shape
+    fields, vmem = _PLANS_WITHOUT_A_GROUP[shape]
+    for kind, counted in zip(("fwd", "dq", "dkv"), vmem):
+        plan = pa.kernel_plan(BH, T, T, D, jnp.bfloat16, True, window,
+                              kind=kind)
+        assert tuple(plan) == fields + (counted, 1), kind
+        assert (plan.kv_heads, plan.shared, plan.passes) == (
+            plan.heads, 1, 1)
+
+
+@pytest.mark.parametrize("BH,D,g,window", [(64, 128, 8, None),
+                                           (64, 128, 8, 2048),
+                                           (32, 64, 4, None)])
+def test_kernel_plan_at_the_grouped_cell_shapes(BH, D, g, window):
+    # trinity-mini-t8192 (32 heads over 4, B 2) and granite-h-t8192 (32
+    # over 8, B 1) at T 8192: the multi-head plan's step, one head and a
+    # chunk of 4096 a side; the forward's and dQ's grid is the Q side's,
+    # the dK/dV pass's is over K/V heads with the group's heads in turn
+    # on the sequential dimension.
+    from horovod_tpu.ops import pallas_attention as pa
+
+    for kind in ("fwd", "dq", "dkv"):
+        one = pa.kernel_plan(BH, 8192, 8192, D, jnp.bfloat16, True, window,
+                             kind=kind)
+        plan = pa.kernel_plan(BH, 8192, 8192, D, jnp.bfloat16, True, window,
+                              kind=kind, group=g)
+        assert plan == one._replace(
+            group=g,
+            grid=(BH // g, 2, 2 * g) if kind == "dkv" else (BH, 2, 2))
+        assert (plan.heads, plan.kv_heads, plan.passes) == (1, 1, g)
+
+
+def test_grouped_calls_are_counted_beside_the_traced():
+    from horovod_tpu.common import metrics
+    from horovod_tpu.ops import pallas_attention as pa  # noqa: F401
+
+    def counted(g):
+        metrics.reset()
+        q, k, v, _, _ = _grouped_operands(32, g, "causal", seed=1)
+        _fwd_and_grads(q, k, v, True)
+        return metrics.counters()
+
+    traced = {f"kernels.traced.flash_{kind}": 1
+              for kind in ("fwd", "dq", "dkv")}
+    assert counted(1) == traced
+    assert counted(4) == dict(traced, **{
+        f"kernels.grouped.flash_{kind}": 1 for kind in ("fwd", "dq", "dkv")})

@@ -1,0 +1,10 @@
+"""``moe_shared_expert_ms``'s reading in a cell of the ``glm-4.7-flash``
+configuration: the scope ``moe_shared``, the shared expert. The accepted
+reader selects by what the job states (``ctx.job.moe_share`` with this
+cell's own numbers); an accepted entry's ``workloads`` cannot be extended
+from here, so the cell reads it under a name of its own, and this is no
+second implementation."""
+from benchmark.layer_metrics.moe_shared_expert_ms import read  # noqa: F401
+
+LAYER = "Step program"
+UNIT = "ms"

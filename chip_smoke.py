@@ -75,6 +75,23 @@ AFMOE = dict(vocab=4096, d_model=256, n_heads=4, d_head=64, n_kv_heads=2,
              post_norms=True, gated_mlp=True, embedding_multiplier=16.0,
              remat=True, remat_keeps=("flash_out", "flash_lse"),
              dtype=jnp.bfloat16)
+# The `glm-4.7-flash-t8192` cell's block at a tiny size: latent attention
+# with a shared rotated key head at the cell's head width (192 + 64 =
+# 256, two lane tiles a row in the flash kernels), a leading dense layer,
+# a sigmoid router over 16 experts of which 4 are held, a shared expert,
+# the balancing bias, and one multi-token-prediction module in the loss.
+GLM_LITE = dict(vocab=4096, d_model=256, n_heads=2, d_head=256, d_ff=512,
+                n_layers=2, max_seq=1024,
+                layer_types=("latent_attention",) * 2, q_lora_rank=96,
+                kv_lora_rank=64, qk_nope_head_dim=192, qk_rope_head_dim=64,
+                rope_theta=1e6, pos_table=False, use_moe=True,
+                num_dense_layers=1, n_experts=16, n_experts_held=4,
+                d_expert=256, moe_top_k=4, moe_score_func="sigmoid",
+                norm_topk_prob=True, route_scale=1.8, n_shared_experts=1,
+                expert_bias_rate=1e-3, norm="rmsnorm", gated_mlp=True,
+                n_mtp_modules=1, remat=True,
+                remat_keeps=("flash_out", "flash_lse", "mla_cq", "mla_ckv"),
+                dtype=jnp.bfloat16)
 # Gradient bucket cap for the four-chip data-parallel step: ResNet-50's
 # 102 MB of fp32 gradients in four buckets.
 BUCKET_CAP_BYTES = 32 << 20
@@ -96,6 +113,9 @@ KERNEL_CASES = [
     dict(shape=(2, 1024, 12, 64), dtype=jnp.float32, kv_heads=6),
     dict(shape=(1, 8192, 4, 128), dtype=jnp.bfloat16, kv_heads=1,
          window=2048),
+    # GLM-4.7-Flash's head width and length: two lane tiles a row, chunks
+    # of 4,096 forward and in dQ and of 2,048 in dK/dV.
+    dict(shape=(1, 8192, 2, 256), dtype=jnp.bfloat16),
 ]
 KERNEL_TOL = {  # dtype name -> (forward, gradients), rtol == atol
     "bfloat16": (2e-2, 1e-1),
@@ -537,6 +557,9 @@ def one_chip_phases():
             jax.devices())),
         ("decoder-afmoe", lambda: phase_decoder(
             "decoder-afmoe", TransformerConfig(**AFMOE), 2, 3,
+            jax.devices())),
+        ("decoder-glm-lite", lambda: phase_decoder(
+            "decoder-glm-lite", TransformerConfig(**GLM_LITE), 2, 3,
             jax.devices())),
         ("kernels", lambda: phase_kernels(KERNEL_CASES)),
     ]

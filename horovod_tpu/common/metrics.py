@@ -43,6 +43,8 @@ from . import logging as _log
 #   stripe.connect_fallback  the stripe sibling (host_world.py)
 #   elastic.evictions      driver-side liveness evictions (driver.py)
 #   elastic.drains         commit-marked graceful drains (driver.py)
+#   model.latent_layers, model.mtp_modules  what a decoder step was built
+#                          with, where it has either (models/transformer.py)
 #
 # The native snapshot carries the self-healing counters alongside these
 # (link.reconnects / link.resume_chunks_discarded /
@@ -239,6 +241,16 @@ class span:
             with span(self.name, **self.counts):
                 return fn(*args, **kwargs)
         return spanned
+
+
+def note(**counts: int) -> None:
+    """Add to the counts of this thread's innermost open span; nothing
+    where none is open."""
+    stack = _mine().open
+    if stack:
+        held = stack[-1]["counts"]
+        for key, n in counts.items():
+            held[key] = held.get(key, 0) + int(n)
 
 
 def tree_counts(tree) -> dict:

@@ -22,9 +22,11 @@ The stack of layers is data. ``TransformerConfig.layer_types`` names each
 layer's mixer: ``"attention"`` (softmax attention, above, with the
 model's one ``attention_window`` and ``rope`` switch), ``"mamba"`` (a
 Mamba-2 state-space mixer, ``_mamba_mixer``), ``"sliding_attention"``
-(attention over ``sliding_window`` tokens, rotated) or
-``"full_attention"`` (causal attention over everything, no positions);
-the three attention kinds share one set of leaves. Each layer ends in a
+(attention over ``sliding_window`` tokens, rotated),
+``"full_attention"`` (causal attention over everything, no positions) or
+``"latent_attention"`` (multi-head latent attention, ``_latent_mixer``,
+with leaves of its own); the three attention kinds share one set of
+leaves. Each layer ends in a
 feed-forward block that is data too: the dense MLP, or with ``use_moe``
 the expert layer in all but the ``num_dense_layers`` leading layers.
 Parameters are stacked per group (the attention mixers, the Mamba mixers,
@@ -52,6 +54,38 @@ three parts, ``m_wzx`` [d, 2, H, P], ``m_wbc`` [d, 2, N] and ``m_wdt``
 [d, H], and the convolution in two, so that the heads shard over ``tp``
 while ``B`` and ``C`` stay whole on every member.
 
+Latent attention (DeepSeek-V2/V3's MLA, arXiv:2412.19437 section 2.1) on
+normed ``h`` [T, d], H heads of ``d_head`` = ``qk_nope_head_dim`` +
+``qk_rope_head_dim`` channels, ``rms`` an RMSNorm with its own weight::
+
+    c_q = rms(h W_qa) [q_lora_rank];  q_i = c_q W_qb[i] = [q_i^nope ; q_i^rope]
+    [c_kv | k^rope] = h W_kva         widths kv_lora_rank | qk_rope_head_dim
+    [k_i^nope | v_i] = rms(c_kv) W_kvb[i]      widths qk_nope_head_dim | d_head
+    k_i = [k_i^nope ; rope(k^rope)],  q_i = [q_i^nope ; rope(q_i^rope)]
+    out = concat_i softmax_causal(q_i k_i^T / sqrt(d_head)) v_i  W_o
+
+``k^rope`` is one rotated head that all H query heads read; the rotation
+(rotate-half) takes the *last* ``qk_rope_head_dim`` channels of a head
+whole. Queries, keys and values enter the flash kernels at the one width
+``d_head``. The two low-rank norms are float32. The heads (``l_wqb``,
+``l_wkvb``, ``l_wo``) shard over ``tp``; the down-projections and their
+norms are whole on every member.
+
+Multi-token prediction (the same paper, section 2.2), one module after
+the stack with ``n_mtp_modules``: on the stack's output ``x`` before the
+final norm and the tokens' *labels* ``t_{i+1}``::
+
+    g_i = [rms_h(x_i) ; rms_e(E[t_{i+1}])] W_eh           [2 d] -> [d]
+    z = layer(g)        one more layer of the stack's last kind, own leaves
+    loss = CE(head(final_ln(x)), t_{i+1})
+           + mtp_loss_weight * CE(head(rms_s(z)), t_{i+2})
+
+through the same ``embed`` and ``head``; the second mean is over the
+positions that have a ``t_{i+2}`` (all but a sequence's last). The
+module's leaves are ``mtp_hnorm``, ``mtp_enorm``, ``mtp_eh``,
+``mtp_final_ln`` and its layer's, each the stack's name after ``mtp_``,
+with the modules (one) as their leading dimension.
+
 Pure-jax pytree params (no flax) so shard_map in_specs map 1:1 onto leaves.
 """
 
@@ -78,15 +112,19 @@ with _metrics.span("import:horovod_tpu.models.transformer"):
     from ..parallel.ulysses import context_parallel_attention
 
 
-LAYER_KINDS = ("attention", "mamba", "sliding_attention", "full_attention")
+LAYER_KINDS = ("attention", "mamba", "sliding_attention", "full_attention",
+               "latent_attention")
 # What a layer names with ``checkpoint_name``, so that a rematerialized
 # layer can keep it (``TransformerConfig.remat_keeps``): the Mamba
 # in-projection's z and x and the scan's output; an attention mixer's Q,
 # K/V and gate projections, its output projection, and the flash kernels'
 # output and row statistics (ops/pallas_attention.py); the gate-up product
-# of a gated MLP or shared expert.
+# of a gated MLP or shared expert; a latent mixer's two down-projections
+# (the query latent; the key/value latent with the shared rotated key),
+# its up-projections under the attention mixer's ``attn_q``, ``attn_kv``.
 REMAT_NAMES = ("mamba_zx", "ssd_out", "attn_q", "attn_kv", "attn_gate",
-               "attn_proj", "flash_out", "flash_lse", "mlp_gu")
+               "attn_proj", "flash_out", "flash_lse", "mlp_gu", "mla_cq",
+               "mla_ckv")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -201,6 +239,17 @@ class TransformerConfig:
     mamba_d_state: int = 0
     mamba_d_conv: int = 4
     mamba_chunk: int = 256
+    # The latent mixer (the module's docstring): the two ranks, and a
+    # head's rotated and unrotated widths, which add up to ``d_head``,
+    # the value's width too. Rotated with ``rope_theta``.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    qk_nope_head_dim: int = 0
+    # Multi-token-prediction modules after the stack (0 or 1; the
+    # module's docstring) and the weight of their cross-entropy.
+    n_mtp_modules: int = 0
+    mtp_loss_weight: float = 0.3
     # Dense feed-forward W_d (silu(h W_g) * h W_u) of width d_ff instead
     # of W_2 gelu(h W_1).
     gated_mlp: bool = False
@@ -246,6 +295,12 @@ class TransformerConfig:
                     not self.sliding_window or self.d_head % 2 != 0):
                 raise ValueError("a sliding_attention layer needs a "
                                  "sliding_window and an even d_head")
+            if "latent_attention" in kinds:
+                self._check_latent()
+        if self.n_mtp_modules not in (0, 1):
+            raise ValueError(
+                f"n_mtp_modules must be 0 or 1, got {self.n_mtp_modules}: "
+                f"a chain of multi-token-prediction modules is not built")
         if self.qk_norm not in (False, True, "head"):
             raise ValueError(f"qk_norm must be False, True or 'head', got "
                              f"{self.qk_norm!r}")
@@ -279,6 +334,27 @@ class TransformerConfig:
                     f"remat_keeps names what a layer writes under "
                     f"checkpoint_name, of {REMAT_NAMES}; got {keeps}")
 
+    def _check_latent(self):
+        rope, nope = self.qk_rope_head_dim, self.qk_nope_head_dim
+        if not (self.q_lora_rank > 0 and self.kv_lora_rank > 0 and nope >= 0
+                and rope > 0 and rope % 2 == 0):
+            raise ValueError(
+                "a latent_attention layer needs q_lora_rank, kv_lora_rank, "
+                "qk_nope_head_dim and an even qk_rope_head_dim")
+        if nope + rope != self.d_head:
+            raise ValueError(
+                f"qk_nope_head_dim + qk_rope_head_dim ({nope} + {rope}) must "
+                f"be d_head ({self.d_head}), the value's width too: a value "
+                f"width that differs from the key's is not built (the flash "
+                f"kernels take q, k and v at one width)")
+        if (self.n_kv_heads is not None or self.qk_norm or self.attn_gate
+                or self.attention_multiplier is not None):
+            raise ValueError(
+                "a latent_attention layer has every head its own key and "
+                "value and neither QK-norm, gate nor attention_multiplier: "
+                "n_kv_heads, qk_norm, attn_gate and attention_multiplier "
+                "are not built through it")
+
     @property
     def kv_heads(self) -> int:
         return self.n_heads if self.n_kv_heads is None else self.n_kv_heads
@@ -300,6 +376,15 @@ class TransformerConfig:
     def kinds(self) -> Tuple[str, ...]:
         """Each layer's mixer, in order."""
         return self.layer_types or ("attention",) * self.n_layers
+
+    @property
+    def mtp_layer(self) -> "TransformerConfig":
+        """The one-layer model whose layer a multi-token-prediction module
+        runs: the stack's last (mixer, feed-forward) pair."""
+        return dataclasses.replace(
+            self, n_layers=1, layer_types=self.kinds[-1:], n_mtp_modules=0,
+            num_dense_layers=int(self.use_moe
+                                 and self.ffn_kinds[-1] == "mlp"))
 
     @property
     def ffn_kinds(self) -> Tuple[str, ...]:
@@ -337,17 +422,23 @@ class TransformerConfig:
 # of the three kinds), the Mamba layers, those that end in the dense MLP,
 # those that end in the expert layer.
 _ATTENTION_LEAVES = ("wqkv", "wq", "wkv", "wo", "gq", "gk", "wgate")
+_ATTENDING = ("attention", "sliding_attention", "full_attention")
 _MLP_LEAVES = ("w1", "w2", "wgu")
 _ROUTED_LEAVES = ("router", "wg", "wu", "wd", "expert_bias")
 _MOE_LEAVES = _ROUTED_LEAVES + ("shared_wgu", "shared_w2")
 _MODEL_LEAVES = ("embed", "pos", "final_ln", "head")
+# A multi-token-prediction module's own leaves; its layer's are the
+# stack's names after the same prefix.
+_MTP = "mtp_"
+_MTP_LEAVES = ("mtp_hnorm", "mtp_enorm", "mtp_eh", "mtp_final_ln")
 # Leaves the train step carries that are no trained parameter.
-_STATE_LEAVES = ("expert_bias",)
+_STATE_LEAVES = ("expert_bias", "mtp_expert_bias")
 
 
 def _mixer_group(kind: str) -> str:
     """The group of stacks a mixer of ``kind`` reads."""
-    return "mamba" if kind == "mamba" else "attention"
+    return {"mamba": "mamba", "latent_attention": "latent"}.get(
+        kind, "attention")
 
 
 def _leaf_group(name: str) -> Optional[str]:
@@ -355,6 +446,8 @@ def _leaf_group(name: str) -> Optional[str]:
     layer has."""
     if name.startswith("m_"):
         return "mamba"
+    if name.startswith("l_"):
+        return "latent"
     for group, leaves in (("attention", _ATTENTION_LEAVES),
                           ("mlp", _MLP_LEAVES), ("moe", _MOE_LEAVES)):
         if name in leaves:
@@ -363,8 +456,9 @@ def _leaf_group(name: str) -> Optional[str]:
 
 
 def trained(params: Dict) -> Dict:
-    """``params`` without the leaves no optimizer may see (the router's
-    ``expert_bias``): what the optimizer state is made for."""
+    """``params`` without the leaves no optimizer may see (the routers'
+    ``expert_bias``, the stack's and a multi-token-prediction module's):
+    what the optimizer state is made for."""
     return {k: v for k, v in params.items() if k not in _STATE_LEAVES}
 
 
@@ -384,7 +478,7 @@ def _param_specs(cfg: TransformerConfig) -> Dict[str, P]:
         specs["head"] = P()
     if cfg.pos_table and not cfg.rope:
         specs["pos"] = P()
-    if set(cfg.kinds) - {"mamba"}:
+    if set(cfg.kinds) & set(_ATTENDING):
         specs["wo"] = P("pp", None, "tp")
         if cfg.kv_heads == cfg.n_heads:
             specs["wqkv"] = P("pp", None, None, None, "tp")
@@ -415,6 +509,21 @@ def _param_specs(cfg: TransformerConfig) -> Dict[str, P]:
             "m_g": P("pp", None, "tp"),
             "m_wo": P("pp", None, "tp"),
         })
+    if "latent_attention" in cfg.kinds:
+        # Heads over tp; the down-projections and their norms whole.
+        specs.update({
+            "l_wqa": P("pp"), "l_qnorm": P("pp"),
+            "l_wqb": P("pp", None, None, "tp"),
+            "l_wkva": P("pp"), "l_kvnorm": P("pp"),
+            "l_wkvb": P("pp", None, None, "tp"),
+            "l_wo": P("pp", None, "tp"),
+        })
+    if cfg.n_mtp_modules:
+        # The layer's leaves with the modules where the stages were.
+        for name, spec in _param_specs(cfg.mtp_layer).items():
+            if name not in _MODEL_LEAVES:
+                specs[_MTP + name] = P(*spec[1:])
+        specs.update({name: P() for name in _MTP_LEAVES})
     if "moe" in cfg.ffn_kinds:
         specs.update({
             "router": P("pp"),
@@ -471,6 +580,12 @@ def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
         params["head"] = norm(ks[4], (d, cfg.vocab), d ** -0.5)
     if cfg.pos_table and not cfg.rope:
         params["pos"] = norm(ks[1], (cfg.max_seq, d), 0.02)
+    k_latent, k_mtp = (jax.random.fold_in(rng, salt) for salt in (1, 2))
+    if cfg.n_mtp_modules:
+        params.update(_init_mtp(cfg, k_mtp, norm))
+    if stage.count("latent"):
+        params.update(_init_latent(
+            cfg, k_latent, (n_stages, stage.count("latent")), norm))
     La = stage.count("attention")
     if La:
         Hkv = cfg.kv_heads
@@ -526,6 +641,40 @@ def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
             "w1": norm(k_gu, (n_stages, Ld, d, F), d ** -0.5),
             "w2": norm(k_2, (n_stages, Ld, F, d), F ** -0.5),
         })
+    return params
+
+
+def _init_latent(cfg: TransformerConfig, rng, lead, norm) -> Dict:
+    """The latent mixers' leaves with leading shape ``lead``: matrices
+    normal at fan-in^-1/2, the two norms' weights one (float32)."""
+    d, H, Dh = cfg.d_model, cfg.n_heads, cfg.d_head
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    rope, nope = cfg.qk_rope_head_dim, cfg.qk_nope_head_dim
+    ks = jax.random.split(rng, 5)
+    return {
+        "l_wqa": norm(ks[0], lead + (d, rq), d ** -0.5),
+        "l_qnorm": jnp.ones(lead + (rq,), jnp.float32),
+        "l_wqb": norm(ks[1], lead + (rq, H, Dh), rq ** -0.5),
+        "l_wkva": norm(ks[2], lead + (d, rkv + rope), d ** -0.5),
+        "l_kvnorm": jnp.ones(lead + (rkv,), jnp.float32),
+        "l_wkvb": norm(ks[3], lead + (rkv, H, nope + Dh), rkv ** -0.5),
+        "l_wo": norm(ks[4], lead + (H, Dh, d), (H * Dh) ** -0.5),
+    }
+
+
+def _init_mtp(cfg: TransformerConfig, rng, norm) -> Dict:
+    """The multi-token-prediction module's leaves, the modules (one) as
+    their leading dimension: its layer's as ``init_params`` makes a
+    one-layer model's, the three norms' weights one, ``mtp_eh`` normal at
+    (2 d)^-1/2."""
+    d, M = cfg.d_model, cfg.n_mtp_modules
+    k_layer, k_eh = jax.random.split(rng)
+    layer = init_params(cfg.mtp_layer, k_layer, n_stages=1)
+    params = {_MTP + name: leaf[0] for name, leaf in layer.items()
+              if name not in _MODEL_LEAVES}
+    params.update({name: jnp.ones((M, d), jnp.float32)
+                   for name in _MTP_LEAVES})
+    params["mtp_eh"] = norm(k_eh, (M, 2 * d, d), (2 * d) ** -0.5)
     return params
 
 
@@ -587,6 +736,15 @@ def _validate_mesh_divisibility(cfg: TransformerConfig, mesh) -> None:
             f"tp axis ({tp}) — wkv shards the KV-head dim over tp; use "
             f"n_kv_heads that is a multiple of tp (or tp <= n_kv_heads)")
     cfg.stage_pattern(_pipeline_stages(mesh))
+    if "latent_attention" in cfg.kinds or cfg.n_mtp_modules:
+        for axis, what in (("sp", "sequence shards"),
+                           ("pp", "pipeline stages")):
+            if shape.get(axis, 1) > 1:
+                raise ValueError(
+                    f"{what} ({axis} > 1) through a latent_attention layer "
+                    f"or a multi-token-prediction module are not built: no "
+                    f"test holds the shared rotated key or the module's "
+                    f"shifted labels across them")
     if cfg.expert_bias_rate and shape.get("sp", 1) > 1:
         raise ValueError(
             "the router's balancing bias is not built over sp > 1: no "
@@ -605,23 +763,44 @@ def _validate_mesh_divisibility(cfg: TransformerConfig, mesh) -> None:
                 "sp member")
 
 
+def _model_counts(cfg: TransformerConfig) -> Dict[str, int]:
+    """What the set-up spans and the ``model.*`` counters say of a model
+    with either: its latent attention layers (a multi-token-prediction
+    module's among them) and its multi-token-prediction modules."""
+    latent = (cfg.kinds + cfg.kinds[-1:] * cfg.n_mtp_modules).count(
+        "latent_attention")
+    counts = {"latent_layers": latent, "mtp_modules": cfg.n_mtp_modules}
+    return {k: n for k, n in counts.items() if n}
+
+
 def shard_params(params: Dict, cfg: TransformerConfig, mesh) -> Dict:
     _validate_mesh_divisibility(cfg, mesh)
     specs = _param_specs(cfg)
-    with _metrics.span("state.shard", **_metrics.tree_counts(params)):
+    with _metrics.span("state.shard", **_metrics.tree_counts(params),
+                       **_model_counts(cfg)):
         return {
             k: jax.device_put(v, NamedSharding(mesh, specs[k]))
             for k, v in params.items()
         }
 
 
-@functools.partial(jax.checkpoint, static_argnums=(2,))
-def _rope(x, positions, theta):
+def _rope(x, positions, theta, last=None):
     """Rotary position embeddings (rotate-half convention).
 
     x: [b, t, H, Dh] (Dh even); positions: [t] GLOBAL token positions —
     sequence-parallel shards pass their offset range, which is what
-    makes RoPE compose with the sp axis."""
+    makes RoPE compose with the sp axis. ``last`` (even) rotates the
+    last ``last`` channels of a head as one whole rotated head and leaves
+    the channels before them as they are."""
+    if last is None or last == x.shape[-1]:
+        return _rotated(x, positions, theta)
+    keep = x.shape[-1] - last
+    return jnp.concatenate([
+        x[..., :keep], _rotated(x[..., keep:], positions, theta)], -1)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(2,))
+def _rotated(x, positions, theta):
     Dh = x.shape[-1]
     half = Dh // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
@@ -747,6 +926,39 @@ def _mamba_mixer(cfg: TransformerConfig, h, lp):
         return jnp.einsum("bthp,hpd->btd", y, lp["m_wo"])
 
 
+def _latent_mixer(cfg: TransformerConfig, h, lp):
+    """The latent attention mixer of the module's docstring on normed h
+    [b, t, d]; heads are this tp member's, the result its partial sum."""
+    rkv, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    nope, theta = cfg.qk_nope_head_dim, cfg.rope_theta
+    b, t, _ = h.shape
+    pos = jnp.arange(t, dtype=jnp.int32)
+    with jax.named_scope("mla_q"):
+        c_q = checkpoint_name(
+            jnp.einsum("btd,dr->btr", h, lp["l_wqa"]), "mla_cq")
+        q = checkpoint_name(jnp.einsum(
+            "btr,rhk->bthk", _rmsnorm(c_q, lp["l_qnorm"], cfg.norm_eps),
+            lp["l_wqb"]), "attn_q")  # h=H/tp
+        q = _rope(q, pos, theta, rope)
+    with jax.named_scope("mla_kv"):
+        ckv = checkpoint_name(
+            jnp.einsum("btd,dr->btr", h, lp["l_wkva"]), "mla_ckv")
+        kv = checkpoint_name(jnp.einsum(
+            "btr,rhk->bthk",
+            _rmsnorm(ckv[..., :rkv], lp["l_kvnorm"], cfg.norm_eps),
+            lp["l_wkvb"]), "attn_kv")
+        # One rotated head, read by every query head.
+        k_rope = _rope(ckv[:, :, None, rkv:], pos, theta)
+        k = jnp.concatenate([
+            kv[..., :nope],
+            jnp.broadcast_to(k_rope, (b, t, kv.shape[2], rope))], -1)
+    attn = context_parallel_attention(
+        q, k, kv[..., nope:], axis_name="sp", causal=True,
+        strategy=cfg.sp_strategy)
+    return checkpoint_name(
+        jnp.einsum("bthk,hkd->btd", attn, lp["l_wo"]), "attn_proj")
+
+
 def _times(x, multiplier):
     """``x * multiplier``; a multiplier of one is no instruction."""
     return x if multiplier == 1.0 else x * multiplier
@@ -784,25 +996,14 @@ def _rows(stack, first, n):
     return lax.slice_in_dim(stack, first, first + n, axis=0)
 
 
-def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
-                   packed: bool = False):
-    """stage_fn(stage_params, x) applying this stage's layers.
-
-    x: [mb, t_local, d], or a tuple that starts with it: then the
-    segment ids with ``packed``, then the router statistics with
-    ``cfg.use_moe`` (``_zero_router_stats``). Both ride the pipeline ring
-    with the activations; the ids pass through each stage unchanged, the
-    statistics gain this stage's layers. Runs under the full (dp, pp, sp,
-    tp) mesh.
-
-    The stage walks the maximal runs of one (mixer, feed-forward) pair in
-    its pattern (``cfg.stage_pattern``) and scans each over its rows of
-    the stacks: the leaves every layer has by the layer's place in the
-    stage, a group's own by its place among the layers of that group.
-    """
+def _make_layer_fn(cfg: TransformerConfig, packed: bool = False):
+    """layer(kind, ffn, x, lp, seg, gathered_seg, experts=None): one
+    layer, its mixer block of ``kind`` then its feed-forward block
+    ``ffn``, on x [mb, t_local, d] with the layer's leaves ``lp``;
+    ``(x, router statistics)`` where ``ffn`` is the expert layer, which
+    takes ``experts`` = (the stacks the layer's expert matrices lie in,
+    its index in them). Rematerialized with ``cfg.remat``."""
     norm = _block_norm(cfg)
-    pattern = cfg.stage_pattern(n_stages)
-    runs = _runs(pattern)
     if packed and "mamba" in cfg.kinds:
         raise ValueError(
             "packed sequences cannot pass a mamba layer: the scan's state "
@@ -812,6 +1013,11 @@ def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
             "packed documents through sliding_attention / full_attention "
             "layers are not built: no test holds their masks together "
             "with a segment's")
+    if packed and ("latent_attention" in cfg.kinds or cfg.n_mtp_modules):
+        raise ValueError(
+            "packed documents through a latent_attention layer or a "
+            "multi-token-prediction module are not built: no test holds "
+            "a segment's mask or its last label through them")
 
     def layer(kind, ffn, x, lp, seg, gathered_seg, experts=None):
         with jax.named_scope(kind):
@@ -823,6 +1029,8 @@ def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
         h = norm(x, lp["ln1"])
         if kind == "mamba":
             out = _mamba_mixer(cfg, h, lp)
+        elif kind == "latent_attention":
+            out = _latent_mixer(cfg, h, lp)
         else:
             out = attention_mixer(kind, h, lp, seg, gathered_seg)
         out = lax.psum(out, "tp")  # combine head shards
@@ -909,10 +1117,32 @@ def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
         return x + _times(y, cfg.residual_multiplier)
 
     keeps = _REMAT_KEEPS if cfg.remat_keeps is None else cfg.remat_keeps
-    layer_fn = jax.checkpoint(
+    return jax.checkpoint(
         layer, static_argnums=(0, 1),
         policy=jax.checkpoint_policies.save_only_these_names(
             *keeps)) if cfg.remat else layer
+
+
+def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
+                   packed: bool = False):
+    """stage_fn(stage_params, x) applying this stage's layers
+    (``_make_layer_fn``'s).
+
+    x: [mb, t_local, d], or a tuple that starts with it: then the
+    segment ids with ``packed``, then the router statistics with
+    ``cfg.use_moe`` (``_zero_router_stats``). Both ride the pipeline ring
+    with the activations; the ids pass through each stage unchanged, the
+    statistics gain this stage's layers. Runs under the full (dp, pp, sp,
+    tp) mesh.
+
+    The stage walks the maximal runs of one (mixer, feed-forward) pair in
+    its pattern (``cfg.stage_pattern``) and scans each over its rows of
+    the stacks: the leaves every layer has by the layer's place in the
+    stage, a group's own by its place among the layers of that group.
+    """
+    pattern = cfg.stage_pattern(n_stages)
+    runs = _runs(pattern)
+    layer_fn = _make_layer_fn(cfg, packed)
 
     def stage_fn(stage_params, x):
         seg = gathered = stats = None
@@ -980,10 +1210,11 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
     sequences — microbatched alongside the activations so each pipeline
     stage masks attention for the microbatch it is holding.
 
-    Returns ``(logits, router statistics)``; the statistics
+    Returns ``(logits, router statistics, hidden)``; the statistics
     (``_zero_router_stats``: the two loss terms as means over this
     member's sequences, tokens per expert summed over them, the most
-    windows a microbatch took) are None without ``cfg.use_moe``."""
+    windows a microbatch took) are None without ``cfg.use_moe``;
+    ``hidden`` [b, t, d] is the stack's output before the final norm."""
     b, t = tokens.shape
     with jax.named_scope("embed"):
         sp_idx = lax.axis_index("sp")
@@ -1009,7 +1240,7 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
     # count than the mesh's pp size, layers would silently be dropped.
     stage_params = {}
     for k, v in params.items():
-        if k in _MODEL_LEAVES:
+        if k in _MODEL_LEAVES or k.startswith(_MTP):
             continue
         assert v.shape[0] == 1, (
             f"param '{k}' has {v.shape[0]} local stages; init_params "
@@ -1031,16 +1262,52 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
                  "load": jnp.sum(per_mb["load"], axis=0),
                  "windows": jnp.max(per_mb["windows"], axis=0)}
     y = y.reshape(b, t, -1)
+    return _head(cfg, params, y, params["final_ln"]), stats, y
 
-    with jax.named_scope("head"):
-        y = _block_norm(cfg)(y, params["final_ln"]).astype(jnp.float32)
-        if cfg.tie_embeddings:
-            logits = jnp.einsum("btd,vd->btv", y,
-                                params["embed"].astype(jnp.float32))
-        else:
-            logits = jnp.einsum("btd,dv->btv", y,
-                                params["head"].astype(jnp.float32))
-        return _times(logits, 1.0 / cfg.logits_scaling), stats
+
+@jax.named_scope("head")
+def _head(cfg: TransformerConfig, params, y, final_ln):
+    """float32 logits [b, t, V] of hidden states y [b, t, d]: the norm
+    with weight ``final_ln``, then the head (or the tied table)."""
+    y = _block_norm(cfg)(y, final_ln).astype(jnp.float32)
+    if cfg.tie_embeddings:
+        logits = jnp.einsum("btd,vd->btv", y,
+                            params["embed"].astype(jnp.float32))
+    else:
+        logits = jnp.einsum("btd,dv->btv", y,
+                            params["head"].astype(jnp.float32))
+    return _times(logits, 1.0 / cfg.logits_scaling)
+
+
+def _mtp_module(cfg: TransformerConfig, layer_fn, params, hidden, inputs,
+                targets):
+    """The multi-token-prediction module of the module's docstring on the
+    stack's output ``hidden`` [b, t, d], the tokens it embeds ``inputs``
+    = ``t_{i+1}`` and those it predicts ``targets`` = ``t_{i+2}`` [b, t]
+    (whole sequences): ``(cross-entropy of the targets per token, float32
+    [b, t], zero at a sequence's last position, which has no target; the
+    mean over the others; its layer's router statistics or None)``."""
+    norm = _block_norm(cfg)
+    lp = {k[len(_MTP):]: v[0] for k, v in params.items()
+          if k.startswith(_MTP)}
+    kind, ffn = cfg.kinds[-1], cfg.ffn_kinds[-1]
+    with jax.named_scope("embed"):
+        emb = _times(params["embed"][inputs],
+                     cfg.embedding_multiplier).astype(cfg.dtype)
+        g = jnp.einsum("btc,cd->btd", jnp.concatenate([
+            norm(hidden, lp["hnorm"]), norm(emb, lp["enorm"])], -1),
+            lp["eh"])
+    stats = None
+    if ffn == "moe":
+        stacks = {k: lax.stop_gradient(params[_MTP + k])
+                  for k in ("wg", "wu", "wd")}
+        g, stats = layer_fn(kind, ffn, g, lp, None, None, (stacks, 0))
+    else:
+        g = layer_fn(kind, ffn, g, lp, None, None)
+    logits = _head(cfg, params, g, lp["final_ln"])
+    with jax.named_scope("loss"):
+        nll = token_nll(logits, targets).at[:, -1].set(0.0)
+        return nll, jnp.sum(nll) / (nll.size - nll.shape[0]), stats
 
 
 @jax.custom_vjp
@@ -1089,7 +1356,12 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
     (``parallel.moe``: 1 where the held experts' rows fit one; a dense
     layer's entry is zero), and ``readings["token_nll"]`` float32 [B, T]
     every token's cross-entropy, sharded as the tokens, whose mean the
-    loss is.
+    loss is. With ``cfg.n_mtp_modules`` both ``load`` and ``windows``
+    have one more row, the module's layer after the stack's, and
+    ``readings["mtp_token_nll"]`` [B, T] is every token's cross-entropy
+    of the token after the next in the module (zero at a sequence's last
+    position), whose mean over the other positions the loss adds
+    ``cfg.mtp_loss_weight`` times (the module's docstring).
 
     With ``cfg.use_moe`` the loss is the mean cross-entropy plus
     ``router_aux_loss_coef`` times the load-balance term and
@@ -1103,35 +1375,52 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
     your data pipeline defines them."""
     _validate_mesh_divisibility(cfg, mesh)
     stage_fn = _make_stage_fn(cfg, _pipeline_stages(mesh), packed=packed)
+    mtp_layer_fn = _make_layer_fn(cfg, packed) if cfg.n_mtp_modules else None
     specs = _param_specs(cfg)
 
     def spmd_loss(params, tokens, labels, segment_ids=None):
-        logits, stats = _spmd_forward(cfg, stage_fn, params, tokens,
-                                      n_microbatches,
-                                      segment_ids=segment_ids)
+        logits, stats, hidden = _spmd_forward(cfg, stage_fn, params, tokens,
+                                              n_microbatches,
+                                              segment_ids=segment_ids)
+        if cfg.n_mtp_modules:
+            with jax.named_scope("mtp"):
+                mtp_nll, mtp_loss, mtp_stats = _mtp_module(
+                    cfg, mtp_layer_fn, params, hidden, labels,
+                    jnp.roll(labels, -1, axis=1))
         with jax.named_scope("loss"):
             nll = token_nll(logits, labels)
             loss = jnp.mean(nll)
             if cfg.use_moe:
                 loss = (loss + cfg.router_aux_loss_coef * stats["lb"]
                         + cfg.router_z_loss_coef * stats["z"])
+            if cfg.n_mtp_modules:
+                loss = loss + cfg.mtp_loss_weight * mtp_loss
             loss = lax.pmean(loss, ("dp", "sp"))
-        if with_readings:
-            return loss, {"load": lax.psum(stats["load"], ("dp", "sp")),
-                          "windows": lax.pmax(stats["windows"],
-                                              ("dp", "sp")),
-                          "token_nll": nll}
-        return loss
+        if not with_readings:
+            return loss
+        if cfg.n_mtp_modules and mtp_stats:
+            # The module's layer after the stack's: one more row.
+            stats = {k: jnp.concatenate([stats[k], mtp_stats[k].astype(
+                jnp.int32)[None]]) for k in ("load", "windows")}
+        readings = {"load": lax.psum(stats["load"], ("dp", "sp")),
+                    "windows": lax.pmax(stats["windows"], ("dp", "sp")),
+                    "token_nll": nll}
+        if cfg.n_mtp_modules:
+            readings["mtp_token_nll"] = mtp_nll
+        return loss, readings
 
     data = P("dp", "sp")
     in_specs = ((specs, data, data, data) if packed
                 else (specs, data, data))
+    out_readings = {"load": P(), "windows": P(), "token_nll": data}
+    if cfg.n_mtp_modules:
+        out_readings["mtp_token_nll"] = data
     # Around the shard_map, so that every instruction of the pass carries
     # jvp(forward), and transpose(jvp(forward)) in the backward pass.
     return jax.named_scope("forward")(_compat_shard_map(
         spmd_loss, mesh=mesh, in_specs=in_specs,
-        out_specs=(P(), {"load": P(), "windows": P(), "token_nll": data})
-        if with_readings else P(), check_vma=False))
+        out_specs=(P(), out_readings) if with_readings else P(),
+        check_vma=False))
 
 
 @_metrics.span("step.build")
@@ -1160,9 +1449,16 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
     ``(params, opt_state, loss, readings)``, ``readings`` the loss
     function's: that ``load`` [n_layers, n_experts], the ``windows``
     [n_layers] its expert layers took and every token's cross-entropy
-    ``token_nll`` [B, T] of this step's forward pass."""
+    ``token_nll`` [B, T] of this step's forward pass. A
+    multi-token-prediction module's layer has a bias of its own,
+    ``params["mtp_expert_bias"]``, carried and moved the same way from
+    the last row of ``load``."""
     import optax
 
+    counts = _model_counts(cfg)
+    for name, n in counts.items():
+        _metrics.inc(f"model.{name}", n)
+    _metrics.note(**counts)
     loss_fn = make_loss_fn(cfg, mesh, n_microbatches, packed=packed,
                            with_readings=bool(cfg.expert_bias_rate))
 
@@ -1213,21 +1509,26 @@ def _biased_step(cfg: TransformerConfig, loss_fn, apply):
     """``make_train_step``'s step of a model whose router has a balancing
     bias; ``loss_fn`` returns its readings beside the loss."""
     @jax.named_scope("router_bias")
-    def move(bias, load):
-        # The expert layers' rows (they follow the dense ones), as the
-        # bias is stacked: [S, L, E].
-        load = load[cfg.num_dense_layers:].reshape(bias.shape)
-        return update_expert_bias(bias, load, cfg.expert_bias_rate)
+    def move(biases, load):
+        # The expert layers' rows (they follow the dense ones), as each
+        # bias is stacked: [S, L, E]; a multi-token-prediction module's
+        # layer [M, E] follows the stack's.
+        rows = {"expert_bias": slice(cfg.num_dense_layers, cfg.n_layers),
+                "mtp_expert_bias": slice(cfg.n_layers, None)}
+        return {k: update_expert_bias(
+            bias, load[rows[k]].reshape(bias.shape), cfg.expert_bias_rate)
+            for k, bias in biases.items()}
 
     # A name of its own on the device trace: a module of this name has
     # always carried the scopes this step writes.
     def hvd_decoder_bias_step(params, opt_state, tokens, labels):
-        bias, weights = params["expert_bias"], trained(params)
+        weights = trained(params)
+        biases = {k: v for k, v in params.items() if k not in weights}
         (loss, readings), grads = jax.value_and_grad(
-            lambda weights: loss_fn({**weights, "expert_bias": bias},
+            lambda weights: loss_fn({**weights, **biases},
                                     tokens, labels), has_aux=True)(weights)
         weights, opt_state = apply(grads, weights, opt_state)
-        return ({**weights, "expert_bias": move(bias, readings["load"])},
+        return ({**weights, **move(biases, readings["load"])},
                 opt_state, loss, readings)
 
     return hvd_decoder_bias_step
@@ -1245,7 +1546,8 @@ def dense_reference_loss(cfg: TransformerConfig, params, tokens, labels,
     ``benchmark/reference_hybrid.py``, and sliding and full attention
     layers, the gate, per-head QK-norm, post-norms, the sigmoid router
     with its bias, held experts and the shared expert to
-    ``benchmark/reference_afmoe.py``."""
+    ``benchmark/reference_afmoe.py``, and latent attention and the
+    multi-token-prediction module to ``benchmark/reference_glm_lite.py``."""
     if (cfg.use_moe or cfg.norm != "layernorm" or cfg.qk_norm
             or set(cfg.kinds) != {"attention"} or cfg.attn_gate
             or cfg.post_norms or cfg.gated_mlp or cfg.tie_embeddings
@@ -1255,8 +1557,9 @@ def dense_reference_loss(cfg: TransformerConfig, params, tokens, labels,
             != (1.0, 1.0, 1.0, None)):
         raise ValueError("dense_reference_loss covers the dense LayerNorm "
                          "decoder only; see benchmark/reference_moe.py, "
-                         "benchmark/reference_hybrid.py and "
-                         "benchmark/reference_afmoe.py")
+                         "benchmark/reference_hybrid.py, "
+                         "benchmark/reference_afmoe.py and "
+                         "benchmark/reference_glm_lite.py")
     from ..parallel.ring_attention import local_flash_attention
 
     def attend(q, k, v):

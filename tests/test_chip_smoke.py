@@ -145,6 +145,21 @@ def test_decoder_phase_with_the_afmoe_block():
     assert len(losses) == 3 and losses[-1] < losses[0]
 
 
+def test_decoder_phase_with_the_glm_lite_block():
+    """The smoke's `decoder-glm-lite` phase at a toy size: latent
+    attention layers and a multi-token-prediction module in a step that
+    moves two balancing biases."""
+    import dataclasses
+
+    toy = dataclasses.replace(
+        chip_smoke.TransformerConfig(**chip_smoke.GLM_LITE), vocab=64,
+        d_model=32, d_head=16, qk_nope_head_dim=12, qk_rope_head_dim=4,
+        q_lora_rank=12, kv_lora_rank=8, d_ff=64, d_expert=16, max_seq=32)
+    losses = chip_smoke.phase_decoder("toy-glm-lite", toy, 2, 3,
+                                      jax.devices()[:1])
+    assert len(losses) == 3 and losses[-1] < losses[0]
+
+
 @pytest.mark.full
 def test_decoder_parallel_phase(monkeypatch):
     """dp 2 x tp 2 and sp 4 (ring, block kernels interpreted) against one
@@ -274,14 +289,16 @@ def _attention(variant):
         jnp.float32).sum(), argnums=(0, 1, 2)), 3
 
 
-@pytest.mark.parametrize("shape", [(2, 1024, 12, 64), (1, 2048, 8, 128)],
-                         ids=["D64-T1024", "D128-T2048"])
+@pytest.mark.parametrize("shape", [(2, 1024, 12, 64), (1, 2048, 8, 128),
+                                   (1, 8192, 4, 256)],
+                         ids=["D64-T1024", "D128-T2048", "D256-T8192"])
 @pytest.mark.parametrize("variant",
                          ["forward", "backward", "segments", "window"])
 def test_attention_kernels_compile_for_v5e(described_chip, monkeypatch,
                                            variant, shape):
     """Forward, and the dq / dkv backward kernels plain, with segment ids
-    and with a window, at the smoke's widths."""
+    and with a window, at the smoke's widths and at GLM-4.7-Flash's head
+    width and length (chunks of 4,096 and 2,048 near the VMEM budget)."""
     # default_backend() is the CPU here; steer the dispatch to Mosaic.
     monkeypatch.setattr(pa, "_resolve_dispatch", lambda up: (True, False))
     fn, n_kernels = _attention(variant)

@@ -108,13 +108,16 @@ KERNEL_CASES = [
     dict(shape=(1, 2048, 8, 128), dtype=jnp.bfloat16, window=512),
     dict(shape=(2, 1024, 12, 64), dtype=jnp.float32),
     # Grouped K/V ([B, T, kv_heads, D]): two query heads of a grid step on
-    # one K/V head, and four steps of one head and two chunks a side on
-    # one (Trinity-Mini's head width, length and window).
+    # one K/V head, and a group of eight (Trinity-Mini's, with its head
+    # width, length and window): two chunks a side forward, and in the
+    # fused backward eight steps of one head on a K/V head's whole
+    # sequence of dK and dV.
     dict(shape=(2, 1024, 12, 64), dtype=jnp.float32, kv_heads=6),
-    dict(shape=(1, 8192, 4, 128), dtype=jnp.bfloat16, kv_heads=1,
+    dict(shape=(1, 8192, 8, 128), dtype=jnp.bfloat16, kv_heads=1,
          window=2048),
     # GLM-4.7-Flash's head width and length: two lane tiles a row, chunks
-    # of 4,096 forward and in dQ and of 2,048 in dK/dV.
+    # of 4,096 forward and in the fused backward, which holds a head's dK
+    # and dV whole (73 MiB of VMEM counted).
     dict(shape=(1, 8192, 2, 256), dtype=jnp.bfloat16),
 ]
 KERNEL_TOL = {  # dtype name -> (forward, gradients), rtol == atol
@@ -467,7 +470,8 @@ def dense_attention(q, k, v, window=None, seg=None):
 
 
 def phase_kernels(cases):
-    """``flash_attention`` forward and ``jax.grad`` (dq, dk, dv) against
+    """``flash_attention`` forward and ``jax.grad`` (dq, dk, dv: the fused
+    backward kernel at every case's shape) against
     :func:`dense_attention`, both on this backend (Mosaic on the chip)."""
     for case in cases:
         B, T, H, D = case["shape"]
@@ -505,7 +509,12 @@ def phase_kernels(cases):
                 check("tpu_custom_call" in fwd.lower(q, k, v).as_text(),
                       "flash_attention did not lower to the Mosaic kernel")
             out = fwd(q, k, v)
-            grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+            bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+            if jax.default_backend() == "tpu":
+                text = bwd.lower(q, k, v).as_text()
+                check("flash_bwd" in text and "flash_dq" not in text,
+                      "the backward is not the one fused kernel")
+            grads = bwd(q, k, v)
         with exact("highest"):
             ref = jax.jit(dense_attention, static_argnums=3)(
                 q, k, v, window, seg)

@@ -19,10 +19,17 @@ lower it (PERF.md section 6, PR 27). ``kernel_plan`` decides heads a
 step, chunk and sub-tile from the shape alone; nothing else does.
 
 Scope: forward AND backward. Training's forward emits the per-row
-log-sum-exp alongside O; the backward is the standard flash backward as
-two Pallas kernels — one accumulating dQ across K tiles, one accumulating
-dK/dV across Q tiles — each re-materializing P = exp(S - lse) on-chip from
-the saved lse, so neither pass ever writes the attention matrix to HBM.
+log-sum-exp alongside O; the backward is one Pallas kernel, ``flash_bwd``,
+that walks the visited tiles once and makes dQ, dK and dV from one S, one
+P = exp(S - lse) and one dP = dO.V^T a tile: five matmuls and one ``exp``,
+the attention matrix never in HBM. dK and dV accumulate in float32 VMEM
+for a K/V head's whole sequence, dQ for the resident chunk of Q across
+the K chunks. Where a sequence is too long for those accumulators
+(``kernel_plan(kind="bwd")`` is None: T 65,536 at D 128) the backward is
+the standard two passes instead, ``flash_dq`` accumulating dQ across K
+tiles and ``flash_dkv`` accumulating dK/dV across Q tiles, each rebuilding
+the tile for itself (seven matmuls, two ``exp``); all three share the
+tile's body (``_p_ds``) and the dK/dV walk is one function.
 ``flash_attention_block_grads`` exposes the same per-block backward for
 ring attention's backward ring pass (``parallel.ring_attention``).
 
@@ -30,7 +37,7 @@ Head counts: the Q side (q, o, dO, dQ, lse, delta, the Q segment ids) is
 merged as ``[B*H, T, D]`` and the K side (k, v, dK, dV, the K segment
 ids) as ``[B*Hkv, T, D]``, Hkv a divisor of H; the group ``g = H / Hkv``
 is read from the two shapes and nothing else. Query head j reads K/V head
-j // g through the K side's index map, and the dK/dV pass, whose grid is
+j // g through the K side's index map, and the backward, whose grid is
 over K/V heads, sums a group's query heads into the float32 accumulators
 it holds in VMEM. So K and V are never repeated to H heads and no
 gradient of theirs is written at H heads. With g = 1 every plan, grid,
@@ -43,8 +50,8 @@ causal masking between sequence blocks) and the plain single-block case.
 On TPU the kernels compile through Mosaic; tests interpret them on CPU
 (``_resolve_dispatch``).
 
-Each ``pallas_call`` carries a ``name`` (``flash_fwd``, ``flash_dq``,
-``flash_dkv``), which jax also writes as a scope into the custom call's
+Each ``pallas_call`` carries a ``name`` (``flash_fwd``, ``flash_bwd``;
+``flash_dq``, ``flash_dkv``), which jax also writes as a scope into the custom call's
 ``op_name``; the XLA twins carry the scope ``flash_xla``. A device trace
 tells the kernels apart, and a fall-back from them, by these names
 (docs/diagnostics.md, "Tracing").
@@ -101,6 +108,12 @@ _STAT_LANES = 128
 # one sub-tile's float32 temporaries. Row vectors ([T, 1] float32: lse,
 # delta, m, l, segment ids) occupy T x 128 lanes there.
 VMEM_BUDGET = 40 << 20
+# The fused backward's: it also holds a K/V head's dK and dV for the whole
+# sequence, in float32 and as the blocks they leave in (T x D x 16 bytes
+# in bf16: 32 MiB at T 8,192 and D 256), and a chunk halved costs it 4 %
+# (PERF.md section 6, PR 37). Mosaic took a limit of 115 MiB of the
+# chip's 128 on the kernel alone; this leaves a step's other programs 40.
+BWD_VMEM_BUDGET = 80 << 20
 _VMEM_DEFAULT_LIMIT = 16 << 20
 
 
@@ -148,7 +161,8 @@ class KernelPlan(NamedTuple):
     tile_q: int          # the in-kernel loop's sub-tile, [tile_q, tile_k]
     tile_k: int
     unroll: int          # heads that share one loop body
-    grid: tuple          # (B*H / heads, outer chunks, inner chunks)
+    grid: tuple          # (head blocks, outer chunks, inner chunks); "bwd":
+    #                      (K/V head blocks, passes, Q chunks, K chunks)
     tiles_visited: int   # sub-tiles a head's walk enters (q_off == k_off)
     vmem_bytes: int      # counted VMEM; ``vmem_limit_bytes`` is set from it
     group: int = 1       # query heads that share one K/V head (H / Hkv)
@@ -168,7 +182,8 @@ class KernelPlan(NamedTuple):
     def passes(self) -> int:
         """Grid steps that a group's query heads take: the forward and the
         dQ pass send ``passes`` consecutive head blocks to one K/V block,
-        the dK/dV pass visits them in turn on its sequential dimension."""
+        the backward and the dK/dV pass visit them in turn on a
+        sequential dimension."""
         return self.group // self.shared
 
 
@@ -207,7 +222,7 @@ def _k_bounds(q_lo, tq, k_base, tk, n, causal, window):
 
 
 def _q_bounds(k_lo, tk, q_base, tq, n, causal, window):
-    """The transposed walk of the dK/dV pass: the Q sub-tiles (of ``n``,
+    """The transposed walk of the backward: the Q sub-tiles (of ``n``,
     ``tq`` tall, the first at global row ``q_base``) that see the K
     sub-tile of global columns ``k_lo .. k_lo+tk-1``."""
     if not causal:
@@ -243,12 +258,14 @@ def _lanes(d: int) -> int:
 
 
 def _vmem_bytes(kind, heads, kv_heads, unroll, cq, ck, tq, tk, d, itemsize,
-                out_itemsize, segments, state):
+                out_itemsize, segments, state, t_k):
     """VMEM one grid step of pass ``kind`` holds, counted as Mosaic lays
     it out: the last dimension padded to 128 lanes (a [T, 1] float32 row
     vector is T x 512 bytes), pipelined blocks double-buffered, and six
     float32 [tq, tk] temporaries for every head of a loop body. The K
-    side (k, v, dk, dv and their accumulators) holds ``kv_heads``."""
+    side (k, v, dk, dv and their accumulators) holds ``kv_heads``; the
+    fused backward's dk and dv, blocks and accumulators, hold a K/V
+    head's whole sequence of ``t_k``."""
     dp = _lanes(d)
     row = 128 * 4
     q_side = heads * cq * dp
@@ -262,23 +279,28 @@ def _vmem_bytes(kind, heads, kv_heads, unroll, cq, ck, tq, tk, d, itemsize,
         scratch = q_side * 4 + 2 * heads * cq * row        # acc, m, l
     else:
         blocks += q_side * itemsize + 2 * heads * cq * row  # do, lse, delta
-        if kind == "dq":
+        scratch = 0
+        if kind != "dkv":                                  # dq and its acc
             blocks += q_side * out_itemsize
-            scratch = q_side * 4
-        else:
-            blocks += 2 * k_side * out_itemsize
-            scratch = 2 * k_side * 4
+            scratch += q_side * 4
+        if kind != "dq":                                   # dk, dv, accs
+            held = kv_heads * (t_k if kind == "bwd" else ck) * dp
+            blocks += 2 * held * out_itemsize
+            scratch += 2 * held * 4
     return 2 * blocks + scratch + 6 * unroll * tq * tk * 4
 
 
 def kernel_plan(BH, Tq, Tk, D, dtype, causal, window=None, *,
                 segments=False, kind="fwd", out_dtype=None, state=False,
                 group=1):
-    """The grid step of pass ``kind`` ("fwd", "dq" or "dkv") for merged
-    ``[BH, Tq, D]`` Q-side operands of ``dtype`` over ``[BH / group, Tk,
-    D]`` K-side ones: a pure function of the shape. None where the
-    kernels do not take the shape and the XLA twins do: a sequence no
-    tile divides, or a head so wide that no chunk fits ``VMEM_BUDGET``.
+    """The grid step of pass ``kind`` ("fwd", "bwd", "dq" or "dkv") for
+    merged ``[BH, Tq, D]`` Q-side operands of ``dtype`` over ``[BH /
+    group, Tk, D]`` K-side ones: a pure function of the shape. None where
+    the kernels do not take the shape and the XLA twins do: a sequence no
+    tile divides, or a head so wide that no chunk fits the budget. For
+    "bwd", None also where a K/V head's whole-sequence accumulators do
+    not fit ``BWD_VMEM_BUDGET`` at any chunk: the two passes "dq" and
+    "dkv" run then.
 
     Heads a step: enough that a step holds ``_STEP_ELEMS`` score elements
     (16 at T 128, 2 at T 1024, 1 from T 2048 on), a divisor of ``BH``
@@ -297,12 +319,20 @@ def kernel_plan(BH, Tq, Tk, D, dtype, causal, window=None, *,
     through the index map. The dK/dV pass has a grid over K/V heads and
     sums a group into the one accumulator pair a K/V head has: as heads
     of one step where the step covers whole groups, else as
-    ``plan.passes`` times the Q chunks on the sequential dimension."""
+    ``plan.passes`` times the Q chunks on the sequential dimension.
+
+    The fused backward is the dK/dV pass's walk with dQ made beside: grid
+    (K/V head blocks, ``plan.passes``, Q chunks, K chunks), the last three
+    sequential. dK and dV accumulate for the K/V head's whole sequence
+    under everything its group sends, and leave in one block when the
+    head is done; dQ accumulates for a head block's resident chunk across
+    the K chunks, the innermost dimension, in ascending order."""
     if _pick_block(Tq, 8) is None or _pick_block(Tk, 8) is None:
         return None
     itemsize = jnp.dtype(dtype).itemsize
     out_itemsize = jnp.dtype(out_dtype or (jnp.float32 if state
                                            else dtype)).itemsize
+    budget = BWD_VMEM_BUDGET if kind == "bwd" else VMEM_BUDGET
     cap = min(max(Tq, Tk), _CHUNK_CAP)
     while True:
         cq, ck = _chunk(Tq, cap), _chunk(Tk, cap)
@@ -317,8 +347,8 @@ def kernel_plan(BH, Tq, Tk, D, dtype, causal, window=None, *,
             unroll = max(u for u in range(1, body + 1) if heads % u == 0)
             vmem = _vmem_bytes(kind, heads, max(1, heads // group), unroll,
                                cq, ck, tq, tk, D, itemsize, out_itemsize,
-                               segments, state)
-            if vmem <= VMEM_BUDGET:
+                               segments, state, Tk)
+            if vmem <= budget:
                 fits = heads, unroll, vmem
                 break
         if fits or cap <= max(_TILE_CAP, 128):
@@ -331,7 +361,7 @@ def kernel_plan(BH, Tq, Tk, D, dtype, causal, window=None, *,
     visited = 0
     for qc in range(n_qc):
         for kc in range(n_kc):
-            if kind == "dkv":
+            if kind in ("dkv", "bwd"):
                 walks = (_q_bounds(kc * ck + j * tk, tk, qc * cq, tq,
                                    cq // tq, causal, window)
                          for j in range(ck // tk))
@@ -342,8 +372,10 @@ def kernel_plan(BH, Tq, Tk, D, dtype, causal, window=None, *,
             visited += sum(end - first for first, end in walks)
     plan = KernelPlan(heads, cq, ck, tq, tk, unroll, (), visited, vmem,
                       group)
-    grid = ((BH // heads // plan.passes, n_kc, plan.passes * n_qc)
-            if kind == "dkv" else (BH // heads, n_qc, n_kc))
+    kv_blocks = BH // heads // plan.passes
+    grid = {"dkv": (kv_blocks, n_kc, plan.passes * n_qc),
+            "bwd": (kv_blocks, plan.passes, n_qc, n_kc)}.get(
+                kind, (BH // heads, n_qc, n_kc))
     return plan._replace(grid=grid)
 
 
@@ -410,19 +442,38 @@ def _for_heads(n, compute, commit=None, unroll=1):
     _for_each(n // unroll, group)
 
 
-def _walk(bounds, tile, chunk, n_chunks, init, finish):
+def _when(cond, fn):
+    """``fn()`` where ``cond`` holds: a Python bool decides at trace
+    time, a traced one predicates."""
+    if isinstance(cond, bool):
+        if cond:
+            fn()
+    else:
+        pl.when(cond)(fn)
+
+
+def _ends(i, n):
+    """Whether step ``i`` of a sequential grid dimension of ``n`` is its
+    (first, last): True of both where the dimension is one step."""
+    return (True, True) if n == 1 else (i == 0, i == n - 1)
+
+
+def _both(a, b):
+    if isinstance(a, bool):
+        return b if a else False
+    if isinstance(b, bool):
+        return a if b else False
+    return jnp.logical_and(a, b)
+
+
+def _walk(bounds, tile, ends, init, finish):
     """Run ``tile(index)`` over the walk's ``(first, end)``, between
-    ``init`` in the first grid step of the sequential chunk dimension and
-    ``finish`` in the last."""
-    if n_chunks == 1:
-        init()
-    else:
-        pl.when(chunk == 0)(init)
+    ``init`` in the first grid step of what the accumulators live across
+    and ``finish`` in the last; ``ends`` says whether this step is
+    either."""
+    _when(ends[0], init)
     jax.lax.fori_loop(*bounds, lambda t, c: (tile(t), c)[1], None)
-    if n_chunks == 1:
-        finish()
-    else:
-        pl.when(chunk == n_chunks - 1)(finish)
+    _when(ends[1], finish)
 
 
 def _rows(i, t):
@@ -574,18 +625,30 @@ def _fwd_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
             _for_heads(G, head)
 
         _walk(_k_bounds(q_lo, tq, k_base, tk, n_k, causal, window), tile,
-              kc, n_kc, init, finish)
+              _ends(kc, n_kc), init, finish)
 
     _for_each(plan.chunk_q // tq, q_tile)
 
 
+def _p_ds(q, k, v, do, lse, delta, keep, scale):
+    """The backward's tile, whichever gradient it is for: P = exp(S -
+    lse), rebuilt on-chip from the saved lse and masked by ``keep``, and
+    dS = P * (dO.V^T - delta), both float32 [tq, tk]. delta = rowsum(dO *
+    O), precomputed by the caller. The softmax scale is left out of dS:
+    it multiplies the dQ and dK accumulators once, at the end."""
+    s = _mxu_dot(q, k, ((1,), (1,))) * scale
+    p = jnp.exp(s - lse)
+    if keep is not None:
+        p = jnp.where(keep, p, 0.0)
+    dp = _mxu_dot(do, v, ((1,), (1,)))
+    return p, p * (dp - delta)
+
+
 def _dq_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
                segments: bool):
-    """dQ pass: the forward's walk. P = exp(S - lse) is rebuilt on-chip
-    from the saved lse; dS = P * (dO.V^T - delta); dQ accumulates dS.K in
-    VMEM along the walk and across the sequential K-chunk grid dimension;
-    the softmax scale multiplies the [tq, D] accumulator once, at the
-    end. delta = rowsum(dO * O), precomputed by the caller."""
+    """dQ pass of the two-pass backward: the forward's walk. dQ
+    accumulates dS.K in VMEM along the walk and across the sequential
+    K-chunk grid dimension."""
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     qs_ref, ks_ref = refs[6:8] if segments else (None, None)
     dq_ref, dq_acc = refs[-2:]
@@ -613,15 +676,11 @@ def _dq_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
             def head(g):
                 c = kv(g)
                 k = k_ref[c, cols, :]
-                s = _mxu_dot(q_ref[g, rows, :], k,
-                             ((1,), (1,))) * scale                # [tq, tk]
-                p = jnp.exp(s - lse_ref[g, rows, :])
-                keep = _keep(mask, qs_ref, ks_ref, g, c, rows, cols)
-                if keep is not None:
-                    p = jnp.where(keep, p, 0.0)
-                dp = _mxu_dot(do_ref[g, rows, :], v_ref[c, cols, :],
-                              ((1,), (1,)))                       # [tq, tk]
-                ds = p * (dp - delta_ref[g, rows, :])
+                _, ds = _p_ds(
+                    q_ref[g, rows, :], k, v_ref[c, cols, :],
+                    do_ref[g, rows, :], lse_ref[g, rows, :],
+                    delta_ref[g, rows, :],
+                    _keep(mask, qs_ref, ks_ref, g, c, rows, cols), scale)
                 return dq_acc[g, rows, :] + _mxu_dot(
                     ds.astype(k.dtype), k, ((1,), (0,)))          # [tq, D]
 
@@ -638,48 +697,81 @@ def _dq_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
             _for_heads(G, head)
 
         _walk(_k_bounds(q_lo, tq, k_base, tk, n_k, causal, window), tile,
-              kc, n_kc, init, finish)
+              _ends(kc, n_kc), init, finish)
 
     _for_each(plan.chunk_q // tq, q_tile)
 
 
-def _dkv_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
-                segments: bool):
-    """dK/dV pass: the transposed walk — for each K sub-tile, the Q
-    sub-tiles that see it; grid (K/V heads, K chunk, Q chunk), sequential
-    over Q chunks. Same [tq, tk] orientation as the dQ pass; the transposed
-    contractions (P^T.dO, dS^T.Q) ride dot_general dimension numbers so
-    no tile is ever explicitly transposed. The softmax scale multiplies
-    dK's accumulator once, at the end, as it does dQ's.
+def _bwd_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
+                segments: bool, fused: bool):
+    """The backward's transposed walk: for each K sub-tile, the Q
+    sub-tiles that see it, in the forward's [tq, tk] orientation; the
+    transposed contractions (P^T.dO, dS^T.Q) ride dot_general dimension
+    numbers so no tile is ever explicitly transposed. The softmax scale
+    multiplies the dK and dQ accumulators once, at the end.
 
-    A K/V head's accumulators (Gkv, chunk_k, D) take every query head of
-    its group: the step's own that share it (each body's products are
-    added once all of the body are computed), and, where the step holds
-    fewer than the group, those of the ``plan.passes`` steps that walk
-    the Q chunks again on the sequential dimension, a head block each."""
+    ``fused`` (``flash_bwd``): dQ, dK and dV from one ``_p_ds`` a tile.
+    Grid (K/V heads, passes, Q chunk, K chunk), the last three
+    sequential. dK and dV accumulate in (Gkv, Tk, D) float32 scratch, a
+    K/V head's whole sequence, from the head's first Q chunk to its last
+    and leave then, a K chunk each step; dQ accumulates dS.K in (G,
+    chunk_q, D) across the K chunks, the K sub-tiles in ascending order,
+    as the dQ pass adds them.
+
+    Not fused (``flash_dkv``, the two-pass backward's second): dK and dV
+    alone, grid (K/V heads, K chunk, Q chunk), sequential over Q chunks,
+    the accumulators a resident chunk's.
+
+    Either way a K/V head's accumulators take every query head of its
+    group: the step's own that share it (each body's products are added
+    once all of the body are computed), and, where the step holds fewer
+    than the group, those of the ``plan.passes`` steps that walk the Q
+    chunks again on a sequential dimension, a head block each."""
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     qs_ref, ks_ref = refs[6:8] if segments else (None, None)
-    dk_ref, dv_ref, dk_acc, dv_acc = refs[-4:]
+    if fused:
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs[-6:]
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = refs[-4:]
     G, Gkv, tq, tk = plan.heads, plan.kv_heads, plan.tile_q, plan.tile_k
     kv = _kv_head(plan)
     shared = plan.shared > 1
-    n_q = plan.chunk_q // tq
-    n_seq = plan.grid[2]             # the Q chunks, ``passes`` times over
-    step = pl.program_id(2)
-    qc = (step if plan.passes == 1
-          else jax.lax.rem(step, n_seq // plan.passes))
+    n_q, n_k = plan.chunk_q // tq, plan.chunk_k // tk
+    if fused:
+        _, n_p, n_qc, n_kc = plan.grid
+        qc = pl.program_id(2)
+        kc = pl.program_id(3) if n_kc > 1 else 0
+        q_ends = tuple(map(_both, _ends(pl.program_id(1), n_p),
+                           _ends(qc, n_qc)))
+        k_ends = _ends(kc, n_kc)
+    else:
+        n_seq = plan.grid[2]          # the Q chunks, ``passes`` times over
+        step = pl.program_id(2)
+        qc = (step if plan.passes == 1
+              else jax.lax.rem(step, n_seq // plan.passes))
+        kc = pl.program_id(1)
+        q_ends = _ends(step, n_seq)
     q_base = offs_ref[0] + qc * plan.chunk_q
-    k_base = offs_ref[1] + pl.program_id(1) * plan.chunk_k
+    k_base = offs_ref[1] + kc * plan.chunk_k
     scale = 1.0 / (q_ref.shape[-1] ** 0.5)
+
+    if fused:
+        def zero_dq(i):
+            dq_acc[:, _rows(i, tq), :] = jnp.zeros(
+                (G, tq, dq_acc.shape[-1]), jnp.float32)
+
+        _when(k_ends[0], lambda: _for_each(n_q, zero_dq))
 
     def k_tile(j):
         cols = _rows(j, tk)
+        # The tile's rows in the accumulators.
+        held = _rows(kc * n_k + j, tk) if fused else cols
         k_lo = k_base + j * tk
 
         def init():
             zeros = jnp.zeros((Gkv, tk, dk_acc.shape[-1]), jnp.float32)
-            dk_acc[:, cols, :] = zeros
-            dv_acc[:, cols, :] = zeros
+            dk_acc[:, held, :] = zeros
+            dv_acc[:, held, :] = zeros
 
         def tile(i):
             rows = _rows(i, tq)
@@ -691,62 +783,91 @@ def _dkv_kernel(offs_ref, *refs, plan: KernelPlan, causal: bool, window,
                 def add(acc, product):
                     # Heads of a body that share an accumulator hand
                     # their products to ``commit``.
-                    return product() if shared else acc[c, cols, :] + product()
+                    return product if shared else acc[c, held, :] + product
 
-                q = q_ref[g, rows, :]
+                q, k = q_ref[g, rows, :], k_ref[c, cols, :]
                 do = do_ref[g, rows, :]
-                s = _mxu_dot(q, k_ref[c, cols, :],
-                             ((1,), (1,))) * scale                # [tq, tk]
-                p = jnp.exp(s - lse_ref[g, rows, :])
-                keep = _keep(mask, qs_ref, ks_ref, g, c, rows, cols)
-                if keep is not None:
-                    p = jnp.where(keep, p, 0.0)
-                dv = add(dv_acc, lambda: _mxu_dot(
-                    p.astype(do.dtype), do, ((0,), (0,))))        # [tk, D]
-                dp = _mxu_dot(do, v_ref[c, cols, :], ((1,), (1,)))
-                ds = p * (dp - delta_ref[g, rows, :])
-                dk = add(dk_acc, lambda: _mxu_dot(
-                    ds.astype(q.dtype), q, ((0,), (0,))))         # [tk, D]
-                return dk, dv
+                p, ds = _p_ds(
+                    q, k, v_ref[c, cols, :], do, lse_ref[g, rows, :],
+                    delta_ref[g, rows, :],
+                    _keep(mask, qs_ref, ks_ref, g, c, rows, cols), scale)
+                ds = ds.astype(q.dtype)
+                dv = add(dv_acc, _mxu_dot(p.astype(do.dtype), do,
+                                          ((0,), (0,))))          # [tk, D]
+                dk = add(dk_acc, _mxu_dot(ds, q, ((0,), (0,))))   # [tk, D]
+                if not fused:
+                    return dk, dv
+                return dk, dv, dq_acc[g, rows, :] + _mxu_dot(
+                    ds, k, ((1,), (0,)))                          # [tq, D]
 
             def commit(g, grads):
                 c = kv(g)
+                dk, dv = grads[:2]
                 if shared:
-                    grads = (dk_acc[c, cols, :] + grads[0],
-                             dv_acc[c, cols, :] + grads[1])
-                dk_acc[c, cols, :], dv_acc[c, cols, :] = grads
+                    dk, dv = dk_acc[c, held, :] + dk, dv_acc[c, held, :] + dv
+                dk_acc[c, held, :], dv_acc[c, held, :] = dk, dv
+                if fused:
+                    dq_acc[g, rows, :] = grads[2]
 
             _for_heads(G, head, commit, plan.unroll)
 
         def finish():
             def head(g):
-                dk_ref[g, cols, :] = (dk_acc[g, cols, :] *
+                dk_ref[g, held, :] = (dk_acc[g, held, :] *
                                       scale).astype(dk_ref.dtype)
-                dv_ref[g, cols, :] = dv_acc[g, cols, :].astype(dv_ref.dtype)
+                dv_ref[g, held, :] = dv_acc[g, held, :].astype(dv_ref.dtype)
 
             _for_heads(Gkv, head)
 
         _walk(_q_bounds(k_lo, tk, q_base, tq, n_q, causal, window), tile,
-              step, n_seq, init, finish)
+              q_ends, init, finish)
 
-    _for_each(plan.chunk_k // tk, k_tile)
+    _for_each(n_k, k_tile)
+
+    if fused:
+        def write_dq(i):
+            rows = _rows(i, tq)
+
+            def head(g):
+                dq_ref[g, rows, :] = (dq_acc[g, rows, :] *
+                                      scale).astype(dq_ref.dtype)
+
+            _for_heads(G, head)
+
+        _when(k_ends[1], lambda: _for_each(n_q, write_dq))
+
+
+def _block_shape(plan, side, shape):
+    """The block of a merged ``shape`` array that follows ``side``: "q"
+    or "k", a step's heads and resident chunk of that side; "K", the K
+    side's heads and the whole sequence (the fused backward's dk, dv and
+    their accumulators)."""
+    heads = plan.heads if side == "q" else plan.kv_heads
+    rows = {"q": plan.chunk_q, "k": plan.chunk_k}.get(side, shape[1])
+    return heads, rows, shape[-1]
 
 
 def _flash_call(kind, kernel, plan, args, out_shapes, out_sides, scratch,
                 interpret):
     """The ``pallas_call`` of one pass. ``args`` are (array, side) pairs,
-    side "q" or "k" saying which chunk and which head count the block
-    follows; the outputs' sides are ``out_sides``. The last grid
-    dimension is the sequential one: K chunks for "fwd" and "dq", Q
-    chunks for "dkv".
+    side "q", "k" or "K" saying which chunk and which head count the
+    block follows (``_block_shape``); the outputs' sides are ``out_sides``.
+    The last grid dimension is the sequential one: K chunks for "fwd"
+    and "dq", Q chunks for "dkv"; of "bwd"'s four, all but the first.
 
     A group's ``plan.passes`` head blocks share one K-side block: the
     forward and the dQ pass send grid row ``b`` to K/V block ``b //
-    passes``; the dK/dV pass, whose rows are K/V blocks, takes the group's
-    head blocks one after another on the sequential dimension (head block
+    passes``; the backward and the dK/dV pass, whose rows are K/V blocks,
+    take the group's head blocks one after another on a sequential
+    dimension: the fused backward's second (head block ``b * passes +
+    i``), and, for "dkv", the one it shares with the Q chunks (head block
     ``b * passes + i // n_qc``, Q chunk ``i % n_qc``)."""
     n = plan.passes
-    if kind == "dkv":
+    if kind == "bwd":
+        maps = {"q": lambda b, i, qc, kc, offs: (b * n + i, qc, 0),
+                "k": lambda b, i, qc, kc, offs: (b, kc, 0),
+                "K": lambda b, i, qc, kc, offs: (b, 0, 0)}
+    elif kind == "dkv":
         maps = {"q": lambda b, kc, qc, offs: (b, qc, 0),
                 "k": lambda b, kc, qc, offs: (b, kc, 0)}
         if n > 1:
@@ -758,12 +879,9 @@ def _flash_call(kind, kernel, plan, args, out_shapes, out_sides, scratch,
                 "k": lambda b, qc, kc, offs: (b, kc, 0)}
         if n > 1:
             maps["k"] = lambda b, qc, kc, offs: (jax.lax.div(b, n), kc, 0)
-    heads = {"q": plan.heads, "k": plan.kv_heads}
-    chunk = {"q": plan.chunk_q, "k": plan.chunk_k}
 
     def spec(shape, side):
-        return pl.BlockSpec((heads[side], chunk[side], shape[-1]),
-                            maps[side])
+        return pl.BlockSpec(_block_shape(plan, side, shape), maps[side])
 
     return pl.pallas_call(
         kernel,
@@ -777,7 +895,8 @@ def _flash_call(kind, kernel, plan, args, out_shapes, out_sides, scratch,
         ),
         out_shape=out_shapes,
         compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel",) + ("arbitrary",) * 3
+            if kind == "bwd" else ("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=max(_VMEM_DEFAULT_LIMIT,
                                  plan.vmem_bytes + (8 << 20))),
         interpret=interpret,
@@ -834,41 +953,52 @@ def _flash_forward(q, k, v, offs, causal: bool, interpret: bool, mode: str,
 def _pallas_bwd(q, k, v, do, lse, delta, offs, causal: bool,
                 interpret: bool, out_dtype=None, q_seg=None, k_seg=None,
                 window=None):
-    """The two flash-backward kernels; returns (dq, dk, dv) in the input
-    dtypes (or ``out_dtype`` when given — ring accumulation wants f32),
-    dk and dv at the head count of k and v. lse/delta: f32 [BH, T, 1]."""
+    """The flash backward; returns (dq, dk, dv) in the input dtypes (or
+    ``out_dtype`` when given — ring accumulation wants f32), dk and dv
+    at the head count of k and v. lse/delta: f32 [BH, T, 1].
+
+    One fused kernel where ``kernel_plan`` has a "bwd" plan for the
+    shape, else the two passes."""
     BH, Tq, D = q.shape
     BHkv, Tk = k.shape[:2]
     segments = q_seg is not None
     operands = [(q, "q"), (k, "k"), (v, "k"), (do, "q"), (lse, "q"),
                 (delta, "q")] + _seg_args(q_seg, k_seg)
-    outs = {}
-    for kind, kernel in (("dq", _dq_kernel), ("dkv", _dkv_kernel)):
-        plan = kernel_plan(BH, Tq, Tk, D, q.dtype, causal, window,
+
+    def plan_of(kind):
+        return kernel_plan(BH, Tq, Tk, D, q.dtype, causal, window,
                            segments=segments, kind=kind,
                            out_dtype=out_dtype, group=BH // BHkv)
+
+    def run(kind, plan, outs):
+        """``outs``: "q" for dq, "k" for dk and dv, in the kernel's
+        order; the accumulators follow them, whole-sequence for "K"."""
         _log_plan(kind, q.shape, q.dtype, causal, window, plan)
-        G, cq, ck = plan.heads, plan.chunk_q, plan.chunk_k
-        if kind == "dq":
-            shapes = [jax.ShapeDtypeStruct((BH, Tq, D),
-                                           out_dtype or q.dtype)]
-            sides = ["q"]
-            scratch = [pltpu.VMEM((G, cq, D), jnp.float32)]
-        else:
-            shapes = [jax.ShapeDtypeStruct((BHkv, Tk, D),
-                                           out_dtype or k.dtype),
-                      jax.ShapeDtypeStruct((BHkv, Tk, D),
-                                           out_dtype or v.dtype)]
-            sides = ["k", "k"]
-            scratch = [pltpu.VMEM((plan.kv_heads, ck, D), jnp.float32),
-                       pltpu.VMEM((plan.kv_heads, ck, D), jnp.float32)]
-        outs[kind] = _flash_call(
+        like = {"q": q, "k": k, "K": k}
+        static = dict(plan=plan, causal=causal, window=window,
+                      segments=segments)
+        return _flash_call(
             kind,
-            functools.partial(kernel, plan=plan, causal=causal,
-                              window=window, segments=segments),
-            plan, operands, shapes, sides, scratch, interpret,
+            functools.partial(_dq_kernel, **static) if kind == "dq"
+            else functools.partial(_bwd_kernel, fused=kind == "bwd",
+                                   **static),
+            plan, operands,
+            [jax.ShapeDtypeStruct(like[o].shape,
+                                  out_dtype or like[o].dtype) for o in outs],
+            outs,
+            [pltpu.VMEM(_block_shape(plan, o, like[o].shape), jnp.float32)
+             for o in outs],
+            interpret,
         )(offs, *(a for a, _ in operands))
-    return (outs["dq"][0], *outs["dkv"])
+
+    plan = plan_of("bwd")
+    if plan is not None:
+        return tuple(run("bwd", plan, ["q", "K", "K"]))
+    _log.debug(f"flash_bwd {tuple(q.shape)} over {Tk} keys: no fused plan "
+               f"(dK and dV for a K/V head's {Tk} rows do not fit "
+               f"{BWD_VMEM_BUDGET} B); the two passes run")
+    return (*run("dq", plan_of("dq"), ["q"]),
+            *run("dkv", plan_of("dkv"), ["k", "k"]))
 
 
 # ---------------------------------------------------------------------------
@@ -1111,7 +1241,7 @@ def flash_attention_block_grads(q, k, v, do, lse, delta, q_off, k_off,
 @jax.named_scope("flash_xla")
 def _xla_block_grads(q, k, v, do, lse, delta, offs, causal: bool,
                      out_dtype=None, q_seg=None, k_seg=None, window=None):
-    """XLA twin of the backward kernels (fallback for untileable shapes
+    """XLA twin of the backward kernel (fallback for untileable shapes
     and non-TPU platforms). Same math, same lse/delta residuals; dk and
     dv summed over each group in float32, at the head count of k and v."""
     dq_dt = out_dtype or q.dtype

@@ -79,7 +79,8 @@ def _sub_jaxprs(eqn):
 def flash_calls(jaxpr, acc=None):
     """{kernel: [(operand shapes, result shapes)]} for every
     ``pallas_call`` of the flash kernels in ``jaxpr`` (``flash_fwd``,
-    ``flash_dq``, ``flash_dkv``: the call's ``name``), recursing through
+    ``flash_bwd``, and ``flash_dq``, ``flash_dkv`` where the two passes
+    run: the call's ``name``), recursing through
     every body but a kernel's own. Operands in the order of the call: the
     block offsets, q, k, v, then what the pass takes besides."""
     acc = {} if acc is None else acc
@@ -122,18 +123,20 @@ def grouped_shapes(B, T, H, Hkv, D):
 
 def assert_kv_stay_grouped(jaxpr, B, T, H, Hkv, D):
     """A traced step whose attention has ``H`` query heads over ``Hkv``
-    K/V heads: every flash kernel takes K and V as ``[B * Hkv, T, D]``,
-    the dK/dV pass returns that shape, and no repeat to ``H`` heads and
-    no sum over a group is anywhere. Returns the calls."""
+    K/V heads: the step's kernels are the forward and the fused backward,
+    every one takes K and V as ``[B * Hkv, T, D]``, the backward returns
+    dQ at the Q side's shape and dK and dV at that one, and no repeat to
+    ``H`` heads and no sum over a group is anywhere. Returns the calls."""
     calls = flash_calls(jaxpr)
-    assert set(calls) == {"flash_fwd", "flash_dq", "flash_dkv"}, set(calls)
+    assert set(calls) == {"flash_fwd", "flash_bwd"}, set(calls)
     for name, found in calls.items():
         for operands, results in found:
             assert operands[1] == (B * H, T, D), (name, operands)
             assert operands[2] == operands[3] == (B * Hkv, T, D), (
                 name, operands)
-            if name == "flash_dkv":
-                assert results == [(B * Hkv, T, D)] * 2, results
+            if name == "flash_bwd":
+                assert results == [(B * H, T, D)] + [(B * Hkv, T, D)] * 2, \
+                    results
     found = grouped_shapes(B, T, H, Hkv, D) & repeats_and_group_sums(jaxpr)
     assert not found, found
     return calls

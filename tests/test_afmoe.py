@@ -832,8 +832,8 @@ def test_the_kernels_take_k_and_v_at_their_own_head_count(monkeypatch):
     calls = hlo_text.assert_kv_stay_grouped(
         _traced_bf16_step(base=cfg), B, T, cfg.n_heads, 1, cfg.d_head)
     # Two runs of layers with a window and one without, each forward
-    # and once more where its layer is rematerialized.
-    assert len(calls["flash_dkv"]) == len(calls["flash_dq"]) >= 2
+    # and once more where its layer is rematerialized; one backward each.
+    assert len(calls["flash_fwd"]) >= len(calls["flash_bwd"]) >= 2
 
 
 # 2 x 24 tokens: one window holds every assignment, no loop. 2 x 256: 1,536

@@ -613,23 +613,27 @@ def test_the_float32_check_sees_a_low_rank_norm_in_bf16(monkeypatch):
 
 @pytest.mark.parametrize("kind, chunk, grid, vmem_mb", [
     ("fwd", 4096, (40, 2, 2), 35.7), ("dq", 4096, (40, 2, 2), 39.8),
-    ("dkv", 2048, (40, 4, 4), 27.3)])
+    ("dkv", 2048, (40, 4, 4), 27.3), ("bwd", 4096, (40, 1, 2, 2), 73.4)])
 def test_the_plan_at_the_cells_shape(kind, chunk, grid, vmem_mb):
     """B 2 x H 20 merged, T 8,192, D 256, bf16, causal: one head a step
-    at chunks of 4,096 forward and in the dQ pass (as at D 128, at more
-    VMEM), chunks of 2,048 in the dK/dV pass; the plans at D 64 and 128
-    are what they were."""
+    at chunks of 4,096 forward and in the fused backward, which holds a
+    head's dK and dV for all 8,192 rows besides (32 MiB of its own
+    budget); of the two passes it replaces, chunks of 4,096 in the dQ
+    pass and 2,048 in the dK/dV pass; the plans at D 64 and 128 are what
+    they were."""
     plan = pallas_attention.kernel_plan(40, 8192, 8192, 256, jnp.bfloat16,
                                         True, kind=kind)
     assert (plan.heads, plan.chunk_q, plan.chunk_k, plan.grid) == (
         1, chunk, chunk, grid)
     assert (plan.tile_q, plan.tile_k, plan.tiles_visited) == (512, 512, 136)
     assert round(plan.vmem_bytes / 1e6, 1) == vmem_mb
-    assert plan.vmem_bytes <= pallas_attention.VMEM_BUDGET
+    fused = kind == "bwd"
+    assert plan.vmem_bytes <= (pallas_attention.BWD_VMEM_BUDGET if fused
+                               else pallas_attention.VMEM_BUDGET)
     narrow = pallas_attention.kernel_plan(64, 8192, 8192, 128, jnp.bfloat16,
                                           True, kind=kind)
     assert (narrow.heads, narrow.chunk_q, narrow.grid) == (
-        1, 4096, (64, 2, 2))
+        (1, 8192, (64, 1, 1, 1)) if fused else (1, 4096, (64, 2, 2)))
 
 
 def test_the_kernels_at_a_head_of_two_lane_tiles_match_their_xla_twins(

@@ -537,7 +537,7 @@ def _live_tiles(Tq, Tk, tq, tk, window):
     return int(keep.reshape(Tq // tq, tq, Tk // tk, tk).any(axis=(1, 3)).sum())
 
 
-@pytest.mark.parametrize("kind", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("kind", ["fwd", "bwd", "dq", "dkv"])
 @pytest.mark.parametrize("BH,T,D,window,heads", [
     (768, 128, 64, None, 16),      # gpt2s-t128
     (96, 1024, 64, None, 2),       # gpt2s-t1024
@@ -553,8 +553,9 @@ def test_kernel_plan_at_cell_shapes(kind, BH, T, D, window, heads):
     assert plan.heads == heads
     # The whole sequence is resident at all three cells' shapes.
     assert (plan.chunk_q, plan.chunk_k) == (T, T)
-    assert plan.grid == (BH // heads, 1, 1)
+    assert plan.grid == (BH // heads,) + (1,) * (3 if kind == "bwd" else 2)
     assert T % plan.tile_q == 0 and T % plan.tile_k == 0
+    # The fused backward within the budget of the passes it replaces.
     assert plan.vmem_bytes <= pa.VMEM_BUDGET
     # Exactly the tiles the mask leaves, with segment ids too.
     assert plan.tiles_visited == _live_tiles(T, T, plan.tile_q, plan.tile_k,
@@ -587,7 +588,7 @@ def test_kernel_plan_long_sequence_keeps_chunk_grid(monkeypatch):
                                                   short.tile_k, None)
 
 
-@pytest.mark.parametrize("kind", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("kind", ["fwd", "bwd", "dq", "dkv"])
 @pytest.mark.parametrize("segments", [False, True])
 @pytest.mark.parametrize("BH,T,D,dtype", [
     (8, 2048, 256, jnp.bfloat16),
@@ -605,10 +606,12 @@ def test_kernel_plan_holds_its_budget_or_declines(kind, segments, BH, T, D,
 
     plan = pa.kernel_plan(BH, T, T, D, dtype, True, segments=segments,
                           kind=kind)
-    if D == 8192:
+    if D == 8192 or (kind == "bwd" and T * D >= 8192 * 512):
+        # The second: float32 dK and dV of 8,192 rows of 512 are 96 MiB.
         assert plan is None
     else:
-        assert plan.vmem_bytes <= pa.VMEM_BUDGET
+        assert plan.vmem_bytes <= (pa.BWD_VMEM_BUDGET if kind == "bwd"
+                                   else pa.VMEM_BUDGET)
         assert T % plan.chunk_q == 0 and plan.chunk_q % plan.tile_q == 0
 
 
@@ -688,6 +691,13 @@ def test_grouped_kv_matches_expand_then_attend(monkeypatch, g, layout, mask):
         assert plan.grid == (
             (B * H // g // plan.kv_heads, n_c, plan.passes * n_c)
             if kind == "dkv" else (B * H // plan.heads, n_c, n_c)), kind
+    # The backward that runs is the fused one: the dK/dV pass's step, the
+    # group's passes and the chunks each a dimension of their own.
+    fused = pa.kernel_plan(B * H, T, T, D, q.dtype, True, window,
+                           segments=mask == "segments", kind="bwd", group=g)
+    assert (fused.heads, fused.kv_heads, fused.passes) == want(g)
+    assert fused.grid == (B * H // g // fused.kv_heads, fused.passes,
+                          T // fused.chunk_q, T // fused.chunk_k)
     expand = lambda x: jnp.repeat(x, g, axis=2)                     # noqa
     fold = lambda x: x.reshape(B, T, H // g, g, D).sum(3)           # noqa
     tol, gtol = _TOL[jnp.float32]["fwd"], _TOL[jnp.float32]["grad"]
@@ -807,6 +817,14 @@ def test_kernel_plan_at_the_grouped_cell_shapes(BH, D, g, window):
             group=g,
             grid=(BH // g, 2, 2 * g) if kind == "dkv" else (BH, 2, 2))
         assert (plan.heads, plan.kv_heads, plan.passes) == (1, 1, g)
+    # The fused backward holds a K/V head's 8,192 rows of dK and dV, so
+    # the whole sequence is one chunk; the group's heads take turns.
+    fused = pa.kernel_plan(BH, 8192, 8192, D, jnp.bfloat16, True, window,
+                           kind="bwd", group=g)
+    assert fused.grid == (BH // g, g, 1, 1) and fused.chunk_q == 8192
+    assert (fused.heads, fused.tile_q, fused.tiles_visited) == (
+        1, 512, plan.tiles_visited)
+    assert fused.vmem_bytes == 65011712 <= pa.BWD_VMEM_BUDGET
 
 
 def test_grouped_calls_are_counted_beside_the_traced():
@@ -819,8 +837,130 @@ def test_grouped_calls_are_counted_beside_the_traced():
         _fwd_and_grads(q, k, v, True)
         return metrics.counters()
 
-    traced = {f"kernels.traced.flash_{kind}": 1
-              for kind in ("fwd", "dq", "dkv")}
+    traced = {f"kernels.traced.flash_{kind}": 1 for kind in ("fwd", "bwd")}
     assert counted(1) == traced
     assert counted(4) == dict(traced, **{
-        f"kernels.grouped.flash_{kind}": 1 for kind in ("fwd", "dq", "dkv")})
+        f"kernels.grouped.flash_{kind}": 1 for kind in ("fwd", "bwd")})
+
+
+# ---------------------------------------------------------------------------
+# The fused backward: dQ, dK and dV from one walk.
+# ---------------------------------------------------------------------------
+
+
+# layout -> (q_off, k_off, out_dtype, chunked): what the library's three
+# callers ask of the backward.
+_BWD_LAYOUTS = {"one-chunk": (0, 0, None, False),
+                "chunked": (0, 0, None, True),
+                "ring-block": (128, 64, jnp.float32, True)}
+
+
+@pytest.mark.parametrize("segments", [False, True], ids=["", "segments"])
+@pytest.mark.parametrize("window", [None, 100])
+@pytest.mark.parametrize("layout", list(_BWD_LAYOUTS))
+@pytest.mark.parametrize("g", [1, 4, 8])
+def test_fused_backward_matches_the_two_passes_and_xla(monkeypatch, g, layout,
+                                                       window, segments):
+    """``flash_bwd`` against ``flash_dq`` + ``flash_dkv`` and against the
+    XLA twin on the same merged operands, lse and delta: one resident
+    chunk, chunks forced by a small budget (the group's heads and the Q
+    chunks on the sequential dimensions), and a ring block (offsets,
+    float32 gradients)."""
+    from horovod_tpu.common import metrics
+    from horovod_tpu.ops import pallas_attention as pa
+
+    q_off, k_off, out_dtype, chunked = _BWD_LAYOUTS[layout]
+    # Two heads a step share a K/V head where there is a group; with one,
+    # a budget a byte short of the whole sequence's halves the chunk.
+    heads = 1 if chunked else 2
+    _force_plan(monkeypatch, tile_cap=32, heads_cap=heads)
+    B, T, H, D = 1, 256, 8, 16
+    rng = np.random.RandomState(5 + g)
+    mk = lambda h: jnp.asarray(rng.randn(B * h, T, D), jnp.float32)  # noqa
+    q, k, v, do = mk(H), mk(H // g), mk(H // g), mk(H)
+    offs = jnp.asarray([q_off, k_off], jnp.int32)
+    q_seg = k_seg = None
+    if segments:
+        ids = np.searchsorted([int(0.3 * T), int(0.7 * T)], np.arange(T),
+                              side="right")
+        q_seg = jnp.asarray(np.tile(ids, (B * H, 1)), jnp.int32)
+        k_seg = jnp.asarray(np.tile(ids, (B * H // g, 1)), jnp.int32)
+    kw = dict(q_seg=q_seg, k_seg=k_seg, window=window)
+
+    def plan_of(kind):
+        return pa.kernel_plan(B * H, T, T, D, q.dtype, True, window,
+                              segments=segments, kind=kind, group=g,
+                              out_dtype=out_dtype)
+
+    if chunked:
+        monkeypatch.setattr(pa, "BWD_VMEM_BUDGET",
+                            plan_of("bwd").vmem_bytes - 1)
+        monkeypatch.setattr(pa, "VMEM_BUDGET", plan_of("dq").vmem_bytes - 1)
+    plan = plan_of("bwd")
+    n_c = 2 if chunked else 1
+    assert plan.grid == (B * H // g // plan.kv_heads, g // plan.shared,
+                         n_c, n_c)
+    assert (plan.heads, plan.chunk_q, plan.tile_q) == (heads, T // n_c, 32)
+    assert plan.vmem_bytes <= pa.BWD_VMEM_BUDGET
+    assert plan_of("dkv").chunk_q == T // n_c
+
+    o, lse = pa._flash_forward(q, k, v, offs, True, True, "train", **kw)
+    delta = jnp.sum(do * o, axis=-1, keepdims=True)
+    args = (q, k, v, do, lse, delta, offs, True)
+    metrics.reset()
+    fused = pa._pallas_bwd(*args, True, out_dtype=out_dtype, **kw)
+    assert metrics.counters() == dict(
+        {"kernels.traced.flash_bwd": 1},
+        **({"kernels.grouped.flash_bwd": 1} if g > 1 else {}))
+    with monkeypatch.context() as m:
+        # A budget of nothing leaves ``_pallas_bwd`` the two passes.
+        m.setattr(pa, "BWD_VMEM_BUDGET", 0)
+        metrics.reset()
+        two = pa._pallas_bwd(*args, True, out_dtype=out_dtype, **kw)
+        assert sorted(n for n in metrics.counters() if "traced" in n) == [
+            "kernels.traced.flash_dkv", "kernels.traced.flash_dq"]
+    twin = pa._xla_block_grads(*args, out_dtype=out_dtype, **kw)
+    for name, a, b, c in zip(("dq", "dk", "dv"), fused, two, twin):
+        assert a.shape == b.shape == c.shape and a.dtype == b.dtype, name
+        assert np.abs(np.asarray(a)).max() > 0, name
+        # The same products added in the same order.
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   err_msg=name, rtol=2e-4, atol=2e-5)
+
+
+def test_fused_backward_declines_by_shape(monkeypatch):
+    """No fused plan where a K/V head's dK and dV do not fit: the two
+    passes run, by ``kernel_plan``'s word alone."""
+    from horovod_tpu.common import metrics
+    from horovod_tpu.ops import pallas_attention as pa
+
+    # The roadmap's longest sequences (R4, R5): a head's dK and dV alone
+    # are over the budget; the two passes have plans. T 32,768 at D 128
+    # is the longest the fused kernel takes, at chunks of 2,048.
+    long = pa.kernel_plan(8, 32768, 32768, 128, jnp.bfloat16, True,
+                          kind="bwd")
+    assert long.grid == (8, 1, 16, 16)
+    assert long.vmem_bytes == pa.BWD_VMEM_BUDGET == 80 << 20
+    for T, D in ((65536, 128), (131072, 128), (32768, 256)):
+        assert pa.kernel_plan(8, T, T, D, jnp.bfloat16, True,
+                              kind="bwd") is None
+        for kind in ("dq", "dkv"):
+            assert pa.kernel_plan(8, T, T, D, jnp.bfloat16, True,
+                                  kind=kind) is not None
+    # The same at a size the interpreter runs: T 512 at a budget that
+    # holds the two passes' chunk of 128 and not the head's 512 rows.
+    _force_plan(monkeypatch, tile_cap=32, heads_cap=1)
+    q, k, v = _qkv(B=1, T=512, H=2, D=16, seed=9)
+    one = pa.kernel_plan(2, 128, 128, 16, jnp.float32, True, kind="bwd")
+    monkeypatch.setattr(pa, "BWD_VMEM_BUDGET", one.vmem_bytes)
+    monkeypatch.setattr(pa, "VMEM_BUDGET", one.vmem_bytes)
+    assert pa.kernel_plan(2, 512, 512, 16, jnp.float32, True,
+                          kind="bwd") is None
+    assert pa.kernel_plan(2, 512, 512, 16, jnp.float32, True,
+                          kind="dkv").grid == (2, 4, 4)
+    metrics.reset()
+    _assert_matches_xla(q, k, v, jnp.float32)
+    assert metrics.counters() == {f"kernels.traced.flash_{kind}": 1
+                                  for kind in ("fwd", "dq", "dkv")}

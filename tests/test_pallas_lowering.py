@@ -43,9 +43,7 @@ def test_flash_attention_fwd_lowers_to_mosaic(mosaic):
 
 
 def test_flash_attention_bwd_lowers_to_mosaic(mosaic):
-    """The backward kernels (dQ and dK/dV) are newer than the forward and
-    have never run on hardware — their Mosaic lowering is the one most
-    worth guarding."""
+    """The backward kernel's Mosaic lowering, beside the forward's."""
     q, k, v = _qkv()
 
     def loss(q, k, v):
@@ -53,8 +51,24 @@ def test_flash_attention_bwd_lowers_to_mosaic(mosaic):
             q, k, v, causal=True).astype(jnp.float32).sum()
 
     txt = _export_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
-    # Forward (rematerialized for residuals) + dq + dkv custom calls.
-    assert txt.count("tpu_custom_call") >= 2
+    # Forward (rematerialized for residuals) + the fused backward.
+    assert txt.count("tpu_custom_call") == 2 and "flash_bwd" in txt
+
+
+def test_flash_attention_two_pass_bwd_lowers_to_mosaic(mosaic, monkeypatch):
+    """The fall-back for a sequence whose dK and dV do not fit the fused
+    kernel's budget: the two passes lower too."""
+    monkeypatch.setattr(pa, "BWD_VMEM_BUDGET", 0)
+    q, k, v = _qkv()
+
+    def loss(q, k, v):
+        return pa.flash_attention(
+            q, k, v, causal=True).astype(jnp.float32).sum()
+
+    txt = _export_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+    assert txt.count("tpu_custom_call") == 3
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert name in txt
 
 
 def test_ring_attention_block_kernels_lower_to_mosaic(mosaic):
@@ -83,7 +97,7 @@ def test_ring_attention_block_kernels_lower_to_mosaic(mosaic):
 def test_segment_id_kernels_lower_to_mosaic(mosaic):
     """The segment-tiled variants (packed sequences) must lower too —
     they stream (1, block) int32 id tiles next to the Q/K/V tiles, a
-    layout Mosaic has to accept in forward AND both backward kernels."""
+    layout Mosaic has to accept in the forward AND the backward kernel."""
     q, k, v = _qkv()
     B, T = q.shape[:2]
     seg = jnp.zeros((B, T), jnp.int32)
@@ -115,31 +129,37 @@ def test_segment_id_kernels_lower_with_small_blocks(mosaic):
     assert txt.count("tpu_custom_call") >= 2
 
 
-# The benchmark's three decoder cells, batch cut: (B, T, H, D).
-_CELL_SHAPES = {"gpt2s-t128": (4, 128, 12, 64),
-                "gpt2s-t1024": (1, 1024, 12, 64),
-                "olmoe-t4096": (1, 4096, 16, 128)}
+# The benchmark's six decoder cells, batch cut: (B, T, H, Hkv, D, window).
+_CELL_SHAPES = {"gpt2s-t128": (4, 128, 12, 12, 64, None),
+                "gpt2s-t1024": (1, 1024, 12, 12, 64, None),
+                "olmoe-t4096": (1, 4096, 16, 16, 128, None),
+                "granite-h-t8192": (1, 8192, 8, 2, 64, None),
+                "trinity-mini-t8192": (1, 8192, 8, 1, 128, 2048),
+                "glm-4.7-flash-t8192": (1, 8192, 2, 2, 256, None)}
 
 
 @pytest.mark.parametrize("segments", [False, True])
 @pytest.mark.parametrize("cell", sorted(_CELL_SHAPES))
 def test_cell_shapes_lower_to_mosaic(mosaic, cell, segments):
     """The grid step ``kernel_plan`` picks at each cell's shape (several
-    heads a step at T 128, the whole sequence resident at T 4,096, the
-    in-kernel walk with its run-time bounds) lowers in all three passes: a
-    step Mosaic refuses fails here, off the chip."""
-    B, T, H, D = _CELL_SHAPES[cell]
+    heads a step at T 128, the whole sequence resident at T 4,096, chunks
+    and a group's heads on the sequential dimensions at T 8,192, the
+    in-kernel walk with its run-time bounds) lowers in the forward and in
+    the fused backward: a step Mosaic refuses fails here, off the
+    chip."""
+    B, T, H, Hkv, D, window = _CELL_SHAPES[cell]
     q, k, v = _qkv(B=B, T=T, H=H, D=D)
+    k, v = k[:, :, :Hkv], v[:, :, :Hkv]
     seg = jnp.zeros((B, T), jnp.int32) if segments else None
 
     def loss(q, k, v):
         return pa.flash_attention(
-            q, k, v, causal=True, q_segment_ids=seg,
-            k_segment_ids=seg).astype(jnp.float32).sum()
+            q, k, v, causal=True, q_segment_ids=seg, k_segment_ids=seg,
+            window=window).astype(jnp.float32).sum()
 
     txt = _export_tpu(loss, q, k, v)
     assert txt.count("tpu_custom_call") == 1 and "flash_fwd" in txt
     txt = _export_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
-    assert txt.count("tpu_custom_call") == 3
-    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
-        assert name in txt
+    assert txt.count("tpu_custom_call") == 2
+    assert "flash_fwd" in txt and "flash_bwd" in txt
+    assert "flash_dq" not in txt and "flash_dkv" not in txt

@@ -182,6 +182,16 @@ def test_the_scopes_change_nothing_but_names(hvd, monkeypatch, kind):
 _Q = jax.ShapeDtypeStruct((2, 16, 8), jnp.float32)
 _ROW = jax.ShapeDtypeStruct((2, 16, 1), jnp.float32)
 _OFFS = jax.ShapeDtypeStruct((2,), jnp.int32)
+def _two_passes(*args, **kw):
+    """``_pallas_bwd`` where the fused kernel has no plan: a budget of
+    nothing, as a sequence too long for it finds."""
+    budget, pa.BWD_VMEM_BUDGET = pa.BWD_VMEM_BUDGET, 0
+    try:
+        return pa._pallas_bwd(*args, **kw)
+    finally:
+        pa.BWD_VMEM_BUDGET = budget
+
+
 PALLAS_SITES = {
     "block_state": (
         lambda q, offs: pa._flash_forward(q, q, q, offs, True, True,
@@ -198,12 +208,16 @@ PALLAS_SITES = {
     "backward": (
         lambda q, row, offs: pa._pallas_bwd(q, q, q, q, row, row, offs,
                                             True, True),
-        (_Q, _ROW, _OFFS), ["flash_dq", "flash_dkv"]),
+        (_Q, _ROW, _OFFS), ["flash_bwd"]),
     "segmented_backward": (
         lambda q, row, offs: pa._pallas_bwd(
             q, q, q, q, row, row, offs, True, True,
             q_seg=jnp.zeros((2, 16), jnp.int32),
             k_seg=jnp.zeros((2, 16), jnp.int32)),
+        (_Q, _ROW, _OFFS), ["flash_bwd"]),
+    "two_pass_backward": (
+        lambda q, row, offs: _two_passes(q, q, q, q, row, row, offs,
+                                         True, True),
         (_Q, _ROW, _OFFS), ["flash_dq", "flash_dkv"]),
 }
 

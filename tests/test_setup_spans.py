@@ -428,8 +428,10 @@ def test_import_spans_exist_for_the_packages_that_take_time():
 
 @pytest.mark.parametrize("kernel, counters", [
     ("forward", {"kernels.traced.flash_fwd": 1}),
-    ("backward", {"kernels.traced.flash_dq": 1,
-                  "kernels.traced.flash_dkv": 1}),
+    ("backward", {"kernels.traced.flash_bwd": 1}),
+    # A sequence too long for the fused kernel (here: no budget for it).
+    ("two-pass backward", {"kernels.traced.flash_dq": 1,
+                           "kernels.traced.flash_dkv": 1}),
 ])
 def test_the_flash_kernels_count_their_traced_calls(monkeypatch, kernel,
                                                     counters):
@@ -443,6 +445,8 @@ def test_the_flash_kernels_count_their_traced_calls(monkeypatch, kernel,
         jaxpr = jax.make_jaxpr(lambda q, o: pa._flash_forward(
             q, q, q, o, True, True, "train"))(q, offs)
     else:
+        if kernel == "two-pass backward":
+            monkeypatch.setattr(pa, "BWD_VMEM_BUDGET", 0)
         jaxpr = jax.make_jaxpr(lambda q, r, o: pa._pallas_bwd(
             q, q, q, q, r, r, o, True, True))(q, row, offs)
     calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]

@@ -8,6 +8,13 @@ interpreted numerics: it needs the chip (no accelerator is a non-zero
 exit). Prints the device line, then one JSON line per (seq_len, phase).
 
 Usage: python tools/pallas_bench.py [--seq-lens 2048,4096] [--iters 20]
+       python tools/pallas_bench.py --kind bwd --seq-lens 8192 --batch 2 \
+           --heads 32 --kv-heads 4 --dim 128 [--window 2048]
+
+``--kind bwd`` times the backward alone at a shape: the fused kernel
+``flash_bwd`` beside the two passes ``flash_dq`` + ``flash_dkv`` on the
+same operands, with ``kernel_plan``'s plan of each and the largest
+difference between their gradients.
 """
 
 import argparse
@@ -145,6 +152,68 @@ def sweep_blocks(T, iters, batch, heads, dim):
         pa._TILE_CAP = cap
 
 
+def bench_bwd(T, iters, batch, heads, kv_heads, dim, window=None):
+    """The backward on merged operands, the fused kernel beside the two
+    passes (which a budget of nothing leaves ``_pallas_bwd``), lse and
+    delta from the forward kernel: one JSON-ready row."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import horovod_tpu.ops.pallas_attention as pa
+
+    rng = np.random.RandomState(0)
+    mk = lambda h: jnp.asarray(                                # noqa: E731
+        rng.randn(batch * h, T, dim), jnp.bfloat16)
+    q, k, v, do = mk(heads), mk(kv_heads), mk(kv_heads), mk(heads)
+    offs = jnp.zeros((2,), jnp.int32)
+
+    @jax.jit
+    def residuals(q, k, v, do):
+        o, lse = pa._flash_forward(q, k, v, offs, True, False, "train",
+                                   window=window)
+        return lse, jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                            axis=-1, keepdims=True)
+
+    lse, delta = residuals(q, k, v, do)
+    group = heads // kv_heads
+    row = {"seq_len": T, "phase": "bwd_kernels", "batch": batch,
+           "heads": heads, "kv_heads": kv_heads, "head_dim": dim,
+           "window": window, "bwd_vmem_budget": pa.BWD_VMEM_BUDGET}
+    budget, grads = pa.BWD_VMEM_BUDGET, {}
+    try:
+        for name, kinds in (("fused", ("bwd",)), ("two_pass", ("dq", "dkv"))):
+            if name == "two_pass":
+                pa.BWD_VMEM_BUDGET = 0
+            plans = {kind: pa.kernel_plan(
+                batch * heads, T, T, dim, q.dtype, True, window, kind=kind,
+                group=group) for kind in kinds}
+            row[name + "_plan"] = {
+                kind: plan and {"heads": plan.heads, "chunk": plan.chunk_q,
+                                "tile": plan.tile_q, "grid": plan.grid,
+                                "vmem": plan.vmem_bytes}
+                for kind, plan in plans.items()}
+            if None in plans.values():
+                continue
+            fn = jax.jit(lambda *a: pa._pallas_bwd(
+                *a, offs, True, False, window=window))
+            try:
+                grads[name] = fn(q, k, v, do, lse, delta)
+                row[name + "_ms"] = round(
+                    _clock(fn, iters, q, k, v, do, lse, delta), 3)
+            except Exception as e:  # VMEM overflow etc.: report, go on
+                row[name + "_error"] = str(e)[-300:]
+    finally:
+        pa.BWD_VMEM_BUDGET = budget
+    if len(grads) == 2:
+        row["speedup"] = round(row["two_pass_ms"] / row["fused_ms"], 3)
+        row["maxdiff_fused_vs_two_pass"] = max(
+            float(jnp.max(jnp.abs(a.astype(jnp.float32) -
+                                  b.astype(jnp.float32))))
+            for a, b in zip(grads["fused"], grads["two_pass"]))
+    return row
+
+
 def main(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--seq-lens", default="2048,4096")
@@ -152,6 +221,14 @@ def main(argv=None):
     p.add_argument("--batch", type=int, default=4)
     p.add_argument("--heads", type=int, default=8)
     p.add_argument("--dim", type=int, default=128)
+    p.add_argument("--kind", choices=["all", "bwd"], default="all",
+                   help="bwd: the fused backward beside the two passes, "
+                        "kernels alone")
+    p.add_argument("--kv-heads", type=int, default=None,
+                   help="K/V heads (--kind bwd; default: --heads)")
+    p.add_argument("--bwd-budgets", default=None,
+                   help="--kind bwd: MiB of VMEM the fused plan may "
+                        "count, one row each (default: the module's)")
     p.add_argument("--window", type=int, default=None,
                    help="sliding-window width: measures the whole-tile "
                         "culling speedup vs the XLA masked path")
@@ -167,7 +244,18 @@ def main(argv=None):
     _, device, _ = claim_devices(1)  # no TPU: exit 3, nothing timed
     print(json.dumps(device))
     for T in [int(t) for t in args.seq_lens.split(",")]:
-        if args.sweep_blocks:
+        if args.kind == "bwd":
+            import horovod_tpu.ops.pallas_attention as pa
+
+            budget = pa.BWD_VMEM_BUDGET
+            for mib in (args.bwd_budgets or str(budget >> 20)).split(","):
+                pa.BWD_VMEM_BUDGET = int(mib) << 20
+                print(json.dumps(bench_bwd(
+                    T, args.iters, args.batch, args.heads,
+                    args.kv_heads or args.heads, args.dim, args.window)))
+                sys.stdout.flush()
+            pa.BWD_VMEM_BUDGET = budget
+        elif args.sweep_blocks:
             sweep_blocks(T, args.iters, args.batch, args.heads, args.dim)
         else:
             rows, _ = bench_one(T, args.iters, args.batch, args.heads,

@@ -92,6 +92,20 @@ GLM_LITE = dict(vocab=4096, d_model=256, n_heads=2, d_head=256, d_ff=512,
                 n_mtp_modules=1, remat=True,
                 remat_keeps=("flash_out", "flash_lse", "mla_cq", "mla_ckv"),
                 dtype=jnp.bfloat16)
+# The `zaya1-8b-t16384` cell's block at a tiny size: CCA mixers at the
+# cell's head width over grouped key/value heads, the MLP router whose
+# state crosses layers over 8 experts of which 4 are held, one a token,
+# learned residual scales, the balancing bias, and the tied head with the
+# loss by blocks of tokens (one that does not divide them).
+ZAYA = dict(vocab=4096, d_model=256, n_heads=4, n_kv_heads=2, d_head=128,
+            n_layers=3, max_seq=1024, layer_types=("cca",) * 3,
+            partial_rotary_factor=0.5, rope_theta=5e6, pos_table=False,
+            use_moe=True, n_experts=8, n_experts_held=4, d_expert=256,
+            moe_top_k=1, router_hidden=64, expert_bias_rate=1e-3,
+            residual_scales=True, tie_embeddings=True, head_block=768,
+            norm="rmsnorm", remat=True,
+            remat_keeps=("flash_out", "flash_lse", "cca_q", "cca_kv"),
+            dtype=jnp.bfloat16)
 # Gradient bucket cap for the four-chip data-parallel step: ResNet-50's
 # 102 MB of fp32 gradients in four buckets.
 BUCKET_CAP_BYTES = 32 << 20
@@ -448,6 +462,30 @@ def phase_decoder(name, cfg, batch, steps, devices, sp=1, tp=1):
     return losses
 
 
+def phase_decoder_zaya(cfg, batch, steps, devices, tol=2e-3):
+    """``phase_decoder`` on the ZAYA block, and the program against the
+    plain float32 reference (``benchmark/reference_zaya.py``) on the same
+    seeded weights and tokens: the first step's loss, relative."""
+    from benchmark import reference_zaya
+
+    losses = phase_decoder("decoder-zaya", cfg, batch, steps, devices)
+    tokens = np.random.RandomState(0).randint(
+        0, cfg.vocab, (batch, cfg.max_seq)).astype(np.int32)
+    model = dict(num_hidden_layers=cfg.n_layers, rms_norm_eps=cfg.norm_eps,
+                 rope_theta=cfg.rope_theta,
+                 rotated=int(cfg.partial_rotary_factor * cfg.d_head),
+                 first_expert_held=cfg.first_expert_held)
+    want = float(reference_zaya.step_readings(
+        init_params(cfg, jax.random.PRNGKey(0), n_stages=1), tokens,
+        np.roll(tokens, -1, axis=1), model)["loss"])
+    err = abs(losses[0] - want) / want
+    say("decoder-zaya", f"first loss {losses[0]:.6f} against the float32 "
+                        f"reference's {want:.6f}: {err:.2e} (tol {tol:g})")
+    check(err <= tol, f"the ZAYA block's first loss is {err:.2e} from the "
+                      f"reference's, over {tol:g}")
+    return losses
+
+
 def dense_attention(q, k, v, window=None, seg=None):
     """Causal softmax attention in plain float32 ``jax.numpy``, the
     reference the kernels are held to (the same math as
@@ -570,6 +608,8 @@ def one_chip_phases():
         ("decoder-glm-lite", lambda: phase_decoder(
             "decoder-glm-lite", TransformerConfig(**GLM_LITE), 2, 3,
             jax.devices())),
+        ("decoder-zaya", lambda: phase_decoder_zaya(
+            TransformerConfig(**ZAYA), 2, 3, jax.devices())),
         ("kernels", lambda: phase_kernels(KERNEL_CASES)),
     ]
 

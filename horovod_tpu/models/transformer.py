@@ -23,10 +23,11 @@ layer's mixer: ``"attention"`` (softmax attention, above, with the
 model's one ``attention_window`` and ``rope`` switch), ``"mamba"`` (a
 Mamba-2 state-space mixer, ``_mamba_mixer``), ``"sliding_attention"``
 (attention over ``sliding_window`` tokens, rotated),
-``"full_attention"`` (causal attention over everything, no positions) or
+``"full_attention"`` (causal attention over everything, no positions),
 ``"latent_attention"`` (multi-head latent attention, ``_latent_mixer``,
-with leaves of its own); the three attention kinds share one set of
-leaves. Each layer ends in a
+with leaves of its own) or ``"cca"`` (compressed convolutional attention,
+``_cca_mixer``, with leaves of its own); the three attention kinds share
+one set of leaves. Each layer ends in a
 feed-forward block that is data too: the dense MLP, or with ``use_moe``
 the expert layer in all but the ``num_dense_layers`` leading layers.
 Parameters are stacked per group (the attention mixers, the Mamba mixers,
@@ -86,6 +87,42 @@ module's leaves are ``mtp_hnorm``, ``mtp_enorm``, ``mtp_eh``,
 ``mtp_final_ln`` and its layer's, each the stack's name after ``mtp_``,
 with the modules (one) as their leading dimension.
 
+Compressed convolutional attention (CCA, arXiv:2510.04476 section 3) on
+normed ``h`` [T, d], ``Hq`` query heads over ``Hkv`` key/value heads of
+``Dh`` channels, group ``g = Hq / Hkv``, no biases; the whole attention is
+inside the compressed space ``Hq Dh`` < d::
+
+    q~ = h W_q [T, Hq, Dh];  k~ = h W_k [T, Hkv, Dh]
+    v  = h W_v [T, Hkv, Dh], the upper half of its heads taken from the
+         token before (zero at t = 0): the values' shift
+    conv0 (width cca_time0): causal, depthwise: y_t[c] = sum_j w0[j, c] x_{t-(K0-1)+j}[c]
+    conv1 (width cca_time1): causal, by head:   y_t[i] = sum_j x_{t-(K1-1)+j}[i] W1[j, i]
+    q^ = conv1(conv0(q~)),  k^ = conv1(conv0(k~))          own filters each
+    q = q^ + (q~ + repeat_g(k~)) / 2;  k = k^ + (mean_g(q~) + k~) / 2
+    q = q * rsqrt(mean(q^2) + eps);  k = beta_j k * rsqrt(mean(k^2) + eps)
+    rotate the first ``partial_rotary_factor`` of a head's channels of q, k
+    out = concat_i softmax_causal(q_i k_{i // g}^T / sqrt(Dh)) v_{i // g}  W_o
+
+The norm (``sqrt(Dh) x / |x|`` with ``eps`` under the root: ``_rmsnorm``
+over a head's channels) and the temperature ``beta`` [Hkv] are float32.
+Queries, keys and values enter the flash kernels at their own head
+counts. Leaves ``c_*``; heads over ``tp``.
+
+The ZAYA router (arXiv:2511.17127) of an expert layer ``l`` with
+``router_hidden`` = R > 0, on the block's normed ``h``, float32 throughout::
+
+    r_l = h W_down + gamma_l r_{l-1}        [T, R]; r_{-1} = 0; on to layer l + 1
+    s = gelu(gelu(rms(r_l) W_1) W_2) W_3    [T, E]
+    p = softmax(s);  picks = top_k(p + bias);  weights = p[picks]
+
+``r_l`` rides the layer scan's carry beside the activations. With
+``residual_scales`` a block joins the stream as ``a * x + b + c * block(
+norm(x))`` with learned float32 ``a``, ``b``, ``c`` [d] (one, zero, one at
+the start), leaves ``res1`` and ``res2`` [3, d].
+
+With ``head_block`` the head and the loss run by blocks of that many
+tokens (``block_nll``): no [b, t, V] array exists in either pass.
+
 Pure-jax pytree params (no flax) so shard_map in_specs map 1:1 onto leaves.
 """
 
@@ -113,7 +150,7 @@ with _metrics.span("import:horovod_tpu.models.transformer"):
 
 
 LAYER_KINDS = ("attention", "mamba", "sliding_attention", "full_attention",
-               "latent_attention")
+               "latent_attention", "cca")
 # What a layer names with ``checkpoint_name``, so that a rematerialized
 # layer can keep it (``TransformerConfig.remat_keeps``): the Mamba
 # in-projection's z and x and the scan's output; an attention mixer's Q,
@@ -121,10 +158,13 @@ LAYER_KINDS = ("attention", "mamba", "sliding_attention", "full_attention",
 # output and row statistics (ops/pallas_attention.py); the gate-up product
 # of a gated MLP or shared expert; a latent mixer's two down-projections
 # (the query latent; the key/value latent with the shared rotated key),
-# its up-projections under the attention mixer's ``attn_q``, ``attn_kv``.
+# its up-projections under the attention mixer's ``attn_q``, ``attn_kv``;
+# a CCA mixer's two latents (the queries'; the keys' with the shifted
+# values) and its convolved queries and keys, what it hands the kernels
+# again under ``attn_q``, ``attn_kv``.
 REMAT_NAMES = ("mamba_zx", "ssd_out", "attn_q", "attn_kv", "attn_gate",
                "attn_proj", "flash_out", "flash_lse", "mlp_gu", "mla_cq",
-               "mla_ckv")
+               "mla_ckv", "cca_q", "cca_kv", "cca_conv")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -246,6 +286,22 @@ class TransformerConfig:
     kv_lora_rank: int = 0
     qk_rope_head_dim: int = 0
     qk_nope_head_dim: int = 0
+    # The CCA mixer (the module's docstring): the widths of its two
+    # causal convolutions over the sequence, and the share of a head's
+    # channels, from the first, that is rotated (with ``rope_theta``).
+    cca_time0: int = 2
+    cca_time1: int = 2
+    partial_rotary_factor: float = 1.0
+    # The expert layers' router is the ZAYA router's MLP over a state of
+    # this width that crosses layers (the module's docstring); 0: the
+    # linear router.
+    router_hidden: int = 0
+    # Learned scales and offsets where a block joins the residual stream
+    # (the module's docstring).
+    residual_scales: bool = False
+    # Tokens a block of the head and the loss (``block_nll``); None: the
+    # head's logits whole.
+    head_block: Optional[int] = None
     # Multi-token-prediction modules after the stack (0 or 1; the
     # module's docstring) and the weight of their cross-entropy.
     n_mtp_modules: int = 0
@@ -297,6 +353,8 @@ class TransformerConfig:
                                  "sliding_window and an even d_head")
             if "latent_attention" in kinds:
                 self._check_latent()
+            if "cca" in kinds:
+                self._check_cca()
         if self.n_mtp_modules not in (0, 1):
             raise ValueError(
                 f"n_mtp_modules must be 0 or 1, got {self.n_mtp_modules}: "
@@ -322,10 +380,24 @@ class TransformerConfig:
                 f"the held experts [{self.first_expert_held}, "
                 f"{self.first_expert_held} + {self.n_experts_held}) are "
                 f"not among the {self.n_experts} the router scores")
-        if self.expert_bias_rate and (not self.use_moe
-                                      or self.moe_score_func != "sigmoid"):
+        if self.expert_bias_rate and (
+                not self.use_moe or (self.moe_score_func != "sigmoid"
+                                     and not self.router_hidden)):
             raise ValueError("expert_bias_rate moves the selection bias of "
-                             "a sigmoid router (use_moe, moe_score_func)")
+                             "a sigmoid router or of the router's MLP "
+                             "(use_moe, moe_score_func, router_hidden)")
+        if self.router_hidden and (not self.use_moe or self.n_mtp_modules
+                                   or self.moe_score_func != "softmax"):
+            raise ValueError(
+                "router_hidden is the softmax MLP router of a use_moe "
+                "model's expert layers; its state through a "
+                "multi-token-prediction module is not built")
+        if self.head_block is not None and (self.head_block < 1
+                                            or self.n_mtp_modules):
+            raise ValueError(
+                "head_block counts the tokens of a block of the head and "
+                "the loss; a multi-token-prediction module's head by "
+                "blocks is not built")
         if self.remat_keeps is not None:
             keeps = tuple(self.remat_keeps)
             object.__setattr__(self, "remat_keeps", keeps)
@@ -354,6 +426,25 @@ class TransformerConfig:
                 "value and neither QK-norm, gate nor attention_multiplier: "
                 "n_kv_heads, qk_norm, attn_gate and attention_multiplier "
                 "are not built through it")
+
+    def _check_cca(self):
+        rotated = self.partial_rotary_factor * self.d_head
+        if (self.cca_time0 < 1 or self.cca_time1 < 1 or rotated % 2
+                or not 0 < rotated <= self.d_head):
+            raise ValueError(
+                "a cca layer needs convolution widths cca_time0, cca_time1 "
+                ">= 1 and an even count of rotated channels "
+                "(partial_rotary_factor x d_head)")
+        if self.kv_heads % 2:
+            raise ValueError(
+                f"a cca layer shifts the upper half of its value heads by "
+                f"one token: kv_heads ({self.kv_heads}) must be even")
+        if (self.qk_norm or self.attn_gate
+                or self.attention_multiplier is not None):
+            raise ValueError(
+                "a cca layer norms its queries and keys itself and has "
+                "neither gate nor attention_multiplier: qk_norm, attn_gate "
+                "and attention_multiplier are not built through it")
 
     @property
     def kv_heads(self) -> int:
@@ -425,7 +516,10 @@ _ATTENTION_LEAVES = ("wqkv", "wq", "wkv", "wo", "gq", "gk", "wgate")
 _ATTENDING = ("attention", "sliding_attention", "full_attention")
 _MLP_LEAVES = ("w1", "w2", "wgu")
 _ROUTED_LEAVES = ("router", "wg", "wu", "wd", "expert_bias")
-_MOE_LEAVES = _ROUTED_LEAVES + ("shared_wgu", "shared_w2")
+# The ZAYA router's own (``router_hidden``), in place of ``router``.
+_ROUTER_MLP_LEAVES = ("r_down", "r_gamma", "r_norm", "r_w1", "r_w2", "r_w3")
+_MOE_LEAVES = (_ROUTED_LEAVES + ("shared_wgu", "shared_w2")
+               + _ROUTER_MLP_LEAVES)
 _MODEL_LEAVES = ("embed", "pos", "final_ln", "head")
 # A multi-token-prediction module's own leaves; its layer's are the
 # stack's names after the same prefix.
@@ -437,8 +531,8 @@ _STATE_LEAVES = ("expert_bias", "mtp_expert_bias")
 
 def _mixer_group(kind: str) -> str:
     """The group of stacks a mixer of ``kind`` reads."""
-    return {"mamba": "mamba", "latent_attention": "latent"}.get(
-        kind, "attention")
+    return {"mamba": "mamba", "latent_attention": "latent",
+            "cca": "cca"}.get(kind, "attention")
 
 
 def _leaf_group(name: str) -> Optional[str]:
@@ -448,6 +542,8 @@ def _leaf_group(name: str) -> Optional[str]:
         return "mamba"
     if name.startswith("l_"):
         return "latent"
+    if name.startswith("c_"):
+        return "cca"
     for group, leaves in (("attention", _ATTENTION_LEAVES),
                           ("mlp", _MLP_LEAVES), ("moe", _MOE_LEAVES)):
         if name in leaves:
@@ -474,6 +570,9 @@ def _param_specs(cfg: TransformerConfig) -> Dict[str, P]:
     if cfg.post_norms:
         specs["ln1_post"] = P("pp")
         specs["ln2_post"] = P("pp")
+    if cfg.residual_scales:
+        specs["res1"] = P("pp")
+        specs["res2"] = P("pp")
     if not cfg.tie_embeddings:
         specs["head"] = P()
     if cfg.pos_table and not cfg.rope:
@@ -518,6 +617,15 @@ def _param_specs(cfg: TransformerConfig) -> Dict[str, P]:
             "l_wkvb": P("pp", None, None, "tp"),
             "l_wo": P("pp", None, "tp"),
         })
+    if "cca" in cfg.kinds:
+        # Heads over tp, the filters and the temperature with them.
+        heads = P("pp", None, None, "tp")
+        specs.update({
+            "c_wq": heads, "c_wk": heads, "c_wv": heads,
+            "c_conv0_q": heads, "c_conv0_k": heads,
+            "c_conv1_q": heads, "c_conv1_k": heads,
+            "c_beta": P("pp", None, "tp"), "c_wo": P("pp", None, "tp"),
+        })
     if cfg.n_mtp_modules:
         # The layer's leaves with the modules where the stages were.
         for name, spec in _param_specs(cfg.mtp_layer).items():
@@ -525,8 +633,11 @@ def _param_specs(cfg: TransformerConfig) -> Dict[str, P]:
                 specs[_MTP + name] = P(*spec[1:])
         specs.update({name: P() for name in _MTP_LEAVES})
     if "moe" in cfg.ffn_kinds:
+        if cfg.router_hidden:
+            specs.update({name: P("pp") for name in _ROUTER_MLP_LEAVES})
+        else:
+            specs["router"] = P("pp")
         specs.update({
-            "router": P("pp"),
             "wg": P("pp", None, "dp"),
             "wu": P("pp", None, "dp"),
             "wd": P("pp", None, "dp"),
@@ -576,11 +687,19 @@ def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
     if cfg.post_norms:
         params["ln1_post"] = jnp.ones((n_stages, lps, d), jnp.float32)
         params["ln2_post"] = jnp.ones((n_stages, lps, d), jnp.float32)
+    if cfg.residual_scales:  # a one, b zero, c one
+        start = jnp.array([1.0, 0.0, 1.0], jnp.float32)[:, None]
+        for name in ("res1", "res2"):
+            params[name] = jnp.broadcast_to(start, (n_stages, lps, 3, d))
     if not cfg.tie_embeddings:
         params["head"] = norm(ks[4], (d, cfg.vocab), d ** -0.5)
     if cfg.pos_table and not cfg.rope:
         params["pos"] = norm(ks[1], (cfg.max_seq, d), 0.02)
-    k_latent, k_mtp = (jax.random.fold_in(rng, salt) for salt in (1, 2))
+    k_latent, k_mtp, k_cca, k_router = (
+        jax.random.fold_in(rng, salt) for salt in (1, 2, 3, 4))
+    if stage.count("cca"):
+        params.update(_init_cca(cfg, k_cca, (n_stages, stage.count("cca")),
+                                norm))
     if cfg.n_mtp_modules:
         params.update(_init_mtp(cfg, k_mtp, norm))
     if stage.count("latent"):
@@ -614,9 +733,12 @@ def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
     if Le:
         E, Eh, Fe = cfg.n_experts, cfg.experts_held, cfg.d_expert
         Fs = cfg.n_shared_experts * Fe
+        if cfg.router_hidden:
+            params.update(_init_router_mlp(cfg, k_router, (n_stages, Le)))
+        else:
+            params["router"] = (jax.random.normal(
+                ks[5], (n_stages, Le, d, E)) * d ** -0.5)
         params.update({
-            "router": (jax.random.normal(ks[5], (n_stages, Le, d, E))
-                       * d ** -0.5),
             "wg": norm(ks[6], (n_stages, Le, Eh, d, Fe), d ** -0.5),
             "wu": norm(ks[9], (n_stages, Le, Eh, d, Fe), d ** -0.5),
             "wd": norm(ks[7], (n_stages, Le, Eh, Fe, d), Fe ** -0.5),
@@ -659,6 +781,49 @@ def _init_latent(cfg: TransformerConfig, rng, lead, norm) -> Dict:
         "l_kvnorm": jnp.ones(lead + (rkv,), jnp.float32),
         "l_wkvb": norm(ks[3], lead + (rkv, H, nope + Dh), rkv ** -0.5),
         "l_wo": norm(ks[4], lead + (H, Dh, d), (H * Dh) ** -0.5),
+    }
+
+
+def _init_cca(cfg: TransformerConfig, rng, lead, norm) -> Dict:
+    """The CCA mixers' leaves with leading shape ``lead``: matrices normal
+    at fan-in^-1/2, both convolutions' filters normal at (taps x fan-in)
+    ^-1/2 (the depthwise one has fan-in one), the temperature one
+    (float32)."""
+    d, H, Hkv, Dh = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.d_head
+    K0, K1 = cfg.cca_time0, cfg.cca_time1
+    ks = jax.random.split(rng, 8)
+    return {
+        "c_wq": norm(ks[0], lead + (d, H, Dh), d ** -0.5),
+        "c_wk": norm(ks[1], lead + (d, Hkv, Dh), d ** -0.5),
+        "c_wv": norm(ks[2], lead + (d, Hkv, Dh), d ** -0.5),
+        "c_conv0_q": norm(ks[3], lead + (K0, H, Dh), K0 ** -0.5),
+        "c_conv0_k": norm(ks[4], lead + (K0, Hkv, Dh), K0 ** -0.5),
+        "c_conv1_q": norm(ks[5], lead + (K1, H, Dh, Dh),
+                          (K1 * Dh) ** -0.5),
+        "c_conv1_k": norm(ks[6], lead + (K1, Hkv, Dh, Dh),
+                          (K1 * Dh) ** -0.5),
+        "c_beta": jnp.ones(lead + (Hkv,), jnp.float32),
+        "c_wo": norm(ks[7], lead + (H, Dh, d), (H * Dh) ** -0.5),
+    }
+
+
+def _init_router_mlp(cfg: TransformerConfig, rng, lead) -> Dict:
+    """The ZAYA routers' leaves with leading shape ``lead``, all float32:
+    matrices normal at fan-in^-1/2, the carried state's weight ``gamma``
+    and the norm's weight one."""
+    d, R, E = cfg.d_model, cfg.router_hidden, cfg.n_experts
+    ks = jax.random.split(rng, 4)
+
+    def normal(key, shape, fan_in):
+        return jax.random.normal(key, lead + shape) * fan_in ** -0.5
+
+    return {
+        "r_down": normal(ks[0], (d, R), d),
+        "r_gamma": jnp.ones(lead + (1,), jnp.float32),
+        "r_norm": jnp.ones(lead + (R,), jnp.float32),
+        "r_w1": normal(ks[1], (R, R), R),
+        "r_w2": normal(ks[2], (R, R), R),
+        "r_w3": normal(ks[3], (R, E), R),
     }
 
 
@@ -745,6 +910,16 @@ def _validate_mesh_divisibility(cfg: TransformerConfig, mesh) -> None:
                     f"or a multi-token-prediction module are not built: no "
                     f"test holds the shared rotated key or the module's "
                     f"shifted labels across them")
+    if "cca" in cfg.kinds or cfg.router_hidden:
+        for axis, what in (("sp", "sequence shards"),
+                           ("pp", "pipeline stages")):
+            if shape.get(axis, 1) > 1:
+                raise ValueError(
+                    f"{what} ({axis} > 1) through a cca layer or the "
+                    f"router's carried state are not built: the "
+                    f"convolutions' and the shifted values' last rows are "
+                    f"not handed to the next sp member, and no test holds "
+                    f"the router's state on the pipeline's ring")
     if cfg.expert_bias_rate and shape.get("sp", 1) > 1:
         raise ValueError(
             "the router's balancing bias is not built over sp > 1: no "
@@ -959,6 +1134,106 @@ def _latent_mixer(cfg: TransformerConfig, h, lp):
         jnp.einsum("bthk,hkd->btd", attn, lp["l_wo"]), "attn_proj")
 
 
+def _causal_conv_by_head(x, w):
+    """``y_t[i] = sum_j x_{t - (K - 1) + j}[i] W[j, i]`` along axis 1 of x
+    [b, T, h, k] with w [K, h, k, c]: each head its own [k, c] matrix a
+    tap, zeros before the sequence's first token. One matmul a head over
+    the taps' channels side by side (contraction K k), operands as they
+    are."""
+    K, T = w.shape[0], x.shape[1]
+    xp = jnp.pad(x, [(0, 0), (K - 1, 0), (0, 0), (0, 0)])
+    taps = jnp.concatenate([xp[:, j:j + T] for j in range(K)], -1)
+    return jnp.einsum("bthk,hkc->bthc", taps,
+                      w.transpose(1, 0, 2, 3).reshape(
+                          w.shape[1], K * w.shape[2], w.shape[3]))
+
+
+def _cca_mixer(cfg: TransformerConfig, h, lp):
+    """The CCA mixer of the module's docstring on normed h [b, t, d];
+    heads are this tp member's (whole groups), the result its partial
+    sum."""
+    b, t, _ = h.shape
+    pos = jnp.arange(t, dtype=jnp.int32)
+    rotated = int(cfg.partial_rotary_factor * cfg.d_head)
+    with jax.named_scope("cca_q"):
+        q0 = checkpoint_name(
+            jnp.einsum("btd,dhk->bthk", h, lp["c_wq"]), "cca_q")  # h=H/tp
+    with jax.named_scope("cca_kv"):
+        k0 = checkpoint_name(
+            jnp.einsum("btd,dhk->bthk", h, lp["c_wk"]), "cca_kv")
+        v = jnp.einsum("btd,dhk->bthk", h, lp["c_wv"])
+    hkv = v.shape[2]
+    g = q0.shape[2] // hkv
+    with jax.named_scope("cca_conv"):
+        # The upper half of the value heads (of all tp members') read the
+        # token before: h_{t-1} W = (h W)_{t-1}.
+        head = lax.axis_index("tp") * hkv + jnp.arange(hkv)
+        before = jnp.pad(v, [(0, 0), (1, 0), (0, 0), (0, 0)])[:, :t]
+        v = checkpoint_name(
+            jnp.where((head >= cfg.kv_heads // 2)[:, None], before, v),
+            "cca_kv")
+        q1, k1 = (checkpoint_name(_causal_conv_by_head(
+            _causal_depthwise_conv(x, lp["c_conv0_" + n], 0.0),
+            lp["c_conv1_" + n]), "cca_conv") for x, n in ((q0, "q"),
+                                                          (k0, "k")))
+    with jax.named_scope("cca_qk_mean"):
+        qf = q0.astype(jnp.float32).reshape(b, t, hkv, g, -1)
+        kf = k0.astype(jnp.float32)
+        q = q1.astype(jnp.float32) + 0.5 * (
+            qf + kf[:, :, :, None]).reshape(q0.shape)
+        k = k1.astype(jnp.float32) + 0.5 * (jnp.mean(qf, axis=3) + kf)
+    with jax.named_scope("cca_norm"):
+        # sqrt(Dh) x / |x| is the RMSNorm over a head's channels; the
+        # keys' weight is their head's temperature.
+        q = _rmsnorm(q, jnp.ones((), jnp.float32), cfg.norm_eps)
+        k = _rmsnorm(k, lp["c_beta"][:, None], cfg.norm_eps)
+        q, k = (jnp.concatenate([
+            _rotated(x[..., :rotated], pos, cfg.rope_theta),
+            x[..., rotated:]], -1).astype(h.dtype) for x in (q, k))
+        q = checkpoint_name(q, "attn_q")
+        k = checkpoint_name(k, "attn_kv")
+    attn = context_parallel_attention(
+        q, k, v, axis_name="sp", causal=True, strategy=cfg.sp_strategy)
+    with jax.named_scope("cca_out"):
+        return checkpoint_name(
+            jnp.einsum("bthk,hkd->btd", attn, lp["c_wo"]), "attn_proj")
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3,))
+def _router_mlp(r, lp_norm, weights, eps):
+    """``gelu(gelu(rms(r) W_1) W_2) W_3`` of the router's state r [b, t,
+    R], float32 at the highest matmul precision."""
+    w1, w2, w3 = weights
+    dot = functools.partial(jnp.einsum, precision=lax.Precision.HIGHEST)
+    y = r * jax.lax.rsqrt(jnp.mean(jnp.square(r), -1, keepdims=True)
+                          + eps) * lp_norm
+    y = jax.nn.gelu(dot("btr,rs->bts", y, w1))
+    y = jax.nn.gelu(dot("btr,rs->bts", y, w2))
+    return dot("btr,re->bte", y, w3)
+
+
+@jax.named_scope("zaya_router")
+def _zaya_router(cfg: TransformerConfig, h, lp, r_prev):
+    """The ZAYA router of the module's docstring on the block's normed h
+    [b, t, d] with the layer before's state ``r_prev`` [b, t, R] float32:
+    (router logits float32 [b, t, E], this layer's state)."""
+    # float32 in earnest, as the linear router's matmul (parallel/moe.py).
+    r = jnp.einsum("btd,dr->btr", h.astype(jnp.float32), lp["r_down"],
+                   precision=lax.Precision.HIGHEST) + lp["r_gamma"] * r_prev
+    logits = _router_mlp(r, lp["r_norm"],
+                         (lp["r_w1"], lp["r_w2"], lp["r_w3"]), cfg.norm_eps)
+    return logits, r
+
+
+@jax.checkpoint
+def _scaled_residual(x, out, scales):
+    """``a * x + b + c * out`` with ``scales`` = (a, b, c) [3, d] float32,
+    in float32; the stream keeps its type."""
+    a, b, c = scales
+    return (a * x.astype(jnp.float32) + b
+            + c * out.astype(jnp.float32)).astype(x.dtype)
+
+
 def _times(x, multiplier):
     """``x * multiplier``; a multiplier of one is no instruction."""
     return x if multiplier == 1.0 else x * multiplier
@@ -1018,12 +1293,27 @@ def _make_layer_fn(cfg: TransformerConfig, packed: bool = False):
             "packed documents through a latent_attention layer or a "
             "multi-token-prediction module are not built: no test holds "
             "a segment's mask or its last label through them")
+    if packed and "cca" in cfg.kinds:
+        raise ValueError(
+            "packed documents through a cca layer are not built: the "
+            "convolutions and the shifted values are not reset at a "
+            "segment boundary")
 
     def layer(kind, ffn, x, lp, seg, gathered_seg, experts=None):
+        # With ``router_hidden`` x is (the activations, the router's state
+        # of the layer before), and so is what comes back.
+        state = None
+        if cfg.router_hidden:
+            x, state = x
         with jax.named_scope(kind):
             x = mixer_block(kind, x, lp, seg, gathered_seg)
         with jax.named_scope(ffn):
-            return feed_forward_block(ffn, x, lp, experts)
+            return feed_forward_block(ffn, x, lp, experts, state)
+
+    def joined(x, out, lp, scales):
+        if cfg.residual_scales:
+            return _scaled_residual(x, out, lp[scales])
+        return x + _times(out, cfg.residual_multiplier)
 
     def mixer_block(kind, x, lp, seg, gathered_seg):
         h = norm(x, lp["ln1"])
@@ -1031,12 +1321,14 @@ def _make_layer_fn(cfg: TransformerConfig, packed: bool = False):
             out = _mamba_mixer(cfg, h, lp)
         elif kind == "latent_attention":
             out = _latent_mixer(cfg, h, lp)
+        elif kind == "cca":
+            out = _cca_mixer(cfg, h, lp)
         else:
             out = attention_mixer(kind, h, lp, seg, gathered_seg)
         out = lax.psum(out, "tp")  # combine head shards
         if cfg.post_norms:
             out = norm(out, lp["ln1_post"])
-        return x + _times(out, cfg.residual_multiplier)
+        return joined(x, out, lp, "res1")
 
     def attention_mixer(kind, h, lp, seg, gathered_seg):
         window, rope = cfg.attention_of(kind)
@@ -1085,27 +1377,33 @@ def _make_layer_fn(cfg: TransformerConfig, packed: bool = False):
         y = jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]
         return jnp.einsum("btf,fd->btd", y, w2)
 
-    def feed_forward_block(ffn, x, lp, experts):
+    def feed_forward_block(ffn, x, lp, experts, state):
+        def carried(x):
+            return (x, state) if cfg.router_hidden else x
+
         h = norm(x, lp["ln2"])
         if ffn == "moe":
             stacks, index = experts
             first = None if cfg.n_experts_held is None else _plus(
                 lax.axis_index("dp") * lp["wg"].shape[0],
                 cfg.first_expert_held)
+            logits = None  # the layer's own linear router makes them
+            if cfg.router_hidden:
+                logits, state = _zaya_router(cfg, h, lp, state)
             y, stats = moe_layer(
                 h, {k: lp[k] for k in _ROUTED_LEAVES if k in lp},
                 cfg.n_experts, first, axis_name="dp", top_k=cfg.moe_top_k,
                 norm_topk_prob=cfg.norm_topk_prob, seq_axis_name="sp",
                 stacks=stacks, layer=index,
                 score_func=cfg.moe_score_func,
-                route_scale=cfg.route_scale)
+                route_scale=cfg.route_scale, logits=logits)
             if cfg.n_shared_experts:
                 with jax.named_scope("moe_shared"):
                     y = y + lax.psum(gated_mlp(
                         h, lp["shared_wgu"], lp["shared_w2"]), "tp")
             if cfg.post_norms:
                 y = norm(y, lp["ln2_post"])
-            return x + _times(y, cfg.residual_multiplier), stats
+            return carried(joined(x, y, lp, "res2")), stats
         if cfg.gated_mlp:
             y = gated_mlp(h, lp["wgu"], lp["w2"])
         else:
@@ -1114,7 +1412,7 @@ def _make_layer_fn(cfg: TransformerConfig, packed: bool = False):
         y = lax.psum(y, "tp")  # combine hidden-dim shards
         if cfg.post_norms:
             y = norm(y, lp["ln2_post"])
-        return x + _times(y, cfg.residual_multiplier)
+        return carried(joined(x, y, lp, "res2"))
 
     keeps = _REMAT_KEEPS if cfg.remat_keeps is None else cfg.remat_keeps
     return jax.checkpoint(
@@ -1130,10 +1428,12 @@ def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
 
     x: [mb, t_local, d], or a tuple that starts with it: then the
     segment ids with ``packed``, then the router statistics with
-    ``cfg.use_moe`` (``_zero_router_stats``). Both ride the pipeline ring
-    with the activations; the ids pass through each stage unchanged, the
-    statistics gain this stage's layers. Runs under the full (dp, pp, sp,
-    tp) mesh.
+    ``cfg.use_moe`` (``_zero_router_stats``), then the router's state [mb,
+    t_local, R] float32 with ``cfg.router_hidden``. They ride the pipeline
+    ring with the activations; the ids pass through each stage unchanged,
+    the statistics gain this stage's layers, the state is the last
+    layer's, and each layer scan carries it beside the activations. Runs
+    under the full (dp, pp, sp, tp) mesh.
 
     The stage walks the maximal runs of one (mixer, feed-forward) pair in
     its pattern (``cfg.stage_pattern``) and scans each over its rows of
@@ -1145,7 +1445,9 @@ def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
     layer_fn = _make_layer_fn(cfg, packed)
 
     def stage_fn(stage_params, x):
-        seg = gathered = stats = None
+        seg = gathered = stats = state = None
+        if cfg.router_hidden:
+            *x, state = x
         if cfg.use_moe:
             *x, stats = x
             x = tuple(x) if packed else x[0]
@@ -1167,6 +1469,8 @@ def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
             # the weight gradients for, one layer an iteration.
             stacks = {k: lax.stop_gradient(stage_params[k])
                       for k in ("wg", "wu", "wd")}
+        if cfg.router_hidden:
+            x = (x, state)
         for kind, ffn, rows, n in runs:
             run_params = {
                 k: _rows(v, rows[_leaf_group(k)], n)
@@ -1194,15 +1498,18 @@ def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
                 **{k: lax.dynamic_update_slice_in_dim(
                     stats[k], layers[k].astype(jnp.int32), at, axis=0)
                    for k in ("load", "windows")}}
+        if cfg.router_hidden:
+            x, state = x
         out = (x,) + ((seg,) if packed else ()) + (
-            (stats,) if cfg.use_moe else ())
+            (stats,) if cfg.use_moe else ()) + (
+            (state,) if cfg.router_hidden else ())
         return out if len(out) > 1 else x
 
     return stage_fn
 
 
 def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
-                  n_microbatches: int, segment_ids=None):
+                  n_microbatches: int, segment_ids=None, logits=True):
     """Shared SPMD forward (embed → pipeline → final norm → logits).
 
     Runs under the (dp, pp, sp, tp) mesh; tokens: local [b, t];
@@ -1210,7 +1517,8 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
     sequences — microbatched alongside the activations so each pipeline
     stage masks attention for the microbatch it is holding.
 
-    Returns ``(logits, router statistics, hidden)``; the statistics
+    Returns ``(logits, router statistics, hidden)``; the logits are None
+    without ``logits`` (a loss that runs the head by blocks); the statistics
     (``_zero_router_stats``: the two loss terms as means over this
     member's sequences, tokens per expert summed over them, the most
     windows a microbatch took) are None without ``cfg.use_moe``;
@@ -1235,6 +1543,8 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
     if cfg.use_moe:
         x = (x if isinstance(x, tuple) else (x,)) + (
             _zero_router_stats(cfg, (M,)),)
+    if cfg.router_hidden:  # r_{-1} = 0
+        x = x + (jnp.zeros((M, b // M, t, cfg.router_hidden), jnp.float32),)
     # Per-stage params: strip the leading pp dim. The local slice MUST be
     # exactly one stage — if init_params was built with a different stage
     # count than the mesh's pp size, layers would silently be dropped.
@@ -1253,6 +1563,8 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
     if segment_ids is not None:
         collect_fn = (lambda s: (s[0], s[2])) if cfg.use_moe else (
             lambda s: s[0])
+    elif cfg.router_hidden:  # the last layer's state is read by none
+        collect_fn = lambda s: s[:2]  # noqa: E731
     y = spmd_pipeline(stage_fn, stage_params, x, axis_name="pp",
                       collect_fn=collect_fn)
     stats = None
@@ -1262,7 +1574,8 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
                  "load": jnp.sum(per_mb["load"], axis=0),
                  "windows": jnp.max(per_mb["windows"], axis=0)}
     y = y.reshape(b, t, -1)
-    return _head(cfg, params, y, params["final_ln"]), stats, y
+    return (_head(cfg, params, y, params["final_ln"]) if logits else None,
+            stats, y)
 
 
 @jax.named_scope("head")
@@ -1340,6 +1653,107 @@ def _token_nll_bwd(residuals, g):
 token_nll.defvjp(_token_nll_fwd, _token_nll_bwd)
 
 
+def _block_logits(y, table, tied, scale):
+    """float32 logits [n, V] of a block's normed float32 hidden states y
+    [n, d] under ``table`` ([V, d] with ``tied``, else [d, V])."""
+    with jax.named_scope("head_block"):
+        logits = jnp.einsum("nd,vd->nv" if tied else "nd,dv->nv", y,
+                            table.astype(jnp.float32))
+        return _times(logits, scale)
+
+
+def _over_blocks(one, rows, block, carry):
+    """``one(carry, rows of a block) -> (carry, what the block gives)``
+    over consecutive blocks of ``block`` rows of every leaf of ``rows``
+    [N, ...], the whole blocks in a ``lax.scan``, what is left of N after
+    them as one shorter block: (carry, the blocks' results joined)."""
+    n = jax.tree.leaves(rows)[0].shape[0]
+    whole = n // block
+    outs = []
+    if whole:
+        carry, out = lax.scan(one, carry, jax.tree.map(
+            lambda a: a[:whole * block].reshape(
+                (whole, block) + a.shape[1:]), rows))
+        outs.append(jax.tree.map(
+            lambda a: a.reshape((whole * block,) + a.shape[2:]), out))
+    if n % block:
+        carry, out = one(carry, jax.tree.map(lambda a: a[whole * block:],
+                                             rows))
+        outs.append(out)
+    return carry, jax.tree.map(lambda *parts: jnp.concatenate(parts), *outs)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def block_nll(y, table, labels, block, tied, scale):
+    """``token_nll`` of the logits ``scale * y table`` without them:
+    cross-entropy per token, float32 [N], of normed float32 hidden states
+    y [N, d] under ``table`` ([V, d] with ``tied``, the embedding table
+    itself, else [d, V]) and int labels [N], by blocks of ``block``
+    tokens. The forward pass forms a block's float32 logits
+    (``head_block``), keeps each token's max and log-sum and picks its
+    label's (``loss_block``); the backward pass forms the block's logits
+    again, ``softmax - onehot`` from the two kept parts, and from it the
+    block's rows of ``dy`` and its term of the table's gradient, summed in
+    float32 over the blocks. No [N, V] array exists in either pass."""
+    return _block_nll_fwd(y, table, labels, block, tied, scale)[0]
+
+
+def _block_nll_fwd(y, table, labels, block, tied, scale):
+    def one(_, rows):
+        y, labels = rows
+        logits = _block_logits(y, table, tied, scale)
+        with jax.named_scope("loss_block"):
+            top = jnp.max(logits, axis=-1)
+            log_sum = jnp.log(jnp.sum(jnp.exp(logits - top[:, None]), -1))
+            picked = jnp.take_along_axis(logits, labels[:, None], -1)[:, 0]
+        return None, (log_sum - (picked - top), top, log_sum)
+
+    _, (nll, top, log_sum) = _over_blocks(one, (y, labels), block, None)
+    return nll, (y, table, labels, top, log_sum)
+
+
+def _block_nll_bwd(block, tied, scale, residuals, g):
+    y, table, labels, top, log_sum = residuals
+
+    def one(d_table, rows):
+        y, labels, top, log_sum, g = rows
+        logits = _block_logits(y, table, tied, scale)
+        with jax.named_scope("loss_block"):
+            probs = jnp.exp(logits - top[:, None] - log_sum[:, None])
+            onehot = jax.nn.one_hot(labels, logits.shape[-1],
+                                    dtype=logits.dtype)
+            d_logits = _times((probs - onehot) * g[:, None], scale)
+        with jax.named_scope("head_block"):
+            table32 = table.astype(jnp.float32)
+            if tied:
+                d_y = jnp.einsum("nv,vd->nd", d_logits, table32)
+                d_table = d_table + jnp.einsum("nv,nd->vd", d_logits, y)
+            else:
+                d_y = jnp.einsum("nv,dv->nd", d_logits, table32)
+                d_table = d_table + jnp.einsum("nd,nv->dv", y, d_logits)
+        return d_table, d_y
+
+    d_table, d_y = _over_blocks(
+        one, (y, labels, top, log_sum, g), block,
+        jnp.zeros(table.shape, jnp.float32))
+    return d_y, d_table.astype(table.dtype), None
+
+
+block_nll.defvjp(_block_nll_fwd, _block_nll_bwd)
+
+
+@jax.named_scope("head")
+def _head_nll(cfg: TransformerConfig, params, y, labels):
+    """``token_nll(_head(y), labels)`` [b, t] by blocks of
+    ``cfg.head_block`` tokens (``block_nll``)."""
+    b, t, d = y.shape
+    y = _block_norm(cfg)(y, params["final_ln"]).astype(jnp.float32)
+    table = params["embed"] if cfg.tie_embeddings else params["head"]
+    return block_nll(y.reshape(b * t, d), table, labels.reshape(b * t),
+                     cfg.head_block, cfg.tie_embeddings,
+                     1.0 / cfg.logits_scaling).reshape(b, t)
+
+
 def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
                  packed: bool = False, with_readings: bool = False):
     """Build loss(params, tokens, labels) -> scalar, shard_mapped over the
@@ -1379,16 +1793,19 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
     specs = _param_specs(cfg)
 
     def spmd_loss(params, tokens, labels, segment_ids=None):
-        logits, stats, hidden = _spmd_forward(cfg, stage_fn, params, tokens,
-                                              n_microbatches,
-                                              segment_ids=segment_ids)
+        logits, stats, hidden = _spmd_forward(
+            cfg, stage_fn, params, tokens, n_microbatches,
+            segment_ids=segment_ids, logits=cfg.head_block is None)
         if cfg.n_mtp_modules:
             with jax.named_scope("mtp"):
                 mtp_nll, mtp_loss, mtp_stats = _mtp_module(
                     cfg, mtp_layer_fn, params, hidden, labels,
                     jnp.roll(labels, -1, axis=1))
+        if cfg.head_block is not None:
+            nll = _head_nll(cfg, params, hidden, labels)
         with jax.named_scope("loss"):
-            nll = token_nll(logits, labels)
+            if cfg.head_block is None:
+                nll = token_nll(logits, labels)
             loss = jnp.mean(nll)
             if cfg.use_moe:
                 loss = (loss + cfg.router_aux_loss_coef * stats["lb"]

@@ -61,9 +61,11 @@ member over the all-gathered tokens, and the partial results are
 reduce-scattered back; at ``ep`` 1 both collectives are the identity.
 
 The router scores by softmax (the loss terms ``lb`` and ``z`` are its) or
-by sigmoid; an ``expert_bias`` moves which experts a token picks and
-never their weights (aux-loss-free balancing: the caller moves it by the
-``load`` this layer returns).
+by sigmoid, of its own linear ``router`` or of logits the caller's router
+made (``moe_layer``'s ``logits``: a model whose router is more than one
+matrix keeps it with the model); under either an ``expert_bias`` moves
+which experts a token picks and never their weights (aux-loss-free
+balancing: the caller moves it by the ``load`` this layer returns).
 """
 
 from __future__ import annotations
@@ -408,7 +410,8 @@ _windows.defvjp(_windows_fwd, _windows_bwd)
 def moe_layer(x, params, n_experts: int, first=None, axis_name: str = "dp",
               top_k: int = 1, norm_topk_prob: bool = False,
               seq_axis_name=None, stacks=None, layer=0,
-              score_func: str = "softmax", route_scale: float = 1.0):
+              score_func: str = "softmax", route_scale: float = 1.0,
+              logits=None):
     """Top-k MoE over the tokens of ``x`` [B, T, d] (local sequences).
 
     ``params``: ``router`` [d, E] float32 (replicated) over all ``E =
@@ -421,9 +424,14 @@ def moe_layer(x, params, n_experts: int, first=None, axis_name: str = "dp",
     those of its ``top_k`` experts that are held (all of them over the
     members of ``axis_name`` together).
 
+    ``logits`` float32 [B, T, E]: the caller's own router's, in place of
+    ``x @ params["router"]``, which is then not read.
+
     ``score_func`` ``"softmax"``: the ``top_k`` largest router
-    probabilities ``p``, and ``w = p`` as they are unless
-    ``norm_topk_prob`` divides them by their sum. ``"sigmoid"``: scores
+    probabilities ``p`` (of ``p + params["expert_bias"]`` where there is
+    one: float32 [E], no gradient, the selection only), and ``w = p`` of
+    the picked as they are unless ``norm_topk_prob`` divides them by their
+    sum. ``"sigmoid"``: scores
     ``s = sigmoid(logits)``; the ``top_k`` largest of ``s +
     params["expert_bias"]`` [E] (float32, no gradient; absent: zero) are
     picked, and ``w = route_scale * s`` of the picked, with
@@ -468,9 +476,10 @@ def moe_layer(x, params, n_experts: int, first=None, axis_name: str = "dp",
     with jax.named_scope("moe_route"):
         # float32 in earnest: at default precision the MXU would round
         # both operands to bf16 and the top-k with them.
-        logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32),
-                            params["router"],
-                            precision=lax.Precision.HIGHEST)
+        if logits is None:
+            logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32),
+                                params["router"],
+                                precision=lax.Precision.HIGHEST)
         if score_func == "sigmoid":
             scores = jax.nn.sigmoid(logits)
             biased = scores
@@ -484,7 +493,13 @@ def moe_layer(x, params, n_experts: int, first=None, axis_name: str = "dp",
             gates = gates * route_scale
         else:
             probs = jax.nn.softmax(logits, axis=-1)
-            gates, experts = lax.top_k(probs, top_k)  # [B, T, k]
+            if "expert_bias" in params:
+                experts = lax.top_k(
+                    probs + lax.stop_gradient(params["expert_bias"]),
+                    top_k)[1]
+                gates = jnp.take_along_axis(probs, experts, axis=-1)
+            else:
+                gates, experts = lax.top_k(probs, top_k)  # [B, T, k]
             if norm_topk_prob:
                 gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
         picked = jnp.sum(

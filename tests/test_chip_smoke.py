@@ -160,6 +160,22 @@ def test_decoder_phase_with_the_glm_lite_block():
     assert len(losses) == 3 and losses[-1] < losses[0]
 
 
+def test_decoder_phase_with_the_zaya_block():
+    """The smoke's `decoder-zaya` phase at a toy size: CCA layers, the MLP
+    router's carried state, residual scales and the head by blocks in a
+    step that moves a balancing bias, and the first loss against the
+    plain reference's."""
+    import dataclasses
+
+    toy = dataclasses.replace(
+        chip_smoke.TransformerConfig(**chip_smoke.ZAYA), vocab=64,
+        d_model=32, d_head=16, d_expert=16, router_hidden=8, max_seq=32,
+        head_block=24, dtype=jnp.float32)
+    losses = chip_smoke.phase_decoder_zaya(toy, 2, 3, jax.devices()[:1],
+                                           tol=1e-5)
+    assert len(losses) == 3 and losses[-1] < losses[0]
+
+
 @pytest.mark.full
 def test_decoder_parallel_phase(monkeypatch):
     """dp 2 x tp 2 and sp 4 (ring, block kernels interpreted) against one

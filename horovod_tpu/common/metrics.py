@@ -45,6 +45,10 @@ from . import logging as _log
 #   elastic.drains         commit-marked graceful drains (driver.py)
 #   model.latent_layers, model.mtp_modules  what a decoder step was built
 #                          with, where it has either (models/transformer.py)
+#   head.blocks_with_gradients_traced  differentiated traces of the head
+#                          and loss by blocks, whose forward pass makes the
+#                          gradients: 1 after a train step's, 0 after an
+#                          evaluation's (models/transformer.py::block_nll)
 #
 # The native snapshot carries the self-healing counters alongside these
 # (link.reconnects / link.resume_chunks_discarded /

@@ -121,7 +121,12 @@ norm(x))`` with learned float32 ``a``, ``b``, ``c`` [d] (one, zero, one at
 the start), leaves ``res1`` and ``res2`` [3, d].
 
 With ``head_block`` the head and the loss run by blocks of that many
-tokens (``block_nll``): no [b, t, V] array exists in either pass.
+tokens (``block_nll``): a block's float32 logits are formed once, in the
+forward pass, which where the loss is differentiated also makes the hidden
+states' and the table's gradients from them, under the weight the loss
+gives each token (the mean's ``1 / N``, an argument of ``block_nll``);
+the backward pass multiplies both by the loss's cotangent. No [b, t, V]
+array exists in either pass.
 
 Pure-jax pytree params (no flax) so shard_map in_specs map 1:1 onto leaves.
 """
@@ -1683,46 +1688,50 @@ def _over_blocks(one, rows, block, carry):
     return carry, jax.tree.map(lambda *parts: jnp.concatenate(parts), *outs)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def block_nll(y, table, labels, block, tied, scale):
-    """``token_nll`` of the logits ``scale * y table`` without them:
-    cross-entropy per token, float32 [N], of normed float32 hidden states
-    y [N, d] under ``table`` ([V, d] with ``tied``, the embedding table
-    itself, else [d, V]) and int labels [N], by blocks of ``block``
-    tokens. The forward pass forms a block's float32 logits
-    (``head_block``), keeps each token's max and log-sum and picks its
-    label's (``loss_block``); the backward pass forms the block's logits
-    again, ``softmax - onehot`` from the two kept parts, and from it the
-    block's rows of ``dy`` and its term of the table's gradient, summed in
-    float32 over the blocks. No [N, V] array exists in either pass."""
-    return _block_nll_fwd(y, table, labels, block, tied, scale)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def block_nll(y, table, labels, weights, block, tied, scale):
+    """The weighted sum of ``token_nll`` of the logits ``scale * y table``
+    without them, and every token's: ``(sum_i weights[i] * nll[i], nll)``,
+    a float32 scalar and float32 [N], of normed float32 hidden states y
+    [N, d] under ``table`` ([V, d] with ``tied``, the embedding table
+    itself, else [d, V]), int labels [N] and the float32 ``weights`` [N]
+    the loss gives the tokens (a mean's ``1 / N``), by blocks of ``block``
+    tokens. Only the sum takes a gradient: ``nll`` is there to be read,
+    and a cotangent of it is dropped.
+
+    A block's float32 logits are formed once (``head_block``) and each
+    token's max, log-sum and picked logit read from them (``loss_block``).
+    Where the sum is differentiated, the forward pass makes the gradients
+    beside them, from the same logits: ``weights * (softmax - onehot)``
+    (``loss_block``), and from it the block's rows of the hidden states'
+    gradient and its term of the table's, summed in float32 over the
+    blocks (``head_block``). The weights are an argument because that
+    needs every token's cotangent before a backward pass exists; the
+    backward pass multiplies the two kept gradients by the sum's
+    cotangent, a scalar. Where nothing is differentiated no gradient is
+    made. No [N, V] array exists in either pass."""
+    return _head_blocks(y, table, labels, weights, block, tied, scale,
+                        gradients=False)[0]
 
 
-def _block_nll_fwd(y, table, labels, block, tied, scale):
-    def one(_, rows):
-        y, labels = rows
+def _head_blocks(y, table, labels, weights, block, tied, scale, gradients):
+    """``block_nll``'s result by blocks of ``block`` tokens and, with
+    ``gradients``, ``(d_y [N, d], d_table, float32)`` of its sum; else
+    None."""
+    def one(d_table, rows):
+        y, labels, weights = rows
         logits = _block_logits(y, table, tied, scale)
         with jax.named_scope("loss_block"):
             top = jnp.max(logits, axis=-1)
             log_sum = jnp.log(jnp.sum(jnp.exp(logits - top[:, None]), -1))
             picked = jnp.take_along_axis(logits, labels[:, None], -1)[:, 0]
-        return None, (log_sum - (picked - top), top, log_sum)
-
-    _, (nll, top, log_sum) = _over_blocks(one, (y, labels), block, None)
-    return nll, (y, table, labels, top, log_sum)
-
-
-def _block_nll_bwd(block, tied, scale, residuals, g):
-    y, table, labels, top, log_sum = residuals
-
-    def one(d_table, rows):
-        y, labels, top, log_sum, g = rows
-        logits = _block_logits(y, table, tied, scale)
-        with jax.named_scope("loss_block"):
+            nll = log_sum - (picked - top)
+            if not gradients:
+                return d_table, (nll, None)
             probs = jnp.exp(logits - top[:, None] - log_sum[:, None])
             onehot = jax.nn.one_hot(labels, logits.shape[-1],
                                     dtype=logits.dtype)
-            d_logits = _times((probs - onehot) * g[:, None], scale)
+            d_logits = _times((probs - onehot) * weights[:, None], scale)
         with jax.named_scope("head_block"):
             table32 = table.astype(jnp.float32)
             if tied:
@@ -1731,12 +1740,27 @@ def _block_nll_bwd(block, tied, scale, residuals, g):
             else:
                 d_y = jnp.einsum("nv,dv->nd", d_logits, table32)
                 d_table = d_table + jnp.einsum("nd,nv->dv", y, d_logits)
-        return d_table, d_y
+        return d_table, (nll, d_y)
 
-    d_table, d_y = _over_blocks(
-        one, (y, labels, top, log_sum, g), block,
-        jnp.zeros(table.shape, jnp.float32))
-    return d_y, d_table.astype(table.dtype), None
+    d_table, (nll, d_y) = _over_blocks(
+        one, (y, labels, weights), block,
+        jnp.zeros(table.shape, jnp.float32) if gradients else None)
+    return (jnp.sum(weights * nll), nll), (
+        (d_y, d_table) if gradients else None)
+
+
+def _block_nll_fwd(y, table, labels, weights, block, tied, scale):
+    # Once a differentiated trace, never in an evaluation's.
+    _metrics.inc("head.blocks_with_gradients_traced")
+    out, (d_y, d_table) = _head_blocks(y, table, labels, weights, block,
+                                       tied, scale, gradients=True)
+    return out, (d_y, d_table, out[1], table)
+
+
+def _block_nll_bwd(block, tied, scale, residuals, cotangents):
+    d_y, d_table, nll, table = residuals
+    g, _ = cotangents  # of the sum; the per-token output takes none
+    return (g * d_y, (g * d_table).astype(table.dtype), None, g * nll)
 
 
 block_nll.defvjp(_block_nll_fwd, _block_nll_bwd)
@@ -1744,14 +1768,16 @@ block_nll.defvjp(_block_nll_fwd, _block_nll_bwd)
 
 @jax.named_scope("head")
 def _head_nll(cfg: TransformerConfig, params, y, labels):
-    """``token_nll(_head(y), labels)`` [b, t] by blocks of
-    ``cfg.head_block`` tokens (``block_nll``)."""
+    """``token_nll(_head(y), labels)`` [b, t] and its mean, by blocks of
+    ``cfg.head_block`` tokens (``block_nll``): ``(mean, [b, t])``."""
     b, t, d = y.shape
     y = _block_norm(cfg)(y, params["final_ln"]).astype(jnp.float32)
     table = params["embed"] if cfg.tie_embeddings else params["head"]
-    return block_nll(y.reshape(b * t, d), table, labels.reshape(b * t),
-                     cfg.head_block, cfg.tie_embeddings,
-                     1.0 / cfg.logits_scaling).reshape(b, t)
+    mean, nll = block_nll(
+        y.reshape(b * t, d), table, labels.reshape(b * t),
+        jnp.full(b * t, 1.0 / (b * t), jnp.float32), cfg.head_block,
+        cfg.tie_embeddings, 1.0 / cfg.logits_scaling)
+    return mean, nll.reshape(b, t)
 
 
 def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
@@ -1759,7 +1785,9 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
     """Build loss(params, tokens, labels) -> scalar, shard_mapped over the
     full mesh. tokens/labels: [B_global, T_global] sharded P('dp','sp').
     The ``loss`` scope covers ``token_nll``'s forward pass and its
-    hand-written backward pass.
+    hand-written backward pass; with ``cfg.head_block`` the mean
+    cross-entropy is ``block_nll``'s weighted sum, under ``head``, and
+    its gradients are made in the forward pass.
 
     ``with_readings`` (a ``cfg.use_moe`` model) returns ``(loss,
     readings)`` instead: ``readings["load"]`` int32 [n_layers, n_experts]
@@ -1802,11 +1830,11 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
                     cfg, mtp_layer_fn, params, hidden, labels,
                     jnp.roll(labels, -1, axis=1))
         if cfg.head_block is not None:
-            nll = _head_nll(cfg, params, hidden, labels)
+            loss, nll = _head_nll(cfg, params, hidden, labels)
         with jax.named_scope("loss"):
             if cfg.head_block is None:
                 nll = token_nll(logits, labels)
-            loss = jnp.mean(nll)
+                loss = jnp.mean(nll)
             if cfg.use_moe:
                 loss = (loss + cfg.router_aux_loss_coef * stats["lb"]
                         + cfg.router_z_loss_coef * stats["z"])

@@ -18,6 +18,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from benchmark import reference_zaya
+from horovod_tpu.common import metrics
 from horovod_tpu.common.compat import shard_map
 from horovod_tpu.models import transformer
 from horovod_tpu.models.transformer import (
@@ -285,7 +286,7 @@ def test_the_two_row_slices_log_sum_exps_combine_to_the_whole_tables():
     zero = jnp.zeros(B * T, jnp.int32)
     lse = []
     for rows in (table[:V // 2], table[V // 2:]):
-        nll = block_nll(y, rows, zero, 24, True, 1.0)
+        _, nll = block_nll(y, rows, zero, jnp.ones(B * T), 24, True, 1.0)
         lse.append(nll + y @ rows[0])  # nll = lse - the picked logit
     want = jax.nn.logsumexp(y @ table.T, axis=-1)
     np.testing.assert_allclose(jnp.logaddexp(*lse), want, rtol=1e-5)
@@ -293,31 +294,113 @@ def test_the_two_row_slices_log_sum_exps_combine_to_the_whole_tables():
 
 # --- the head and the loss by blocks
 
+def _head_inputs(tied=True):
+    y = jax.random.normal(jax.random.PRNGKey(10), (B * T, 64))
+    table = jax.random.normal(jax.random.PRNGKey(11),
+                              (V, 64) if tied else (64, V)) * 0.3
+    labels = jax.random.randint(jax.random.PRNGKey(12), (B * T,), 0, V)
+    weights = jax.random.normal(jax.random.PRNGKey(13), (B * T,))
+    return y, table, labels, weights
+
+
 @pytest.mark.parametrize("tied", [True, False])
 @pytest.mark.parametrize("block", [16, 24, 80, 200])
 def test_block_nll_equals_the_whole_head_in_loss_and_both_gradients(
         block, tied):
     """80 tokens: 16 divides them, 24 leaves a block of 8, 200 is more
     than there are."""
-    y = jax.random.normal(jax.random.PRNGKey(10), (B * T, 64))
-    table = jax.random.normal(jax.random.PRNGKey(11),
-                              (V, 64) if tied else (64, V)) * 0.3
-    labels = jax.random.randint(jax.random.PRNGKey(12), (B * T,), 0, V)
-    weights = jax.random.normal(jax.random.PRNGKey(13), (B * T,))
+    y, table, labels, weights = _head_inputs(tied)
 
     def whole(y, table):
         logits = (y @ (table.T if tied else table)) * 0.5
         return jnp.sum(weights * token_nll(logits[None], labels[None])[0])
 
     def blocks(y, table):
-        return jnp.sum(weights * block_nll(y, table, labels, block, tied,
-                                           0.5))
+        return block_nll(y, table, labels, weights, block, tied, 0.5)[0]
 
     want, want_grads = jax.value_and_grad(whole, (0, 1))(y, table)
     got, got_grads = jax.value_and_grad(blocks, (0, 1))(y, table)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for g, w in zip(got_grads, want_grads):
         np.testing.assert_allclose(g, w, atol=1e-5 * np.abs(w).max() + 1e-6)
+
+
+@pytest.mark.parametrize("tied", [True, False])
+def test_a_cotangent_other_than_one_scales_both_gradients(tied):
+    """The forward pass made the gradients for a cotangent of one: the
+    backward pass has the loss's own to multiply them by."""
+    y, table, labels, weights = _head_inputs(tied)
+
+    def times(c):
+        return jax.grad(lambda y, table: c * block_nll(
+            y, table, labels, weights, 24, tied, 0.5)[0], (0, 1))(y, table)
+
+    for one, three in zip(times(1.0), times(3.0)):
+        assert np.abs(np.asarray(one)).max() > 1e-3
+        np.testing.assert_allclose(three, 3.0 * one, rtol=1e-6)
+
+
+def test_the_weights_gradient_is_each_tokens_cross_entropy():
+    y, table, labels, weights = _head_inputs()
+    nll = block_nll(y, table, labels, weights, 24, True, 0.5)[1]
+    got = jax.grad(lambda w: 3.0 * block_nll(y, table, labels, w, 24, True,
+                                             0.5)[0])(weights)
+    np.testing.assert_allclose(got, 3.0 * nll, rtol=1e-6)
+
+
+def test_the_per_token_output_takes_no_gradient():
+    y, table, labels, weights = _head_inputs()
+    grads = jax.grad(lambda y, table: jnp.sum(block_nll(
+        y, table, labels, weights, 24, True, 0.5)[1]), (0, 1))(y, table)
+    assert not any(np.asarray(g).any() for g in grads)
+
+
+def _head_matmuls(jaxpr):
+    """(einsum, whether under the transposed scope, operand and result
+    types) of every ``dot_general`` under ``head_block`` in ``jaxpr``."""
+    return [(path.rsplit("/", 1)[-1], "transpose(" in path,
+             set(sum(_types(eqn), [])))
+            for eqn, path in _eqns(jaxpr)
+            if "head_block" in path and eqn.primitive.name == "dot_general"]
+
+
+# 80 tokens by blocks of 24: the scan's body and a last block of 8.
+BODIES = 2
+
+
+def test_a_differentiated_step_forms_a_blocks_logits_once(bf16_step):
+    """Three matmuls a block, all float32 and all in the forward pass: the
+    logits, the hidden states' gradient, the table's. A fourth would be
+    the logits formed again."""
+    found = _head_matmuls(bf16_step)
+    assert sorted(name for name, _, _ in found) == sorted(
+        ["nd,vd->nv", "nv,vd->nd", "nv,nd->vd"] * BODIES)
+    assert all(types == {jnp.dtype(jnp.float32)} for _, _, types in found)
+    assert not any(transposed for _, transposed, _ in found)
+    # What the backward pass has under ``head`` is the two products with
+    # the cotangent and the final norm's own rule: no matmul.
+    backward = [eqn.primitive.name for eqn, path in _eqns(bf16_step)
+                if "transpose(jvp(forward))/head" in path]
+    assert "mul" in backward and "dot_general" not in backward
+
+
+@pytest.mark.parametrize("differentiated, matmuls, counted", [
+    (False, 1, 0), (True, 3, 1)])
+def test_gradients_are_made_where_the_loss_is_differentiated_and_counted(
+        differentiated, matmuls, counted):
+    """An evaluation (the benchmark's reference comparison) does a block's
+    logits and nothing of the gradients; ``hvd.metrics()`` says which of
+    the two a job traced."""
+    params = jax.eval_shape(lambda: init_params(CFG, jax.random.PRNGKey(0),
+                                                1))
+    tokens = jax.ShapeDtypeStruct((B, T), jnp.int32)
+    loss_fn = make_loss_fn(CFG, _mesh(), 1)
+    fn = jax.grad(loss_fn) if differentiated else loss_fn
+    metrics.reset()
+    jaxpr = jax.make_jaxpr(fn)(params, tokens, tokens).jaxpr
+    assert metrics.counters().get(
+        "head.blocks_with_gradients_traced", 0) == counted
+    assert len(_head_matmuls(jaxpr)) == matmuls * BODIES
 
 
 def test_no_array_of_all_logits_is_in_the_lowered_step():
@@ -332,10 +415,12 @@ def test_no_array_of_all_logits_is_in_the_lowered_step():
             params, jax.eval_shape(optimizer.init, trained(params)),
             tokens, tokens).as_text()
 
-    whole = (f"tensor<{B}x{T}x{V}xf32>", f"tensor<{B * T}x{V}xf32>")
+    # [b, t, V] and [N, V] of any type: the one-hot and a mask among them.
+    whole = (f"tensor<{B}x{T}x{V}x", f"tensor<{B * T}x{V}x")
     assert not any(shape in text(CFG) for shape in whole)
     assert f"tensor<24x{V}xf32>" in text(CFG)
-    assert whole[0] in text(dataclasses.replace(CFG, head_block=None))
+    assert whole[0] + "f32>" in text(dataclasses.replace(CFG,
+                                                         head_block=None))
 
 
 def test_the_whole_head_and_the_head_by_blocks_give_one_loss(both):
@@ -541,13 +626,18 @@ def _traced_bf16_step():
 
 
 @pytest.fixture(scope="module")
-def bf16_step_parts():
-    return _not_float32(_traced_bf16_step())
+def bf16_step():
+    return _traced_bf16_step()
+
+
+@pytest.fixture(scope="module")
+def bf16_step_parts(bf16_step):
+    return _not_float32(bf16_step)
 
 
 @pytest.mark.parametrize("part, at_least", [
     ("norms", 2 * L + 1), ("qk_norm", 2 * L), ("router", 6 * L),
-    ("head", 3), ("loss", 3), ("bias", 2)])
+    ("head", 3 * BODIES), ("loss", 3 * BODIES), ("bias", 2)])
 def test_a_bf16_step_computes_its_float32_parts_in_float32(
         bf16_step_parts, part, at_least):
     """What the cell's ``correct`` cannot tell apart on the chip for every

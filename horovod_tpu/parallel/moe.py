@@ -5,9 +5,9 @@ and nothing is dropped. The ``top_k * N`` assignments are sorted by
 expert, the tokens' rows gathered in that order, and the experts run as
 three grouped matmuls over the ragged groups (bf16 operands where the
 parameters are bf16, float32 accumulation on the MXU) whose sizes are
-data, so every shape is static: on the chip jax's Pallas grouped matmul
-(``megablox``) under the scope ``moe_gmm``, elsewhere XLA's
-``lax.ragged_dot``, by the attention kernels' own rule
+data, so every shape is static: on the chip the repository's Pallas
+grouped matmul (``ops/grouped_matmul.py``) under the scope ``moe_gmm``,
+elsewhere XLA's ``lax.ragged_dot``, by the attention kernels' own rule
 (``ops.pallas_attention._resolve_dispatch``). The kernels read a layer's
 matrices where they lie in a stack of layers (``moe_layer``'s ``stacks``),
 so a scan over layers copies none out for them. The rows go back to their
@@ -71,7 +71,6 @@ balancing: the caller moves it by the ``load`` this layer returns).
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -79,13 +78,8 @@ from jax import lax
 
 from ..common import metrics as _metrics
 from ..common.compat import axis_size as _axis_size
+from ..ops import grouped_matmul as _grouped_matmul_ops
 from ..ops import pallas_attention as _pallas_attention
-
-# Largest (rows, contraction, columns) tile of the Pallas grouped matmul
-# by operand item size: what fits the kernel's fast memory on a v5e, and
-# of the tilings tried on the chip at [65536, 2048] x [64, 2048, 1024] the
-# fastest (PERF.md, PR 26).
-_GMM_TILE_CAPS = {2: (512, 1024, 1024), 4: (512, 512, 512)}
 
 
 def init_moe_params(rng, d_model: int, d_ff: int, n_experts: int,
@@ -118,7 +112,7 @@ def _placed_sums(table, place, index, count, fan, dtype):
     ``place`` is a permutation of them.
 
     With a ``count`` the sums are a sorted segment sum. On the chip: the
-    rows that count gathered token-major, then jax's Pallas ``tgmm`` with
+    rows that count gathered token-major, then the Pallas ``tgmm`` with
     blocks of ``_TOKEN_BLOCK`` tokens as its groups and, as its other
     operand, which of its block's tokens each row belongs to; float32
     accumulation on the MXU, every product a row's own value. Elsewhere a
@@ -129,23 +123,20 @@ def _placed_sums(table, place, index, count, fan, dtype):
         return table[place].reshape(tokens, fan, d).sum(1, dtype=dtype)
     use_pallas, interpret = _pallas_attention._resolve_dispatch(None)
     if use_pallas and d % 128 == 0 and tokens % _TOKEN_BLOCK == 0:
-        from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
-
         # The rows that do not count last, past every token.
         at = jnp.arange(rows)
         entry, by_token = lax.sort_key_val(
             jnp.where(at < count, index, len(place)), at)
         token = entry // fan
         blocks = tokens // _TOKEN_BLOCK
-        which = (token % _TOKEN_BLOCK
-                 == jnp.arange(_TOKEN_BLOCK)[:, None]).astype(table.dtype)
+        which = (token[:, None] % _TOKEN_BLOCK
+                 == jnp.arange(_TOKEN_BLOCK)).astype(table.dtype)
         sizes = jnp.sum(token[:, None] // _TOKEN_BLOCK == jnp.arange(blocks),
                         axis=0, dtype=jnp.int32)
         _metrics.inc("kernels.traced.tgmm")
         with jax.named_scope("moe_token_sums"):
-            sums = tgmm(which, table[by_token], sizes, dtype,
-                        _gmm_tiling((rows, _TOKEN_BLOCK, d), table.dtype),
-                        None, blocks, interpret=interpret)
+            sums = _grouped_matmul_ops.tgmm(which, table[by_token], sizes,
+                                            dtype, interpret=interpret)
         return sums.reshape(tokens, d)
     picked = jnp.where(((place >= 0) & (place < count))[:, None],
                        table[jnp.clip(place, 0, rows - 1)],
@@ -204,36 +195,21 @@ def _sum_rows_bwd(fan, residuals, g):
 _sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
 
 
-def _gmm_tiling(sizes, dtype):
-    """The kernels' (rows, contraction, columns) tile of a product of
-    those ``sizes`` with operands of ``dtype``: one tiling for the product
-    and both of its gradients."""
-    caps = _GMM_TILE_CAPS[jnp.dtype(dtype).itemsize]
-    return tuple(math.gcd(size, cap) for size, cap in zip(sizes, caps))
-
-
-def _stack_tiling(lhs, stack):
-    """``_gmm_tiling`` of ``lhs`` [m, k] by ``stack`` [L, g, k, n]."""
-    return _gmm_tiling((*lhs.shape, stack.shape[3]), lhs.dtype)
-
-
-def _gmm_of_layer(lhs, stack, layer, group_sizes, tiling, transpose_rhs,
-                  interpret):
+def _gmm_of_layer(lhs, stack, layer, group_sizes, transpose_rhs, interpret):
     """The Pallas grouped matmul of ``lhs`` with the matrices
     ``stack[layer]``, read where they lie: the kernel takes the whole
     stack as ``L * g`` groups of which only this layer's have rows, and
     its index map, which skips empty groups, finds a tile's matrix at
     ``layer * g`` + its group. A slice of the stack would have to be
     copied out first: a custom call's operand is a buffer of its own."""
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
-
     _metrics.inc("kernels.traced.gmm")
     n_layers, groups = stack.shape[:2]
     sizes = lax.dynamic_update_slice(
         jnp.zeros(n_layers * groups, jnp.int32), group_sizes,
         (layer * groups,))
-    return gmm(lhs, stack.reshape((-1,) + stack.shape[2:]), sizes, lhs.dtype,
-               tiling, None, None, transpose_rhs, interpret)
+    return _grouped_matmul_ops.gmm(
+        lhs, stack.reshape((-1,) + stack.shape[2:]), sizes,
+        transpose_rhs=transpose_rhs, interpret=interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -248,21 +224,16 @@ def _stacked_gmm(lhs, rhs, stack, layer, group_sizes, interpret):
 
 def _stacked_gmm_fwd(lhs, rhs, stack, layer, group_sizes, interpret):
     del rhs
-    out = _gmm_of_layer(lhs, stack, layer, group_sizes,
-                        _stack_tiling(lhs, stack), False, interpret)
+    out = _gmm_of_layer(lhs, stack, layer, group_sizes, False, interpret)
     return out, (lhs, stack, layer, group_sizes)
 
 
 def _stacked_gmm_bwd(interpret, residuals, grad):
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
-
     lhs, stack, layer, group_sizes = residuals
-    tiling = _stack_tiling(lhs, stack)
-    grad_lhs = _gmm_of_layer(grad, stack, layer, group_sizes, tiling, True,
-                             interpret)
+    grad_lhs = _gmm_of_layer(grad, stack, layer, group_sizes, True, interpret)
     _metrics.inc("kernels.traced.tgmm")
-    grad_rhs = tgmm(lhs.swapaxes(0, 1), grad, group_sizes, stack.dtype,
-                    tiling, None, stack.shape[1], interpret=interpret)
+    grad_rhs = _grouped_matmul_ops.tgmm(lhs, grad, group_sizes, stack.dtype,
+                                        interpret=interpret)
     return grad_lhs, grad_rhs, None, None, None
 
 
@@ -305,7 +276,7 @@ def _window_rows(assignments: int, e_local: int, n_experts: int) -> int:
     """Positions of the sorted assignments a window holds: the least
     multiple of the grouped matmul's row tile that is ``_WINDOW_OVER_
     BALANCE`` times ``assignments * e_local / n_experts`` or more."""
-    tile = _GMM_TILE_CAPS[2][0]
+    tile = _grouped_matmul_ops.ROW_TILE
     return -(-_WINDOW_OVER_BALANCE * e_local * assignments
              // (n_experts * tile)) * tile
 

@@ -12,6 +12,7 @@ file keeps it from rotting between chip runs, without the chip:
 Nothing here is a chip result.
 """
 
+import functools
 import json
 import os
 import re
@@ -342,6 +343,74 @@ def test_kernels_compile_for_v5e_at_highest_precision(
     # ... and only there: at jax's default setting nothing asks for it.
     assert "Precision.HIGHEST" not in str(jax.make_jaxpr(fn)(x, x, x))
     assert sorted(_flash_custom_calls(text)) == sorted(kernels)
+
+
+_GROUPED_PRODUCTS = ["gate_up", "down", "row_gradient", "tgmm", "token_sums"]
+
+
+def _grouped_product(product, m, d, f, groups, stacked, dtype, chip):
+    """(function, operands' shapes on ``chip``, kind) of one of the expert
+    layer's grouped matmuls: ``m`` rows, widths ``d`` and ``f``, ``groups``
+    a layer inside a stack of ``stacked``; its plan counted under the
+    budget."""
+    from horovod_tpu.ops import grouped_matmul as gm
+
+    kind, k, n = {"gate_up": ("gmm", d, f), "down": ("gmm", f, d),
+                  "row_gradient": ("gmm_t", f, d), "tgmm": ("tgmm", d, f),
+                  "token_sums": ("tgmm", 256, d)}[product]
+
+    def spec(*shape, dtype=dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    if kind == "tgmm":
+        groups = m // 512 if product == "token_sums" else groups
+        fn, args = gm.tgmm, (spec(m, k), spec(m, n),
+                             spec(groups, dtype=jnp.int32))
+    else:
+        transposed = kind == "gmm_t"
+        fn = functools.partial(gm.gmm, transpose_rhs=transposed)
+        args = (spec(m, k), spec(stacked, *((n, k) if transposed else (k, n))),
+                spec(stacked, dtype=jnp.int32))
+    plan = gm.kernel_plan(m, k, n, groups, dtype, kind)
+    assert plan.vmem_bytes <= gm.VMEM_BUDGET
+    return fn, args, kind
+
+
+@pytest.mark.parametrize("product", _GROUPED_PRODUCTS)
+@pytest.mark.parametrize("cell", ["olmoe", "zaya", "trinity", "glm"])
+def test_grouped_matmul_kernels_compile_for_v5e(described_chip, cell,
+                                                product):
+    """``ops/grouped_matmul.py``'s kernels at the four expert cells'
+    shapes, each within the scoped VMEM a Mosaic call has without asking
+    (the calls set no limit, and the chip's compiler refuses a kernel
+    that needs more): the contraction whole beside the columns its plan
+    takes, ``tgmm``'s accumulator of a ``[1024, 1024]`` block, the sums
+    over a token's rows at k 256."""
+    from tools.pallas_bench import GMM_CELLS  # the four cells' shapes
+
+    m, d, f, groups, layers = (GMM_CELLS[cell][key] for key in (
+        "m", "d", "f", "groups", "layers"))
+    fn, args, kind = _grouped_product(product, m, d, f, groups,
+                                      layers * groups, jnp.bfloat16,
+                                      described_chip)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    name = "%tgmm." if kind == "tgmm" else "%gmm."
+    assert [line for line in text.splitlines()
+            if name in line and "tpu_custom_call" in line], text[-2000:]
+
+
+@pytest.mark.parametrize("product", _GROUPED_PRODUCTS)
+def test_grouped_matmul_kernels_compile_for_v5e_in_float32(described_chip,
+                                                           product):
+    """The gradient checks' float32 programs run the same kernels under
+    ``jax.default_matmul_precision("highest")``, where Mosaic holds the
+    bf16 parts of the rows it multiplies: the plans take narrower blocks
+    and fewer rows at a time, within the same scoped VMEM."""
+    fn, args, _ = _grouped_product(product, 16384, 2048, 1024, 16, 32,
+                                   jnp.float32, described_chip)
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("which", ["state", "grads"])

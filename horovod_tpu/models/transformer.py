@@ -19,22 +19,26 @@ reference's DP-only surface (SURVEY §2.5): every mesh axis of
   dropless top-k routing, sort-by-expert dispatch, grouped matmuls.
 
 The stack of layers is data. ``TransformerConfig.layer_types`` names each
-layer's mixer: ``"attention"`` (softmax attention, above, with the
-model's one ``attention_window`` and ``rope`` switch), ``"mamba"`` (a
-Mamba-2 state-space mixer, ``_mamba_mixer``), ``"sliding_attention"``
-(attention over ``sliding_window`` tokens, rotated),
-``"full_attention"`` (causal attention over everything, no positions),
-``"latent_attention"`` (multi-head latent attention, ``_latent_mixer``,
-with leaves of its own) or ``"cca"`` (compressed convolutional attention,
-``_cca_mixer``, with leaves of its own); the three attention kinds share
-one set of leaves. Each layer ends in a
-feed-forward block that is data too: the dense MLP, or with ``use_moe``
-the expert layer in all but the ``num_dense_layers`` leading layers.
-Parameters are stacked per group (the attention mixers, the Mamba mixers,
-the dense blocks, the expert blocks, and what every layer has), and a
-stage scans each maximal run of one (mixer, feed-forward) pair over its
-slice of the stacks (``_make_stage_fn``); a pattern of one pair is one
-scan.
+layer's mixer, a key of the one table ``MIXERS``. A kind's entry
+(``Mixer``) is all the module knows of it: the group of stacks it reads,
+their ``PartitionSpec``s and how they are drawn, what it checks of a
+configuration and refuses of a layout, its mixer function; a new kind is
+an entry. The kinds: ``"attention"`` (softmax attention, above, with the
+model's one ``attention_window`` and ``rope`` switch),
+``"sliding_attention"`` (over ``sliding_window`` tokens, rotated) and
+``"full_attention"`` (causal over everything, no positions), three
+entries over one function and one set of leaves; ``"mamba"`` (Mamba-2,
+``_mamba_mixer``), ``"latent_attention"`` (``_latent_mixer``) and
+``"cca"`` (``_cca_mixer``), each with leaves of its own. Each layer ends
+in a feed-forward block that is data too: the dense MLP, or with
+``use_moe`` the expert layer in all but the ``num_dense_layers`` leading
+layers. Parameters are stacked per group (each group of mixers, the dense
+blocks, the expert blocks, and what every layer has), and a stage scans
+each maximal run of one (mixer, feed-forward) pair over its slice of the
+stacks (``_make_stage_fn``); a pattern of one pair is one scan. What
+rides beside the activations from stage to stage has names (``Carry``:
+the segment ids of packed documents, the router statistics, the router's
+state); a member a model does not have is None.
 
 The Mamba-2 mixer (Dao & Gu, arXiv:2405.21060; HF
 ``GraniteMoeHybridMambaLayer``), for one sequence of normed hidden states
@@ -115,7 +119,7 @@ The ZAYA router (arXiv:2511.17127) of an expert layer ``l`` with
     s = gelu(gelu(rms(r_l) W_1) W_2) W_3    [T, E]
     p = softmax(s);  picks = top_k(p + bias);  weights = p[picks]
 
-``r_l`` rides the layer scan's carry beside the activations. With
+``r_l`` is ``Carry.state``, beside the activations in every scan. With
 ``residual_scales`` a block joins the stream as ``a * x + b + c * block(
 norm(x))`` with learned float32 ``a``, ``b``, ``c`` [d] (one, zero, one at
 the start), leaves ``res1`` and ``res2`` [3, d].
@@ -133,9 +137,10 @@ Pure-jax pytree params (no flax) so shard_map in_specs map 1:1 onto leaves.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 from ..common import metrics as _metrics
 
@@ -154,8 +159,6 @@ with _metrics.span("import:horovod_tpu.models.transformer"):
     from ..parallel.ulysses import context_parallel_attention
 
 
-LAYER_KINDS = ("attention", "mamba", "sliding_attention", "full_attention",
-               "latent_attention", "cca")
 # What a layer names with ``checkpoint_name``, so that a rematerialized
 # layer can keep it (``TransformerConfig.remat_keeps``): the Mamba
 # in-projection's z and x and the scan's output; an attention mixer's Q,
@@ -274,7 +277,7 @@ class TransformerConfig:
     # with ``rope`` False: no positional signal at all (NoPE).
     pos_table: bool = True
     # Each layer's mixer, one of LAYER_KINDS, in order; None = every
-    # layer is "attention". A published ``layer_types``.
+    # layer is the first of them, "attention". A published ``layer_types``.
     layer_types: Optional[Tuple[str, ...]] = None
     # The Mamba-2 mixer: heads of ``mamba_d_head`` channels (their product
     # is the inner width), state size, causal depthwise convolution
@@ -343,23 +346,12 @@ class TransformerConfig:
         if self.layer_types is not None:
             kinds = tuple(self.layer_types)
             object.__setattr__(self, "layer_types", kinds)
-            if len(kinds) != self.n_layers or set(kinds) - set(LAYER_KINDS):
+            if len(kinds) != self.n_layers or set(kinds) - set(MIXERS):
                 raise ValueError(
                     f"layer_types must name {self.n_layers} layers, each "
-                    f"one of {LAYER_KINDS}; got {kinds}")
-            if "mamba" in kinds and not (self.mamba_heads
-                                         and self.mamba_d_head
-                                         and self.mamba_d_state):
-                raise ValueError("a mamba layer needs mamba_heads, "
-                                 "mamba_d_head and mamba_d_state")
-            if "sliding_attention" in kinds and (
-                    not self.sliding_window or self.d_head % 2 != 0):
-                raise ValueError("a sliding_attention layer needs a "
-                                 "sliding_window and an even d_head")
-            if "latent_attention" in kinds:
-                self._check_latent()
-            if "cca" in kinds:
-                self._check_cca()
+                    f"one of {tuple(MIXERS)}; got {kinds}")
+            for kind in dict.fromkeys(kinds):
+                MIXERS[kind].check(self)
         if self.n_mtp_modules not in (0, 1):
             raise ValueError(
                 f"n_mtp_modules must be 0 or 1, got {self.n_mtp_modules}: "
@@ -411,57 +403,9 @@ class TransformerConfig:
                     f"remat_keeps names what a layer writes under "
                     f"checkpoint_name, of {REMAT_NAMES}; got {keeps}")
 
-    def _check_latent(self):
-        rope, nope = self.qk_rope_head_dim, self.qk_nope_head_dim
-        if not (self.q_lora_rank > 0 and self.kv_lora_rank > 0 and nope >= 0
-                and rope > 0 and rope % 2 == 0):
-            raise ValueError(
-                "a latent_attention layer needs q_lora_rank, kv_lora_rank, "
-                "qk_nope_head_dim and an even qk_rope_head_dim")
-        if nope + rope != self.d_head:
-            raise ValueError(
-                f"qk_nope_head_dim + qk_rope_head_dim ({nope} + {rope}) must "
-                f"be d_head ({self.d_head}), the value's width too: a value "
-                f"width that differs from the key's is not built (the flash "
-                f"kernels take q, k and v at one width)")
-        if (self.n_kv_heads is not None or self.qk_norm or self.attn_gate
-                or self.attention_multiplier is not None):
-            raise ValueError(
-                "a latent_attention layer has every head its own key and "
-                "value and neither QK-norm, gate nor attention_multiplier: "
-                "n_kv_heads, qk_norm, attn_gate and attention_multiplier "
-                "are not built through it")
-
-    def _check_cca(self):
-        rotated = self.partial_rotary_factor * self.d_head
-        if (self.cca_time0 < 1 or self.cca_time1 < 1 or rotated % 2
-                or not 0 < rotated <= self.d_head):
-            raise ValueError(
-                "a cca layer needs convolution widths cca_time0, cca_time1 "
-                ">= 1 and an even count of rotated channels "
-                "(partial_rotary_factor x d_head)")
-        if self.kv_heads % 2:
-            raise ValueError(
-                f"a cca layer shifts the upper half of its value heads by "
-                f"one token: kv_heads ({self.kv_heads}) must be even")
-        if (self.qk_norm or self.attn_gate
-                or self.attention_multiplier is not None):
-            raise ValueError(
-                "a cca layer norms its queries and keys itself and has "
-                "neither gate nor attention_multiplier: qk_norm, attn_gate "
-                "and attention_multiplier are not built through it")
-
     @property
     def kv_heads(self) -> int:
         return self.n_heads if self.n_kv_heads is None else self.n_kv_heads
-
-    def attention_of(self, kind: str) -> Tuple[Optional[int], bool]:
-        """(window, whether queries and keys are rotated) of an attending
-        layer of ``kind``: the model's switches for "attention"; a sliding
-        layer has its window and rotates, a full layer neither."""
-        return {"attention": (self.attention_window, self.rope),
-                "sliding_attention": (self.sliding_window, True),
-                "full_attention": (None, False)}[kind]
 
     @property
     def experts_held(self) -> int:
@@ -471,7 +415,7 @@ class TransformerConfig:
     @property
     def kinds(self) -> Tuple[str, ...]:
         """Each layer's mixer, in order."""
-        return self.layer_types or ("attention",) * self.n_layers
+        return self.layer_types or (LAYER_KINDS[0],) * self.n_layers
 
     @property
     def mtp_layer(self) -> "TransformerConfig":
@@ -514,11 +458,8 @@ class TransformerConfig:
 
 
 # Which layers each stacked leaf has one slice for: every layer (the
-# norms), or the layers of one group: those whose mixer attends (of any
-# of the three kinds), the Mamba layers, those that end in the dense MLP,
-# those that end in the expert layer.
-_ATTENTION_LEAVES = ("wqkv", "wq", "wkv", "wo", "gq", "gk", "wgate")
-_ATTENDING = ("attention", "sliding_attention", "full_attention")
+# norms), or those of one group (``_leaf_groups``): of mixers, by the
+# table; that end in the dense MLP; that end in the expert layer.
 _MLP_LEAVES = ("w1", "w2", "wgu")
 _ROUTED_LEAVES = ("router", "wg", "wu", "wd", "expert_bias")
 # The ZAYA router's own (``router_hidden``), in place of ``router``.
@@ -534,26 +475,21 @@ _MTP_LEAVES = ("mtp_hnorm", "mtp_enorm", "mtp_eh", "mtp_final_ln")
 _STATE_LEAVES = ("expert_bias", "mtp_expert_bias")
 
 
-def _mixer_group(kind: str) -> str:
-    """The group of stacks a mixer of ``kind`` reads."""
-    return {"mamba": "mamba", "latent_attention": "latent",
-            "cca": "cca"}.get(kind, "attention")
+def _mixer_groups(kinds) -> Dict[str, "Mixer"]:
+    """The groups of stacks that layers of ``kinds`` read, each with an
+    entry of ``MIXERS`` that reads it (a group's kinds share its leaves)."""
+    return {MIXERS[kind].group: MIXERS[kind] for kind in kinds}
 
 
-def _leaf_group(name: str) -> Optional[str]:
-    """The group of layers whose stack ``name`` is, None for a leaf every
-    layer has."""
-    if name.startswith("m_"):
-        return "mamba"
-    if name.startswith("l_"):
-        return "latent"
-    if name.startswith("c_"):
-        return "cca"
-    for group, leaves in (("attention", _ATTENTION_LEAVES),
-                          ("mlp", _MLP_LEAVES), ("moe", _MOE_LEAVES)):
-        if name in leaves:
-            return group
-    return None
+def _leaf_groups(cfg: TransformerConfig) -> Dict[str, str]:
+    """Stacked leaf -> the group of layers whose stack it is; a leaf every
+    layer has is in none."""
+    groups = {name: group
+              for group, entry in _mixer_groups(cfg.kinds).items()
+              for name in entry.specs(cfg)}
+    groups.update({name: "mlp" for name in _MLP_LEAVES})
+    groups.update({name: "moe" for name in _MOE_LEAVES})
+    return groups
 
 
 def trained(params: Dict) -> Dict:
@@ -566,71 +502,17 @@ def trained(params: Dict) -> Dict:
 def _param_specs(cfg: TransformerConfig) -> Dict[str, P]:
     """PartitionSpecs for every param leaf (leading dims: [S(tage), L(ayers
     of the leaf's kind in the stage)] on per-layer params)."""
-    specs = {
-        "embed": P(),
-        "ln1": P("pp"),
-        "ln2": P("pp"),
-        "final_ln": P(),
-    }
+    specs = {"embed": P(), "ln1": P("pp"), "ln2": P("pp"), "final_ln": P()}
     if cfg.post_norms:
-        specs["ln1_post"] = P("pp")
-        specs["ln2_post"] = P("pp")
+        specs.update(ln1_post=P("pp"), ln2_post=P("pp"))
     if cfg.residual_scales:
-        specs["res1"] = P("pp")
-        specs["res2"] = P("pp")
+        specs.update(res1=P("pp"), res2=P("pp"))
     if not cfg.tie_embeddings:
         specs["head"] = P()
     if cfg.pos_table and not cfg.rope:
         specs["pos"] = P()
-    if set(cfg.kinds) & set(_ATTENDING):
-        specs["wo"] = P("pp", None, "tp")
-        if cfg.kv_heads == cfg.n_heads:
-            specs["wqkv"] = P("pp", None, None, None, "tp")
-        else:
-            specs["wq"] = P("pp", None, None, "tp")
-            specs["wkv"] = P("pp", None, None, None, "tp")
-        if cfg.qk_norm == "head":  # one [d_head] vector for all heads
-            specs["gq"] = P("pp")
-            specs["gk"] = P("pp")
-        elif cfg.qk_norm:
-            specs["gq"] = P("pp", None, "tp")
-            specs["gk"] = P("pp", None, "tp")
-        if cfg.attn_gate:
-            specs["wgate"] = P("pp", None, None, "tp")
-    if "mamba" in cfg.kinds:
-        # Heads over tp; B, C and their convolution channels whole.
-        specs.update({
-            "m_wzx": P("pp", None, None, None, "tp"),
-            "m_wbc": P("pp"),
-            "m_wdt": P("pp", None, None, "tp"),
-            "m_conv_x": P("pp", None, None, "tp"),
-            "m_conv_xb": P("pp", None, "tp"),
-            "m_conv_bc": P("pp"),
-            "m_conv_bcb": P("pp"),
-            "m_dt_bias": P("pp", None, "tp"),
-            "m_A_log": P("pp", None, "tp"),
-            "m_D": P("pp", None, "tp"),
-            "m_g": P("pp", None, "tp"),
-            "m_wo": P("pp", None, "tp"),
-        })
-    if "latent_attention" in cfg.kinds:
-        # Heads over tp; the down-projections and their norms whole.
-        specs.update({
-            "l_wqa": P("pp"), "l_qnorm": P("pp"),
-            "l_wqb": P("pp", None, None, "tp"),
-            "l_wkva": P("pp"), "l_kvnorm": P("pp"),
-            "l_wkvb": P("pp", None, None, "tp"),
-            "l_wo": P("pp", None, "tp"),
-        })
-    if "cca" in cfg.kinds:
-        # Heads over tp, the filters and the temperature with them.
-        heads = P("pp", None, None, "tp")
-        specs.update({
-            "c_wq": heads, "c_wk": heads, "c_wv": heads,
-            "c_conv0_q": heads, "c_conv0_k": heads,
-            "c_conv1_q": heads, "c_conv1_k": heads,
-            "c_beta": P("pp", None, "tp"), "c_wo": P("pp", None, "tp"),
-        })
+    for entry in _mixer_groups(cfg.kinds).values():
+        specs.update(entry.specs(cfg))
     if cfg.n_mtp_modules:
         # The layer's leaves with the modules where the stages were.
         for name, spec in _param_specs(cfg.mtp_layer).items():
@@ -642,41 +524,34 @@ def _param_specs(cfg: TransformerConfig) -> Dict[str, P]:
             specs.update({name: P("pp") for name in _ROUTER_MLP_LEAVES})
         else:
             specs["router"] = P("pp")
-        specs.update({
-            "wg": P("pp", None, "dp"),
-            "wu": P("pp", None, "dp"),
-            "wd": P("pp", None, "dp"),
-        })
+        specs.update({k: P("pp", None, "dp") for k in ("wg", "wu", "wd")})
         if cfg.n_shared_experts:  # the dense MLP's layout: width over tp
             specs["shared_wgu"] = P("pp", None, None, None, "tp")
             specs["shared_w2"] = P("pp", None, "tp")
         if cfg.expert_bias_rate:
             specs["expert_bias"] = P("pp")
-    if "mlp" not in cfg.ffn_kinds:
-        return specs
-    if cfg.gated_mlp:
-        specs.update({
-            "wgu": P("pp", None, None, None, "tp"),
-            "w2": P("pp", None, "tp"),
-        })
-    else:
-        specs.update({
-            "w1": P("pp", None, None, "tp"),
-            "w2": P("pp", None, "tp"),
-        })
+    if "mlp" in cfg.ffn_kinds:  # width over tp
+        specs["w2"] = P("pp", None, "tp")
+        if cfg.gated_mlp:
+            specs["wgu"] = P("pp", None, None, None, "tp")
+        else:
+            specs["w1"] = P("pp", None, None, "tp")
     return specs
 
 
 def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
     """Global (unsharded) parameter pytree; shard with ``shard_params``."""
     pattern = cfg.stage_pattern(n_stages)
-    stage = [_mixer_group(mixer) for mixer, _ in pattern]
+    stage = [MIXERS[mixer].group for mixer, _ in pattern]
     ffns = [ffn for _, ffn in pattern]
     lps = len(pattern)
-    H, Dh, d, F = cfg.n_heads, cfg.d_head, cfg.d_model, cfg.d_ff
+    d, F = cfg.d_model, cfg.d_ff
+    # The model's keys: twelve; the last again five ways for the leaves
+    # that came after those were dealt out (the first is the attention
+    # gate's); the root folded with a salt for what came later still (1
+    # and 3 in ``MIXERS``, which gives an entry the root; 2 and 4 below).
     ks = jax.random.split(rng, 12)
-    # The leaves that came after the twelve were dealt out.
-    k_gate, k_shared_gu, k_shared_2, k_dense_gu, k_dense_2 = \
+    _, k_shared_gu, k_shared_2, k_dense_gu, k_dense_2 = \
         jax.random.split(ks[11], 5)
     dt = cfg.dtype
 
@@ -700,46 +575,18 @@ def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
         params["head"] = norm(ks[4], (d, cfg.vocab), d ** -0.5)
     if cfg.pos_table and not cfg.rope:
         params["pos"] = norm(ks[1], (cfg.max_seq, d), 0.02)
-    k_latent, k_mtp, k_cca, k_router = (
-        jax.random.fold_in(rng, salt) for salt in (1, 2, 3, 4))
-    if stage.count("cca"):
-        params.update(_init_cca(cfg, k_cca, (n_stages, stage.count("cca")),
-                                norm))
     if cfg.n_mtp_modules:
-        params.update(_init_mtp(cfg, k_mtp, norm))
-    if stage.count("latent"):
-        params.update(_init_latent(
-            cfg, k_latent, (n_stages, stage.count("latent")), norm))
-    La = stage.count("attention")
-    if La:
-        Hkv = cfg.kv_heads
-        params["wo"] = norm(ks[3], (n_stages, La, H, Dh, d),
-                            (H * Dh) ** -0.5)
-        if Hkv == H:
-            params["wqkv"] = norm(ks[2], (n_stages, La, d, 3, H, Dh),
-                                  d ** -0.5)
-        else:
-            params["wq"] = norm(ks[2], (n_stages, La, d, H, Dh), d ** -0.5)
-            params["wkv"] = norm(ks[8], (n_stages, La, d, 2, Hkv, Dh),
-                                 d ** -0.5)
-        if cfg.qk_norm == "head":
-            params["gq"] = jnp.ones((n_stages, La, Dh), jnp.float32)
-            params["gk"] = jnp.ones((n_stages, La, Dh), jnp.float32)
-        elif cfg.qk_norm:
-            params["gq"] = jnp.ones((n_stages, La, H, Dh), jnp.float32)
-            params["gk"] = jnp.ones((n_stages, La, Hkv, Dh), jnp.float32)
-        if cfg.attn_gate:
-            params["wgate"] = norm(k_gate, (n_stages, La, d, H, Dh),
-                                   d ** -0.5)
-    Lm = stage.count("mamba")
-    if Lm:
-        params.update(_init_mamba(cfg, ks[10], (n_stages, Lm), norm))
+        params.update(_init_mtp(cfg, jax.random.fold_in(rng, 2), norm))
+    for group, entry in _mixer_groups(cfg.kinds).items():
+        params.update(entry.init(
+            cfg, rng, (n_stages, stage.count(group)), norm))
     Le, Ld = ffns.count("moe"), ffns.count("mlp")
     if Le:
         E, Eh, Fe = cfg.n_experts, cfg.experts_held, cfg.d_expert
         Fs = cfg.n_shared_experts * Fe
         if cfg.router_hidden:
-            params.update(_init_router_mlp(cfg, k_router, (n_stages, Le)))
+            params.update(_init_router_mlp(
+                cfg, jax.random.fold_in(rng, 4), (n_stages, Le)))
         else:
             params["router"] = (jax.random.normal(
                 ks[5], (n_stages, Le, d, E)) * d ** -0.5)
@@ -758,16 +605,32 @@ def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
                                               jnp.float32)
     # A model of dense layers alone takes the keys it always took.
     k_gu, k_2 = (k_dense_gu, k_dense_2) if Le else (ks[5], ks[6])
-    if Ld and cfg.gated_mlp:
-        params.update({
-            "wgu": norm(k_gu, (n_stages, Ld, d, 2, F), d ** -0.5),
-            "w2": norm(k_2, (n_stages, Ld, F, d), F ** -0.5),
-        })
-    elif Ld:
-        params.update({
-            "w1": norm(k_gu, (n_stages, Ld, d, F), d ** -0.5),
-            "w2": norm(k_2, (n_stages, Ld, F, d), F ** -0.5),
-        })
+    if Ld:
+        name, width = ("wgu", (2, F)) if cfg.gated_mlp else ("w1", (F,))
+        params[name] = norm(k_gu, (n_stages, Ld, d) + width, d ** -0.5)
+        params["w2"] = norm(k_2, (n_stages, Ld, F, d), F ** -0.5)
+    return params
+
+
+def _init_attention(cfg: TransformerConfig, rng, lead, norm) -> Dict:
+    """The attention mixers' leaves with leading shape ``lead``: matrices
+    normal at fan-in^-1/2 under the keys they have always had, of the
+    model's root (``init_params``), the QK-norms' weights one (float32)."""
+    d, H, Hkv, Dh = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.d_head
+    ks = jax.random.split(rng, 12)
+    params = {"wo": norm(ks[3], lead + (H, Dh, d), (H * Dh) ** -0.5)}
+    if Hkv == H:
+        params["wqkv"] = norm(ks[2], lead + (d, 3, H, Dh), d ** -0.5)
+    else:
+        params["wq"] = norm(ks[2], lead + (d, H, Dh), d ** -0.5)
+        params["wkv"] = norm(ks[8], lead + (d, 2, Hkv, Dh), d ** -0.5)
+    if cfg.qk_norm:
+        heads = ((), ()) if cfg.qk_norm == "head" else ((H,), (Hkv,))
+        params.update({name: jnp.ones(lead + h + (Dh,), jnp.float32)
+                       for name, h in zip(("gq", "gk"), heads)})
+    if cfg.attn_gate:
+        params["wgate"] = norm(jax.random.split(ks[11], 5)[0],
+                               lead + (d, H, Dh), d ** -0.5)
     return params
 
 
@@ -892,8 +755,8 @@ def _validate_mesh_divisibility(cfg: TransformerConfig, mesh) -> None:
     surfaces later as an opaque XLA sharding error at compile time.
     Checked here — where the mesh is known — rather than in
     ``__post_init__``, which never sees it. Likewise a pipeline whose
-    stages would not hold whole periods of the layer pattern, and what a
-    Mamba layer or the balancing bias cannot yet do across ``sp``."""
+    stages would not hold whole periods of the layer pattern, and what
+    the model's layers refuse across ``sp`` or ``pp`` (``_refuse``)."""
     shape = dict(mesh.shape)
     tp = shape.get("tp", 1)
     if cfg.n_heads % tp != 0:
@@ -906,50 +769,26 @@ def _validate_mesh_divisibility(cfg: TransformerConfig, mesh) -> None:
             f"tp axis ({tp}) — wkv shards the KV-head dim over tp; use "
             f"n_kv_heads that is a multiple of tp (or tp <= n_kv_heads)")
     cfg.stage_pattern(_pipeline_stages(mesh))
-    if "latent_attention" in cfg.kinds or cfg.n_mtp_modules:
-        for axis, what in (("sp", "sequence shards"),
-                           ("pp", "pipeline stages")):
-            if shape.get(axis, 1) > 1:
+    for axis in ("sp", "pp"):
+        if shape.get(axis, 1) > 1:
+            _refuse(cfg, axis)
+    for kind in dict.fromkeys(cfg.kinds):
+        for name in MIXERS[kind].tp_divides:
+            if getattr(cfg, name) % tp != 0:
                 raise ValueError(
-                    f"{what} ({axis} > 1) through a latent_attention layer "
-                    f"or a multi-token-prediction module are not built: no "
-                    f"test holds the shared rotated key or the module's "
-                    f"shifted labels across them")
-    if "cca" in cfg.kinds or cfg.router_hidden:
-        for axis, what in (("sp", "sequence shards"),
-                           ("pp", "pipeline stages")):
-            if shape.get(axis, 1) > 1:
-                raise ValueError(
-                    f"{what} ({axis} > 1) through a cca layer or the "
-                    f"router's carried state are not built: the "
-                    f"convolutions' and the shifted values' last rows are "
-                    f"not handed to the next sp member, and no test holds "
-                    f"the router's state on the pipeline's ring")
-    if cfg.expert_bias_rate and shape.get("sp", 1) > 1:
-        raise ValueError(
-            "the router's balancing bias is not built over sp > 1: no "
-            "test holds the counts of a sequence's shards")
-    if "mamba" in cfg.kinds:
-        if cfg.mamba_heads % tp != 0:
-            raise ValueError(
-                f"mamba_heads ({cfg.mamba_heads}) must be divisible by "
-                f"the mesh's tp axis ({tp}) — the mixer shards its heads "
-                f"over tp")
-        if shape.get("sp", 1) > 1:
-            raise ValueError(
-                "a mamba layer cannot run over sp > 1: the scan's state "
-                "at a shard's last token and the convolution's last "
-                f"{cfg.mamba_d_conv - 1} rows are not handed to the next "
-                "sp member")
+                    f"{name} ({getattr(cfg, name)}) must be divisible by "
+                    f"the mesh's tp axis ({tp}) — the mixer shards its "
+                    f"heads over tp")
 
 
 def _model_counts(cfg: TransformerConfig) -> Dict[str, int]:
     """What the set-up spans and the ``model.*`` counters say of a model
-    with either: its latent attention layers (a multi-token-prediction
-    module's among them) and its multi-token-prediction modules."""
-    latent = (cfg.kinds + cfg.kinds[-1:] * cfg.n_mtp_modules).count(
-        "latent_attention")
-    counts = {"latent_layers": latent, "mtp_modules": cfg.n_mtp_modules}
+    with either: its layers of each kind whose entry names a count (a
+    multi-token-prediction module's among them) and those modules."""
+    layers = cfg.kinds + cfg.kinds[-1:] * cfg.n_mtp_modules
+    counts = {MIXERS[kind].counts: layers.count(kind)
+              for kind in dict.fromkeys(layers) if MIXERS[kind].counts}
+    counts["mtp_modules"] = cfg.n_mtp_modules
     return {k: n for k, n in counts.items() if n}
 
 
@@ -1204,6 +1043,272 @@ def _cca_mixer(cfg: TransformerConfig, h, lp):
             jnp.einsum("bthk,hkd->btd", attn, lp["c_wo"]), "attn_proj")
 
 
+def _attention_mixer(cfg: TransformerConfig, h, lp, seg, gathered_seg,
+                     window, rope):
+    """Softmax attention on normed h [b, t, d] over ``window`` tokens (None:
+    all before), queries and keys rotated with ``rope``; heads are this tp
+    member's, the sequence this sp member's, the result its partial sum."""
+    if "wqkv" in lp:
+        qkv = jnp.einsum("btd,dchk->btchk", h, lp["wqkv"])  # h=H/tp
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+    else:  # GQA: separate q and (fewer-headed) kv projections
+        q = checkpoint_name(
+            jnp.einsum("btd,dhk->bthk", h, lp["wq"]), "attn_q")
+        kv = checkpoint_name(
+            jnp.einsum("btd,dchk->btchk", h, lp["wkv"]),  # h=Hkv/tp
+            "attn_kv")
+        k, v = kv[:, :, 0], kv[:, :, 1]
+    if cfg.qk_norm == "head":  # [b, t, h, k] over k, one scale [k]
+        q = _rmsnorm(q, lp["gq"], cfg.norm_eps)
+        k = _rmsnorm(k, lp["gk"], cfg.norm_eps)
+    elif cfg.qk_norm:
+        q = _qk_norm(q, lp["gq"], cfg.norm_eps)
+        k = _qk_norm(k, lp["gk"], cfg.norm_eps)
+    if rope:
+        t_local = h.shape[1]
+        pos = (lax.axis_index("sp") * t_local
+               + jnp.arange(t_local, dtype=jnp.int32))
+        q = _rope(q, pos, cfg.rope_theta)
+        k = _rope(k, pos, cfg.rope_theta)
+    if cfg.attention_multiplier is not None:
+        # The kernels keep their d_head^-1/2; the rest goes on q.
+        q = _times(q, cfg.attention_multiplier * cfg.d_head ** 0.5)
+    # GQA K/V stay at their reduced head width here — the
+    # context-parallel strategies carry them across the sp fabric
+    # at that width and expand only at the kernel boundary.
+    attn = context_parallel_attention(
+        q, k, v, axis_name="sp", causal=True,
+        strategy=cfg.sp_strategy, segment_ids=seg,
+        gathered_segment_ids=gathered_seg, window=window)
+    if cfg.attn_gate:
+        with jax.named_scope("attn_gate"):
+            attn = _sigmoid_gated(attn, checkpoint_name(jnp.einsum(
+                "btd,dhk->bthk", h, lp["wgate"]), "attn_gate"))
+    return checkpoint_name(
+        jnp.einsum("bthk,hkd->btd", attn, lp["wo"]), "attn_proj")
+
+
+def _check_mamba(cfg: TransformerConfig):
+    if not (cfg.mamba_heads and cfg.mamba_d_head and cfg.mamba_d_state):
+        raise ValueError("a mamba layer needs mamba_heads, "
+                         "mamba_d_head and mamba_d_state")
+
+
+def _check_sliding(cfg: TransformerConfig):
+    if not cfg.sliding_window or cfg.d_head % 2 != 0:
+        raise ValueError("a sliding_attention layer needs a "
+                         "sliding_window and an even d_head")
+
+
+def _check_latent(cfg: TransformerConfig):
+    rope, nope = cfg.qk_rope_head_dim, cfg.qk_nope_head_dim
+    if not (cfg.q_lora_rank > 0 and cfg.kv_lora_rank > 0 and nope >= 0
+            and rope > 0 and rope % 2 == 0):
+        raise ValueError(
+            "a latent_attention layer needs q_lora_rank, kv_lora_rank, "
+            "qk_nope_head_dim and an even qk_rope_head_dim")
+    if nope + rope != cfg.d_head:
+        raise ValueError(
+            f"qk_nope_head_dim + qk_rope_head_dim ({nope} + {rope}) must "
+            f"be d_head ({cfg.d_head}), the value's width too: a value "
+            f"width that differs from the key's is not built (the flash "
+            f"kernels take q, k and v at one width)")
+    if (cfg.n_kv_heads is not None or cfg.qk_norm or cfg.attn_gate
+            or cfg.attention_multiplier is not None):
+        raise ValueError(
+            "a latent_attention layer has every head its own key and "
+            "value and neither QK-norm, gate nor attention_multiplier: "
+            "n_kv_heads, qk_norm, attn_gate and attention_multiplier "
+            "are not built through it")
+
+
+def _check_cca(cfg: TransformerConfig):
+    rotated = cfg.partial_rotary_factor * cfg.d_head
+    if (cfg.cca_time0 < 1 or cfg.cca_time1 < 1 or rotated % 2
+            or not 0 < rotated <= cfg.d_head):
+        raise ValueError(
+            "a cca layer needs convolution widths cca_time0, cca_time1 "
+            ">= 1 and an even count of rotated channels "
+            "(partial_rotary_factor x d_head)")
+    if cfg.kv_heads % 2:
+        raise ValueError(
+            f"a cca layer shifts the upper half of its value heads by "
+            f"one token: kv_heads ({cfg.kv_heads}) must be even")
+    if (cfg.qk_norm or cfg.attn_gate
+            or cfg.attention_multiplier is not None):
+        raise ValueError(
+            "a cca layer norms its queries and keys itself and has "
+            "neither gate nor attention_multiplier: qk_norm, attn_gate "
+            "and attention_multiplier are not built through it")
+
+
+def _attention_specs(cfg: TransformerConfig) -> Dict[str, P]:
+    specs = {"wo": P("pp", None, "tp")}
+    if cfg.kv_heads == cfg.n_heads:
+        specs["wqkv"] = P("pp", None, None, None, "tp")
+    else:
+        specs["wq"] = P("pp", None, None, "tp")
+        specs["wkv"] = P("pp", None, None, None, "tp")
+    if cfg.qk_norm:  # "head": one [d_head] vector for all heads
+        scale = P("pp") if cfg.qk_norm == "head" else P("pp", None, "tp")
+        specs.update(gq=scale, gk=scale)
+    if cfg.attn_gate:
+        specs["wgate"] = P("pp", None, None, "tp")
+    return specs
+
+
+@dataclasses.dataclass(frozen=True)
+class Mixer:
+    """One kind of layer, its entry in ``MIXERS``: all the module does by
+    a layer's kind it reads here, and nothing else names a kind."""
+    # The group of stacks its layers read; a group's kinds share its
+    # leaves, and so ``specs`` and ``init``.
+    group: str
+    # specs(cfg) -> the PartitionSpec of each of the group's stacks [S, L,
+    # ...]. Which leaves are the group's is read here: a prefix of its own.
+    specs: Callable[[TransformerConfig], Dict[str, P]]
+    # init(cfg, rng, lead, norm) -> those leaves with leading shape
+    # ``lead``, from the model's root key (a new kind folds in a salt of
+    # its own) and ``init_params``'s ``norm(key, shape, scale)``.
+    init: Callable[..., Dict]
+    # mixer(cfg, h, lp, seg, gathered_seg) -> this tp member's partial sum
+    # [b, t, d] on normed h; ``seg`` the ids of packed documents, or None.
+    mixer: Callable[..., Any]
+    # check(cfg) raises what the kind needs of a configuration.
+    check: Callable[[TransformerConfig], None] = lambda cfg: None
+    # refuses(cfg) -> the ValueError's sentence for each of "packed"
+    # documents, "sp" > 1 and "pp" > 1 that is not built through it.
+    refuses: Callable[[TransformerConfig], Dict[str, str]] = lambda cfg: {}
+    # Fields of the configuration (head counts) that tp must divide.
+    tp_divides: Tuple[str, ...] = ()
+    # The name under which ``_model_counts`` counts the kind's layers.
+    counts: Optional[str] = None
+
+
+def _attending(window, rope, **more) -> Mixer:
+    """The entry of a kind that attends with the shared attention leaves
+    over ``window(cfg)`` tokens, rotated where ``rope(cfg)``."""
+    return Mixer(
+        group="attention", specs=_attention_specs, init=_init_attention,
+        mixer=lambda cfg, h, lp, seg, gathered_seg: _attention_mixer(
+            cfg, h, lp, seg, gathered_seg, window(cfg), rope(cfg)), **more)
+
+
+def _across(rest: str) -> Dict[str, str]:
+    """The refusals of sequence shards and of pipeline stages, ``rest``
+    what they are refused through and why."""
+    return {"sp": f"sequence shards (sp > 1) {rest}",
+            "pp": f"pipeline stages (pp > 1) {rest}"}
+
+
+_NO_SEGMENT_MASK = {"packed": (
+    "packed documents through sliding_attention / full_attention layers "
+    "are not built: no test holds their masks together with a segment's")}
+# A multi-token-prediction module refuses what a latent layer does, the
+# router's carried state what a CCA layer does across members.
+_LATENT_REFUSES = {
+    "packed": ("packed documents through a latent_attention layer or a "
+               "multi-token-prediction module are not built: no test holds "
+               "a segment's mask or its last label through them"),
+    **_across("through a latent_attention layer or a "
+              "multi-token-prediction module are not built: no test holds "
+              "the shared rotated key or the module's shifted labels "
+              "across them")}
+_CCA_ACROSS = _across(
+    "through a cca layer or the router's carried state are not built: the "
+    "convolutions' and the shifted values' last rows are not handed to the "
+    "next sp member, and no test holds the router's state on the "
+    "pipeline's ring")
+
+# A mixer function is looked up when a layer is traced, as is what it
+# calls: benchmark/limit_check_*.py replace some in this module.
+MIXERS: Dict[str, Mixer] = {
+    # The model's one ``attention_window`` and ``rope`` switch.
+    "attention": _attending(lambda cfg: cfg.attention_window,
+                            lambda cfg: cfg.rope),
+    "mamba": Mixer(
+        group="mamba",
+        # Heads over tp; B, C and their convolution channels whole.
+        specs=lambda cfg: {
+            "m_wzx": P("pp", None, None, None, "tp"), "m_wbc": P("pp"),
+            "m_wdt": P("pp", None, None, "tp"),
+            "m_conv_x": P("pp", None, None, "tp"),
+            "m_conv_bc": P("pp"), "m_conv_bcb": P("pp"),
+            **{k: P("pp", None, "tp") for k in (
+                "m_conv_xb", "m_dt_bias", "m_A_log", "m_D", "m_g", "m_wo")}},
+        init=lambda cfg, rng, lead, norm: _init_mamba(
+            cfg, jax.random.split(rng, 12)[10], lead, norm),
+        mixer=lambda cfg, h, lp, seg, gathered_seg: _mamba_mixer(cfg, h, lp),
+        check=_check_mamba, tp_divides=("mamba_heads",),
+        refuses=lambda cfg: {
+            "packed": ("packed sequences cannot pass a mamba layer: the "
+                       "scan's state and the convolution are not reset at "
+                       "a segment boundary"),
+            "sp": ("a mamba layer cannot run over sp > 1: the scan's state "
+                   "at a shard's last token and the convolution's last "
+                   f"{cfg.mamba_d_conv - 1} rows are not handed to the "
+                   "next sp member")}),
+    # Over ``sliding_window`` tokens, always rotated.
+    "sliding_attention": _attending(
+        lambda cfg: cfg.sliding_window, lambda cfg: True,
+        check=_check_sliding, refuses=lambda cfg: _NO_SEGMENT_MASK),
+    # Over everything before, no positions.
+    "full_attention": _attending(
+        lambda cfg: None, lambda cfg: False,
+        refuses=lambda cfg: _NO_SEGMENT_MASK),
+    "latent_attention": Mixer(
+        group="latent",
+        # Heads over tp; the down-projections and their norms whole.
+        specs=lambda cfg: {
+            "l_wqa": P("pp"), "l_qnorm": P("pp"),
+            "l_wkva": P("pp"), "l_kvnorm": P("pp"),
+            "l_wqb": P("pp", None, None, "tp"),
+            "l_wkvb": P("pp", None, None, "tp"), "l_wo": P("pp", None, "tp")},
+        init=lambda cfg, rng, lead, norm: _init_latent(
+            cfg, jax.random.fold_in(rng, 1), lead, norm),
+        mixer=lambda cfg, h, lp, seg, gathered_seg: _latent_mixer(
+            cfg, h, lp),
+        check=_check_latent, refuses=lambda cfg: _LATENT_REFUSES,
+        counts="latent_layers"),
+    "cca": Mixer(
+        group="cca",
+        # Heads over tp, the filters and the temperature with them.
+        specs=lambda cfg: {
+            **{k: P("pp", None, None, "tp") for k in (
+                "c_wq", "c_wk", "c_wv", "c_conv0_q", "c_conv0_k",
+                "c_conv1_q", "c_conv1_k")},
+            "c_beta": P("pp", None, "tp"), "c_wo": P("pp", None, "tp")},
+        init=lambda cfg, rng, lead, norm: _init_cca(
+            cfg, jax.random.fold_in(rng, 3), lead, norm),
+        mixer=lambda cfg, h, lp, seg, gathered_seg: _cca_mixer(cfg, h, lp),
+        check=_check_cca,
+        refuses=lambda cfg: {
+            "packed": ("packed documents through a cca layer are not "
+                       "built: the convolutions and the shifted values are "
+                       "not reset at a segment boundary"), **_CCA_ACROSS}),
+}
+LAYER_KINDS = tuple(MIXERS)
+
+
+def _refuse(cfg: TransformerConfig, what: str) -> None:
+    """Raise if ``what`` ("packed" documents, "sp" > 1 or "pp" > 1) is not
+    built through a model of ``cfg``, with the sentence of the first to
+    refuse it: its kinds in order, a multi-token-prediction module, the
+    router's carried state, its balancing bias."""
+    refused = [MIXERS[kind].refuses(cfg) for kind in dict.fromkeys(cfg.kinds)]
+    if cfg.n_mtp_modules:
+        refused.append(_LATENT_REFUSES)
+    if cfg.router_hidden:
+        refused.append(_CCA_ACROSS)
+    if cfg.expert_bias_rate:
+        refused.append({"sp": (
+            "the router's balancing bias is not built over sp > 1: no test "
+            "holds the counts of a sequence's shards")})
+    for refuses in refused:
+        if what in refuses:
+            raise ValueError(refuses[what])
+
+
 @functools.partial(jax.checkpoint, static_argnums=(3,))
 def _router_mlp(r, lp_norm, weights, eps):
     """``gelu(gelu(rms(r) W_1) W_2) W_3`` of the router's state r [b, t,
@@ -1252,12 +1357,12 @@ def _plus(x, offset: int):
 def _runs(pattern):
     """The maximal runs of one (mixer, feed-forward) pair in ``pattern``,
     in order: (mixer, feed-forward, the run's first row in each group of
-    stacks, length). The rows are by ``_leaf_group``: None the run's first
+    stacks, length). The rows are by ``_leaf_groups``: None the run's first
     layer, the mixer's and the feed-forward's group how many earlier
     layers read that group."""
     runs, seen = [], {}
     for at, (mixer, ffn) in enumerate(pattern):
-        groups = (_mixer_group(mixer), ffn)
+        groups = (MIXERS[mixer].group, ffn)
         if runs and runs[-1][:2] == [mixer, ffn]:
             runs[-1][3] += 1
         else:
@@ -1276,44 +1381,36 @@ def _rows(stack, first, n):
     return lax.slice_in_dim(stack, first, first + n, axis=0)
 
 
-def _make_layer_fn(cfg: TransformerConfig, packed: bool = False):
-    """layer(kind, ffn, x, lp, seg, gathered_seg, experts=None): one
-    layer, its mixer block of ``kind`` then its feed-forward block
-    ``ffn``, on x [mb, t_local, d] with the layer's leaves ``lp``;
-    ``(x, router statistics)`` where ``ffn`` is the expert layer, which
-    takes ``experts`` = (the stacks the layer's expert matrices lie in,
-    its index in them). Rematerialized with ``cfg.remat``."""
-    norm = _block_norm(cfg)
-    if packed and "mamba" in cfg.kinds:
-        raise ValueError(
-            "packed sequences cannot pass a mamba layer: the scan's state "
-            "and the convolution are not reset at a segment boundary")
-    if packed and set(cfg.kinds) & {"sliding_attention", "full_attention"}:
-        raise ValueError(
-            "packed documents through sliding_attention / full_attention "
-            "layers are not built: no test holds their masks together "
-            "with a segment's")
-    if packed and ("latent_attention" in cfg.kinds or cfg.n_mtp_modules):
-        raise ValueError(
-            "packed documents through a latent_attention layer or a "
-            "multi-token-prediction module are not built: no test holds "
-            "a segment's mask or its last label through them")
-    if packed and "cca" in cfg.kinds:
-        raise ValueError(
-            "packed documents through a cca layer are not built: the "
-            "convolutions and the shifted values are not reset at a "
-            "segment boundary")
+class Carry(NamedTuple):
+    """What rides beside the activations from stage to stage; a member a
+    model does not have is None (no leaf). The pipeline's ring carries all
+    four; a stage's layer scans carry ``x`` and ``state`` (the ids are the
+    scans' constant, the statistics their result)."""
+    x: Any  # the activations [mb, t_local, d]
+    seg: Any = None  # segment ids int32 [mb, t_local] (packed documents)
+    stats: Any = None  # ``_zero_router_stats`` (``cfg.use_moe``)
+    state: Any = None  # the router's, float32 [mb, t_local, R]
 
-    def layer(kind, ffn, x, lp, seg, gathered_seg, experts=None):
-        # With ``router_hidden`` x is (the activations, the router's state
-        # of the layer before), and so is what comes back.
-        state = None
-        if cfg.router_hidden:
-            x, state = x
+
+def _make_layer_fn(cfg: TransformerConfig, packed: bool = False):
+    """layer(kind, ffn, carry, lp, seg, gathered_seg, experts): one layer,
+    its mixer block of ``kind`` then its feed-forward block ``ffn``, on
+    ``Carry(x [mb, t_local, d], state=the router's of the layer before)``
+    with the layer's leaves ``lp``: ``(the carry after the layer, an
+    expert layer's statistics or None)``. ``experts`` = (the stacks an
+    expert layer's matrices lie in, its index in them). Rematerialized
+    with ``cfg.remat``."""
+    norm = _block_norm(cfg)
+    if packed:
+        _refuse(cfg, "packed")
+
+    def layer(kind, ffn, carry, lp, seg, gathered_seg, experts):
         with jax.named_scope(kind):
-            x = mixer_block(kind, x, lp, seg, gathered_seg)
+            x = mixer_block(kind, carry.x, lp, seg, gathered_seg)
         with jax.named_scope(ffn):
-            return feed_forward_block(ffn, x, lp, experts, state)
+            x, state, stats = feed_forward_block(ffn, x, lp, experts,
+                                                 carry.state)
+        return carry._replace(x=x, state=state), stats
 
     def joined(x, out, lp, scales):
         if cfg.residual_scales:
@@ -1322,60 +1419,11 @@ def _make_layer_fn(cfg: TransformerConfig, packed: bool = False):
 
     def mixer_block(kind, x, lp, seg, gathered_seg):
         h = norm(x, lp["ln1"])
-        if kind == "mamba":
-            out = _mamba_mixer(cfg, h, lp)
-        elif kind == "latent_attention":
-            out = _latent_mixer(cfg, h, lp)
-        elif kind == "cca":
-            out = _cca_mixer(cfg, h, lp)
-        else:
-            out = attention_mixer(kind, h, lp, seg, gathered_seg)
+        out = MIXERS[kind].mixer(cfg, h, lp, seg, gathered_seg)
         out = lax.psum(out, "tp")  # combine head shards
         if cfg.post_norms:
             out = norm(out, lp["ln1_post"])
         return joined(x, out, lp, "res1")
-
-    def attention_mixer(kind, h, lp, seg, gathered_seg):
-        window, rope = cfg.attention_of(kind)
-        # tp-sharded heads, sp ring
-        if "wqkv" in lp:
-            qkv = jnp.einsum("btd,dchk->btchk", h, lp["wqkv"])  # h=H/tp
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        else:  # GQA: separate q and (fewer-headed) kv projections
-            q = checkpoint_name(
-                jnp.einsum("btd,dhk->bthk", h, lp["wq"]), "attn_q")
-            kv = checkpoint_name(
-                jnp.einsum("btd,dchk->btchk", h, lp["wkv"]),  # h=Hkv/tp
-                "attn_kv")
-            k, v = kv[:, :, 0], kv[:, :, 1]
-        if cfg.qk_norm == "head":  # [b, t, h, k] over k, one scale [k]
-            q = _rmsnorm(q, lp["gq"], cfg.norm_eps)
-            k = _rmsnorm(k, lp["gk"], cfg.norm_eps)
-        elif cfg.qk_norm:
-            q = _qk_norm(q, lp["gq"], cfg.norm_eps)
-            k = _qk_norm(k, lp["gk"], cfg.norm_eps)
-        if rope:
-            t_local = h.shape[1]
-            pos = (lax.axis_index("sp") * t_local
-                   + jnp.arange(t_local, dtype=jnp.int32))
-            q = _rope(q, pos, cfg.rope_theta)
-            k = _rope(k, pos, cfg.rope_theta)
-        if cfg.attention_multiplier is not None:
-            # The kernels keep their d_head^-1/2; the rest goes on q.
-            q = _times(q, cfg.attention_multiplier * cfg.d_head ** 0.5)
-        # GQA K/V stay at their reduced head width here — the
-        # context-parallel strategies carry them across the sp fabric
-        # at that width and expand only at the kernel boundary.
-        attn = context_parallel_attention(
-            q, k, v, axis_name="sp", causal=True,
-            strategy=cfg.sp_strategy, segment_ids=seg,
-            gathered_segment_ids=gathered_seg, window=window)
-        if cfg.attn_gate:
-            with jax.named_scope("attn_gate"):
-                attn = _sigmoid_gated(attn, checkpoint_name(jnp.einsum(
-                    "btd,dhk->bthk", h, lp["wgate"]), "attn_gate"))
-        return checkpoint_name(
-            jnp.einsum("bthk,hkd->btd", attn, lp["wo"]), "attn_proj")
 
     def gated_mlp(h, wgu, w2):
         gu = checkpoint_name(jnp.einsum("btd,dcf->btcf", h, wgu), "mlp_gu")
@@ -1383,10 +1431,10 @@ def _make_layer_fn(cfg: TransformerConfig, packed: bool = False):
         return jnp.einsum("btf,fd->btd", y, w2)
 
     def feed_forward_block(ffn, x, lp, experts, state):
-        def carried(x):
-            return (x, state) if cfg.router_hidden else x
-
+        """(x after the block, the router's state after it, the expert
+        layer's statistics or None)."""
         h = norm(x, lp["ln2"])
+        stats = None
         if ffn == "moe":
             stacks, index = experts
             first = None if cfg.n_experts_held is None else _plus(
@@ -1406,18 +1454,16 @@ def _make_layer_fn(cfg: TransformerConfig, packed: bool = False):
                 with jax.named_scope("moe_shared"):
                     y = y + lax.psum(gated_mlp(
                         h, lp["shared_wgu"], lp["shared_w2"]), "tp")
-            if cfg.post_norms:
-                y = norm(y, lp["ln2_post"])
-            return carried(joined(x, y, lp, "res2")), stats
-        if cfg.gated_mlp:
-            y = gated_mlp(h, lp["wgu"], lp["w2"])
         else:
-            y = jax.nn.gelu(jnp.einsum("btd,df->btf", h, lp["w1"]))
-            y = jnp.einsum("btf,fd->btd", y, lp["w2"])
-        y = lax.psum(y, "tp")  # combine hidden-dim shards
+            if cfg.gated_mlp:
+                y = gated_mlp(h, lp["wgu"], lp["w2"])
+            else:
+                y = jax.nn.gelu(jnp.einsum("btd,df->btf", h, lp["w1"]))
+                y = jnp.einsum("btf,fd->btd", y, lp["w2"])
+            y = lax.psum(y, "tp")  # combine hidden-dim shards
         if cfg.post_norms:
             y = norm(y, lp["ln2_post"])
-        return carried(joined(x, y, lp, "res2"))
+        return joined(x, y, lp, "res2"), state, stats
 
     keeps = _REMAT_KEEPS if cfg.remat_keeps is None else cfg.remat_keeps
     return jax.checkpoint(
@@ -1428,17 +1474,10 @@ def _make_layer_fn(cfg: TransformerConfig, packed: bool = False):
 
 def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
                    packed: bool = False):
-    """stage_fn(stage_params, x) applying this stage's layers
-    (``_make_layer_fn``'s).
-
-    x: [mb, t_local, d], or a tuple that starts with it: then the
-    segment ids with ``packed``, then the router statistics with
-    ``cfg.use_moe`` (``_zero_router_stats``), then the router's state [mb,
-    t_local, R] float32 with ``cfg.router_hidden``. They ride the pipeline
-    ring with the activations; the ids pass through each stage unchanged,
-    the statistics gain this stage's layers, the state is the last
-    layer's, and each layer scan carries it beside the activations. Runs
-    under the full (dp, pp, sp, tp) mesh.
+    """stage_fn(stage_params, carry) -> carry, applying this stage's
+    layers (``_make_layer_fn``'s) to a ``Carry``: the ids pass through
+    unchanged, the statistics gain this stage's layers, the state is the
+    last layer's. Runs under the full (dp, pp, sp, tp) mesh.
 
     The stage walks the maximal runs of one (mixer, feed-forward) pair in
     its pattern (``cfg.stage_pattern``) and scans each over its rows of
@@ -1447,25 +1486,19 @@ def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
     """
     pattern = cfg.stage_pattern(n_stages)
     runs = _runs(pattern)
+    groups = _leaf_groups(cfg)
     layer_fn = _make_layer_fn(cfg, packed)
 
-    def stage_fn(stage_params, x):
-        seg = gathered = stats = state = None
-        if cfg.router_hidden:
-            *x, state = x
-        if cfg.use_moe:
-            *x, stats = x
-            x = tuple(x) if packed else x[0]
-        if packed:
-            x, seg = x
-            if cfg.sp_strategy in ("ulysses", "auto"):
-                # Hoist the loop-invariant id gather out of the layer
-                # scan (XLA won't lift collectives out of scan bodies);
-                # if "auto" resolves to ring, the unused gather is DCE'd.
-                from ..parallel.ulysses import gather_segment_ids
+    def stage_fn(stage_params, carry):
+        seg, stats = carry.seg, carry.stats
+        gathered = stacks = None
+        if packed and cfg.sp_strategy in ("ulysses", "auto"):
+            # Hoist the loop-invariant id gather out of the layer
+            # scan (XLA won't lift collectives out of scan bodies);
+            # if "auto" resolves to ring, the unused gather is DCE'd.
+            from ..parallel.ulysses import gather_segment_ids
 
-                gathered = gather_segment_ids(seg, "sp")
-
+            gathered = gather_segment_ids(seg, "sp")
         if cfg.use_moe:
             # The expert kernels read a layer's matrices out of the
             # stage's stacks, constants of the scan, by the layer's index
@@ -1474,28 +1507,25 @@ def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
             # the weight gradients for, one layer an iteration.
             stacks = {k: lax.stop_gradient(stage_params[k])
                       for k in ("wg", "wu", "wd")}
-        if cfg.router_hidden:
-            x = (x, state)
+        scanned = Carry(carry.x, state=carry.state)
         for kind, ffn, rows, n in runs:
             run_params = {
-                k: _rows(v, rows[_leaf_group(k)], n)
+                k: _rows(v, rows[groups.get(k)], n)
                 for k, v in stage_params.items()
-                if _leaf_group(k) in rows}
-            if ffn != "moe":
-                x, _ = lax.scan(
-                    lambda x, lp: (layer_fn(kind, ffn, x, lp, seg,
-                                            gathered), None), x, run_params)
-                continue
+                if groups.get(k) in rows}
 
-            def body(x, scanned):
-                lp, index = scanned
-                return layer_fn(kind, ffn, x, lp, seg, gathered,
+            def body(scanned, layer):
+                lp, index = layer
+                return layer_fn(kind, ffn, scanned, lp, seg, gathered,
                                 (stacks, index))
 
-            # The layers' places among the stage's expert layers, for
+            # An expert layer's place among the stage's expert layers, for
             # the expert kernels.
-            x, layers = lax.scan(
-                body, x, (run_params, _plus(jnp.arange(n), rows["moe"])))
+            index = _plus(jnp.arange(n), rows["moe"]) if ffn == "moe" \
+                else None
+            scanned, layers = lax.scan(body, scanned, (run_params, index))
+            if ffn != "moe":
+                continue
             at = _plus(lax.axis_index("pp") * len(pattern), rows[None])
             stats = {
                 "lb": stats["lb"] + jnp.sum(layers["lb"]) / cfg.n_layers,
@@ -1503,14 +1533,12 @@ def _make_stage_fn(cfg: TransformerConfig, n_stages: int = 1,
                 **{k: lax.dynamic_update_slice_in_dim(
                     stats[k], layers[k].astype(jnp.int32), at, axis=0)
                    for k in ("load", "windows")}}
-        if cfg.router_hidden:
-            x, state = x
-        out = (x,) + ((seg,) if packed else ()) + (
-            (stats,) if cfg.use_moe else ()) + (
-            (state,) if cfg.router_hidden else ())
-        return out if len(out) > 1 else x
+        return carry._replace(x=scanned.x, stats=stats, state=scanned.state)
 
     return stage_fn
+
+
+Forward = collections.namedtuple("Forward", "logits stats hidden")
 
 
 def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
@@ -1522,12 +1550,12 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
     sequences — microbatched alongside the activations so each pipeline
     stage masks attention for the microbatch it is holding.
 
-    Returns ``(logits, router statistics, hidden)``; the logits are None
-    without ``logits`` (a loss that runs the head by blocks); the statistics
-    (``_zero_router_stats``: the two loss terms as means over this
-    member's sequences, tokens per expert summed over them, the most
-    windows a microbatch took) are None without ``cfg.use_moe``;
-    ``hidden`` [b, t, d] is the stack's output before the final norm."""
+    Returns a ``Forward``: ``logits``, None without ``logits`` (a loss that
+    runs the head by blocks); ``stats`` (``_zero_router_stats``: the two
+    loss terms as means over this member's sequences, tokens per expert
+    summed over them, the most windows a microbatch took), None without
+    ``cfg.use_moe``; ``hidden`` [b, t, d], the stack's output before the
+    final norm."""
     b, t = tokens.shape
     with jax.named_scope("embed"):
         sp_idx = lax.axis_index("sp")
@@ -1541,15 +1569,13 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
 
     # microbatch for the pipeline: [M, mb, t, d]
     M = n_microbatches
-    x = x.reshape(M, b // M, t, x.shape[-1])
-    if segment_ids is not None:
-        seg_mb = jnp.asarray(segment_ids, jnp.int32).reshape(M, b // M, t)
-        x = (x, seg_mb)
-    if cfg.use_moe:
-        x = (x if isinstance(x, tuple) else (x,)) + (
-            _zero_router_stats(cfg, (M,)),)
-    if cfg.router_hidden:  # r_{-1} = 0
-        x = x + (jnp.zeros((M, b // M, t, cfg.router_hidden), jnp.float32),)
+    carry = Carry(
+        x=x.reshape(M, b // M, t, x.shape[-1]),
+        seg=None if segment_ids is None else jnp.asarray(
+            segment_ids, jnp.int32).reshape(M, b // M, t),
+        stats=_zero_router_stats(cfg, (M,)) if cfg.use_moe else None,
+        state=jnp.zeros((M, b // M, t, cfg.router_hidden), jnp.float32)
+        if cfg.router_hidden else None)  # r_{-1} = 0
     # Per-stage params: strip the leading pp dim. The local slice MUST be
     # exactly one stage — if init_params was built with a different stage
     # count than the mesh's pp size, layers would silently be dropped.
@@ -1561,26 +1587,21 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
             f"param '{k}' has {v.shape[0]} local stages; init_params "
             "n_stages must equal the mesh pp size")
         stage_params[k] = v[0]
-    # Packed mode: segment ids ride the ring carry for later stages but
-    # are side data, not outputs — collect only the activation leaf (and
-    # the router statistics, which are outputs).
-    collect_fn = None
-    if segment_ids is not None:
-        collect_fn = (lambda s: (s[0], s[2])) if cfg.use_moe else (
-            lambda s: s[0])
-    elif cfg.router_hidden:  # the last layer's state is read by none
-        collect_fn = lambda s: s[:2]  # noqa: E731
-    y = spmd_pipeline(stage_fn, stage_params, x, axis_name="pp",
-                      collect_fn=collect_fn)
-    stats = None
+    # The activations and the router statistics are outputs. The segment
+    # ids ride the ring for the later stages but are side data, and the
+    # last layer's router state is read by none.
+    out = spmd_pipeline(
+        stage_fn, stage_params, carry, axis_name="pp",
+        collect_fn=lambda carry: carry._replace(seg=None, state=None))
+    stats = out.stats
     if cfg.use_moe:
-        y, per_mb = y
-        stats = {"lb": jnp.mean(per_mb["lb"]), "z": jnp.mean(per_mb["z"]),
-                 "load": jnp.sum(per_mb["load"], axis=0),
-                 "windows": jnp.max(per_mb["windows"], axis=0)}
-    y = y.reshape(b, t, -1)
-    return (_head(cfg, params, y, params["final_ln"]) if logits else None,
-            stats, y)
+        stats = {"lb": jnp.mean(stats["lb"]), "z": jnp.mean(stats["z"]),
+                 "load": jnp.sum(stats["load"], axis=0),
+                 "windows": jnp.max(stats["windows"], axis=0)}
+    y = out.x.reshape(b, t, -1)
+    return Forward(
+        _head(cfg, params, y, params["final_ln"]) if logits else None,
+        stats, y)
 
 
 @jax.named_scope("head")
@@ -1588,12 +1609,10 @@ def _head(cfg: TransformerConfig, params, y, final_ln):
     """float32 logits [b, t, V] of hidden states y [b, t, d]: the norm
     with weight ``final_ln``, then the head (or the tied table)."""
     y = _block_norm(cfg)(y, final_ln).astype(jnp.float32)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("btd,vd->btv", y,
-                            params["embed"].astype(jnp.float32))
-    else:
-        logits = jnp.einsum("btd,dv->btv", y,
-                            params["head"].astype(jnp.float32))
+    tied = cfg.tie_embeddings
+    logits = jnp.einsum("btd,vd->btv" if tied else "btd,dv->btv", y,
+                        params["embed" if tied else "head"].astype(
+                            jnp.float32))
     return _times(logits, 1.0 / cfg.logits_scaling)
 
 
@@ -1615,14 +1634,10 @@ def _mtp_module(cfg: TransformerConfig, layer_fn, params, hidden, inputs,
         g = jnp.einsum("btc,cd->btd", jnp.concatenate([
             norm(hidden, lp["hnorm"]), norm(emb, lp["enorm"])], -1),
             lp["eh"])
-    stats = None
-    if ffn == "moe":
-        stacks = {k: lax.stop_gradient(params[_MTP + k])
-                  for k in ("wg", "wu", "wd")}
-        g, stats = layer_fn(kind, ffn, g, lp, None, None, (stacks, 0))
-    else:
-        g = layer_fn(kind, ffn, g, lp, None, None)
-    logits = _head(cfg, params, g, lp["final_ln"])
+    stacks = {k: lax.stop_gradient(params[_MTP + k])
+              for k in ("wg", "wu", "wd")} if ffn == "moe" else None
+    carry, stats = layer_fn(kind, ffn, Carry(g), lp, None, None, (stacks, 0))
+    logits = _head(cfg, params, carry.x, lp["final_ln"])
     with jax.named_scope("loss"):
         nll = token_nll(logits, targets).at[:, -1].set(0.0)
         return nll, jnp.sum(nll) / (nll.size - nll.shape[0]), stats
@@ -1821,19 +1836,20 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
     specs = _param_specs(cfg)
 
     def spmd_loss(params, tokens, labels, segment_ids=None):
-        logits, stats, hidden = _spmd_forward(
+        out = _spmd_forward(
             cfg, stage_fn, params, tokens, n_microbatches,
             segment_ids=segment_ids, logits=cfg.head_block is None)
+        stats = out.stats
         if cfg.n_mtp_modules:
             with jax.named_scope("mtp"):
                 mtp_nll, mtp_loss, mtp_stats = _mtp_module(
-                    cfg, mtp_layer_fn, params, hidden, labels,
+                    cfg, mtp_layer_fn, params, out.hidden, labels,
                     jnp.roll(labels, -1, axis=1))
         if cfg.head_block is not None:
-            loss, nll = _head_nll(cfg, params, hidden, labels)
+            loss, nll = _head_nll(cfg, params, out.hidden, labels)
         with jax.named_scope("loss"):
             if cfg.head_block is None:
-                nll = token_nll(logits, labels)
+                nll = token_nll(out.logits, labels)
                 loss = jnp.mean(nll)
             if cfg.use_moe:
                 loss = (loss + cfg.router_aux_loss_coef * stats["lb"]
@@ -1855,8 +1871,7 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
         return loss, readings
 
     data = P("dp", "sp")
-    in_specs = ((specs, data, data, data) if packed
-                else (specs, data, data))
+    in_specs = (specs,) + (data,) * (3 if packed else 2)
     out_readings = {"load": P(), "windows": P(), "token_nll": data}
     if cfg.n_mtp_modules:
         out_readings["mtp_token_nll"] = data
@@ -1886,26 +1901,29 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
     ``packed=True`` builds step(params, opt_state, tokens, labels,
     segment_ids) for packed-sequence training (``make_loss_fn``).
 
-    With ``cfg.expert_bias_rate`` the step carries state that is no
-    trained parameter: ``params["expert_bias"]`` takes no gradient and
-    the optimizer never sees it (``opt_state`` is made for
-    ``trained(params)``). The step moves it from the tokens each expert
-    got in this very step, under the scope ``router_bias``, and returns
-    ``(params, opt_state, loss, readings)``, ``readings`` the loss
-    function's: that ``load`` [n_layers, n_experts], the ``windows``
-    [n_layers] its expert layers took and every token's cross-entropy
-    ``token_nll`` [B, T] of this step's forward pass. A
+    The step holds the leaves that are no trained parameter aside
+    (``trained``'s complement; most models have none): they take no
+    gradient and the optimizer never sees them (``opt_state`` is made for
+    ``trained(params)``). With ``cfg.expert_bias_rate`` they are
+    ``params["expert_bias"]``, which the step moves from the tokens each
+    expert got in this very step, under the scope ``router_bias``, and it
+    returns ``(params, opt_state, loss, readings)``, ``readings`` the loss
+    function's of this step's forward pass (``make_loss_fn``). A
     multi-token-prediction module's layer has a bias of its own,
-    ``params["mtp_expert_bias"]``, carried and moved the same way from
-    the last row of ``load``."""
+    ``params["mtp_expert_bias"]``, moved the same way from the last row
+    of ``readings["load"]``."""
     import optax
 
     counts = _model_counts(cfg)
     for name, n in counts.items():
         _metrics.inc(f"model.{name}", n)
     _metrics.note(**counts)
+    with_readings = bool(cfg.expert_bias_rate)
+    if with_readings and packed:
+        raise ValueError("packed documents with the router's balancing "
+                         "bias are not built")
     loss_fn = make_loss_fn(cfg, mesh, n_microbatches, packed=packed,
-                           with_readings=bool(cfg.expert_bias_rate))
+                           with_readings=with_readings)
 
     @jax.named_scope("optimizer")
     def apply(grads, params, opt_state):
@@ -1915,44 +1933,6 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
                 opt_state, opt_shardings)
         return optax.apply_updates(params, updates), opt_state
 
-    if cfg.expert_bias_rate:
-        if packed:
-            raise ValueError("packed documents with the router's balancing "
-                             "bias are not built")
-        return jax.jit(_biased_step(cfg, loss_fn, apply),
-                       donate_argnums=(0, 1))
-    # The function's name is the module's on the device trace
-    # (docs/diagnostics.md, "Tracing").
-    if packed:
-        def hvd_decoder_step(params, opt_state, tokens, labels,
-                             segment_ids):
-            loss, grads = jax.value_and_grad(loss_fn)(
-                params, tokens, labels, segment_ids)
-            params, opt_state = apply(grads, params, opt_state)
-            return params, opt_state, loss
-    else:
-        def hvd_decoder_step(params, opt_state, tokens, labels):
-            loss, grads = jax.value_and_grad(loss_fn)(params, tokens,
-                                                      labels)
-            params, opt_state = apply(grads, params, opt_state)
-            return params, opt_state, loss
-
-    return jax.jit(hvd_decoder_step, donate_argnums=(0, 1))
-
-
-def update_expert_bias(bias, load, rate: float):
-    """Aux-loss-free balancing (Wang et al., arXiv:2408.15664), centred:
-    ``bias + delta - mean(delta)`` with ``delta = rate * sign(mean(load) -
-    load)`` over each layer's experts; bias float32 [..., E], load the
-    tokens each expert got, same shape."""
-    load = load.astype(jnp.float32)
-    delta = rate * jnp.sign(jnp.mean(load, -1, keepdims=True) - load)
-    return bias + delta - jnp.mean(delta, -1, keepdims=True)
-
-
-def _biased_step(cfg: TransformerConfig, loss_fn, apply):
-    """``make_train_step``'s step of a model whose router has a balancing
-    bias; ``loss_fn`` returns its readings beside the loss."""
     @jax.named_scope("router_bias")
     def move(biases, load):
         # The expert layers' rows (they follow the dense ones), as each
@@ -1964,19 +1944,35 @@ def _biased_step(cfg: TransformerConfig, loss_fn, apply):
             bias, load[rows[k]].reshape(bias.shape), cfg.expert_bias_rate)
             for k, bias in biases.items()}
 
-    # A name of its own on the device trace: a module of this name has
-    # always carried the scopes this step writes.
-    def hvd_decoder_bias_step(params, opt_state, tokens, labels):
+    def step(params, opt_state, tokens, labels, *segment_ids):
         weights = trained(params)
-        biases = {k: v for k, v in params.items() if k not in weights}
-        (loss, readings), grads = jax.value_and_grad(
-            lambda weights: loss_fn({**weights, **biases},
-                                    tokens, labels), has_aux=True)(weights)
+        held = {k: v for k, v in params.items() if k not in weights}
+        out, grads = jax.value_and_grad(
+            lambda weights: loss_fn({**weights, **held}, tokens, labels,
+                                    *segment_ids),
+            has_aux=with_readings)(weights)
         weights, opt_state = apply(grads, weights, opt_state)
-        return ({**weights, **move(biases, readings["load"])},
-                opt_state, loss, readings)
+        if not with_readings:
+            return weights, opt_state, out
+        loss, readings = out
+        return ({**weights, **move(held, readings["load"])}, opt_state,
+                loss, readings)
 
-    return hvd_decoder_bias_step
+    # The module's name on the device trace (docs/diagnostics.md,
+    # "Tracing"); a step with a bias has always had its own there.
+    step.__name__ = step.__qualname__ = (
+        "hvd_decoder_bias_step" if with_readings else "hvd_decoder_step")
+    return jax.jit(step, donate_argnums=(0, 1))
+
+
+def update_expert_bias(bias, load, rate: float):
+    """Aux-loss-free balancing (Wang et al., arXiv:2408.15664), centred:
+    ``bias + delta - mean(delta)`` with ``delta = rate * sign(mean(load) -
+    load)`` over each layer's experts; bias float32 [..., E], load the
+    tokens each expert got, same shape."""
+    load = load.astype(jnp.float32)
+    delta = rate * jnp.sign(jnp.mean(load, -1, keepdims=True) - load)
+    return bias + delta - jnp.mean(delta, -1, keepdims=True)
 
 
 def dense_reference_loss(cfg: TransformerConfig, params, tokens, labels,
@@ -1994,7 +1990,7 @@ def dense_reference_loss(cfg: TransformerConfig, params, tokens, labels,
     ``benchmark/reference_afmoe.py``, and latent attention and the
     multi-token-prediction module to ``benchmark/reference_glm_lite.py``."""
     if (cfg.use_moe or cfg.norm != "layernorm" or cfg.qk_norm
-            or set(cfg.kinds) != {"attention"} or cfg.attn_gate
+            or set(cfg.kinds) != {LAYER_KINDS[0]} or cfg.attn_gate
             or cfg.post_norms or cfg.gated_mlp or cfg.tie_embeddings
             or not (cfg.pos_table or cfg.rope)
             or (cfg.embedding_multiplier, cfg.residual_multiplier,
@@ -2077,7 +2073,7 @@ def make_forward_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2):
 
     def spmd_fwd(params, tokens):
         return _spmd_forward(cfg, stage_fn, params, tokens,
-                             n_microbatches)[0]
+                             n_microbatches).logits
 
     return jax.jit(_compat_shard_map(
         spmd_fwd, mesh=mesh,
@@ -2098,7 +2094,7 @@ def make_router_load_fn(cfg: TransformerConfig, mesh,
 
     def spmd_load(params, tokens):
         stats = _spmd_forward(cfg, stage_fn, params, tokens,
-                              n_microbatches)[1]
+                              n_microbatches).stats
         return lax.psum(stats["load"], ("dp", "sp"))
 
     return jax.jit(_compat_shard_map(

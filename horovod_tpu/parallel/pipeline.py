@@ -32,17 +32,18 @@ def spmd_pipeline(stage_fn: Callable, stage_params, microbatches,
     stage_fn(params, x) -> y : applies ONE stage (same structure in/out).
     stage_params: this member's stage parameters (already pp-local).
     microbatches: [M, ...] stacked microbatch activations — a single
-    array or any pytree of [M, ...] leaves (e.g. ``(x, segment_ids)``
-    for packed sequences: per-microbatch side data rides the activation
-    ring with the activations). Stage-0 input layout; other stages
-    ignore the values and receive via the ring.
+    array or any pytree of [M, ...] leaves (e.g. the decoder's
+    ``transformer.Carry(x, seg, stats, state)``: per-microbatch side
+    data rides the activation ring with the activations). Stage-0 input
+    layout; other stages ignore the values and receive via the ring.
 
-    collect_fn(y) selects the sub-pytree that is actually an OUTPUT;
-    defaults to the whole structure. Side data the stages merely pass
-    through (segment ids) still rides the per-tick ring carry — later
-    stages consume it — but is excluded from the per-tick output
-    collect and the closing psum-broadcast, saving a dynamic-update per
-    tick and collective bandwidth per leaf.
+    collect_fn(y) selects the sub-pytree that is actually an OUTPUT
+    (e.g. ``lambda carry: carry._replace(seg=None, state=None)``: a
+    member that is None has no leaf); defaults to the whole structure.
+    Side data the stages merely pass through (segment ids) still rides
+    the per-tick ring carry — later stages consume it — but is excluded
+    from the per-tick output collect and the closing psum-broadcast,
+    saving a dynamic-update per tick and collective bandwidth per leaf.
 
     Returns ``collect_fn``-selected [M, ...] outputs as produced by the
     LAST stage (valid on every member after the closing psum-broadcast).

@@ -461,3 +461,53 @@ def test_dryrun_config_train_step():
     for _ in range(2):
         p, o, loss = step(p, o, tokens, labels)
         assert np.isfinite(float(np.asarray(loss)))
+
+
+@pytest.fixture
+def toy_kind():
+    """A seventh kind entered into the table, a linear mixer over one leaf
+    under a prefix of its own; the table is as it was afterwards."""
+    from horovod_tpu.models import transformer
+
+    refused = "packed documents through a toy layer are not built: it is a toy"
+    entry = transformer.Mixer(
+        group="toy",
+        specs=lambda cfg: {"t_w": P("pp")},
+        init=lambda cfg, rng, lead, norm: {"t_w": norm(
+            jax.random.fold_in(rng, 99), lead + (cfg.d_model, cfg.d_model),
+            cfg.d_model ** -0.5)},
+        # Whole on every tp member: its share of what the block sums.
+        mixer=lambda cfg, h, lp, seg, gathered_seg: jnp.einsum(
+            "btd,de->bte", h, lp["t_w"]) / jax.lax.psum(1, "tp"),
+        refuses=lambda cfg: {"packed": refused})
+    before = dict(transformer.MIXERS)
+    transformer.MIXERS["toy"] = entry
+    yield "toy", refused
+    transformer.MIXERS.clear()
+    transformer.MIXERS.update(before)
+
+
+def test_a_new_layer_kind_is_one_entry_of_the_table(toy_kind):
+    """The seam: with nothing but its entry in ``MIXERS`` a new kind is
+    checked, laid out, drawn, sharded, stepped through and refused."""
+    kind, refused = toy_kind
+    cfg = TransformerConfig(vocab=64, d_model=32, n_heads=4, d_head=8,
+                            d_ff=64, n_layers=2, max_seq=64,
+                            layer_types=("attention", kind))
+    mesh = build_parallel_mesh(jax.devices()[:2], dp=1, pp=1, sp=1, tp=2)
+    params, tokens, labels = _setup(cfg, mesh)
+    assert params["t_w"].shape == (1, 1, 32, 32)  # one layer of the kind
+    assert params["wqkv"].shape[:2] == (1, 1)
+    before = np.asarray(params["t_w"])  # the step takes its arguments
+    sharded = shard_params(params, cfg, mesh)
+    opt = optax.sgd(0.1)
+    step = make_train_step(cfg, opt, mesh, n_microbatches=1)
+    new, _, loss = step(sharded, init_opt_state(opt, sharded, mesh), tokens,
+                        labels)
+    assert np.isfinite(float(loss))
+    # Plain SGD: the toy leaf moved by its gradient, which is not zero.
+    assert np.abs(np.asarray(new["t_w"]) - before).max() > 0
+    with pytest.raises(ValueError, match=refused):
+        make_loss_fn(cfg, mesh, n_microbatches=1, packed=True)
+    with pytest.raises(ValueError, match="layer_types must name"):
+        TransformerConfig(n_layers=1, layer_types=("no such kind",))

@@ -249,7 +249,7 @@ def _hidden(cfg, params, tokens):
     stage_fn = transformer._make_stage_fn(cfg, 1)
     return shard_map(
         lambda p, t: transformer._spmd_forward(cfg, stage_fn, p, t, 1,
-                                               logits=False)[2],
+                                               logits=False).hidden,
         mesh=mesh, in_specs=(transformer._param_specs(cfg), P("dp", "sp")),
         out_specs=P("dp", "sp"), check_vma=False)(
             shard_params(params, cfg, mesh), tokens)

@@ -28,8 +28,9 @@ model's one ``attention_window`` and ``rope`` switch),
 ``"sliding_attention"`` (over ``sliding_window`` tokens, rotated) and
 ``"full_attention"`` (causal over everything, no positions), three
 entries over one function and one set of leaves; ``"mamba"`` (Mamba-2,
-``_mamba_mixer``), ``"latent_attention"`` (``_latent_mixer``) and
-``"cca"`` (``_cca_mixer``), each with leaves of its own. Each layer ends
+``_mamba_mixer``), ``"latent_attention"`` (``_latent_mixer``),
+``"cca"`` (``_cca_mixer``) and ``"eva"`` (``_eva_mixer``), each with leaves
+of its own. Each layer ends
 in a feed-forward block that is data too: the dense MLP, or with
 ``use_moe`` the expert layer in all but the ``num_dense_layers`` leading
 layers. Parameters are stacked per group (each group of mixers, the dense
@@ -124,6 +125,35 @@ The ZAYA router (arXiv:2511.17127) of an expert layer ``l`` with
 norm(x))`` with learned float32 ``a``, ``b``, ``c`` [d] (one, zero, one at
 the start), leaves ``res1`` and ``res2`` [3, d].
 
+EVA attention (Zheng et al., arXiv:2302.04542, the deterministic form
+EvaByte runs) on normed ``h`` [T, d], H heads of D channels, windows of
+``eva_window`` = W positions and chunks of ``eva_chunk`` = C, two learned
+vectors ``mu``, ``phi`` [D] a head::
+
+    q, k, v = h W_q, h W_k, h W_v;  q, k rotated over the whole head
+    k~_c = sum_{m in chunk c} softmax_m(mu . k_m) k_m        (keys rotated)
+    v~_c = sum_{m in chunk c} softmax_m(phi . k_m) v_m
+    E_i = {m : m // W = i // W, m <= i};  R_i = {c : c < (W / C) (i // W)}
+    o_i = (sum_E e^{s_im} v_m + sum_R e^{r_ic} v~_c) / (sum_E e^{s_im} + sum_R e^{r_ic})
+    s_im = q_i . k_m / sqrt(D),  r_ic = q_i . k~_c / sqrt(D);  out = concat(o) W_o
+
+A query sees its own window exactly and a summary a chunk of every earlier
+window, under one softmax (``ops/eva_attention.py``: two calls of the flash
+kernels, the second under the block-causal rule, joined by the
+online-softmax combine). The poolings, the softmax and its statistics are
+float32. With C = 1 the summaries are the keys and values themselves and
+with W >= T there is none: either way the layer is causal softmax
+attention. Leaves ``e_*``; heads over ``tp``, ``mu`` and ``phi`` with them.
+
+With ``float32_stream`` the residual stream (``Carry.x``, a rematerialized
+layer's kept input) is float32 while every block computes in the model's
+type: a norm reads the stream and writes the model's type, a block's
+result joins the stream in float32. ``norm_unit_offset`` scales a norm by
+``1 + g`` (g zero at the start). With ``n_pred_heads`` = P > 1 the head's
+one matrix [d, P V] gives P predictions a position, head ``j`` of the
+token ``1 + j`` ahead (labels shifted by ``j`` more, as the labels are by
+one), and the loss is the mean cross-entropy over heads and positions.
+
 With ``head_block`` the head and the loss run by blocks of that many
 tokens (``block_nll``): a block's float32 logits are formed once, in the
 forward pass, which where the loss is differentiated also makes the hidden
@@ -169,7 +199,9 @@ with _metrics.span("import:horovod_tpu.models.transformer"):
 # its up-projections under the attention mixer's ``attn_q``, ``attn_kv``;
 # a CCA mixer's two latents (the queries'; the keys' with the shifted
 # values) and its convolved queries and keys, what it hands the kernels
-# again under ``attn_q``, ``attn_kv``.
+# again under ``attn_q``, ``attn_kv``; an EVA mixer's rotated queries and
+# its rotated keys with the values under the same two, its joint output
+# and row statistics under ``flash_out``, ``flash_lse``.
 REMAT_NAMES = ("mamba_zx", "ssd_out", "attn_q", "attn_kv", "attn_gate",
                "attn_proj", "flash_out", "flash_lse", "mlp_gu", "mla_cq",
                "mla_ckv", "cca_q", "cca_kv", "cca_conv")
@@ -300,6 +332,11 @@ class TransformerConfig:
     cca_time0: int = 2
     cca_time1: int = 2
     partial_rotary_factor: float = 1.0
+    # The EVA mixer (the module's docstring): positions a window, whose
+    # keys a query sees exactly, and positions a chunk, each summarised
+    # as one key and value for the queries of later windows.
+    eva_window: int = 0
+    eva_chunk: int = 0
     # The expert layers' router is the ZAYA router's MLP over a state of
     # this width that crosses layers (the module's docstring); 0: the
     # linear router.
@@ -310,6 +347,21 @@ class TransformerConfig:
     # Tokens a block of the head and the loss (``block_nll``); None: the
     # head's logits whole.
     head_block: Optional[int] = None
+    # Tokens a block of the dense MLP: the blocks run one after another
+    # (a ``lax.scan``), each rematerialized in the backward pass, so that
+    # the MLP's [t, d_ff] arrays exist a block at a time in either pass
+    # (under ``remat`` at no FLOPs more: the layer's second forward then
+    # keeps a block's input alone and its matmuls are dead code). A
+    # weight's gradient is the blocks' terms summed in the model's type.
+    # None: the sequence whole.
+    mlp_block: Optional[int] = None
+    # Predictions a position from the head's one matrix [d, P V]: head j
+    # of the token 1 + j ahead (the module's docstring).
+    n_pred_heads: int = 1
+    # RMSNorm scales by ``1 + g``; the residual stream is float32
+    # whatever ``dtype`` the blocks compute in (the module's docstring).
+    norm_unit_offset: bool = False
+    float32_stream: bool = False
     # Multi-token-prediction modules after the stack (0 or 1; the
     # module's docstring) and the weight of their cross-entropy.
     n_mtp_modules: int = 0
@@ -395,6 +447,22 @@ class TransformerConfig:
                 "head_block counts the tokens of a block of the head and "
                 "the loss; a multi-token-prediction module's head by "
                 "blocks is not built")
+        if self.mlp_block is not None and self.mlp_block < 1:
+            raise ValueError("mlp_block counts the tokens of a block of "
+                             "the dense MLP")
+        if self.n_pred_heads < 1 or (self.n_pred_heads > 1 and (
+                self.head_block is not None or self.tie_embeddings
+                or self.n_mtp_modules)):
+            raise ValueError(
+                "n_pred_heads counts the predictions a position of an "
+                "untied head; through a tied head, the head by blocks or a "
+                "multi-token-prediction module they are not built")
+        if (self.norm_unit_offset or self.float32_stream) and (
+                self.norm != "rmsnorm" or self.residual_scales):
+            raise ValueError(
+                "norm_unit_offset and float32_stream are RMSNorm's and the "
+                "plain residual's; through LayerNorm or residual_scales "
+                "they are not built")
         if self.remat_keeps is not None:
             keeps = tuple(self.remat_keeps)
             object.__setattr__(self, "remat_keeps", keeps)
@@ -548,8 +616,8 @@ def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
     d, F = cfg.d_model, cfg.d_ff
     # The model's keys: twelve; the last again five ways for the leaves
     # that came after those were dealt out (the first is the attention
-    # gate's); the root folded with a salt for what came later still (1
-    # and 3 in ``MIXERS``, which gives an entry the root; 2 and 4 below).
+    # gate's); the root folded with a salt for what came later still (1,
+    # 3 and 5 in ``MIXERS``, which gives an entry the root; 2 and 4 below).
     ks = jax.random.split(rng, 12)
     _, k_shared_gu, k_shared_2, k_dense_gu, k_dense_2 = \
         jax.random.split(ks[11], 5)
@@ -558,21 +626,24 @@ def init_params(cfg: TransformerConfig, rng, n_stages: int) -> Dict:
     def norm(key, shape, scale):
         return (jax.random.normal(key, shape) * scale).astype(dt)
 
+    # A norm's weight starts where it scales by one.
+    unit = jnp.zeros if cfg.norm_unit_offset else jnp.ones
     params = {
         "embed": norm(ks[0], (cfg.vocab, d), 0.02),
-        "ln1": jnp.ones((n_stages, lps, d), jnp.float32),
-        "ln2": jnp.ones((n_stages, lps, d), jnp.float32),
-        "final_ln": jnp.ones((d,), jnp.float32),
+        "ln1": unit((n_stages, lps, d), jnp.float32),
+        "ln2": unit((n_stages, lps, d), jnp.float32),
+        "final_ln": unit((d,), jnp.float32),
     }
     if cfg.post_norms:
-        params["ln1_post"] = jnp.ones((n_stages, lps, d), jnp.float32)
-        params["ln2_post"] = jnp.ones((n_stages, lps, d), jnp.float32)
+        params["ln1_post"] = unit((n_stages, lps, d), jnp.float32)
+        params["ln2_post"] = unit((n_stages, lps, d), jnp.float32)
     if cfg.residual_scales:  # a one, b zero, c one
         start = jnp.array([1.0, 0.0, 1.0], jnp.float32)[:, None]
         for name in ("res1", "res2"):
             params[name] = jnp.broadcast_to(start, (n_stages, lps, 3, d))
     if not cfg.tie_embeddings:
-        params["head"] = norm(ks[4], (d, cfg.vocab), d ** -0.5)
+        params["head"] = norm(ks[4], (d, cfg.n_pred_heads * cfg.vocab),
+                              d ** -0.5)
     if cfg.pos_table and not cfg.rope:
         params["pos"] = norm(ks[1], (cfg.max_seq, d), 0.02)
     if cfg.n_mtp_modules:
@@ -672,6 +743,20 @@ def _init_cca(cfg: TransformerConfig, rng, lead, norm) -> Dict:
                           (K1 * Dh) ** -0.5),
         "c_beta": jnp.ones(lead + (Hkv,), jnp.float32),
         "c_wo": norm(ks[7], lead + (H, Dh, d), (H * Dh) ** -0.5),
+    }
+
+
+def _init_eva(cfg: TransformerConfig, rng, lead, norm) -> Dict:
+    """The EVA mixers' leaves with leading shape ``lead``: matrices normal
+    at fan-in^-1/2, the two pooling vectors of a head normal at D^-1/2
+    (float32)."""
+    d, H, Dh = cfg.d_model, cfg.n_heads, cfg.d_head
+    ks = jax.random.split(rng, 4)
+    return {
+        "e_wqkv": norm(ks[0], lead + (d, 3, H, Dh), d ** -0.5),
+        "e_mu": jax.random.normal(ks[1], lead + (H, Dh)) * Dh ** -0.5,
+        "e_phi": jax.random.normal(ks[2], lead + (H, Dh)) * Dh ** -0.5,
+        "e_wo": norm(ks[3], lead + (H, Dh, d), (H * Dh) ** -0.5),
     }
 
 
@@ -784,11 +869,13 @@ def _validate_mesh_divisibility(cfg: TransformerConfig, mesh) -> None:
 def _model_counts(cfg: TransformerConfig) -> Dict[str, int]:
     """What the set-up spans and the ``model.*`` counters say of a model
     with either: its layers of each kind whose entry names a count (a
-    multi-token-prediction module's among them) and those modules."""
+    multi-token-prediction module's among them), those modules, and the
+    head's predictions a position where they are more than one."""
     layers = cfg.kinds + cfg.kinds[-1:] * cfg.n_mtp_modules
     counts = {MIXERS[kind].counts: layers.count(kind)
               for kind in dict.fromkeys(layers) if MIXERS[kind].counts}
     counts["mtp_modules"] = cfg.n_mtp_modules
+    counts["pred_heads"] = cfg.n_pred_heads if cfg.n_pred_heads > 1 else 0
     return {k: n for k, n in counts.items() if n}
 
 
@@ -849,8 +936,21 @@ def _rmsnorm(x, scale, eps):
     return (xf * jax.lax.rsqrt(ms + eps) * scale).astype(x.dtype)
 
 
+@functools.partial(jax.checkpoint, static_argnums=(2, 3, 4))
+def _rmsnorm_as(x, scale, eps, offset, dtype):
+    """``_rmsnorm`` scaled by ``offset + scale``, written in ``dtype``
+    whatever the operand's type (a float32 stream's norms)."""
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(xf), -1, keepdims=True)
+    return (xf * jax.lax.rsqrt(ms + eps) * (offset + scale)).astype(dtype)
+
+
 def _block_norm(cfg: TransformerConfig):
-    """norm(x, scale) of the residual stream, by ``cfg.norm``."""
+    """norm(x, scale) of the residual stream, by ``cfg.norm``; the
+    model's type from a float32 stream."""
+    if cfg.norm_unit_offset or cfg.float32_stream:
+        return lambda x, scale: _rmsnorm_as(
+            x, scale, cfg.norm_eps, float(cfg.norm_unit_offset), cfg.dtype)
     if cfg.norm == "rmsnorm":
         return lambda x, scale: _rmsnorm(x, scale, cfg.norm_eps)
     return _layernorm
@@ -1043,6 +1143,49 @@ def _cca_mixer(cfg: TransformerConfig, h, lp):
             jnp.einsum("bthk,hkd->btd", attn, lp["c_wo"]), "attn_proj")
 
 
+@functools.partial(jax.checkpoint, static_argnums=(4,))
+def _eva_summaries(k, v, mu, phi, chunk):
+    """A chunk's summary key and value of rotated k and v [b, t, h, D]
+    under a head's pooling vectors mu, phi [h, D]: ``(sum_m softmax_m(mu .
+    k_m) k_m, sum_m softmax_m(phi . k_m) v_m)`` over each chunk's tokens,
+    [b, t / chunk, h, D] in k's type, computed in float32 (formed anew in
+    the backward pass, as the norms' values are)."""
+    b, t, h, D = k.shape
+    kf, vf = (x.astype(jnp.float32).reshape(b, t // chunk, chunk, h, D)
+              for x in (k, v))
+
+    def pooled(w, x):
+        a = jax.nn.softmax(jnp.sum(kf * w, -1), axis=2)  # [b, n, chunk, h]
+        return jnp.sum(a[..., None] * x, axis=2).astype(k.dtype)
+
+    return pooled(mu, kf), pooled(phi, vf)
+
+
+def _eva_mixer(cfg: TransformerConfig, h, lp):
+    """The EVA mixer of the module's docstring on normed h [b, t, d];
+    heads are this tp member's, the result its partial sum."""
+    from ..ops.eva_attention import eva_attention
+
+    t = h.shape[1]
+    pos = jnp.arange(t, dtype=jnp.int32)
+    with jax.named_scope("eva_qkv"):
+        qkv = jnp.einsum("btd,dchk->btchk", h, lp["e_wqkv"])  # h=H/tp
+        q = checkpoint_name(_rope(qkv[:, :, 0], pos, cfg.rope_theta),
+                            "attn_q")
+        k = checkpoint_name(_rope(qkv[:, :, 1], pos, cfg.rope_theta),
+                            "attn_kv")
+        v = checkpoint_name(qkv[:, :, 2], "attn_kv")
+    with jax.named_scope("eva_chunks"):
+        k_sum, v_sum = _eva_summaries(k, v, lp["e_mu"], lp["e_phi"],
+                                      cfg.eva_chunk)
+    # A sequence inside one window is that window.
+    attn = eva_attention(q, k, v, k_sum, v_sum, min(cfg.eva_window, t),
+                         cfg.eva_chunk)
+    with jax.named_scope("eva_out"):
+        return checkpoint_name(
+            jnp.einsum("bthk,hkd->btd", attn, lp["e_wo"]), "attn_proj")
+
+
 def _attention_mixer(cfg: TransformerConfig, h, lp, seg, gathered_seg,
                      window, rope):
     """Softmax attention on normed h [b, t, d] over ``window`` tokens (None:
@@ -1140,6 +1283,20 @@ def _check_cca(cfg: TransformerConfig):
             "a cca layer norms its queries and keys itself and has "
             "neither gate nor attention_multiplier: qk_norm, attn_gate "
             "and attention_multiplier are not built through it")
+
+
+def _check_eva(cfg: TransformerConfig):
+    W, C = cfg.eva_window, cfg.eva_chunk
+    if C < 1 or W < C or W % C or cfg.d_head % 2:
+        raise ValueError(
+            "an eva layer needs eva_window, whole chunks of eva_chunk >= 1, "
+            "and an even d_head (its queries and keys are rotated whole)")
+    if (cfg.n_kv_heads is not None or cfg.qk_norm or cfg.attn_gate
+            or cfg.attention_multiplier is not None):
+        raise ValueError(
+            "an eva layer has every head its own key and value and neither "
+            "QK-norm, gate nor attention_multiplier: n_kv_heads, qk_norm, "
+            "attn_gate and attention_multiplier are not built through it")
 
 
 def _attention_specs(cfg: TransformerConfig) -> Dict[str, P]:
@@ -1286,6 +1443,28 @@ MIXERS: Dict[str, Mixer] = {
             "packed": ("packed documents through a cca layer are not "
                        "built: the convolutions and the shifted values are "
                        "not reset at a segment boundary"), **_CCA_ACROSS}),
+    "eva": Mixer(
+        group="eva",
+        # Heads over tp, the pooling vectors with them.
+        specs=lambda cfg: {
+            "e_wqkv": P("pp", None, None, None, "tp"),
+            "e_mu": P("pp", None, "tp"), "e_phi": P("pp", None, "tp"),
+            "e_wo": P("pp", None, "tp")},
+        init=lambda cfg, rng, lead, norm: _init_eva(
+            cfg, jax.random.fold_in(rng, 5), lead, norm),
+        mixer=lambda cfg, h, lp, seg, gathered_seg: _eva_mixer(cfg, h, lp),
+        check=_check_eva,
+        refuses=lambda cfg: {
+            "packed": ("packed documents through an eva layer are not "
+                       "built: the windows and the chunks are counted from "
+                       "the sequence's first token, not a segment's"),
+            "sp": ("sequence shards (sp > 1) through an eva layer are not "
+                   "built: a shard's queries would need the chunk summaries "
+                   "of every earlier shard, and no exchange hands them on"),
+            "pp": ("pipeline stages (pp > 1) through an eva layer are not "
+                   "built: no test holds the pipeline's ring with a "
+                   "float32 stream")},
+        counts="eva_layers"),
 }
 LAYER_KINDS = tuple(MIXERS)
 
@@ -1294,7 +1473,7 @@ def _refuse(cfg: TransformerConfig, what: str) -> None:
     """Raise if ``what`` ("packed" documents, "sp" > 1 or "pp" > 1) is not
     built through a model of ``cfg``, with the sentence of the first to
     refuse it: its kinds in order, a multi-token-prediction module, the
-    router's carried state, its balancing bias."""
+    router's carried state, its balancing bias, its prediction heads."""
     refused = [MIXERS[kind].refuses(cfg) for kind in dict.fromkeys(cfg.kinds)]
     if cfg.n_mtp_modules:
         refused.append(_LATENT_REFUSES)
@@ -1304,6 +1483,13 @@ def _refuse(cfg: TransformerConfig, what: str) -> None:
         refused.append({"sp": (
             "the router's balancing bias is not built over sp > 1: no test "
             "holds the counts of a sequence's shards")})
+    if cfg.n_pred_heads > 1:
+        refused.append({
+            "packed": ("packed documents under n_pred_heads > 1 are not "
+                       "built: a head's shifted labels would cross a "
+                       "segment's end"),
+            "sp": ("n_pred_heads > 1 is not built over sp > 1: a head's "
+                   "labels are shifted along the whole sequence")})
     for refuses in refused:
         if what in refuses:
             raise ValueError(refuses[what])
@@ -1347,6 +1533,12 @@ def _scaled_residual(x, out, scales):
 def _times(x, multiplier):
     """``x * multiplier``; a multiplier of one is no instruction."""
     return x if multiplier == 1.0 else x * multiplier
+
+
+def _residual(x, out, multiplier):
+    """A block's result joins the stream: in the stream's type, float32
+    under ``float32_stream`` whatever the block computed in."""
+    return x + _times(out, multiplier)
 
 
 def _plus(x, offset: int):
@@ -1415,7 +1607,7 @@ def _make_layer_fn(cfg: TransformerConfig, packed: bool = False):
     def joined(x, out, lp, scales):
         if cfg.residual_scales:
             return _scaled_residual(x, out, lp[scales])
-        return x + _times(out, cfg.residual_multiplier)
+        return _residual(x, out, cfg.residual_multiplier)
 
     def mixer_block(kind, x, lp, seg, gathered_seg):
         h = norm(x, lp["ln1"])
@@ -1429,6 +1621,20 @@ def _make_layer_fn(cfg: TransformerConfig, packed: bool = False):
         gu = checkpoint_name(jnp.einsum("btd,dcf->btcf", h, wgu), "mlp_gu")
         y = jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]
         return jnp.einsum("btf,fd->btd", y, w2)
+
+    def dense_mlp(h, lp):
+        if cfg.gated_mlp:
+            return gated_mlp(h, lp["wgu"], lp["w2"])
+        y = jax.nn.gelu(jnp.einsum("btd,df->btf", h, lp["w1"]))
+        return jnp.einsum("btf,fd->btd", y, lp["w2"])
+
+    def mlp_by_blocks(h, lp):
+        """``dense_mlp`` by blocks of ``cfg.mlp_block`` tokens."""
+        b, t, d = h.shape
+        one = jax.checkpoint(lambda rows: dense_mlp(rows[None], lp)[0])
+        _, y = _over_blocks(lambda carry, rows: (carry, one(rows)),
+                            h.reshape(b * t, d), cfg.mlp_block, None)
+        return y.reshape(b, t, d)
 
     def feed_forward_block(ffn, x, lp, experts, state):
         """(x after the block, the router's state after it, the expert
@@ -1455,11 +1661,8 @@ def _make_layer_fn(cfg: TransformerConfig, packed: bool = False):
                     y = y + lax.psum(gated_mlp(
                         h, lp["shared_wgu"], lp["shared_w2"]), "tp")
         else:
-            if cfg.gated_mlp:
-                y = gated_mlp(h, lp["wgu"], lp["w2"])
-            else:
-                y = jax.nn.gelu(jnp.einsum("btd,df->btf", h, lp["w1"]))
-                y = jnp.einsum("btf,fd->btd", y, lp["w2"])
+            y = dense_mlp(h, lp) if cfg.mlp_block is None else \
+                mlp_by_blocks(h, lp)
             y = lax.psum(y, "tp")  # combine hidden-dim shards
         if cfg.post_norms:
             y = norm(y, lp["ln2_post"])
@@ -1565,7 +1768,7 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
             pos = lax.dynamic_slice_in_dim(params["pos"], sp_idx * t, t,
                                            axis=0)
             x = x + pos[None]
-        x = x.astype(cfg.dtype)
+        x = x.astype(jnp.float32 if cfg.float32_stream else cfg.dtype)
 
     # microbatch for the pipeline: [M, mb, t, d]
     M = n_microbatches
@@ -1607,12 +1810,15 @@ def _spmd_forward(cfg: TransformerConfig, stage_fn, params, tokens,
 @jax.named_scope("head")
 def _head(cfg: TransformerConfig, params, y, final_ln):
     """float32 logits [b, t, V] of hidden states y [b, t, d]: the norm
-    with weight ``final_ln``, then the head (or the tied table)."""
+    with weight ``final_ln``, then the head (or the tied table). With
+    ``cfg.n_pred_heads`` = P > 1 [b, t, P, V], a head's logits a row."""
     y = _block_norm(cfg)(y, final_ln).astype(jnp.float32)
     tied = cfg.tie_embeddings
     logits = jnp.einsum("btd,vd->btv" if tied else "btd,dv->btv", y,
                         params["embed" if tied else "head"].astype(
                             jnp.float32))
+    if cfg.n_pred_heads > 1:
+        logits = logits.reshape(logits.shape[:2] + (cfg.n_pred_heads, -1))
     return _times(logits, 1.0 / cfg.logits_scaling)
 
 
@@ -1804,16 +2010,17 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
     cross-entropy is ``block_nll``'s weighted sum, under ``head``, and
     its gradients are made in the forward pass.
 
-    ``with_readings`` (a ``cfg.use_moe`` model) returns ``(loss,
-    readings)`` instead: ``readings["load"]`` int32 [n_layers, n_experts]
+    ``with_readings`` returns ``(loss, readings)`` instead:
+    ``readings["token_nll"]`` float32 [B, T] every token's cross-entropy,
+    sharded as the tokens, whose mean the loss is ([B, T, P] with
+    ``cfg.n_pred_heads`` = P > 1, a head's a column); and of a
+    ``cfg.use_moe`` model ``readings["load"]`` int32 [n_layers, n_experts]
     the tokens of the global batch that chose each expert in each layer
     (a dense layer's row is zero), what the train step moves the
     balancing bias by, ``readings["windows"]`` int32 [n_layers] the most
     windows of the sorted assignments an expert layer took on any member
     (``parallel.moe``: 1 where the held experts' rows fit one; a dense
-    layer's entry is zero), and ``readings["token_nll"]`` float32 [B, T]
-    every token's cross-entropy, sharded as the tokens, whose mean the
-    loss is. With ``cfg.n_mtp_modules`` both ``load`` and ``windows``
+    layer's entry is zero). With ``cfg.n_mtp_modules`` both ``load`` and ``windows``
     have one more row, the module's layer after the stack's, and
     ``readings["mtp_token_nll"]`` [B, T] is every token's cross-entropy
     of the token after the next in the module (zero at a sequence's last
@@ -1849,6 +2056,11 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
             loss, nll = _head_nll(cfg, params, out.hidden, labels)
         with jax.named_scope("loss"):
             if cfg.head_block is None:
+                if cfg.n_pred_heads > 1:
+                    # Head j's labels: the labels shifted by j more.
+                    labels = jnp.stack([
+                        jnp.roll(labels, -j, axis=1)
+                        for j in range(cfg.n_pred_heads)], -1)
                 nll = token_nll(out.logits, labels)
                 loss = jnp.mean(nll)
             if cfg.use_moe:
@@ -1863,16 +2075,20 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
             # The module's layer after the stack's: one more row.
             stats = {k: jnp.concatenate([stats[k], mtp_stats[k].astype(
                 jnp.int32)[None]]) for k in ("load", "windows")}
-        readings = {"load": lax.psum(stats["load"], ("dp", "sp")),
-                    "windows": lax.pmax(stats["windows"], ("dp", "sp")),
-                    "token_nll": nll}
+        readings = {"token_nll": nll}
+        if cfg.use_moe:
+            readings.update(
+                load=lax.psum(stats["load"], ("dp", "sp")),
+                windows=lax.pmax(stats["windows"], ("dp", "sp")))
         if cfg.n_mtp_modules:
             readings["mtp_token_nll"] = mtp_nll
         return loss, readings
 
     data = P("dp", "sp")
     in_specs = (specs,) + (data,) * (3 if packed else 2)
-    out_readings = {"load": P(), "windows": P(), "token_nll": data}
+    out_readings = {"token_nll": data}
+    if cfg.use_moe:
+        out_readings.update(load=P(), windows=P())
     if cfg.n_mtp_modules:
         out_readings["mtp_token_nll"] = data
     # Around the shard_map, so that every instruction of the pass carries
@@ -1886,7 +2102,7 @@ def make_loss_fn(cfg: TransformerConfig, mesh, n_microbatches: int = 2,
 @_metrics.span("step.build")
 def make_train_step(cfg: TransformerConfig, optimizer, mesh,
                     n_microbatches: int = 2, opt_shardings=None,
-                    packed: bool = False):
+                    packed: bool = False, with_readings: bool = False):
     """Full sharded training step: loss + grads + optimizer update, jitted
     once over the 4-axis mesh.
 
@@ -1904,11 +2120,12 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
     The step holds the leaves that are no trained parameter aside
     (``trained``'s complement; most models have none): they take no
     gradient and the optimizer never sees them (``opt_state`` is made for
-    ``trained(params)``). With ``cfg.expert_bias_rate`` they are
-    ``params["expert_bias"]``, which the step moves from the tokens each
-    expert got in this very step, under the scope ``router_bias``, and it
-    returns ``(params, opt_state, loss, readings)``, ``readings`` the loss
-    function's of this step's forward pass (``make_loss_fn``). A
+    ``trained(params)``). With ``with_readings`` the step returns
+    ``(params, opt_state, loss, readings)``, ``readings`` the loss
+    function's of this step's forward pass (``make_loss_fn``); a step
+    with ``cfg.expert_bias_rate`` always does: its held leaves are
+    ``params["expert_bias"]``, which it moves from the tokens each
+    expert got in this very step, under the scope ``router_bias``. A
     multi-token-prediction module's layer has a bias of its own,
     ``params["mtp_expert_bias"]``, moved the same way from the last row
     of ``readings["load"]``."""
@@ -1918,8 +2135,9 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
     for name, n in counts.items():
         _metrics.inc(f"model.{name}", n)
     _metrics.note(**counts)
-    with_readings = bool(cfg.expert_bias_rate)
-    if with_readings and packed:
+    biased = bool(cfg.expert_bias_rate)
+    with_readings = with_readings or biased
+    if biased and packed:
         raise ValueError("packed documents with the router's balancing "
                          "bias are not built")
     loss_fn = make_loss_fn(cfg, mesh, n_microbatches, packed=packed,
@@ -1955,13 +2173,14 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
         if not with_readings:
             return weights, opt_state, out
         loss, readings = out
-        return ({**weights, **move(held, readings["load"])}, opt_state,
-                loss, readings)
+        if biased:
+            held = move(held, readings["load"])
+        return {**weights, **held}, opt_state, loss, readings
 
     # The module's name on the device trace (docs/diagnostics.md,
     # "Tracing"); a step with a bias has always had its own there.
     step.__name__ = step.__qualname__ = (
-        "hvd_decoder_bias_step" if with_readings else "hvd_decoder_step")
+        "hvd_decoder_bias_step" if biased else "hvd_decoder_step")
     return jax.jit(step, donate_argnums=(0, 1))
 
 

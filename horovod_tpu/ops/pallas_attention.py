@@ -44,6 +44,16 @@ gradient of theirs is written at H heads. With g = 1 every plan, grid,
 index map and kernel body is the multi-head one. The XLA twins take the
 same operands and repeat K and V inside themselves.
 
+A third mask rule beside the diagonal and the window is the block-causal
+one (``BlockCausal``, the public ``blocks=(q_block, k_block)``): key ``j``
+is visible to query ``i`` iff ``(k_off + j) // k_block < (q_off + i) //
+q_block``, the two sides counted in blocks of their own sizes, a query's
+own block and every later one hidden. It rides where the window does, so
+the bounds, the mask and the XLA twins are the only places that know it:
+the walk enters the tiles some query of the tile may see and no other.
+The EVA mixer's chunk summaries are attended under it
+(``ops/eva_attention.py``).
+
 Block offsets ride in as prefetched scalars and enter the walk's bounds,
 so the same kernel serves ring attention's rotating K/V blocks (global
 causal masking between sequence blocks) and the plain single-block case.
@@ -148,6 +158,23 @@ def row_lse(m, l):
     return jnp.where(l > 0.0, m + log_l, -NEG_INF)
 
 
+def merge_state(m, l, acc, m_b, l_b, acc_b):
+    """The online-softmax combine of a running state with one more key
+    set's (``flash_attention_block``'s): m, l [B, H, T] and acc [B, T, H,
+    D], float32; returns the merged (m, l, acc). A row that neither set
+    has a key for stays empty (m = NEG_INF, l = 0). Ring attention joins
+    its K/V blocks with it, EVA attention its two key sets."""
+    m_new = jnp.maximum(m, m_b)
+    alive = m_new > NEG_INF / 2
+    c_old = jnp.where(alive, jnp.exp(m - m_new), 1.0)
+    c_blk = jnp.where(alive & (m_b > NEG_INF / 2),
+                      jnp.exp(m_b - m_new), 0.0)
+    l = l * c_old + l_b * c_blk
+    acc = (acc * c_old.transpose(0, 2, 1)[..., None] +
+           acc_b * c_blk.transpose(0, 2, 1)[..., None])
+    return m_new, l, acc
+
+
 # ---------------------------------------------------------------------------
 # The plan: heads a grid step, resident chunk, sub-tile, and the walk.
 # ---------------------------------------------------------------------------
@@ -207,6 +234,25 @@ def _clip_div(x, t, n):
     return jnp.minimum(jax.lax.div(jnp.maximum(x, 0), t), n)
 
 
+class BlockCausal(NamedTuple):
+    """The block-causal rule, carried where a window is: global row ``i``
+    sees global column ``j`` iff ``j // k_block < i // q_block``."""
+    q_block: int
+    k_block: int
+
+
+def _div(x, t):
+    """``floor(x / t)`` of a non-negative Python int or int32 value."""
+    return x // t if _is_static(x) else jax.lax.div(x, jnp.int32(t))
+
+
+def _block_visible(rows, cols, rule: BlockCausal):
+    """``BlockCausal``'s mask of global ``rows`` [tq, 1] against global
+    ``cols`` [1, tk] (non-negative int32): the single definition, the
+    kernels' and the twins'."""
+    return _div(cols, rule.k_block) < _div(rows, rule.q_block)
+
+
 def _k_bounds(q_lo, tq, k_base, tk, n, causal, window):
     """The K sub-tiles (of ``n``, ``tk`` wide, the first at global column
     ``k_base``) that the Q sub-tile of global rows ``q_lo .. q_lo+tq-1``
@@ -214,6 +260,10 @@ def _k_bounds(q_lo, tq, k_base, tk, n, causal, window):
     the window lie outside."""
     if not causal:
         return 0, n
+    if isinstance(window, BlockCausal):
+        # Columns under the tile's last row's block: j < limit.
+        limit = _div(q_lo + tq - 1, window.q_block) * window.k_block
+        return 0, _clip_div(limit - k_base + tk - 1, tk, n)
     end = _clip_div(q_lo + tq - 1 - k_base + tk, tk, n)   # starting <= q_hi
     if window is None:
         return 0, end
@@ -227,6 +277,10 @@ def _q_bounds(k_lo, tk, q_base, tq, n, causal, window):
     sub-tile of global columns ``k_lo .. k_lo+tk-1``."""
     if not causal:
         return 0, n
+    if isinstance(window, BlockCausal):
+        # Rows past the block of the tile's first column: i >= start.
+        start = (_div(k_lo, window.k_block) + 1) * window.q_block
+        return _clip_div(start - q_base, tq, n), n
     first = _clip_div(k_lo - q_base, tq, n)        # first with q_hi >= k_lo
     if window is None:
         return first, n
@@ -396,10 +450,13 @@ def _log_plan(kind, shape, dtype, causal, window, plan):
     the call is traced (``HOROVOD_LOG_LEVEL=debug``), and counted: the
     host traces this ``pallas_call`` and lowers it to Mosaic. A call
     whose K side has fewer heads than its Q side counts a second time,
-    under ``kernels.grouped.``."""
+    under ``kernels.grouped.``, and one under the block-causal rule under
+    ``kernels.blockcausal.``."""
     _metrics.inc(f"kernels.traced.flash_{kind}")
     if plan.group > 1:
         _metrics.inc(f"kernels.grouped.flash_{kind}")
+    if isinstance(window, BlockCausal):
+        _metrics.inc(f"kernels.blockcausal.flash_{kind}")
     _log.debug(
         f"flash_{kind} {tuple(shape)} {jnp.dtype(dtype).name} "
         f"causal={causal} window={window}: {plan.heads} heads a step "
@@ -487,6 +544,10 @@ def _tile_mask(q_lo, k_lo, tq, tk, causal, window):
     all heads of a loop body."""
     if not causal:
         return None
+    if isinstance(window, BlockCausal):
+        return _block_visible(
+            q_lo + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0),
+            k_lo + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1), window)
     rel = (q_lo - k_lo) + (jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0) -
                            jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1))
     keep = rel >= 0
@@ -1027,14 +1088,24 @@ def _require_both_segs(q_seg, k_seg):
         raise ValueError("pass both q_segment_ids and k_segment_ids")
 
 
-def _check_window(window, causal):
-    if window is None:
-        return
+def _mask_rule(window, blocks, causal):
+    """What rides in the kernels' ``window`` slot: the window itself, or
+    the ``BlockCausal`` of ``blocks`` = (q_block, k_block); either needs
+    ``causal`` and they exclude each other."""
+    if window is None and blocks is None:
+        return None
     if not causal:
         raise ValueError("sliding-window attention is defined for the "
                          "causal case; pass causal=True with window")
-    if window < 1:
-        raise ValueError(f"window must be >= 1, got {window}")
+    if blocks is None:
+        if window < 1:
+            raise ValueError(f"window must be >= 1, got {window}")
+        return window
+    if window is not None or min(blocks) < 1:
+        raise ValueError(
+            f"blocks = (q_block, k_block) >= 1 is a mask rule of its own, "
+            f"not one under a window; got blocks {blocks}, window {window}")
+    return BlockCausal(*map(int, blocks))
 
 
 def _xla_expand(q, *k_side):
@@ -1061,9 +1132,12 @@ def _xla_block_state(q, k, v, offs, causal, q_seg=None, k_seg=None,
     if causal:
         iq = jnp.arange(q.shape[1])[:, None] + offs[0]
         ik = jnp.arange(k.shape[1])[None, :] + offs[1]
-        s = jnp.where(iq >= ik, s, NEG_INF)
-        if window is not None:
-            s = jnp.where(iq - ik < window, s, NEG_INF)
+        if isinstance(window, BlockCausal):
+            s = jnp.where(_block_visible(iq, ik, window), s, NEG_INF)
+        else:
+            s = jnp.where(iq >= ik, s, NEG_INF)
+            if window is not None:
+                s = jnp.where(iq - ik < window, s, NEG_INF)
     if q_seg is not None:
         s = _apply_segment_mask(s, q_seg, k_seg, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
@@ -1152,8 +1226,11 @@ def _kv_heads(q, k, v):
 def flash_attention_block(q, k, v, q_off, k_off, causal: bool = True,
                           use_pallas: Optional[bool] = None,
                           q_segment_ids=None, k_segment_ids=None,
-                          window: Optional[int] = None):
-    """One K/V block's unmerged attention state for ring attention.
+                          window: Optional[int] = None, blocks=None):
+    """One K/V block's unmerged attention state for ring attention, and
+    for one softmax over two key sets on one chip (``ops/eva_attention``:
+    ``blocks`` = (q_block, k_block) puts the block-causal rule in the
+    diagonal's place, the module's docstring).
 
     q: [B, T, H, D]; k/v: [B, T, Hkv, D], Hkv a divisor of H (query head
     j reads K/V head j // (H / Hkv)). Returns (acc, m, l) with acc f32
@@ -1173,7 +1250,7 @@ def flash_attention_block(q, k, v, q_off, k_off, causal: bool = True,
     if q_segment_ids is not None:
         q_seg = _tile_seg(q_segment_ids, H)
         k_seg = _tile_seg(k_segment_ids, Hkv)
-    _check_window(window, causal)
+    window = _mask_rule(window, blocks, causal)
     use_pallas, interpret = _resolve_dispatch(use_pallas)
     if use_pallas:
         acc, m, l = _block_state_core(
@@ -1193,16 +1270,18 @@ def flash_attention_block_grads(q, k, v, do, lse, delta, q_off, k_off,
                                 causal: bool = True,
                                 use_pallas: Optional[bool] = None,
                                 q_segment_ids=None, k_segment_ids=None,
-                                window: Optional[int] = None):
-    """One K/V block's (dq, dk, dv) for ring attention's backward pass.
+                                window: Optional[int] = None, blocks=None,
+                                out_dtype=jnp.float32):
+    """One K/V block's (dq, dk, dv) for ring attention's backward pass
+    (and ``ops/eva_attention``'s; ``blocks`` as ``flash_attention_block``).
 
     q/do: [B, T, H, D]; k/v: [B, T, Hkv, D]; lse/delta: f32 [B, H, T] —
     the GLOBAL row statistics (lse over all keys, delta = rowsum(dO*O)),
     so each block's P = exp(S - lse) is already globally normalized and
-    the per-block gradients simply sum across the ring. Returns f32
-    arrays in the layout of q, k and v, dk and dv summed over each group
-    of query heads (f32 so the ring's cross-block accumulation doesn't
-    round at the model dtype each step).
+    the per-block gradients simply sum across the ring. Returns arrays
+    of ``out_dtype`` in the layout of q, k and v, dk and dv summed over
+    each group of query heads (f32 by default so the ring's cross-block
+    accumulation doesn't round at the model dtype each step).
     """
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
@@ -1219,16 +1298,16 @@ def flash_attention_block_grads(q, k, v, do, lse, delta, q_off, k_off,
     if q_segment_ids is not None:
         q_seg = _tile_seg(q_segment_ids, H)
         k_seg = _tile_seg(k_segment_ids, Hkv)
-    _check_window(window, causal)
+    window = _mask_rule(window, blocks, causal)
     if use_pallas and _kernels_take(("dq", "dkv"), qm, km, causal, window,
                                     q_seg is not None,
-                                    out_dtype=jnp.float32):
+                                    out_dtype=out_dtype):
         dq, dk, dv = _pallas_bwd(qm, km, vm, dom, lse_m, delta_m, offs,
-                                 causal, interpret, out_dtype=jnp.float32,
+                                 causal, interpret, out_dtype=out_dtype,
                                  q_seg=q_seg, k_seg=k_seg, window=window)
     else:
         dq, dk, dv = _xla_block_grads(qm, km, vm, dom, lse_m, delta_m,
-                                      offs, causal, out_dtype=jnp.float32,
+                                      offs, causal, out_dtype=out_dtype,
                                       q_seg=q_seg, k_seg=k_seg,
                                       window=window)
 
@@ -1256,9 +1335,12 @@ def _xla_block_grads(q, k, v, do, lse, delta, offs, causal: bool,
     if causal:
         iq = jnp.arange(q.shape[1])[:, None] + offs[0]
         ik = jnp.arange(k.shape[1])[None, :] + offs[1]
-        p = jnp.where((iq >= ik)[None], p, 0.0)
-        if window is not None:
-            p = jnp.where((iq - ik < window)[None], p, 0.0)
+        if isinstance(window, BlockCausal):
+            p = jnp.where(_block_visible(iq, ik, window)[None], p, 0.0)
+        else:
+            p = jnp.where((iq >= ik)[None], p, 0.0)
+            if window is not None:
+                p = jnp.where((iq - ik < window)[None], p, 0.0)
     if q_seg is not None:
         p = _apply_segment_mask(p, q_seg, k_seg, 0.0)
     dof = do.astype(jnp.float32)
@@ -1286,9 +1368,12 @@ def _xla_flash(q, k, v, q_off, k_off, causal, q_seg=None, k_seg=None,
     if causal:
         iq = jnp.arange(q.shape[1])[:, None] + q_off
         ik = jnp.arange(k.shape[1])[None, :] + k_off
-        s = jnp.where(iq >= ik, s, NEG_INF)
-        if window is not None:
-            s = jnp.where(iq - ik < window, s, NEG_INF)
+        if isinstance(window, BlockCausal):
+            s = jnp.where(_block_visible(iq, ik, window), s, NEG_INF)
+        else:
+            s = jnp.where(iq >= ik, s, NEG_INF)
+            if window is not None:
+                s = jnp.where(iq - ik < window, s, NEG_INF)
     if q_seg is not None:
         s = _apply_segment_mask(s, q_seg, k_seg, NEG_INF)
     # Rows whose keys are all masked normalize to zero output, matching
@@ -1359,7 +1444,7 @@ def _tile_seg(seg, heads):
 def flash_attention(q, k, v, causal: bool = True, q_off: int = 0,
                     k_off: int = 0, use_pallas: Optional[bool] = None,
                     q_segment_ids=None, k_segment_ids=None,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, blocks=None):
     """Blocked flash attention. q: [B, T, H, D]; k/v: [B, T, Hkv, D] with
     Hkv a divisor of H: query head j reads K/V head j // (H / Hkv)
     (grouped-query attention; Hkv == H is multi-head). The kernels fetch
@@ -1376,6 +1461,10 @@ def flash_attention(q, k, v, causal: bool = True, q_off: int = 0,
     with the causal mask). The Mosaic kernels stream the ids as extra
     (heads, chunk, 1) int32 blocks; the mask composes at trace time so
     the segment-free path compiles unchanged.
+
+    ``blocks`` = (q_block, k_block): the block-causal rule in the
+    diagonal's place (the module's docstring); k and v may then be
+    shorter than q, a row a block's summary.
     """
     B, Tq, H, D = q.shape
     Hkv = _kv_heads(q, k, v)
@@ -1384,7 +1473,7 @@ def flash_attention(q, k, v, causal: bool = True, q_off: int = 0,
         return x.reshape(B, H, t, D).transpose(0, 2, 1, 3)
 
     _require_both_segs(q_segment_ids, k_segment_ids)
-    _check_window(window, causal)
+    window = _mask_rule(window, blocks, causal)
     q_seg = k_seg = None
     if q_segment_ids is not None:
         q_seg = _tile_seg(q_segment_ids, H)

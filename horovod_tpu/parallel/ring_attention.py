@@ -77,7 +77,8 @@ def _ring_fwd_pass(q, k, v, seg, axis_name: str, causal: bool,
         # online-softmax combine. GQA K/V travel the ring at their own
         # head count, and the kernels read them at it (query head j reads
         # K/V head j // g through the index map).
-        from ..ops.pallas_attention import flash_attention_block
+        from ..ops.pallas_attention import (
+            flash_attention_block, merge_state)
 
         k_blk = (my - step) % sp
         acc_b, m_b, l_b = flash_attention_block(
@@ -86,14 +87,7 @@ def _ring_fwd_pass(q, k, v, seg, axis_name: str, causal: bool,
             causal=causal, q_segment_ids=seg,
             k_segment_ids=None if seg is None else kseg_cur,
             window=window)
-        m_new = jnp.maximum(m, m_b)                       # [B,H,Tq]
-        alive = m_new > NEG_INF / 2
-        c_old = jnp.where(alive, jnp.exp(m - m_new), 1.0)
-        c_blk = jnp.where(alive & (m_b > NEG_INF / 2),
-                          jnp.exp(m_b - m_new), 0.0)
-        l = l * c_old + l_b * c_blk
-        o = (o * c_old.transpose(0, 2, 1)[..., None] +
-             acc_b * c_blk.transpose(0, 2, 1)[..., None])
+        m_new, l, o = merge_state(m, l, o, m_b, l_b, acc_b)  # m [B,H,Tq]
         k_nxt = lax.ppermute(k_cur, axis_name, fwd_perm)
         v_nxt = lax.ppermute(v_cur, axis_name, fwd_perm)
         kseg_nxt = (kseg_cur if seg is None else

@@ -437,6 +437,35 @@ def test_ring_block_kernels_compile_for_v5e(described_chip, monkeypatch,
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("which", ["forward", "backward"])
+def test_eva_attention_compiles_for_v5e(described_chip, monkeypatch, which):
+    """``evabyte-t32768``'s mixer at its own size (32 heads of 128, 32,768
+    bytes, windows of 2,048, chunks of 16): the exact set's kernels and
+    the summaries' under the block-causal rule, whose mask divides integer
+    vectors, compile to Mosaic, two calls a pass, and no score array of
+    either set is in the program."""
+    from horovod_tpu.ops.eva_attention import eva_attention
+
+    monkeypatch.setattr(pa, "_resolve_dispatch", lambda up: (True, False))
+    T, H, D, W, C = 32768, 32, 128, 2048, 16
+    x, s = (jax.ShapeDtypeStruct((1, t, H, D), jnp.bfloat16,
+                                 sharding=described_chip)
+            for t in (T, T // C))
+
+    def attend(q, k, v, k_sum, v_sum):
+        return eva_attention(q, k, v, k_sum, v_sum, W, C)
+
+    fn = attend if which == "forward" else jax.grad(
+        lambda *a: attend(*a).astype(jnp.float32).sum(), argnums=range(5))
+    text = jax.jit(fn).lower(x, x, x, s, s).compile().as_text()
+    calls = _flash_custom_calls(text)
+    assert {k: len(v) for k, v in calls.items()} == (
+        {"flash_fwd": 2} if which == "forward"
+        else {"flash_fwd": 2, "flash_bwd": 2})
+    for dims in (f"{T},{T}]", f"{T},{T // C}]", f"{W},{W}]"):
+        assert dims not in text, dims
+
+
 def _flash_custom_calls(text):
     """{kernel: [(result types, operand types)]} of a compiled program's
     Mosaic flash kernels, each type as (element type, dims) text."""

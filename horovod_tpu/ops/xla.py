@@ -155,23 +155,25 @@ def allreduce(
 
 
 def _grouped(tensors, reduce_fn, bucket_cap_bytes=None, compression=None):
-    """Shared dtype-concat fusion: flatten, concatenate per plan bucket,
-    reduce each fused buffer with ``reduce_fn``, slice results back out.
+    """The packed path: flatten, concatenate per plan bucket, reduce each
+    flat buffer with ``reduce_fn``, slice the results back out.
 
-    TPU-native tensor fusion: rather than memcpy into a fusion buffer
-    (reference ``MemcpyInFusionBuffer``, ``gpu_operations.cc:97``), we
-    concatenate flattened tensors inside the compiled program and let XLA
-    emit one AllReduce per bucket; the concat/split are fused away or
-    become cheap on-chip moves.
+    What is left on it is what needs a contiguous vector by construction:
+    the hierarchical ladder (``grouped_hierarchical_allreduce``) scatters a
+    flat buffer along the local axis. An elementwise all-reduce does not,
+    and ``grouped_allreduce`` no longer comes here: on a v5e the pack was
+    never "fused away". At ResNet-50's 102 MB of float32 gradients in one
+    bucket the trace read, a step, 0.42 ms for the ``concatenate``, 0.14
+    for the division over the whole buffer and 0.32 for the slices and
+    reshapes that unpack it, beside an all-reduce of 1.79 (the 0.96 ms of
+    asynchronous slices the records put with them are something else's:
+    the step has them without the buffer too); the step is 1.13 ms of
+    50.57 shorter by leaves (PERF.md section 6, PR 45).
 
-    ``bucket_cap_bytes`` unset → the v1 monolithic plan (one bucket per
-    dtype, parameter order) — byte-identical programs to before the
-    planner existed. Set → size-capped dtype-pure buckets in reverse
-    parameter (≈ backward-production) order from
-    ``common/fusion.plan_buckets``, so each bucket's AllReduce depends
-    only on its own gradients and XLA can overlap communication with the
-    rest of the backward pass (tensor-fusion v2; see
-    ``docs/tensor-fusion.md``).
+    ``bucket_cap_bytes`` unset → the monolithic plan (one bucket per
+    dtype, parameter order). Set → size-capped dtype-pure buckets in
+    reverse parameter (≈ backward-production) order from
+    ``common/fusion.plan_buckets`` (``docs/tensor-fusion.md``).
     """
     from ..common.fusion import plan_buckets_for
 
@@ -200,45 +202,51 @@ def _grouped(tensors, reduce_fn, bucket_cap_bytes=None, compression=None):
 def grouped_allreduce(tensors, axis_name: str = AXIS_GLOBAL, op: int = ReduceOp.SUM,
                       prescale_factor: float = 1.0, postscale_factor: float = 1.0,
                       bucket_cap_bytes=None, compression=None):
-    """Allreduce a list of tensors as one fused operation (see ``_grouped``).
+    """Allreduce a list of tensors, each leaf where it lies.
 
-    ``bucket_cap_bytes`` (bytes, or ``"auto"`` to follow
-    ``HOROVOD_FUSION_THRESHOLD``) switches v1's one-AllReduce-per-dtype
-    fusion to size-capped backward-order buckets — one AllReduce per
-    bucket that XLA can launch while earlier-layer gradients are still
-    being computed. Unset keeps the v1 monolithic behavior exactly.
+    Every leaf is ``allreduce``d by itself: nothing is ravelled,
+    concatenated or sliced back out, and the scaling, the wire cast and the
+    averaging are per leaf, where they fuse into the gradient's producer and
+    the update's consumer. The fusion is the compiler's: XLA's all-reduce
+    combiner packs the leaves' all-reduces into variadic (tuple)
+    instructions that take the leaves as operands, all of them into one
+    unless the enclosing ``jit`` gives it a threshold. The numbers are those
+    of the packed path (``_grouped``) bit for bit: an elementwise reduction
+    does not care how its elements are grouped.
+
+    ``bucket_cap_bytes`` therefore shapes nothing that is traced here for
+    sum, average, min and max: below XLA a bucket of leaves cannot be told
+    from its leaves. It reaches the compiled program as the combiner's
+    threshold, which ``make_train_step`` passes on a TPU when a cap is set
+    (``fusion.exchange_compiler_options``).
 
     Adasum is NOT a per-element reduction: its dot/norm coefficients are
     per tensor, so a fused Adasum group applies the combination per
-    tensor instead of on the concatenated buffer (reference
-    ``tensor_counts`` contract, ``adasum_gpu_operations.cc:208-232``) —
-    XLA still compiles the whole group into one program, so fusion's
-    launch-overhead win is preserved. Bucketing partitions the *launch*
-    groups only; the per-tensor Adasum contract is unchanged.
+    tensor (reference ``tensor_counts`` contract,
+    ``adasum_gpu_operations.cc:208-232``). There ``bucket_cap_bytes``
+    (bytes, ``"auto"`` to follow ``HOROVOD_FUSION_THRESHOLD``, ``None`` for
+    one group) partitions the *launch* groups; the per-tensor Adasum
+    contract is unchanged.
 
-    ``compression`` (see ``allreduce``) makes each bucket reduce in the
-    compressed wire dtype, and the plan budget the compressed width.
-    Adasum ignores it (per-tensor fp32 coefficients).
+    ``compression`` (see ``allreduce``) makes each leaf reduce in the
+    compressed wire dtype. Adasum ignores it (per-tensor fp32
+    coefficients).
     """
-    from ..common.fusion import resolve_bucket_cap
-
-    cap = resolve_bucket_cap(bucket_cap_bytes)
     if op == ReduceOp.ADASUM:
+        from ..common.fusion import resolve_bucket_cap
         from .adasum import grouped_adasum_allreduce
 
+        cap = resolve_bucket_cap(bucket_cap_bytes)
         pre = [_apply_prescale(t, prescale_factor) for t in tensors]
         red = _grouped_per_tensor(
             pre, lambda chunk: grouped_adasum_allreduce(
                 chunk, axis_name=axis_name), cap)
         return [_apply_postscale(t, postscale_factor) for t in red]
     comp = _resolve_compression(compression)
-    return _grouped(
-        tensors,
-        lambda fused: allreduce(fused, axis_name=axis_name, op=op,
-                                prescale_factor=prescale_factor,
-                                postscale_factor=postscale_factor,
-                                compression=comp),
-        bucket_cap_bytes=cap, compression=comp)
+    return [allreduce(t, axis_name=axis_name, op=op,
+                      prescale_factor=prescale_factor,
+                      postscale_factor=postscale_factor, compression=comp)
+            for t in tensors]
 
 
 def _grouped_per_tensor(tensors, group_fn, bucket_cap_bytes):
@@ -315,12 +323,14 @@ def grouped_hierarchical_allreduce(tensors, op: int = ReduceOp.SUM,
                                    prescale_factor: float = 1.0,
                                    postscale_factor: float = 1.0,
                                    bucket_cap_bytes=None, compression=None):
-    """Fused hierarchical allreduce (dtype-concat fusion like
-    ``grouped_allreduce``, ICI/DCN split like ``hierarchical_allreduce``).
+    """Fused hierarchical allreduce (``_grouped``'s packed buckets: the
+    ladder scatters a flat vector; ICI/DCN split like
+    ``hierarchical_allreduce``).
     Supports SUM/AVERAGE (``psum_scatter``-expressible) and ADASUM — the
     latter per tensor (Adasum coefficients are per-tensor; see
     ``grouped_allreduce``) via ``hierarchical_adasum_allreduce``.
-    ``bucket_cap_bytes`` buckets exactly as in ``grouped_allreduce``;
+    ``bucket_cap_bytes`` set or ``"auto"`` under a set
+    ``HOROVOD_FUSION_THRESHOLD`` caps the buckets, unset is one per dtype;
     each bucket runs the full ICI/DCN ladder independently, so the
     scatter leg of bucket k overlaps the backward that produces bucket
     k+1."""

@@ -6,8 +6,9 @@ for the JAX/optax idiom: instead of hooking per-parameter gradient
 accumulators, we wrap the optax ``GradientTransformation`` so that
 ``update()`` allreduces the gradient pytree across the mesh axis before the
 inner optimizer sees it. Inside ``jit``/``shard_map`` the allreduce compiles
-to a single fused XLA AllReduce per dtype over ICI — tensor fusion falls out
-of compilation rather than a background fusion buffer.
+to one tuple XLA AllReduce over the gradient leaves where they lie, over
+ICI — tensor fusion falls out of compilation (XLA's all-reduce combiner)
+rather than a background fusion buffer.
 
 ``backward_passes_per_step`` (gradient accumulation before communication,
 reference ``torch/optimizer.py:46``) is supported via
@@ -59,13 +60,17 @@ def DistributedOptimizer(
     pjit over ``hvd.mesh()``); single-device programs may simply not bind
     the axis and pass ``axis_name=None`` to skip communication.
 
-    ``bucket_cap_bytes`` selects tensor-fusion v2 (backward-order bucketed
-    AllReduces that overlap backprop, ``common/fusion.py``): an int caps
-    each bucket at that many bytes; ``"auto"`` (default) follows
-    ``HOROVOD_FUSION_THRESHOLD`` — the same knob that paces the host
-    plane's cycle fusion, including its autotuned value — and stays
-    monolithic (v1, one AllReduce per dtype) when the knob was never set;
-    ``None`` forces monolithic.
+    ``bucket_cap_bytes`` is the tensor-fusion knob (``common/fusion.py``):
+    ``"auto"`` (default) follows ``HOROVOD_FUSION_THRESHOLD`` — the same
+    knob that paces the host plane's cycle fusion, including its autotuned
+    value — and is ``None`` when the knob was never set: the leaves are
+    all-reduced where they lie and the compiler packs them into one tuple
+    all-reduce. An int caps Adasum's launch groups here; for sum and
+    average a bucket cannot be told from its leaves below XLA, and the cap
+    takes effect as the compiler's combiner threshold:
+    ``make_train_step`` passes it on a TPU, and a step jitted by hand
+    does with ``jax.jit(..., compiler_options=
+    fusion.exchange_compiler_options(cap, "tpu"))``.
 
     ``compression`` selects the on-wire gradient format
     (``common/compression.py``; docs/compression.md):

@@ -27,6 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .common import metrics as _metrics
 from .common.compat import shard_map as _shard_map
+from .common.fusion import exchange_compiler_options, resolve_bucket_cap
 from .common.state import AXIS_GLOBAL
 from .opt import DistributedOptimizer
 from .zero import (  # noqa: F401  (re-export: the ZeRO step builders)
@@ -64,11 +65,15 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
     ``axis_name``. Batch-norm statistics are cross-chip averaged each step
     (the reference ships SyncBatchNorm for this, ``torch/sync_batch_norm.py``).
 
-    ``bucket_cap_bytes`` is the tensor-fusion v2 knob (see
-    ``DistributedOptimizer``): an int buckets the gradient AllReduce at
-    that byte cap in backward order so communication overlaps backprop;
-    ``"auto"`` (default) follows ``HOROVOD_FUSION_THRESHOLD`` and stays
-    monolithic when that knob was never set; ``None`` forces monolithic.
+    ``bucket_cap_bytes`` is the tensor-fusion knob (see
+    ``DistributedOptimizer``): ``"auto"`` (default) follows
+    ``HOROVOD_FUSION_THRESHOLD``; unset, or ``None``, the gradient leaves
+    are reduced where they lie and the compiler packs them into one tuple
+    all-reduce. An int (or the threshold, where set) caps what one
+    all-reduce instruction may hold: on a TPU the step is jitted with the
+    combiner's threshold at that many bytes
+    (``fusion.exchange_compiler_options``), and the compiler issues the
+    all-reduces behind the backward pass as their gradients appear.
 
     ``compression`` is the on-wire gradient format (see
     ``DistributedOptimizer``; docs/compression.md): ``"auto"`` (default)
@@ -123,10 +128,12 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
         out_specs=(replicated, replicated),
         check_vma=False,
     )
-    donate_args = (0,) if donate else ()
-    jitted = jax.jit(sharded_step, donate_argnums=donate_args)
     del n_axes
-    return jitted
+    # An explicit cap is also the compiler's: see the helper's docstring.
+    options = exchange_compiler_options(
+        resolve_bucket_cap(bucket_cap_bytes), mesh.devices.flat[0].platform)
+    return jax.jit(sharded_step, donate_argnums=(0,) if donate else (),
+                   compiler_options=options or None)
 
 
 def init_train_state(model, optimizer, rng, sample_input,

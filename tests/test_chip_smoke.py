@@ -30,6 +30,7 @@ sys.path.insert(0, REPO)
 import chip_smoke  # noqa: E402
 import horovod_tpu.ops.pallas_attention as pa  # noqa: E402
 from benchmark import flops  # noqa: E402
+from horovod_tpu.common.state import AXIS_GLOBAL  # noqa: E402
 from tools import compile_cache  # noqa: E402
 
 BENCHMARK_RUN = ("benchmark/run.py", "--workload", "gpt2s-t128", "--seed",
@@ -269,14 +270,13 @@ def test_cache_helper_sets_the_fixed_path_otherwise(
 # ---- compiles for the described chip ---------------------------------------
 
 @pytest.fixture(scope="module")
-def described_chip():
-    """One device of a described (not attached) v5e 2x2, with the
-    persistent cache off around the compiles: such an entry could not be
-    read back without a chip."""
+def described_topology():
+    """A described (not attached) v5e 2x2, with the persistent cache off
+    around the compiles: such an entry could not be read back without a
+    chip."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -286,9 +286,26 @@ def described_chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def described_chip(described_topology):
+    """One device of the described v5e 2x2."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(described_topology.devices[0])
+
+
+@pytest.fixture(scope="module")
+def described_host(described_topology):
+    """The four chips of the described host as the data-parallel mesh."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(described_topology.devices), (AXIS_GLOBAL,))
 
 
 def _attention(variant):
@@ -782,3 +799,134 @@ def test_the_einsum_form_writes_what_the_scan_kernels_keep_in_vmem(
     assert "tpu_custom_call" not in text
     tiles, wide = _tiles_and_wide_copies(text, 64)
     assert tiles and wide
+
+
+# ---- the gradient exchange on the four chips of a described host -----------
+
+class _ConvNet(nn.Module):
+    """Ten 3x3 convolutions of 256 channels under batch-norm: 23.6 MB of
+    float32 kernels."""
+
+    @nn.compact
+    def __call__(self, x, train=False):
+        x = nn.Conv(256, (3, 3), dtype=jnp.bfloat16)(x)
+        for _ in range(10):
+            x = nn.Conv(256, (3, 3), dtype=jnp.bfloat16)(x)
+            x = nn.BatchNorm(use_running_average=not train,
+                             dtype=jnp.bfloat16)(x)
+            x = nn.relu(x)
+        return nn.Dense(10, dtype=jnp.float32)(jnp.mean(x, axis=(1, 2)))
+
+
+class _MLP8(nn.Module):
+    """tests/test_fusion_overlap.py's model: 16 float32 leaves."""
+
+    @nn.compact
+    def __call__(self, x, train=False):
+        x = x.reshape((x.shape[0], -1))
+        for f in (32,) * 7 + (10,):
+            x = nn.Dense(f)(x)
+            if f != 10:
+                x = jax.nn.relu(x)
+        return x
+
+
+EXCHANGES = {
+    # model, sample shape, bucket_cap_bytes
+    "conv-bn-auto": (_ConvNet, (8, 32, 32, 3), "auto"),
+    "conv-bn-cap6MiB": (_ConvNet, (8, 32, 32, 3), 6 << 20),
+    "mlp8-cap8192": (_MLP8, (16, 16), 8192),
+}
+
+
+def _entry_schedule(text):
+    """[(name, opcode, line)] of the entry computation, in the order the
+    compiler scheduled it."""
+    lines = text.splitlines()
+    start = next(i for i, l in enumerate(lines) if l.startswith("ENTRY"))
+    found = []
+    for line in lines[start + 1:]:
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = .*? ([a-z][a-z\-]*)\(",
+                     line)
+        if m:
+            found.append((m.group(1), m.group(2), line))
+    return found
+
+
+def _dp_step_text(model, shape, mesh, cap):
+    """(abstract parameters, the scheduled entry computation) of
+    ``make_train_step`` compiled for the described ``mesh``, through
+    ``.lower(...).compile()`` as the benchmark compiles it."""
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from horovod_tpu.training import init_train_state, make_train_step
+
+    opt = optax.sgd(0.1, momentum=0.9)
+    replicated = NamedSharding(mesh, P())
+    sharded = NamedSharding(mesh, P(AXIS_GLOBAL))
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=replicated),
+        jax.eval_shape(lambda k: init_train_state(
+            model, opt, k, jnp.zeros((1,) + shape[1:], jnp.float32)),
+            jax.random.PRNGKey(0)))
+    images = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharded)
+    labels = jax.ShapeDtypeStruct(shape[:1], jnp.int32, sharding=sharded)
+    step = make_train_step(model, opt, mesh, bucket_cap_bytes=cap)
+    return state.params, _entry_schedule(
+        step.lower(state, images, labels).compile().as_text())
+
+
+@pytest.mark.parametrize("case", sorted(EXCHANGES))
+def test_the_exchange_moves_the_leaves_on_v5e(described_host, monkeypatch,
+                                              case):
+    """``make_train_step`` compiled for four described chips, through
+    ``.lower(...).compile()`` (the benchmark's way): every all-reduce is
+    over leaves where they lie (no operand of the model's size, no
+    ``concatenate`` under ``exchange``). With no cap it is one tuple
+    all-reduce; with one the option reaches the executable: the gradients
+    are reduced in at least as many all-reduce instructions as the
+    planner would cut buckets at that cap, and the first is scheduled
+    before the last weight gradient."""
+    import math
+
+    from horovod_tpu.common import fusion
+
+    monkeypatch.delenv("HOROVOD_FUSION_THRESHOLD", raising=False)
+    model_cls, shape, cap = EXCHANGES[case]
+    params, schedule = _dp_step_text(model_cls(), shape, described_host, cap)
+    leaves = jax.tree_util.tree_leaves(params)
+    reduces = [(i, line) for i, (_, opcode, line) in enumerate(schedule)
+               if opcode in ("all-reduce", "all-reduce-start")]
+    total = sum(math.prod(l.shape) for l in leaves)
+    for _, line in reduces:
+        result = line.split(" all-reduce")[0]
+        assert all(math.prod(int(d) for d in dims.split(",") if d) < total
+                   for dims in re.findall(r"\[([0-9,]*)\]", result))
+    assert not [line for _, opcode, line in schedule
+                if opcode == "concatenate" and "exchange" in line]
+    if cap == "auto":
+        assert len(reduces) == 1
+        return
+    planned = fusion.plan_buckets_for(leaves, cap)
+    assert len(planned) > 2
+    assert len(reduces) >= len(planned), (len(reduces), len(planned))
+    weight_gradients = [i for i, (_, opcode, line) in enumerate(schedule)
+                        if opcode == "fusion"
+                        and "transpose(jvp(forward))" in line]
+    assert reduces[0][0] < weight_gradients[-1]
+
+
+def test_one_participant_compiles_with_no_all_reduce(described_chip):
+    """``resnet50-1chip``'s case: the program of a mesh of one chip holds
+    no all-reduce, cap or none."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array([next(iter(described_chip.device_set))]),
+                (AXIS_GLOBAL,))
+    for cap in ("auto", 8192):
+        _, schedule = _dp_step_text(_MLP8(), (16, 16), mesh, cap)
+        assert not [line for _, opcode, line in schedule
+                    if opcode.startswith("all-reduce")]

@@ -45,6 +45,14 @@ from . import logging as _log
 #   elastic.drains         commit-marked graceful drains (driver.py)
 #   model.latent_layers, model.mtp_modules  what a decoder step was built
 #                          with, where it has either (models/transformer.py)
+#   kernels.traced.<kernel>, kernels.grouped.flash_<kind>,
+#   kernels.blockcausal.flash_<kind>  the pallas_calls the host traced,
+#                          those with a grouped K side and those under the
+#                          block-causal rule (ops/pallas_attention.py's
+#                          _log_plan; ops/ssd.py, parallel/moe.py)
+#   kernels.eva.merged_operands  differentiated passes of EVA attention
+#                          traced whose two calls read one merged q: 2
+#                          after a train step's (ops/eva_attention.py)
 #   head.blocks_with_gradients_traced  differentiated traces of the head
 #                          and loss by blocks, whose forward pass makes the
 #                          gradients: 1 after a train step's, 0 after an

@@ -31,7 +31,10 @@ tiles and ``flash_dkv`` accumulating dK/dV across Q tiles, each rebuilding
 the tile for itself (seven matmuls, two ``exp``); all three share the
 tile's body (``_p_ds``) and the dK/dV walk is one function.
 ``flash_attention_block_grads`` exposes the same per-block backward for
-ring attention's backward ring pass (``parallel.ring_attention``).
+ring attention's backward ring pass (``parallel.ring_attention``); it and
+``flash_attention_block`` merge the heads, call their ``_merged`` forms
+and split the heads again, and a caller that hands several calls one
+merged array calls those forms itself (``ops/eva_attention.py``).
 
 Head counts: the Q side (q, o, dO, dQ, lse, delta, the Q segment ids) is
 merged as ``[B*H, T, D]`` and the K side (k, v, dK, dV, the K segment
@@ -1211,6 +1214,12 @@ def _merge_heads(x):
     return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
 
 
+def _split_heads(x, B):
+    """[B*H, T, D] -> [B, T, H, D]: ``_merge_heads`` back."""
+    BH, T, D = x.shape
+    return x.reshape(B, BH // B, T, D).transpose(0, 2, 1, 3)
+
+
 def _kv_heads(q, k, v):
     """Hkv of ``k``, ``v`` [B, T, Hkv, D], which divides the H of ``q``
     [B, T, H, D]: query head j reads K/V head j // (H / Hkv). The group
@@ -1223,14 +1232,51 @@ def _kv_heads(q, k, v):
     return Hkv
 
 
+def _block_offsets(q_off, k_off):
+    """int32[2], what the kernels prefetch: traced where ring attention
+    rotates the K block."""
+    return jnp.stack([jnp.asarray(q_off, jnp.int32),
+                      jnp.asarray(k_off, jnp.int32)])
+
+
+def _tiled_segs(q_segment_ids, k_segment_ids, H, Hkv):
+    """The two sides' [B, T] segment ids at their merged head counts, or
+    (None, None)."""
+    _require_both_segs(q_segment_ids, k_segment_ids)
+    if q_segment_ids is None:
+        return None, None
+    return _tile_seg(q_segment_ids, H), _tile_seg(k_segment_ids, Hkv)
+
+
+def flash_attention_block_merged(q, k, v, offs, causal: bool = True,
+                                 use_pallas: Optional[bool] = None,
+                                 q_seg=None, k_seg=None,
+                                 window: Optional[int] = None, blocks=None):
+    """``flash_attention_block`` on operands whose heads are merged
+    already: q [BH, Tq, D], k/v [BHkv, Tk, D] (the module's docstring on
+    head counts), ``offs`` int32[2] = (q_off, k_off), ``q_seg``/``k_seg``
+    int32 [BH, Tq] / [BHkv, Tk] or None. Returns (acc f32 [BH, Tq, D], m,
+    l f32 [BH, Tq, 1]) as the kernel leaves them. For a caller that hands
+    several calls one merged array (``ops/eva_attention``); the kernels
+    where ``kernel_plan`` has a step for the shape, else the XLA twin."""
+    window = _mask_rule(window, blocks, causal)
+    use_pallas, interpret = _resolve_dispatch(use_pallas)
+    if use_pallas:
+        return _block_state_core(q, k, v, offs, q_seg, k_seg, causal,
+                                 interpret, window)
+    return _xla_block_state(q, k, v, offs, causal, q_seg=q_seg,
+                            k_seg=k_seg, window=window)
+
+
 def flash_attention_block(q, k, v, q_off, k_off, causal: bool = True,
                           use_pallas: Optional[bool] = None,
                           q_segment_ids=None, k_segment_ids=None,
                           window: Optional[int] = None, blocks=None):
     """One K/V block's unmerged attention state for ring attention, and
-    for one softmax over two key sets on one chip (``ops/eva_attention``:
-    ``blocks`` = (q_block, k_block) puts the block-causal rule in the
-    diagonal's place, the module's docstring).
+    for one softmax over two key sets on one chip (``ops/eva_attention``,
+    through ``flash_attention_block_merged``: ``blocks`` = (q_block,
+    k_block) puts the block-causal rule in the diagonal's place, the
+    module's docstring).
 
     q: [B, T, H, D]; k/v: [B, T, Hkv, D], Hkv a divisor of H (query head
     j reads K/V head j // (H / Hkv)). Returns (acc, m, l) with acc f32
@@ -1240,30 +1286,37 @@ def flash_attention_block(q, k, v, q_off, k_off, causal: bool = True,
     (shared ``_resolve_dispatch``); segment ids stream into the same
     kernels as extra id blocks (packed sequences).
     """
-    B, Tq, H, D = q.shape
+    B, Tq, H, _ = q.shape
     Hkv = _kv_heads(q, k, v)
+    offs = _block_offsets(q_off, k_off)
+    q_seg, k_seg = _tiled_segs(q_segment_ids, k_segment_ids, H, Hkv)
+    acc, m, l = flash_attention_block_merged(
+        _merge_heads(q), _merge_heads(k), _merge_heads(v), offs, causal,
+        use_pallas, q_seg, k_seg, window, blocks)
+    return _split_heads(acc, B), m.reshape(B, H, Tq), l.reshape(B, H, Tq)
 
-    offs = jnp.stack([jnp.asarray(q_off, jnp.int32),
-                      jnp.asarray(k_off, jnp.int32)])
-    _require_both_segs(q_segment_ids, k_segment_ids)
-    q_seg = k_seg = None
-    if q_segment_ids is not None:
-        q_seg = _tile_seg(q_segment_ids, H)
-        k_seg = _tile_seg(k_segment_ids, Hkv)
+
+def flash_attention_block_grads_merged(q, k, v, do, lse, delta, offs,
+                                       causal: bool = True,
+                                       use_pallas: Optional[bool] = None,
+                                       q_seg=None, k_seg=None,
+                                       window: Optional[int] = None,
+                                       blocks=None, out_dtype=jnp.float32):
+    """``flash_attention_block_grads`` on merged operands: q/do [BH, Tq,
+    D], k/v [BHkv, Tk, D], lse/delta f32 [BH, Tq, 1] (the columns the
+    kernels take), ``offs`` and the segment ids as
+    ``flash_attention_block_merged``. Returns (dq [BH, Tq, D], dk, dv
+    [BHkv, Tk, D]) of ``out_dtype``."""
     window = _mask_rule(window, blocks, causal)
     use_pallas, interpret = _resolve_dispatch(use_pallas)
-    if use_pallas:
-        acc, m, l = _block_state_core(
-            _merge_heads(q), _merge_heads(k), _merge_heads(v), offs,
-            q_seg, k_seg, causal, interpret, window)
-    else:
-        acc, m, l = _xla_block_state(
-            _merge_heads(q), _merge_heads(k), _merge_heads(v), offs,
-            causal, q_seg=q_seg, k_seg=k_seg, window=window)
-    acc = acc.reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
-    m = m.reshape(B, H, Tq)
-    l = l.reshape(B, H, Tq)
-    return acc, m, l
+    if use_pallas and _kernels_take(("dq", "dkv"), q, k, causal, window,
+                                    q_seg is not None, out_dtype=out_dtype):
+        return _pallas_bwd(q, k, v, do, lse, delta, offs, causal, interpret,
+                           out_dtype=out_dtype, q_seg=q_seg, k_seg=k_seg,
+                           window=window)
+    return _xla_block_grads(q, k, v, do, lse, delta, offs, causal,
+                            out_dtype=out_dtype, q_seg=q_seg, k_seg=k_seg,
+                            window=window)
 
 
 def flash_attention_block_grads(q, k, v, do, lse, delta, q_off, k_off,
@@ -1273,7 +1326,9 @@ def flash_attention_block_grads(q, k, v, do, lse, delta, q_off, k_off,
                                 window: Optional[int] = None, blocks=None,
                                 out_dtype=jnp.float32):
     """One K/V block's (dq, dk, dv) for ring attention's backward pass
-    (and ``ops/eva_attention``'s; ``blocks`` as ``flash_attention_block``).
+    (and ``ops/eva_attention``'s, through
+    ``flash_attention_block_grads_merged``; ``blocks`` as
+    ``flash_attention_block``).
 
     q/do: [B, T, H, D]; k/v: [B, T, Hkv, D]; lse/delta: f32 [B, H, T] —
     the GLOBAL row statistics (lse over all keys, delta = rowsum(dO*O)),
@@ -1283,38 +1338,17 @@ def flash_attention_block_grads(q, k, v, do, lse, delta, q_off, k_off,
     each group of query heads (f32 by default so the ring's cross-block
     accumulation doesn't round at the model dtype each step).
     """
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    B, Tq, H, _ = q.shape
     Hkv = _kv_heads(q, k, v)
-    use_pallas, interpret = _resolve_dispatch(use_pallas)
-
-    offs = jnp.stack([jnp.asarray(q_off, jnp.int32),
-                      jnp.asarray(k_off, jnp.int32)])
+    offs = _block_offsets(q_off, k_off)
     qm, km, vm, dom = (_merge_heads(x) for x in (q, k, v, do))
     lse_m = lse.reshape(B * H, Tq, 1)
     delta_m = delta.reshape(B * H, Tq, 1)
-    _require_both_segs(q_segment_ids, k_segment_ids)
-    q_seg = k_seg = None
-    if q_segment_ids is not None:
-        q_seg = _tile_seg(q_segment_ids, H)
-        k_seg = _tile_seg(k_segment_ids, Hkv)
-    window = _mask_rule(window, blocks, causal)
-    if use_pallas and _kernels_take(("dq", "dkv"), qm, km, causal, window,
-                                    q_seg is not None,
-                                    out_dtype=out_dtype):
-        dq, dk, dv = _pallas_bwd(qm, km, vm, dom, lse_m, delta_m, offs,
-                                 causal, interpret, out_dtype=out_dtype,
-                                 q_seg=q_seg, k_seg=k_seg, window=window)
-    else:
-        dq, dk, dv = _xla_block_grads(qm, km, vm, dom, lse_m, delta_m,
-                                      offs, causal, out_dtype=out_dtype,
-                                      q_seg=q_seg, k_seg=k_seg,
-                                      window=window)
-
-    def split(x, t, h):
-        return x.reshape(B, h, t, D).transpose(0, 2, 1, 3)
-
-    return split(dq, Tq, H), split(dk, Tk, Hkv), split(dv, Tk, Hkv)
+    q_seg, k_seg = _tiled_segs(q_segment_ids, k_segment_ids, H, Hkv)
+    dq, dk, dv = flash_attention_block_grads_merged(
+        qm, km, vm, dom, lse_m, delta_m, offs, causal, use_pallas, q_seg,
+        k_seg, window, blocks, out_dtype)
+    return tuple(_split_heads(x, B) for x in (dq, dk, dv))
 
 
 @jax.named_scope("flash_xla")

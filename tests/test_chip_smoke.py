@@ -460,7 +460,17 @@ def test_eva_attention_compiles_for_v5e(described_chip, monkeypatch, which):
     bytes, windows of 2,048, chunks of 16): the exact set's kernels and
     the summaries' under the block-causal rule, whose mask divides integer
     vectors, compile to Mosaic, two calls a pass, and no score array of
-    either set is in the program."""
+    either set is in the program. Both calls read the head-major operands
+    where they lie: nothing of q's size is copied into or out of the
+    window-major order ``[16, 32, 2048, 128]`` (before the windows were
+    cut from the merged heads: q, k, v and the float32 accumulator
+    forward, those, dO, dQ, dK and dV backward), and the row statistics
+    are written as ``[., ., 1]`` columns, 512 MiB each as they lie, three
+    times where it was six: ``lse`` once for both calls, ``delta`` once
+    for each (the compiler makes the windows' form from the rows by a
+    reshape of its own)."""
+    import re
+
     from horovod_tpu.ops.eva_attention import eva_attention
 
     monkeypatch.setattr(pa, "_resolve_dispatch", lambda up: (True, False))
@@ -481,6 +491,18 @@ def test_eva_attention_compiles_for_v5e(described_chip, monkeypatch, which):
         else {"flash_fwd": 2, "flash_bwd": 2})
     for dims in (f"{T},{T}]", f"{T},{T // C}]", f"{W},{W}]"):
         assert dims not in text, dims
+    n = T // W
+    made = re.findall(  # (name, type and dims, opcode) of every instruction
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+\[[\d,]*\])\S* ([\w\-]+)\(",
+        text[text.index("\nENTRY"):], re.M)
+    window_major = [m for m in made if m[1].endswith((
+        f"[{n},{H},{W},{D}]", f"[{n},{W},{H},{D}]"))
+        and (m[2] == "copy" or "copy" in m[0])]
+    assert not window_major, window_major
+    columns = [m for m in made if m[1] in (f"f32[{H},{T},1]",
+                                           f"f32[{H * n},{W},1]")
+               and m[2] in ("copy", "reshape", "fusion", "transpose")]
+    assert len(columns) <= (0 if which == "forward" else 3), columns
 
 
 def _flash_custom_calls(text):

@@ -114,26 +114,92 @@ def _qkv(seed=0, B=2, H=2, D=16, t=T):
                  for _ in range(3))
 
 
+# (B, H, window): two heads over four windows; H != n and B > 1, which a
+# wrong row order ((b H + h) n + w against (b n + w) H + h) cannot pass;
+# one window, where no summary is seen and one call runs.
+SHAPES = {"2x2-4windows": (2, 2, W), "2x3-4windows": (2, 3, W),
+          "2x3-1window": (2, 3, T)}
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
 @pytest.mark.parametrize("kernels", ["xla", "interpreted"])
-def test_attention_and_its_gradients_against_dense(kernels, monkeypatch):
+def test_attention_and_its_gradients_against_dense(kernels, shape,
+                                                   monkeypatch):
     if kernels == "interpreted":
         monkeypatch.setenv("HVD_PALLAS_INTERPRET", "1")
-    q, k, v = _qkv()
+    B, H, window = SHAPES[shape]
+    q, k, v = _qkv(B=B, H=H)
     k_sum, v_sum = (x[:, ::C] * 0.7 for x in (k, v))  # any summaries
     w = jnp.asarray(np.random.RandomState(5).randn(*q.shape), jnp.float32)
 
     def loss(fn, *args):
-        return jnp.sum(fn(*args, W, C) * w)
+        return jnp.sum(fn(*args, window, C) * w)
 
     got = jax.grad(lambda *a: loss(eva_attention, *a), argnums=range(5))(
         q, k, v, k_sum, v_sum)
     want = jax.grad(lambda *a: loss(_dense_eva, *a), argnums=range(5))(
         q, k, v, k_sum, v_sum)
-    np.testing.assert_allclose(eva_attention(q, k, v, k_sum, v_sum, W, C),
-                               _dense_eva(q, k, v, k_sum, v_sum, W, C),
-                               atol=2e-5)
+    np.testing.assert_allclose(
+        eva_attention(q, k, v, k_sum, v_sum, window, C),
+        _dense_eva(q, k, v, k_sum, v_sum, window, C), atol=2e-5)
     for g, r in zip(got, want):
         np.testing.assert_allclose(g, r, atol=5e-5)
+
+
+def _head_major_transposes(jaxpr, like):
+    """The ``transpose`` equations of a traced program that give the
+    head-major form of an array of ``like``'s logical shape and type
+    ([B, T, H, D] -> [B, H, T, D])."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            aval = eqn.invars[0].aval if eqn.invars else None
+            if (eqn.primitive.name == "transpose"
+                    and eqn.params["permutation"] == (0, 2, 1, 3)
+                    and aval.shape == like.shape
+                    and aval.dtype == like.dtype):
+                found.append(eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("window, passes", [(W, 2), (T, 0)],
+                         ids=["4windows", "1window"])
+def test_the_heads_are_merged_once_a_pass(window, passes):
+    """A traced forward asks for the head-major form of q, k and v once
+    each and a traced backward for those and dO's, whichever key sets read
+    them (cut into windows before the heads were merged, q and dO were
+    transposed once a call: 4 forward, 6 backward); and the counter reads
+    the two differentiated passes of a sequence of several windows."""
+    q = jax.ShapeDtypeStruct((2, T, 3, 16), jnp.bfloat16)
+    s = jax.ShapeDtypeStruct((2, T // C, 3, 16), jnp.bfloat16)
+
+    def attend(*a):
+        return eva_attention(*a, window, C)
+
+    forward = jax.make_jaxpr(attend)(q, q, q, s, s)
+    assert len(_head_major_transposes(forward, q)) == 3
+    before = metrics()["python"].get("kernels.eva.merged_operands", 0)
+    both = jax.make_jaxpr(jax.grad(
+        lambda *a: attend(*a).astype(jnp.float32).sum(),
+        argnums=range(5)))(q, q, q, s, s)
+    assert len(_head_major_transposes(both, q)) == 3 + 4
+    assert metrics()["python"].get("kernels.eva.merged_operands",
+                                   0) - before == passes
+
+
+def test_refuses_a_grouped_key_side():
+    """The windows are cut from the head-major rows, an order the kernels'
+    grouped K side cannot follow: fewer K/V heads are refused here, as the
+    configuration refuses ``n_kv_heads``."""
+    q, k, v = _qkv(H=4)
+    with pytest.raises(ValueError, match="its own key and value"):
+        eva_attention(q, k[:, :, :2], v[:, :, :2], k[:, ::C, :2],
+                      v[:, ::C, :2], W, C)
 
 
 @pytest.mark.parametrize("window, chunk", [(W, 1), (T, C), (4 * T, C)])

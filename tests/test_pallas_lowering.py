@@ -6,6 +6,9 @@ host — the exported module must contain the ``tpu_custom_call`` carrying
 the serialized kernel, so lowering regressions fail here, in CI, without
 a chip."""
 
+import hashlib
+import re
+
 import numpy as np
 import pytest
 
@@ -71,27 +74,80 @@ def test_flash_attention_two_pass_bwd_lowers_to_mosaic(mosaic, monkeypatch):
         assert name in txt
 
 
-def test_ring_attention_block_kernels_lower_to_mosaic(mosaic):
-    """The ring-attention per-block state/grad kernels lower too."""
+def _block_programs(level):
+    """(function, arguments) of the per-block state and of its gradients
+    at T 512: through the public functions on [B, T, H, D], or through the
+    merged-operand ones on [B H, T, D] called directly."""
     q, k, v = _qkv(T=512)
-
-    def fwd(q, k, v):
-        return pa.flash_attention_block(q, k, v, q_off=0, k_off=0,
-                                        causal=True)
-
-    txt = _export_tpu(fwd, q, k, v)
-    assert "tpu_custom_call" in txt
-
-    def bwd(q, k, v, do, lse, delta):
-        return pa.flash_attention_block_grads(
-            q, k, v, do, lse, delta, q_off=0, k_off=0, causal=True)
-
     B, T, H, D = q.shape
     do = jnp.ones_like(q)
-    lse = jnp.zeros((B, H, T), jnp.float32)
-    delta = jnp.zeros((B, H, T), jnp.float32)
-    txt = _export_tpu(bwd, q, k, v, do, lse, delta)
-    assert "tpu_custom_call" in txt
+    if level == "merged":
+        q, k, v, do = (pa._merge_heads(x) for x in (q, k, v, do))
+        offs = jnp.zeros((2,), jnp.int32)
+        stat = jnp.zeros((B * H, T, 1), jnp.float32)
+
+        def fwd(q, k, v):
+            return pa.flash_attention_block_merged(q, k, v, offs,
+                                                   causal=True)
+
+        def bwd(q, k, v, do, lse, delta):
+            return pa.flash_attention_block_grads_merged(
+                q, k, v, do, lse, delta, offs, causal=True)
+    else:
+        stat = jnp.zeros((B, H, T), jnp.float32)
+
+        def fwd(q, k, v):
+            return pa.flash_attention_block(q, k, v, q_off=0, k_off=0,
+                                            causal=True)
+
+        def bwd(q, k, v, do, lse, delta):
+            return pa.flash_attention_block_grads(
+                q, k, v, do, lse, delta, q_off=0, k_off=0, causal=True)
+
+    return {"state": (fwd, (q, k, v)),
+            "grads": (bwd, (q, k, v, do, stat, stat))}
+
+
+@pytest.mark.parametrize("level", ["heads", "merged"])
+def test_ring_attention_block_kernels_lower_to_mosaic(mosaic, level):
+    """The ring-attention per-block state/grad kernels lower too, and so
+    do the merged-operand functions under them that EVA attention
+    calls."""
+    for fn, args in _block_programs(level).values():
+        assert "tpu_custom_call" in _export_tpu(fn, *args)
+
+
+def _lowered_without_kernel_bodies(fn, *args):
+    """The text ``fn`` lowers to for a TPU, with no debug locations and
+    each Mosaic call's serialized body cut out: a body carries the file
+    paths and line numbers of the kernel's source, so it differs between
+    two checkouts of one program. What is left is every operation around
+    the kernels with its types, and each call's name, operand and result
+    layouts and scoped memory."""
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    cut, n = re.subn(r'(\\22body\\22: \\22)[^\\]*', r"\1", text)
+    assert n == text.count("tpu_custom_call") > 0
+    return cut
+
+
+# sha256 (first 16 hex digits) of ``_lowered_without_kernel_bodies`` of the
+# two public block functions, read with it on the commit before they became
+# merge -> the merged-operand function -> split (PR 45's tree, jax 0.9.0):
+# ring attention's programs are what they were.
+BLOCK_FUNCTIONS = {"state": "38626217b64fbfa0", "grads": "59076b9b1fc33862"}
+
+
+@pytest.mark.parametrize("which", sorted(BLOCK_FUNCTIONS))
+def test_the_public_block_functions_lower_to_the_text_they_lowered_to(
+        mosaic, which):
+    fn, args = _block_programs("heads")[which]
+    got = hashlib.sha256(_lowered_without_kernel_bodies(fn, *args).encode()
+                         ).hexdigest()[:16]
+    assert got == BLOCK_FUNCTIONS[which], (
+        f"flash_attention_block{'_grads' if which == 'grads' else ''} "
+        f"lowers to another program than on the commit this hash was read "
+        f"on ({got} != {BLOCK_FUNCTIONS[which]})")
 
 
 def test_segment_id_kernels_lower_to_mosaic(mosaic):

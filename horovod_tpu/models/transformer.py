@@ -29,8 +29,8 @@ model's one ``attention_window`` and ``rope`` switch),
 ``"full_attention"`` (causal over everything, no positions), three
 entries over one function and one set of leaves; ``"mamba"`` (Mamba-2,
 ``_mamba_mixer``), ``"latent_attention"`` (``_latent_mixer``),
-``"cca"`` (``_cca_mixer``) and ``"eva"`` (``_eva_mixer``), each with leaves
-of its own. Each layer ends
+``"cca"`` (``_cca_mixer``), ``"eva"`` (``_eva_mixer``) and ``"kda"``
+(``_kda_mixer``), each with leaves of its own. Each layer ends
 in a feed-forward block that is data too: the dense MLP, or with
 ``use_moe`` the expert layer in all but the ``num_dense_layers`` leading
 layers. Parameters are stacked per group (each group of mixers, the dense
@@ -75,14 +75,44 @@ normed ``h`` [T, d], H heads of ``d_head`` = ``qk_nope_head_dim`` +
 whole. Queries, keys and values enter the flash kernels at the one width
 ``d_head``. The two low-rank norms are float32. The heads (``l_wqb``,
 ``l_wkvb``, ``l_wo``) shard over ``tp``; the down-projections and their
-norms are whole on every member.
+norms are whole on every member. Four switches: ``q_lora_rank`` 0 makes
+the query one full-rank matrix, ``q_i = h W_q[i]`` (``l_wq``, no latent
+and no norm); ``v_head_dim`` gives the values a width of their own,
+narrower than a key's ``qk_nope_head_dim + qk_rope_head_dim`` (they ride
+zeros up to it into the kernels, whose softmax scale is the keys' width's,
+and the zeros' columns of the result are dropped); ``qk_norm`` "head" is a
+learned RMSNorm over a head's whole query and whole key (its own channels
+beside the shared ones) before the rotation, one weight vector each for
+all heads (``l_gq``, ``l_gk``); ``attn_gate`` "head" multiplies a head's
+result by ``sigmoid(h W_gate)_i``, one gate a head (``l_wgate`` [d, H]).
+
+Kimi Delta Attention (KDA; Kimi Linear, arXiv:2510.26692 section 3) on
+normed ``h`` [T, d], H heads whose keys and values have ``kda_head_dim``
+= K channels, ``conv`` a causal depth-wise convolution of ``kda_conv``
+taps (zeros before the first token, no bias)::
+
+    q~, k~, v = silu(conv(h W_q)), silu(conv(h W_k)), silu(conv(h W_v))
+    q = K^-1/2 q~ / |q~|;  k = k~ / |k~|        over a head's channels
+    g = floor * sigmoid(exp(A_h) (h W_f + b))     [H, K], in (floor, 0)
+    beta = sigmoid(h W_beta)                      [H]
+    S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t                               S_0 = 0, [K, K] a head
+    out = concat_i(rms(o_i) g_o * sigmoid(h W_g)_i) W_o
+
+``floor`` is ``kda_gate_floor`` (-5: the decay ``exp(g)`` of a channel
+lies in (e^-5, 1)), ``A_h`` a scalar a head, ``b`` a bias, ``g_o`` one
+weight [K] for all heads; no rotation. The norms, the decay and what it is
+made of, beta, both sigmoids and the scan's state are float32 (``ops/
+kda.py`` has the recurrence in its chunked form, ``kda_chunk`` tokens a
+chunk). Leaves ``k_*``; heads over ``tp``.
 
 Multi-token prediction (the same paper, section 2.2), one module after
 the stack with ``n_mtp_modules``: on the stack's output ``x`` before the
 final norm and the tokens' *labels* ``t_{i+1}``::
 
     g_i = [rms_h(x_i) ; rms_e(E[t_{i+1}])] W_eh           [2 d] -> [d]
-    z = layer(g)        one more layer of the stack's last kind, own leaves
+    z = layer(g)        one more layer, own leaves: of the stack's last
+                        kind, or of ``mtp_layer_type`` where that is stated
     loss = CE(head(final_ln(x)), t_{i+1})
            + mtp_loss_weight * CE(head(rms_s(z)), t_{i+2})
 
@@ -120,7 +150,11 @@ The ZAYA router (arXiv:2511.17127) of an expert layer ``l`` with
     s = gelu(gelu(rms(r_l) W_1) W_2) W_3    [T, E]
     p = softmax(s);  picks = top_k(p + bias);  weights = p[picks]
 
-``r_l`` is ``Carry.state``, beside the activations in every scan. With
+``r_l`` is ``Carry.state``, beside the activations in every scan. A
+sigmoid router with ``moe_n_group`` > 1 picks its ``moe_top_k`` among the
+experts of the ``moe_topk_group`` best groups of consecutive experts, a
+group scored by the sum of its two largest ``score + bias``
+(``parallel.moe.moe_layer``). With
 ``residual_scales`` a block joins the stream as ``a * x + b + c * block(
 norm(x))`` with learned float32 ``a``, ``b``, ``c`` [d] (one, zero, one at
 the start), leaves ``res1`` and ``res2`` [3, d].
@@ -183,6 +217,7 @@ with _metrics.span("import:horovod_tpu.models.transformer"):
 
     from ..common.compat import axis_size as _axis_size
     from ..common.compat import shard_map as _compat_shard_map
+    from ..ops.kda import kda_chunked
     from ..ops.ssd import ssd_chunked
     from ..parallel.moe import moe_layer
     from ..parallel.pipeline import spmd_pipeline
@@ -201,10 +236,13 @@ with _metrics.span("import:horovod_tpu.models.transformer"):
 # values) and its convolved queries and keys, what it hands the kernels
 # again under ``attn_q``, ``attn_kv``; an EVA mixer's rotated queries and
 # its rotated keys with the values under the same two, its joint output
-# and row statistics under ``flash_out``, ``flash_lse``.
+# and row statistics under ``flash_out``, ``flash_lse``; a KDA mixer's
+# query, key and value projection, its two gates' projections and the
+# scan's output.
 REMAT_NAMES = ("mamba_zx", "ssd_out", "attn_q", "attn_kv", "attn_gate",
                "attn_proj", "flash_out", "flash_lse", "mlp_gu", "mla_cq",
-               "mla_ckv", "cca_q", "cca_kv", "cca_conv")
+               "mla_ckv", "cca_q", "cca_kv", "cca_conv", "kda_qkv",
+               "kda_gates", "kda_out")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -235,6 +273,12 @@ class TransformerConfig:
     # by the picked scores' sum first).
     moe_score_func: str = "softmax"
     route_scale: float = 1.0
+    # Group-limited selection of a sigmoid router (DeepSeek-V3's): the
+    # experts in ``moe_n_group`` groups of consecutive ones, a group scored
+    # by the sum of its two largest ``score + bias``, and the top-k taken
+    # among the ``moe_topk_group`` best groups' experts. One group: plain.
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
     # Gated MLPs of width ``n_shared_experts * d_expert`` that every
     # token passes beside its routed experts.
     n_shared_experts: int = 0
@@ -262,8 +306,9 @@ class TransformerConfig:
     # channels, one weight vector [d_head] for all heads.
     qk_norm: Any = False
     # The kernels' output times ``sigmoid(h W_gate)`` (W_gate [d, H, Dh])
-    # before the output projection.
-    attn_gate: bool = False
+    # before the output projection. "head": one gate a head (W_gate [d,
+    # H]), through a latent layer, which takes no other.
+    attn_gate: Any = False
     # Each branch is normed again before it joins the residual stream:
     # ``x + norm(mixer(norm(x)))``, ``x + norm(ffn(norm(x)))``.
     post_norms: bool = False
@@ -319,13 +364,24 @@ class TransformerConfig:
     mamba_d_state: int = 0
     mamba_d_conv: int = 4
     mamba_chunk: int = 256
-    # The latent mixer (the module's docstring): the two ranks, and a
-    # head's rotated and unrotated widths, which add up to ``d_head``,
-    # the value's width too. Rotated with ``rope_theta``.
+    # The latent mixer (the module's docstring): the two ranks
+    # (``q_lora_rank`` 0: the query's one full-rank matrix, no latent),
+    # and a head's rotated and unrotated widths, which add up to a query's
+    # and a key's width; ``v_head_dim`` the value's (0: ``d_head``, which
+    # the two then add up to). Rotated with ``rope_theta``.
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_rope_head_dim: int = 0
     qk_nope_head_dim: int = 0
+    v_head_dim: int = 0
+    # The KDA mixer (the module's docstring): ``n_heads`` heads whose keys
+    # and values have ``kda_head_dim`` channels, the taps of its three
+    # causal depthwise convolutions, the lower bound of a token's log
+    # decay, and the chunk of the scan (ops/kda.py).
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_gate_floor: float = -5.0
+    kda_chunk: int = 64
     # The CCA mixer (the module's docstring): the widths of its two
     # causal convolutions over the sequence, and the share of a head's
     # channels, from the first, that is rotated (with ``rope_theta``).
@@ -366,6 +422,8 @@ class TransformerConfig:
     # module's docstring) and the weight of their cross-entropy.
     n_mtp_modules: int = 0
     mtp_loss_weight: float = 0.3
+    # The kind of the module's layer; None: the stack's last.
+    mtp_layer_type: Optional[str] = None
     # Dense feed-forward W_d (silu(h W_g) * h W_u) of width d_ff instead
     # of W_2 gelu(h W_1).
     gated_mlp: bool = False
@@ -404,6 +462,13 @@ class TransformerConfig:
                     f"one of {tuple(MIXERS)}; got {kinds}")
             for kind in dict.fromkeys(kinds):
                 MIXERS[kind].check(self)
+        if self.mtp_layer_type is not None:
+            if self.mtp_layer_type not in MIXERS or not self.n_mtp_modules:
+                raise ValueError(
+                    f"mtp_layer_type names the kind of a multi-token-"
+                    f"prediction module's layer (n_mtp_modules), one of "
+                    f"{tuple(MIXERS)}; got {self.mtp_layer_type!r}")
+            MIXERS[self.mtp_layer_type].check(self)
         if self.n_mtp_modules not in (0, 1):
             raise ValueError(
                 f"n_mtp_modules must be 0 or 1, got {self.n_mtp_modules}: "
@@ -414,6 +479,25 @@ class TransformerConfig:
         if self.moe_score_func not in ("softmax", "sigmoid"):
             raise ValueError(f"moe_score_func must be 'softmax' or "
                              f"'sigmoid', got {self.moe_score_func!r}")
+        if self.attn_gate not in (False, True, "head") or (
+                self.attn_gate == "head" and any(
+                    MIXERS[kind].group == "attention"
+                    for kind in self.mixer_kinds)):
+            raise ValueError(
+                f"attn_gate must be False, True or 'head', and one gate a "
+                f"head is built through latent_attention layers alone; got "
+                f"{self.attn_gate!r} with layers {self.mixer_kinds}")
+        if self.moe_n_group != 1 and (
+                self.moe_score_func != "sigmoid" or self.router_hidden
+                or self.n_experts % self.moe_n_group
+                or not 1 <= self.moe_topk_group <= self.moe_n_group
+                or self.moe_top_k > self.moe_topk_group
+                * (self.n_experts // self.moe_n_group)):
+            raise ValueError(
+                f"moe_n_group ({self.moe_n_group}) groups of a sigmoid "
+                f"router's {self.n_experts} experts, of which "
+                f"moe_topk_group ({self.moe_topk_group}) must hold the "
+                f"{self.moe_top_k} a token")
         if not 0 <= self.num_dense_layers <= self.n_layers or (
                 self.num_dense_layers and not self.use_moe):
             raise ValueError(
@@ -486,11 +570,23 @@ class TransformerConfig:
         return self.layer_types or (LAYER_KINDS[0],) * self.n_layers
 
     @property
+    def mtp_kind(self) -> str:
+        """The mixer of a multi-token-prediction module's layer."""
+        return self.mtp_layer_type or self.kinds[-1]
+
+    @property
+    def mixer_kinds(self) -> Tuple[str, ...]:
+        """Each layer's mixer, a multi-token-prediction module's layer
+        after the stack's."""
+        return self.kinds + (self.mtp_kind,) * self.n_mtp_modules
+
+    @property
     def mtp_layer(self) -> "TransformerConfig":
         """The one-layer model whose layer a multi-token-prediction module
-        runs: the stack's last (mixer, feed-forward) pair."""
+        runs: the stack's last feed-forward block under ``mtp_kind``."""
         return dataclasses.replace(
-            self, n_layers=1, layer_types=self.kinds[-1:], n_mtp_modules=0,
+            self, n_layers=1, layer_types=(self.mtp_kind,), n_mtp_modules=0,
+            mtp_layer_type=None,
             num_dense_layers=int(self.use_moe
                                  and self.ffn_kinds[-1] == "mlp"))
 
@@ -708,18 +804,57 @@ def _init_attention(cfg: TransformerConfig, rng, lead, norm) -> Dict:
 def _init_latent(cfg: TransformerConfig, rng, lead, norm) -> Dict:
     """The latent mixers' leaves with leading shape ``lead``: matrices
     normal at fan-in^-1/2, the two norms' weights one (float32)."""
-    d, H, Dh = cfg.d_model, cfg.n_heads, cfg.d_head
+    d, H = cfg.d_model, cfg.n_heads
     rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
     rope, nope = cfg.qk_rope_head_dim, cfg.qk_nope_head_dim
+    Dqk, Dv = nope + rope, cfg.v_head_dim or cfg.d_head
     ks = jax.random.split(rng, 5)
-    return {
-        "l_wqa": norm(ks[0], lead + (d, rq), d ** -0.5),
-        "l_qnorm": jnp.ones(lead + (rq,), jnp.float32),
-        "l_wqb": norm(ks[1], lead + (rq, H, Dh), rq ** -0.5),
+    params = {
         "l_wkva": norm(ks[2], lead + (d, rkv + rope), d ** -0.5),
         "l_kvnorm": jnp.ones(lead + (rkv,), jnp.float32),
-        "l_wkvb": norm(ks[3], lead + (rkv, H, nope + Dh), rkv ** -0.5),
-        "l_wo": norm(ks[4], lead + (H, Dh, d), (H * Dh) ** -0.5),
+        "l_wkvb": norm(ks[3], lead + (rkv, H, nope + Dv), rkv ** -0.5),
+        "l_wo": norm(ks[4], lead + (H, Dv, d), (H * Dv) ** -0.5),
+    }
+    if rq:
+        params.update({
+            "l_wqa": norm(ks[0], lead + (d, rq), d ** -0.5),
+            "l_qnorm": jnp.ones(lead + (rq,), jnp.float32),
+            "l_wqb": norm(ks[1], lead + (rq, H, Dqk), rq ** -0.5)})
+    else:
+        params["l_wq"] = norm(ks[0], lead + (d, H, Dqk), d ** -0.5)
+    if cfg.qk_norm:
+        params.update({name: jnp.ones(lead + (Dqk,), jnp.float32)
+                       for name in ("l_gq", "l_gk")})
+    if cfg.attn_gate:
+        params["l_wgate"] = norm(jax.random.fold_in(rng, 7),
+                                 lead + (d, H), d ** -0.5)
+    return params
+
+
+def _init_kda(cfg: TransformerConfig, rng, lead, norm) -> Dict:
+    """The KDA mixers' leaves with leading shape ``lead``: matrices normal
+    at fan-in^-1/2, the convolutions uniform at taps^-1/2 (no bias), the
+    output norm's weight one, and the decay's three parts so that a token's
+    log decay starts log-uniform in [-1e-1, -1e-3] before the projection
+    moves it: ``A`` zero and the bias the logit of that over the floor
+    (float32)."""
+    d, H, Dk, taps = cfg.d_model, cfg.n_heads, cfg.kda_head_dim, cfg.kda_conv
+    ks = jax.random.split(rng, 8)
+    f32 = jnp.float32
+    slow = jnp.exp(jax.random.uniform(ks[5], lead + (H, Dk), f32,
+                                      jnp.log(1e-3), jnp.log(1e-1)))
+    share = slow / -cfg.kda_gate_floor
+    return {
+        "k_wqkv": norm(ks[0], lead + (d, 3, H, Dk), d ** -0.5),
+        "k_conv": jax.random.uniform(ks[1], lead + (taps, 3, H, Dk), f32,
+                                     -1.0, 1.0) * taps ** -0.5,
+        "k_wf": norm(ks[2], lead + (d, H, Dk), d ** -0.5),
+        "k_fb": jnp.log(share) - jnp.log1p(-share),
+        "k_A": jnp.zeros(lead + (H,), f32),
+        "k_wbeta": norm(ks[3], lead + (d, H), d ** -0.5),
+        "k_wg": norm(ks[4], lead + (d, H, Dk), d ** -0.5),
+        "k_norm": jnp.ones(lead + (Dk,), f32),
+        "k_wo": norm(ks[6], lead + (H, Dk, d), (H * Dk) ** -0.5),
     }
 
 
@@ -857,7 +992,7 @@ def _validate_mesh_divisibility(cfg: TransformerConfig, mesh) -> None:
     for axis in ("sp", "pp"):
         if shape.get(axis, 1) > 1:
             _refuse(cfg, axis)
-    for kind in dict.fromkeys(cfg.kinds):
+    for kind in dict.fromkeys(cfg.mixer_kinds):
         for name in MIXERS[kind].tp_divides:
             if getattr(cfg, name) % tp != 0:
                 raise ValueError(
@@ -871,11 +1006,13 @@ def _model_counts(cfg: TransformerConfig) -> Dict[str, int]:
     with either: its layers of each kind whose entry names a count (a
     multi-token-prediction module's among them), those modules, and the
     head's predictions a position where they are more than one."""
-    layers = cfg.kinds + cfg.kinds[-1:] * cfg.n_mtp_modules
+    layers = cfg.mixer_kinds
     counts = {MIXERS[kind].counts: layers.count(kind)
               for kind in dict.fromkeys(layers) if MIXERS[kind].counts}
     counts["mtp_modules"] = cfg.n_mtp_modules
     counts["pred_heads"] = cfg.n_pred_heads if cfg.n_pred_heads > 1 else 0
+    counts["router_groups_kept"] = (cfg.moe_topk_group
+                                    if cfg.moe_n_group > 1 else 0)
     return {k: n for k, n in counts.items() if n}
 
 
@@ -1053,11 +1190,17 @@ def _latent_mixer(cfg: TransformerConfig, h, lp):
     b, t, _ = h.shape
     pos = jnp.arange(t, dtype=jnp.int32)
     with jax.named_scope("mla_q"):
-        c_q = checkpoint_name(
-            jnp.einsum("btd,dr->btr", h, lp["l_wqa"]), "mla_cq")
-        q = checkpoint_name(jnp.einsum(
-            "btr,rhk->bthk", _rmsnorm(c_q, lp["l_qnorm"], cfg.norm_eps),
-            lp["l_wqb"]), "attn_q")  # h=H/tp
+        if "l_wq" in lp:  # the query's one full-rank matrix
+            q = checkpoint_name(
+                jnp.einsum("btd,dhk->bthk", h, lp["l_wq"]), "attn_q")
+        else:
+            c_q = checkpoint_name(
+                jnp.einsum("btd,dr->btr", h, lp["l_wqa"]), "mla_cq")
+            q = checkpoint_name(jnp.einsum(
+                "btr,rhk->bthk", _rmsnorm(c_q, lp["l_qnorm"], cfg.norm_eps),
+                lp["l_wqb"]), "attn_q")  # h=H/tp
+        if cfg.qk_norm:  # over a head's channels, before the rotation
+            q = _rmsnorm(q, lp["l_gq"], cfg.norm_eps)
         q = _rope(q, pos, theta, rope)
     with jax.named_scope("mla_kv"):
         ckv = checkpoint_name(
@@ -1067,15 +1210,93 @@ def _latent_mixer(cfg: TransformerConfig, h, lp):
             _rmsnorm(ckv[..., :rkv], lp["l_kvnorm"], cfg.norm_eps),
             lp["l_wkvb"]), "attn_kv")
         # One rotated head, read by every query head.
-        k_rope = _rope(ckv[:, :, None, rkv:], pos, theta)
-        k = jnp.concatenate([
-            kv[..., :nope],
-            jnp.broadcast_to(k_rope, (b, t, kv.shape[2], rope))], -1)
+        k_rope = ckv[:, :, None, rkv:]
+        if cfg.qk_norm:
+            # A head's key is normed whole, its own channels beside the
+            # shared ones, so the shared ones are normed a head at a time.
+            k = _rmsnorm(jnp.concatenate([
+                kv[..., :nope],
+                jnp.broadcast_to(k_rope, (b, t, kv.shape[2], rope))], -1),
+                lp["l_gk"], cfg.norm_eps)
+            k = _rope(k, pos, theta, rope)
+        else:
+            k_rope = _rope(k_rope, pos, theta)
+            k = jnp.concatenate([
+                kv[..., :nope],
+                jnp.broadcast_to(k_rope, (b, t, kv.shape[2], rope))], -1)
+    v = kv[..., nope:]
+    narrower = q.shape[-1] - v.shape[-1]
+    if narrower:
+        # The kernels take one width: the values ride zeros up to the
+        # keys', and the zeros' columns of the result are dropped.
+        v = jnp.pad(v, [(0, 0)] * 3 + [(0, narrower)])
     attn = context_parallel_attention(
-        q, k, kv[..., nope:], axis_name="sp", causal=True,
-        strategy=cfg.sp_strategy)
+        q, k, v, axis_name="sp", causal=True, strategy=cfg.sp_strategy)
+    if narrower:
+        attn = attn[..., :-narrower]
+    if cfg.attn_gate:  # one gate a head
+        with jax.named_scope("attn_gate"):
+            attn = _sigmoid_gated(attn, checkpoint_name(jnp.einsum(
+                "btd,dh->bth", h, lp["l_wgate"]), "attn_gate")[..., None])
     return checkpoint_name(
         jnp.einsum("bthk,hkd->btd", attn, lp["l_wo"]), "attn_proj")
+
+
+@functools.partial(jax.checkpoint, static_argnums=(1,))
+def _l2_normed(x, scale):
+    """``scale * x / |x|`` over the last axis in float32 (1e-6 under the
+    root), in x's type."""
+    xf = x.astype(jnp.float32)
+    ss = jnp.sum(jnp.square(xf), -1, keepdims=True)
+    return (xf * (scale * jax.lax.rsqrt(ss + 1e-6))).astype(x.dtype)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3,))
+def _kda_decay(a, bias, A, floor):
+    """A token's log decay by channel, float32 in (floor, 0): ``floor *
+    sigmoid(exp(A_h) (a + bias))`` of the projection a [b, t, h, k]."""
+    return floor * jax.nn.sigmoid(
+        jnp.exp(A)[:, None] * (a.astype(jnp.float32) + bias))
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3,))
+def _head_norm_gated(o, gate, scale, eps):
+    """``rmsnorm(o) * scale * sigmoid(gate)`` over each head's channels of
+    o, gate [b, t, h, k] in float32, in o's type."""
+    of = o.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(of), -1, keepdims=True)
+    return (of * jax.lax.rsqrt(ms + eps) * scale
+            * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(o.dtype)
+
+
+def _kda_mixer(cfg: TransformerConfig, h, lp):
+    """The KDA mixer of the module's docstring on normed h [b, t, d];
+    heads are this tp member's, the result its partial sum."""
+    with jax.named_scope("kda_proj"):
+        qkv = checkpoint_name(
+            jnp.einsum("btd,dchk->btchk", h, lp["k_wqkv"]),  # h=H/tp
+            "kda_qkv")
+        a = checkpoint_name(
+            jnp.einsum("btd,dhk->bthk", h, lp["k_wf"]), "kda_gates")
+        gate = checkpoint_name(
+            jnp.einsum("btd,dhk->bthk", h, lp["k_wg"]), "kda_gates")
+        beta = jnp.einsum("btd,dh->bth", h, lp["k_wbeta"])
+    with jax.named_scope("kda_conv"):
+        qkv = jax.nn.silu(_causal_depthwise_conv(qkv, lp["k_conv"], 0.0))
+    with jax.named_scope("kda_gate"):
+        q = _l2_normed(qkv[:, :, 0], cfg.kda_head_dim ** -0.5)
+        k = _l2_normed(qkv[:, :, 1], 1.0)
+        g = _kda_decay(a, lp["k_fb"], lp["k_A"], cfg.kda_gate_floor)
+        beta = jax.nn.sigmoid(beta.astype(jnp.float32))
+    with jax.named_scope("kda_scan"):
+        o = checkpoint_name(
+            kda_chunked(q, k, qkv[:, :, 2], g, beta, cfg.kda_chunk),
+            "kda_out")
+    with jax.named_scope("kda_gate"):
+        o = _head_norm_gated(o, gate, lp["k_norm"], cfg.norm_eps)
+    with jax.named_scope("kda_out"):
+        return checkpoint_name(
+            jnp.einsum("bthk,hkd->btd", o, lp["k_wo"]), "attn_proj")
 
 
 def _causal_conv_by_head(x, w):
@@ -1245,24 +1466,43 @@ def _check_sliding(cfg: TransformerConfig):
 
 def _check_latent(cfg: TransformerConfig):
     rope, nope = cfg.qk_rope_head_dim, cfg.qk_nope_head_dim
-    if not (cfg.q_lora_rank > 0 and cfg.kv_lora_rank > 0 and nope >= 0
+    if not (cfg.q_lora_rank >= 0 and cfg.kv_lora_rank > 0 and nope >= 0
             and rope > 0 and rope % 2 == 0):
         raise ValueError(
-            "a latent_attention layer needs q_lora_rank, kv_lora_rank, "
-            "qk_nope_head_dim and an even qk_rope_head_dim")
-    if nope + rope != cfg.d_head:
+            "a latent_attention layer needs kv_lora_rank, qk_nope_head_dim "
+            "and an even qk_rope_head_dim (q_lora_rank 0: a full-rank "
+            "query)")
+    if not cfg.v_head_dim and nope + rope != cfg.d_head:
         raise ValueError(
             f"qk_nope_head_dim + qk_rope_head_dim ({nope} + {rope}) must "
             f"be d_head ({cfg.d_head}), the value's width too: a value "
-            f"width that differs from the key's is not built (the flash "
-            f"kernels take q, k and v at one width)")
-    if (cfg.n_kv_heads is not None or cfg.qk_norm or cfg.attn_gate
+            f"width that differs from the key's is not built unless "
+            f"v_head_dim states it")
+    if not 0 <= cfg.v_head_dim <= nope + rope:
+        raise ValueError(
+            f"v_head_dim ({cfg.v_head_dim}) wider than a key ({nope} + "
+            f"{rope}) is not built: the values ride zeros up to the keys' "
+            f"width into the flash kernels")
+    if (cfg.n_kv_heads is not None or cfg.qk_norm not in (False, "head")
+            or cfg.attn_gate not in (False, "head")
             or cfg.attention_multiplier is not None):
         raise ValueError(
             "a latent_attention layer has every head its own key and "
-            "value and neither QK-norm, gate nor attention_multiplier: "
-            "n_kv_heads, qk_norm, attn_gate and attention_multiplier "
-            "are not built through it")
+            "value, QK-norm over a head's channels (qk_norm 'head') or "
+            "none, one gate a head (attn_gate 'head') or none, and no "
+            "attention_multiplier: n_kv_heads, qk_norm, attn_gate and "
+            "attention_multiplier are not built through it otherwise")
+
+
+def _check_kda(cfg: TransformerConfig):
+    if cfg.kda_head_dim < 1 or cfg.kda_conv < 1 or cfg.kda_chunk < 1:
+        raise ValueError("a kda layer needs kda_head_dim, kda_conv and "
+                         "kda_chunk >= 1")
+    if not -5.0 <= cfg.kda_gate_floor < 0:
+        raise ValueError(
+            f"kda_gate_floor ({cfg.kda_gate_floor}) must lie in [-5, 0): "
+            f"the scan forms a block's decays around its first row, and "
+            f"16 rows of -5 are what float32 holds (ops/kda.py)")
 
 
 def _check_cca(cfg: TransformerConfig):
@@ -1297,6 +1537,22 @@ def _check_eva(cfg: TransformerConfig):
             "an eva layer has every head its own key and value and neither "
             "QK-norm, gate nor attention_multiplier: n_kv_heads, qk_norm, "
             "attn_gate and attention_multiplier are not built through it")
+
+
+def _latent_specs(cfg: TransformerConfig) -> Dict[str, P]:
+    specs = {"l_wkva": P("pp"), "l_kvnorm": P("pp"),
+             "l_wkvb": P("pp", None, None, "tp"),
+             "l_wo": P("pp", None, "tp")}
+    if cfg.q_lora_rank:
+        specs.update(l_wqa=P("pp"), l_qnorm=P("pp"),
+                     l_wqb=P("pp", None, None, "tp"))
+    else:
+        specs["l_wq"] = P("pp", None, None, "tp")
+    if cfg.qk_norm:  # one [qk] vector for all heads
+        specs.update(l_gq=P("pp"), l_gk=P("pp"))
+    if cfg.attn_gate:
+        specs["l_wgate"] = P("pp", None, None, "tp")
+    return specs
 
 
 def _attention_specs(cfg: TransformerConfig) -> Dict[str, P]:
@@ -1416,11 +1672,7 @@ MIXERS: Dict[str, Mixer] = {
     "latent_attention": Mixer(
         group="latent",
         # Heads over tp; the down-projections and their norms whole.
-        specs=lambda cfg: {
-            "l_wqa": P("pp"), "l_qnorm": P("pp"),
-            "l_wkva": P("pp"), "l_kvnorm": P("pp"),
-            "l_wqb": P("pp", None, None, "tp"),
-            "l_wkvb": P("pp", None, None, "tp"), "l_wo": P("pp", None, "tp")},
+        specs=_latent_specs,
         init=lambda cfg, rng, lead, norm: _init_latent(
             cfg, jax.random.fold_in(rng, 1), lead, norm),
         mixer=lambda cfg, h, lp, seg, gathered_seg: _latent_mixer(
@@ -1465,6 +1717,32 @@ MIXERS: Dict[str, Mixer] = {
                    "built: no test holds the pipeline's ring with a "
                    "float32 stream")},
         counts="eva_layers"),
+    "kda": Mixer(
+        group="kda",
+        # Heads over tp; the output norm's one weight whole.
+        specs=lambda cfg: {
+            "k_wqkv": P("pp", None, None, None, "tp"),
+            "k_conv": P("pp", None, None, None, "tp"),
+            "k_norm": P("pp"),
+            **{k: P("pp", None, None, "tp") for k in (
+                "k_wf", "k_wbeta", "k_wg")},
+            **{k: P("pp", None, "tp") for k in ("k_fb", "k_A", "k_wo")}},
+        init=lambda cfg, rng, lead, norm: _init_kda(
+            cfg, jax.random.fold_in(rng, 6), lead, norm),
+        mixer=lambda cfg, h, lp, seg, gathered_seg: _kda_mixer(cfg, h, lp),
+        check=_check_kda,
+        refuses=lambda cfg: {
+            "packed": ("packed documents through a kda layer are not "
+                       "built: the scan's state and the convolutions are "
+                       "not reset at a segment boundary"),
+            "sp": ("sequence shards (sp > 1) through a kda layer are not "
+                   "built: the scan's state at a shard's last token and "
+                   f"the convolutions' last {cfg.kda_conv - 1} rows are "
+                   "not handed to the next sp member"),
+            "pp": ("pipeline stages (pp > 1) through a kda layer are not "
+                   "built: no test holds the scan's kernels on the "
+                   "pipeline's ring")},
+        counts="kda_layers"),
 }
 LAYER_KINDS = tuple(MIXERS)
 
@@ -1474,7 +1752,8 @@ def _refuse(cfg: TransformerConfig, what: str) -> None:
     built through a model of ``cfg``, with the sentence of the first to
     refuse it: its kinds in order, a multi-token-prediction module, the
     router's carried state, its balancing bias, its prediction heads."""
-    refused = [MIXERS[kind].refuses(cfg) for kind in dict.fromkeys(cfg.kinds)]
+    refused = [MIXERS[kind].refuses(cfg)
+               for kind in dict.fromkeys(cfg.mixer_kinds)]
     if cfg.n_mtp_modules:
         refused.append(_LATENT_REFUSES)
     if cfg.router_hidden:
@@ -1655,7 +1934,8 @@ def _make_layer_fn(cfg: TransformerConfig, packed: bool = False):
                 norm_topk_prob=cfg.norm_topk_prob, seq_axis_name="sp",
                 stacks=stacks, layer=index,
                 score_func=cfg.moe_score_func,
-                route_scale=cfg.route_scale, logits=logits)
+                route_scale=cfg.route_scale, logits=logits,
+                n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group)
             if cfg.n_shared_experts:
                 with jax.named_scope("moe_shared"):
                     y = y + lax.psum(gated_mlp(
@@ -1833,7 +2113,7 @@ def _mtp_module(cfg: TransformerConfig, layer_fn, params, hidden, inputs,
     norm = _block_norm(cfg)
     lp = {k[len(_MTP):]: v[0] for k, v in params.items()
           if k.startswith(_MTP)}
-    kind, ffn = cfg.kinds[-1], cfg.ffn_kinds[-1]
+    kind, ffn = cfg.mtp_kind, cfg.ffn_kinds[-1]
     with jax.named_scope("embed"):
         emb = _times(params["embed"][inputs],
                      cfg.embedding_multiplier).astype(cfg.dtype)

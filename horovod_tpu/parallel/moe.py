@@ -378,11 +378,24 @@ def _windows_bwd(top_k, rows, residuals, g):
 _windows.defvjp(_windows_fwd, _windows_bwd)
 
 
+def _in_best_groups(biased, n_group: int, topk_group: int):
+    """``biased`` [..., E] with the experts outside the ``topk_group``
+    best of ``n_group`` groups of consecutive experts at -inf; a group's
+    score is the sum of its two largest entries."""
+    E = biased.shape[-1]
+    groups = biased.reshape(biased.shape[:-1] + (n_group, E // n_group))
+    score = jnp.sum(lax.top_k(groups, 2)[0], axis=-1)  # [..., n_group]
+    best = lax.top_k(score, topk_group)[1]
+    kept = jnp.any(best[..., None] == jnp.arange(n_group, dtype=best.dtype),
+                   axis=-2)  # [..., n_group]
+    return jnp.where(kept[..., None], groups, -jnp.inf).reshape(biased.shape)
+
+
 def moe_layer(x, params, n_experts: int, first=None, axis_name: str = "dp",
               top_k: int = 1, norm_topk_prob: bool = False,
               seq_axis_name=None, stacks=None, layer=0,
               score_func: str = "softmax", route_scale: float = 1.0,
-              logits=None):
+              logits=None, n_group: int = 1, topk_group: int = 1):
     """Top-k MoE over the tokens of ``x`` [B, T, d] (local sequences).
 
     ``params``: ``router`` [d, E] float32 (replicated) over all ``E =
@@ -407,7 +420,11 @@ def moe_layer(x, params, n_experts: int, first=None, axis_name: str = "dp",
     params["expert_bias"]`` [E] (float32, no gradient; absent: zero) are
     picked, and ``w = route_scale * s`` of the picked, with
     ``norm_topk_prob`` over their sum (+ 1e-20) first. The sum is over
-    all ``top_k`` picked experts, held or not.
+    all ``top_k`` picked experts, held or not. With ``n_group`` > 1 the
+    selection is group-limited (DeepSeek-V3's, arXiv:2412.19437 section
+    2.1.2): the experts in ``n_group`` groups of consecutive ones, a group
+    scored by the sum of its two largest ``s + bias``, and the ``top_k``
+    taken among the experts of the ``topk_group`` best groups.
 
     Returns ``(y [B, T, d], stats)``. ``stats["lb"]`` is the load-balance
     term ``E * sum_e f_e P_e`` of each sequence (``f_e`` the share of its
@@ -444,6 +461,13 @@ def moe_layer(x, params, n_experts: int, first=None, axis_name: str = "dp",
     if score_func not in ("softmax", "sigmoid"):
         raise ValueError(f"score_func must be 'softmax' or 'sigmoid', got "
                          f"{score_func!r}")
+    if n_group > 1 and (score_func != "sigmoid" or E % n_group
+                        or not 1 <= topk_group <= n_group
+                        or top_k > topk_group * (E // n_group)):
+        raise ValueError(
+            f"n_group={n_group}, topk_group={topk_group}: groups of a "
+            f"sigmoid router's {E} experts, of which the kept must hold "
+            f"top_k={top_k}")
     with jax.named_scope("moe_route"):
         # float32 in earnest: at default precision the MXU would round
         # both operands to bf16 and the top-k with them.
@@ -456,6 +480,8 @@ def moe_layer(x, params, n_experts: int, first=None, axis_name: str = "dp",
             biased = scores
             if "expert_bias" in params:
                 biased = scores + lax.stop_gradient(params["expert_bias"])
+            if n_group > 1:
+                biased = _in_best_groups(biased, n_group, topk_group)
             experts = lax.top_k(biased, top_k)[1]  # [B, T, k]
             gates = jnp.take_along_axis(scores, experts, axis=-1)
             if norm_topk_prob:

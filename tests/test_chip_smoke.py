@@ -952,3 +952,58 @@ def test_one_participant_compiles_with_no_all_reduce(described_chip):
         _, schedule = _dp_step_text(_MLP8(), (16, 16), mesh, cap)
         assert not [line for _, opcode, line in schedule
                     if opcode.startswith("all-reduce")]
+
+
+# ---- the KDA scan's kernels and the latent mixer's two widths ---------------
+
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_kda_kernels_compile_for_v5e(described_chip, monkeypatch, chunk):
+    """``ops/kda.py``'s kernel pair at the cell's shape (one sequence of
+    16,384 tokens, 32 heads of 128) at both chunks the plan takes: both
+    Mosaic calls by name, and the states a chunk starts from the forward
+    call's second result."""
+    from horovod_tpu.ops import kda
+
+    monkeypatch.setattr(pa, "_resolve_dispatch", lambda up: (True, False))
+    T, H, K = 16384, 32, 128
+    wide = jax.ShapeDtypeStruct((1, T, H, K), jnp.bfloat16,
+                                sharding=described_chip)
+    gate = jax.ShapeDtypeStruct((1, T, H, K), jnp.float32,
+                                sharding=described_chip)
+    beta = jax.ShapeDtypeStruct((1, T, H), jnp.float32,
+                                sharding=described_chip)
+    assert kda.kernel_plan(H, K, K, chunk, jnp.bfloat16) is not None
+    fn = jax.grad(lambda *a: kda.kda_chunked(*a, chunk=chunk).astype(
+        jnp.float32).sum(), argnums=range(5))
+    text = jax.jit(fn).lower(wide, wide, wide, gate, beta).compile().as_text()
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert len(calls) == 2
+    assert any("kda_fwd" in c for c in calls)
+    assert any("kda_bwd" in c for c in calls)
+    assert f"f32[1,{H},{T // chunk},{K},{K}]" in text
+
+
+def test_the_latent_mixers_two_widths_compile_for_v5e(described_chip,
+                                                      monkeypatch):
+    """Queries and keys of 192 channels, values of 128 riding zeros up to
+    192, at the cell's 32 heads and 16,384 tokens: the forward and the
+    fused backward kernel at the one width they are given, and the
+    result's first 128 columns."""
+    monkeypatch.setattr(pa, "_resolve_dispatch", lambda up: (True, False))
+
+    def attend(q, k, v):
+        v = jnp.pad(v, [(0, 0)] * 3 + [(0, q.shape[-1] - v.shape[-1])])
+        return pa.flash_attention(q, k, v, causal=True)[..., :128]
+
+    fn = jax.grad(lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+                  argnums=(0, 1, 2))
+    qk = jax.ShapeDtypeStruct((1, 16384, 32, 192), jnp.bfloat16,
+                              sharding=described_chip)
+    v = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16,
+                             sharding=described_chip)
+    calls = _flash_custom_calls(
+        jax.jit(fn).lower(qk, qk, v).compile().as_text())
+    assert sorted(calls) == ["flash_bwd", "flash_fwd"]
+    (results, operands), = calls["flash_fwd"]
+    assert all(dims.endswith("16384,192") for _, dims in operands[1:])

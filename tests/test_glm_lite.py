@@ -662,7 +662,7 @@ def test_the_kernels_at_a_head_of_two_lane_tiles_match_their_xla_twins(
     ("value width", "a value width that differs from the key's is not "
                     "built"),
     ("odd rope", "an even qk_rope_head_dim"),
-    ("no rank", "needs q_lora_rank, kv_lora_rank"),
+    ("no rank", "needs kv_lora_rank"),
     ("grouped", "n_kv_heads, qk_norm, attn_gate and attention_multiplier"),
     ("gate", "n_kv_heads, qk_norm, attn_gate and attention_multiplier"),
     ("two modules", "a chain of multi-token-prediction modules is not "
@@ -671,7 +671,7 @@ def test_the_kernels_at_a_head_of_two_lane_tiles_match_their_xla_twins(
 def test_a_configuration_that_is_not_built_raises(case, match):
     changed = {"value width": dict(qk_nope_head_dim=8),
                "odd rope": dict(qk_rope_head_dim=3, qk_nope_head_dim=13),
-               "no rank": dict(q_lora_rank=0),
+               "no rank": dict(kv_lora_rank=0),
                "grouped": dict(n_kv_heads=2), "gate": dict(attn_gate=True),
                "two modules": dict(n_mtp_modules=2),
                "keeps": dict(remat=True, remat_keeps=("mla_c",))}[case]

@@ -50,6 +50,8 @@ from . import logging as _log
 #                          those with a grouped K side and those under the
 #                          block-causal rule (ops/pallas_attention.py's
 #                          _log_plan; ops/ssd.py, parallel/moe.py)
+#   kernels.kda_<kind>.heads_per_step  the heads a grid step of each traced
+#                           kda_fwd / kda_bwd call carries (ops/kda.py)
 #   kernels.eva.merged_operands  differentiated passes of EVA attention
 #                          traced whose two calls read one merged q: 2
 #                          after a train step's (ops/eva_attention.py)

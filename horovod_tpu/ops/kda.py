@@ -56,8 +56,16 @@ Two drivers run the same chunk mathematics: a ``lax.scan`` over the chunks
 under ``vmap`` over sequences and heads (XLA), and a Pallas kernel pair
 (``kda_fwd``, ``kda_bwd``: the ``name`` of each ``pallas_call``, which jax
 writes as a scope into the custom call's ``op_name``) whose grid is
-(sequence, head, chunk), the chunks sequential with the state in VMEM
-scratch, the operands read where they lie in ``[b, T, H K]``.
+(sequence, heads of a step, chunk), the chunks sequential with the states
+in VMEM scratch, the operands read where they lie in ``[b, T, H K]``. A
+grid step holds one chunk of each of ``ScanPlan.heads`` neighbouring heads
+(four where they divide ``H`` and fit the VMEM budget, else two, else
+one), the chunk mathematics over a leading head axis: a float32 product at
+``highest`` is six bf16 passes, which fall on the chip's four MXUs as one
+full round and one half-empty, and each product of the solve waits for the
+one before it, so one head's chunk leaves the MXUs a third idle; the heads
+are independent, and their passes fill the rounds (PERF.md section 6,
+PR 48).
 ``_pallas_attention._resolve_dispatch`` decides as for the flash kernels:
 Mosaic on the chip, interpreted under ``HVD_PALLAS_INTERPRET=1``, else — and
 for a shape ``kernel_plan`` refuses — the scan.
@@ -87,6 +95,8 @@ _LANES = 128
 SUB = 16
 _CAP = 80.0
 _NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+# Heads a grid step, the most first (``kernel_plan``).
+_HEADS = (4, 2, 1)
 
 
 def kda_chunked(q, k, v, g, beta, chunk: int = 64):
@@ -294,7 +304,8 @@ class ScanPlan(NamedTuple):
     """What the ``pallas_call`` of a pass does, all of it static."""
     chunk: int       # tokens a grid step
     sub: int         # rows of a block of the [chunk, chunk] tiles
-    vmem_bytes: int  # counted VMEM
+    heads: int       # heads a grid step, each with its own chunk
+    vmem_bytes: int  # counted VMEM, of all the step's heads
 
 
 def kernel_plan(H, K, V, chunk, dtype, *, kind="bwd"):
@@ -303,8 +314,11 @@ def kernel_plan(H, K, V, chunk, dtype, *, kind="bwd"):
     ``dtype``: a pure function of the shape. None where the kernels do not
     take the shape and the scan does: channels off the lane grid (128), or
     a chunk that blocks of ``SUB`` rows do not divide or that is neither
-    half a lane tile nor whole ones."""
-    del H  # a grid step is one head whatever their number
+    half a lane tile nor whole ones.
+
+    A grid step carries ``heads`` heads, the most of ``_HEADS`` that
+    divides ``H`` and whose counted VMEM, a head's times ``heads``, fits
+    the budget (why several: the module's docstring)."""
     if K % _LANES or V % _LANES or chunk % SUB:
         return None
     if chunk != _LANES // 2 and chunk % _LANES:
@@ -315,13 +329,18 @@ def kernel_plan(H, K, V, chunk, dtype, *, kind="bwd"):
         wide = 2 * wide + chunk * V * 4                # their gradients, do
     state = V * K * 4
     # Pipelined blocks twice, the carried state, and the float32 values a
-    # chunk forms: a dozen [chunk, K], the blocks' decays, six tiles.
-    vmem = (2 * (wide + state) + state
-            + (12 + chunk // SUB) * chunk * max(K, V) * 4
-            + 6 * chunk * chunk * 4)
-    if vmem > _pallas_attention.VMEM_BUDGET:
-        return None
-    return ScanPlan(chunk, SUB, vmem)
+    # chunk forms: a dozen [chunk, K], the blocks' decays, six tiles; the
+    # backward pass twice as many, and float32 operands twice as many
+    # again (the parts of their products at ``highest``). Held to what
+    # Mosaic allocates for a described v5e (PERF.md, PR 48).
+    values = ((12 + chunk // SUB) * chunk * max(K, V) * 4
+              + 6 * chunk * chunk * 4)
+    values *= (2 if kind == "bwd" else 1) * (itemsize // 2)
+    vmem = 2 * (wide + state) + state + values
+    for heads in _HEADS:
+        if H % heads == 0 and heads * vmem <= _pallas_attention.VMEM_BUDGET:
+            return ScanPlan(chunk, SUB, heads, heads * vmem)
+    return None
 
 
 def _log_plan(kind, shape, dtype, plan):
@@ -329,15 +348,36 @@ def _log_plan(kind, shape, dtype, plan):
     call is traced (``HOROVOD_LOG_LEVEL=debug``), and counted: the host
     traces this ``pallas_call`` and lowers it to Mosaic."""
     _metrics.inc(f"kernels.traced.kda_{kind}")
+    _metrics.inc(f"kernels.kda_{kind}.heads_per_step", plan.heads)
     _log.debug(
         f"kda_{kind} {tuple(shape)} {jnp.dtype(dtype).name}: chunks of "
-        f"{plan.chunk} tokens, one head a step, blocks of {plan.sub} rows, "
-        f"VMEM {plan.vmem_bytes} B")
+        f"{plan.chunk} tokens, {plan.heads} heads a step, blocks of "
+        f"{plan.sub} rows, VMEM {plan.vmem_bytes} B")
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, sub):
-    """One chunk of one head; the state rides the chunks in scratch, and
-    with a ``states`` output each chunk leaves the one it started from."""
+def _heads_of(ref, heads, width=None):
+    """[heads, C, width] of a block [C, heads * K]: each head's lanes, or
+    the first ``width`` of them."""
+    K = ref.shape[-1] // heads
+    return jnp.stack([ref[:, i * K:i * K + (width or K)]
+                      for i in range(heads)])
+
+
+def _put_heads(ref, x):
+    """``_heads_of`` back: x [heads, C, K] into the block's lanes."""
+    K = x.shape[-1]
+    for i in range(x.shape[0]):
+        ref[:, i * K:(i + 1) * K] = x[i].astype(ref.dtype)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, sub, heads):
+    """One chunk of each of the step's heads, ``_chunk_forward`` over a
+    leading head axis: every matmul of it is one batched ``dot_general``,
+    which Mosaic unrolls a head after a head, so a link of one head's chain
+    stands beside the same link of the others (traced a head at a time the
+    chains are scheduled one after another: PERF.md, PR 48). The states
+    ride the chunks in scratch, and with a ``states`` output each chunk
+    leaves the one it started from."""
     s_ref, st_ref = rest if len(rest) == 2 else (None, rest[0])
 
     @pl.when(pl.program_id(2) == 0)
@@ -347,28 +387,32 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, sub):
     St0 = st_ref[...]
     if s_ref is not None:
         s_ref[...] = St0
-    o, St1 = _chunk_forward(q_ref[...], k_ref[...], v_ref[...], g_ref[...],
-                            b_ref[:, :1], St0, sub)
-    o_ref[...] = o.astype(o_ref.dtype)
+    o, St1 = jax.vmap(lambda *x: _chunk_forward(*x, sub))(
+        *(_heads_of(ref, heads) for ref in (q_ref, k_ref, v_ref, g_ref)),
+        _heads_of(b_ref, heads, 1), St0)
+    _put_heads(o_ref, o)
     st_ref[...] = St1
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
-                dk_ref, dv_ref, dg_ref, db_ref, dst_ref, *, sub):
-    """One chunk of one head, the chunks from the last to the first (the
-    index maps turn them round); the state's gradient rides in scratch."""
+                dk_ref, dv_ref, dg_ref, db_ref, dst_ref, *, sub, heads):
+    """One chunk of each of the step's heads as in ``_fwd_kernel``, the
+    chunks from the last to the first (the index maps turn them round);
+    the states' gradients ride in scratch."""
     @pl.when(pl.program_id(2) == 0)
     def _():
         dst_ref[...] = jnp.zeros(dst_ref.shape, jnp.float32)
 
-    dq, dk, dv, dG, dbeta, dSt0 = _chunk_backward(
-        q_ref[...], k_ref[...], v_ref[...], g_ref[...], b_ref[:, :1],
-        s_ref[...], do_ref[...].astype(jnp.float32), dst_ref[...], sub)
-    dq_ref[...] = dq.astype(dq_ref.dtype)
-    dk_ref[...] = dk.astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
-    dg_ref[...] = dG
-    db_ref[...] = jnp.broadcast_to(dbeta, db_ref.shape)
+    dq, dk, dv, dG, dbeta, dSt0 = jax.vmap(
+        lambda *x: _chunk_backward(*x, sub))(
+        *(_heads_of(ref, heads) for ref in (q_ref, k_ref, v_ref, g_ref)),
+        _heads_of(b_ref, heads, 1), s_ref[...],
+        _heads_of(do_ref, heads).astype(jnp.float32), dst_ref[...])
+    _put_heads(dq_ref, dq)
+    _put_heads(dk_ref, dk)
+    _put_heads(dv_ref, dv)
+    _put_heads(dg_ref, dG)
+    _put_heads(db_ref, jnp.broadcast_to(dbeta, dG.shape))
     dst_ref[...] = dSt0
 
 
@@ -389,14 +433,16 @@ def _kda_call(kind, kernel, plan, grid, in_specs, out_specs, out_shape,
     )
 
 
-def _specs(C, K, V, order):
-    """Block specs over the grid (b, head, chunk) of the operands as they
-    lie, [b, T, H K] and [b, T, H V], and of the states [b, H, nc, V, K];
+def _specs(plan, K, V, order):
+    """Block specs over the grid (b, heads of a step, chunk) of the
+    operands as they lie, [b, T, H K] and [b, T, H V], where a step's heads
+    are neighbouring lane tiles, and of the states [b, H, nc, V, K];
     ``order`` turns a grid step into its chunk."""
+    C, n = plan.chunk, plan.heads
     return {
-        "k": pl.BlockSpec((None, C, K), lambda b, h, c: (b, order(c), h)),
-        "v": pl.BlockSpec((None, C, V), lambda b, h, c: (b, order(c), h)),
-        "state": pl.BlockSpec((None, None, None, V, K),
+        "k": pl.BlockSpec((None, C, n * K), lambda b, h, c: (b, order(c), h)),
+        "v": pl.BlockSpec((None, C, n * V), lambda b, h, c: (b, order(c), h)),
+        "state": pl.BlockSpec((None, n, None, V, K),
                               lambda b, h, c: (b, h, order(c), 0, 0)),
     }
 
@@ -417,16 +463,17 @@ def _pallas_forward(q, k, v, G, beta, chunk, interpret, states):
     V, nc = v.shape[-1], T // chunk
     plan = kernel_plan(H, K, V, chunk, q.dtype, kind="fwd")
     _log_plan("fwd", q.shape, q.dtype, plan)
-    specs = _specs(chunk, K, V, lambda c: c)
+    specs = _specs(plan, K, V, lambda c: c)
     out_specs, out_shape = [specs["v"]], [
         jax.ShapeDtypeStruct((b, T, H * V), v.dtype)]
     if states:
         out_specs.append(specs["state"])
         out_shape.append(jax.ShapeDtypeStruct((b, H, nc, V, K), jnp.float32))
     out = _kda_call(
-        "fwd", functools.partial(_fwd_kernel, sub=plan.sub), plan,
-        (b, H, nc), [specs[x] for x in "kkvkk"], out_specs, out_shape,
-        [pltpu.VMEM((V, K), jnp.float32)], interpret,
+        "fwd", functools.partial(_fwd_kernel, sub=plan.sub,
+                                 heads=plan.heads), plan,
+        (b, H // plan.heads, nc), [specs[x] for x in "kkvkk"], out_specs,
+        out_shape, [pltpu.VMEM((plan.heads, V, K), jnp.float32)], interpret,
     )(*_operands(q, k, v, G, beta))
     return out[0].reshape(b, T, H, V), (out[1] if states else None)
 
@@ -436,17 +483,18 @@ def _pallas_backward(q, k, v, G, beta, S, do, chunk, interpret):
     V, nc, f32 = v.shape[-1], T // chunk, jnp.float32
     plan = kernel_plan(H, K, V, chunk, q.dtype, kind="bwd")
     _log_plan("bwd", q.shape, q.dtype, plan)
-    specs = _specs(chunk, K, V, lambda c: nc - 1 - c)
+    specs = _specs(plan, K, V, lambda c: nc - 1 - c)
     wide = jax.ShapeDtypeStruct((b, T, H * K), f32)
     dq, dk, dv, dG, dbeta = _kda_call(
-        "bwd", functools.partial(_bwd_kernel, sub=plan.sub), plan,
-        (b, H, nc), [specs[x] for x in ("k", "k", "v", "k", "k", "state",
-                                        "v")],
+        "bwd", functools.partial(_bwd_kernel, sub=plan.sub,
+                                 heads=plan.heads), plan,
+        (b, H // plan.heads, nc),
+        [specs[x] for x in ("k", "k", "v", "k", "k", "state", "v")],
         [specs[x] for x in "kkvkk"],
         [jax.ShapeDtypeStruct((b, T, H * K), q.dtype),
          jax.ShapeDtypeStruct((b, T, H * K), k.dtype),
          jax.ShapeDtypeStruct((b, T, H * V), v.dtype), wide, wide],
-        [pltpu.VMEM((V, K), f32)], interpret,
+        [pltpu.VMEM((plan.heads, V, K), f32)], interpret,
     )(*_operands(q, k, v, G, beta), S, _merged(do))
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
             dG.reshape(G.shape), dbeta.reshape(G.shape)[..., 0])

@@ -956,26 +956,34 @@ def test_one_participant_compiles_with_no_all_reduce(described_chip):
 
 # ---- the KDA scan's kernels and the latent mixer's two widths ---------------
 
-@pytest.mark.parametrize("chunk", [64, 128])
-def test_kda_kernels_compile_for_v5e(described_chip, monkeypatch, chunk):
+@pytest.mark.parametrize("chunk, dtype, heads", [
+    (64, "bfloat16", 4), (128, "bfloat16", 4), (256, "bfloat16", 2),
+    (128, "float32", 4)])
+def test_kda_kernels_compile_for_v5e(described_chip, monkeypatch, chunk,
+                                     dtype, heads):
     """``ops/kda.py``'s kernel pair at the cell's shape (one sequence of
-    16,384 tokens, 32 heads of 128) at both chunks the plan takes: both
-    Mosaic calls by name, and the states a chunk starts from the forward
-    call's second result."""
+    16,384 tokens, 32 heads of 128) at the chunks the plan takes, and in
+    float32 at ``highest`` as ``grad_check_ling`` runs them (where a
+    step's heads hold most VMEM): both Mosaic calls by name inside what
+    the plan counted, ``heads`` heads a step of the backward call, and the
+    states a chunk starts from the forward call's second result."""
     from horovod_tpu.ops import kda
 
     monkeypatch.setattr(pa, "_resolve_dispatch", lambda up: (True, False))
     T, H, K = 16384, 32, 128
-    wide = jax.ShapeDtypeStruct((1, T, H, K), jnp.bfloat16,
+    wide = jax.ShapeDtypeStruct((1, T, H, K), jnp.dtype(dtype),
                                 sharding=described_chip)
     gate = jax.ShapeDtypeStruct((1, T, H, K), jnp.float32,
                                 sharding=described_chip)
     beta = jax.ShapeDtypeStruct((1, T, H), jnp.float32,
                                 sharding=described_chip)
-    assert kda.kernel_plan(H, K, K, chunk, jnp.bfloat16) is not None
+    assert kda.kernel_plan(H, K, K, chunk, wide.dtype).heads == heads
     fn = jax.grad(lambda *a: kda.kda_chunked(*a, chunk=chunk).astype(
         jnp.float32).sum(), argnums=range(5))
-    text = jax.jit(fn).lower(wide, wide, wide, gate, beta).compile().as_text()
+    with jax.default_matmul_precision(
+            "highest" if dtype == "float32" else "default"):
+        text = jax.jit(fn).lower(wide, wide, wide, gate,
+                                 beta).compile().as_text()
     calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
                        r'"tpu_custom_call"', text)
     assert len(calls) == 2

@@ -87,14 +87,17 @@ def test_a_length_the_chunk_does_not_divide_is_padded():
     assert max(rel(a, b) for a, b in zip(grads, want_grads)) < 5e-6
 
 
-@pytest.mark.parametrize("chunk", [64, 128])
+@pytest.mark.parametrize("chunk, H, heads", [
+    (64, 2, 2), (128, 2, 2), (64, 3, 1), (128, 4, 4), (64, 6, 2)])
 def test_the_kernel_pair_interpreted_is_the_scan_over_chunks(monkeypatch,
-                                                             chunk):
-    """At heads of one lane tile, which the plan takes: the same chunk
-    mathematics under the other driver, the operands read where they lie
-    in [b, T, H K], the chunks turned round for the backward walk."""
-    args = operands(5, 1, 256, 2, 128, 128, (-5.0, 0.0), (0.0, 1.0))
-    assert kda.kernel_plan(2, 128, 128, chunk, jnp.float32) is not None
+                                                             chunk, H, heads):
+    """At heads of one lane tile, which the plan takes, one, two and four
+    of them a grid step: the same chunk mathematics under the other
+    driver, the operands read where they lie in [b, T, H K], a step's
+    heads neighbouring lane tiles, the chunks turned round for the
+    backward walk."""
+    args = operands(5, 1, 256, H, 128, 128, (-5.0, 0.0), (0.0, 1.0))
+    assert kda.kernel_plan(H, 128, 128, chunk, jnp.float32).heads == heads
     monkeypatch.delenv("HVD_PALLAS_INTERPRET", raising=False)
     want, want_grads = both(lambda *a: kda.kda_chunked(*a, chunk=chunk),
                             args)
@@ -103,12 +106,37 @@ def test_the_kernel_pair_interpreted_is_the_scan_over_chunks(monkeypatch,
     got, grads = both(lambda *a: kda.kda_chunked(*a, chunk=chunk), args)
     after = metrics.counters()
     for kind in ("fwd", "bwd"):
-        name = f"kernels.traced.kda_{kind}"
-        assert after.get(name, 0) > before.get(name, 0)
+        traced = (after.get(f"kernels.traced.kda_{kind}", 0)
+                  - before.get(f"kernels.traced.kda_{kind}", 0))
+        assert traced > 0
+        name = f"kernels.kda_{kind}.heads_per_step"
+        assert after.get(name, 0) - before.get(name, 0) == traced * heads
     assert rel(got, want) < 1e-6
     assert max(rel(a, b) for a, b in zip(grads, want_grads)) < 1e-6
     # And both are the recurrence.
     assert rel(got, recurrence(*args)) < 2e-5
+
+
+def test_the_kernels_reach_the_chunk_mathematics_through_the_module(
+        monkeypatch):
+    """``benchmark/limit_check_ling.py`` rounds the state a chunk hands on
+    by patching ``kda._chunk_forward`` with a wrapper of positional
+    arguments: the kernels look the function up when they are traced and
+    pass nothing by name."""
+    args = operands(8, 1, 128, 2, 128, 128, (-1.0, 0.0), (0.0, 1.0))
+    monkeypatch.setenv("HVD_PALLAS_INTERPRET", "1")
+    want = kda.kda_chunked(*args, chunk=64)
+    forward, calls = kda._chunk_forward, []
+
+    def rounded(*xs):
+        calls.append(len(xs))
+        o, state = forward(*xs)
+        return o, state.astype(jnp.bfloat16).astype(jnp.float32)
+
+    monkeypatch.setattr(kda, "_chunk_forward", rounded)
+    got = kda.kda_chunked(*args, chunk=64)
+    assert calls == [7]
+    assert 1e-4 < rel(got, want) < 1e-2
 
 
 def test_bf16_operands_stay_near_the_float32_recurrence():
@@ -148,6 +176,31 @@ def test_the_plan_takes_lane_tiles_and_whole_blocks(shape, taken):
         assert plan.vmem_bytes <= 40 << 20
         assert kda.kernel_plan(*shape, jnp.bfloat16, kind="fwd").vmem_bytes \
             <= plan.vmem_bytes
+
+
+@pytest.mark.parametrize("shape, dtype, heads", [
+    ((32, 128, 128, 128), jnp.bfloat16, kda._HEADS[0]),   # the cell's
+    ((3, 128, 128, 128), jnp.bfloat16, 1),
+    ((6, 128, 128, 128), jnp.bfloat16, 2),
+    ((5, 128, 128, 64), jnp.float32, 1),
+    # What the counted VMEM refuses: four heads' chunks of 256 tokens are
+    # 50 MB of the 40 backward, two fit; in float32, or of 384 tokens, only
+    # one head's do.
+    ((32, 128, 128, 256), jnp.bfloat16, 2),
+    ((32, 128, 128, 256), jnp.float32, 1),
+    ((32, 128, 128, 384), jnp.bfloat16, 1)])
+def test_the_plan_carries_the_heads_that_divide_and_fit(shape, dtype, heads):
+    """The most of four, two and one that divides the heads and whose
+    chunks fit the VMEM budget together (``heads`` is the backward pass's,
+    which counts more a head than the forward's)."""
+    plans = {}
+    for kind in ("fwd", "bwd"):
+        plan = plans[kind] = kda.kernel_plan(*shape, dtype, kind=kind)
+        one = kda.kernel_plan(1, *shape[1:], dtype, kind=kind)
+        assert one.heads == 1
+        assert plan.vmem_bytes == plan.heads * one.vmem_bytes <= 40 << 20
+        assert shape[0] % plan.heads == 0
+    assert plans["fwd"].heads >= plans["bwd"].heads == heads
 
 
 def test_a_shape_the_plan_refuses_runs_as_the_scan(monkeypatch):

@@ -2,18 +2,28 @@
 """On-chip check and timing of the KDA scan's kernels (``ops/kda.py``).
 
 Needs the chip (no accelerator is a non-zero exit). At a short length the
-kernel pair and the scan over chunks are held to the recurrence over time
-in float32 (output and every gradient); at the cell's length the kernels
-are timed, forward and forward + backward, a line of JSON each.
+kernel pair is held to the recurrence over time in float32 (output and
+every gradient); at the cell's length a call of ``kda_fwd`` (with the
+states it keeps for the backward pass) and a call of ``kda_bwd`` are timed
+apart, each a line of JSON with the plan's heads a grid step: ``device_ms``
+is the Mosaic custom call's own time in a profiler trace, what the
+benchmark's ``breakdown`` reads per call, ``host_ms`` the host's clock
+around the jitted pass (beta's broadcast and the reshapes with it).
+
+``--try-heads 1,2,4`` runs everything again with the plan held to at most
+that many heads a step (the bench's own override of ``kda._HEADS``: the
+program takes what ``kernel_plan`` gives).
 
 Usage: python tools/kda_bench.py [--chunks 64,128] [--seq-len 16384]
-           [--heads 32] [--dim 128] [--iters 5]
+           [--heads 32] [--dim 128] [--iters 5] [--try-heads 1,2,4]
 """
 
 import argparse
+import glob
 import json
 import os
 import sys
+import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
@@ -33,6 +43,45 @@ def operands(seed, b, T, H, K, dtype):
     return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
 
 
+def call_ms(fn, args, iters):
+    """(host-clock ms, the Mosaic kernels' device ms) a call of the jitted
+    ``fn``, compiled before either is read."""
+    import jax
+
+    from benchmark import trace_reduce
+
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    host = 1e3 * (time.perf_counter() - t0) / iters
+    with tempfile.TemporaryDirectory() as where:
+        jax.profiler.start_trace(where)
+        for _ in range(iters):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        found = glob.glob(os.path.join(where, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        planes = trace_reduce.device_planes(trace_reduce.load(found[0]))
+    kernels = trace_reduce.op_events(planes[0][1],
+                                     match=trace_reduce.is_mosaic_kernel)
+    return host, sum(e[2] for e in kernels) / 1e6 / iters
+
+
+def passes(kda, chunk):
+    """The two passes as ``kda_chunked`` reaches them on the chip, on the
+    sums inside a chunk, jitted apart."""
+    import jax
+
+    fwd = jax.jit(lambda q, k, v, G, beta: kda._pallas_forward(
+        q, k, v, G, beta, chunk, False, True))
+    bwd = jax.jit(lambda q, k, v, G, beta, S, do: kda._pallas_backward(
+        q, k, v, G, beta, S, do, chunk, False))
+    return fwd, bwd
+
+
 def main(argv=None):
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--chunks", default="64,128")
@@ -40,6 +89,7 @@ def main(argv=None):
     parser.add_argument("--heads", type=int, default=32)
     parser.add_argument("--dim", type=int, default=128)
     parser.add_argument("--iters", type=int, default=5)
+    parser.add_argument("--try-heads", default="")
     args = parser.parse_args(argv)
 
     import jax
@@ -60,37 +110,52 @@ def main(argv=None):
         want = jax.jit(jax.value_and_grad(
             lambda *a: jnp.sum(recurrence(*a) * weight),
             argnums=(0, 1, 2, 3, 4)))(*small)
-    for chunk in map(int, args.chunks.split(",")):
-        for dtype in (jnp.float32, jnp.bfloat16):
-            cast = [x.astype(dtype) for x in small[:3]] + list(small[3:])
-            with jax.default_matmul_precision("highest"):
-                got = jax.jit(jax.value_and_grad(
-                    lambda *a: jnp.sum(kda.kda_chunked(
-                        *a, chunk=chunk).astype(jnp.float32) * weight),
-                    argnums=(0, 1, 2, 3, 4)))(*cast)
-            print(json.dumps({
-                "chunk": chunk, "dtype": jnp.dtype(dtype).name,
-                "loss": [float(got[0]), float(want[0])],
-                "grad_rel_err": [rel(a.astype(jnp.float32), b)
-                                 for a, b in zip(got[1], want[1])]}),
-                flush=True)
-        big = operands(3, 1, args.seq_len, args.heads, args.dim,
-                       jnp.bfloat16)
-        fwd = jax.jit(lambda *a: kda.kda_chunked(*a, chunk=chunk))
-        both = jax.jit(jax.grad(
-            lambda *a: jnp.sum(kda.kda_chunked(*a, chunk=chunk).astype(
-                jnp.float32)), argnums=(0, 1, 2, 3, 4)))
-        for name, fn in (("fwd", fwd), ("fwd+bwd", both)):
-            jax.block_until_ready(fn(*big))
-            t0 = time.perf_counter()
-            for _ in range(args.iters):
-                out = fn(*big)
-            jax.block_until_ready(out)
-            print(json.dumps({
-                "chunk": chunk, "phase": name, "shape": list(big[0].shape),
-                "ms": 1e3 * (time.perf_counter() - t0) / args.iters}),
-                flush=True)
+    q, k, v, g, beta = operands(3, 1, args.seq_len, args.heads, args.dim,
+                                jnp.bfloat16)
+    b, T, H, K = q.shape
+    the_rule = kda._HEADS
+    for at_most in (list(map(int, args.try_heads.split(",")))
+                    if args.try_heads else [the_rule[0]]):
+        kda._HEADS = tuple(n for n in the_rule if n <= at_most)
+        for chunk in map(int, args.chunks.split(",")):
+            check(kda, chunk, small, weight, want, rel)
+            plan = kda.kernel_plan(H, K, K, chunk, q.dtype)
+            G = jnp.cumsum(g.reshape(b, T // chunk, chunk, H, K),
+                           axis=2).reshape(b, T, H, K)
+            big = (q, k, v, G, beta)
+            fwd, bwd = passes(kda, chunk)
+            o, S = fwd(*big)
+            for name, fn, xs in (("kda_fwd", fwd, big),
+                                 ("kda_bwd", bwd, big + (S, o))):
+                host, device = call_ms(fn, xs, args.iters)
+                print(json.dumps({
+                    "chunk": chunk, "call": name, "shape": list(q.shape),
+                    "heads_per_step": plan.heads, "device_ms": device,
+                    "host_ms": host}), flush=True)
     return 0
+
+
+def check(kda, chunk, small, weight, want, rel):
+    """The kernels' output and gradients against the recurrence's, at the
+    plan the shape gets."""
+    import jax
+    import jax.numpy as jnp
+
+    for dtype in (jnp.float32, jnp.bfloat16):
+        cast = [x.astype(dtype) for x in small[:3]] + list(small[3:])
+        H, K = cast[0].shape[2:]
+        with jax.default_matmul_precision("highest"):
+            got = jax.jit(jax.value_and_grad(
+                lambda *a: jnp.sum(kda.kda_chunked(
+                    *a, chunk=chunk).astype(jnp.float32) * weight),
+                argnums=(0, 1, 2, 3, 4)))(*cast)
+        print(json.dumps({
+            "chunk": chunk, "dtype": jnp.dtype(dtype).name,
+            "heads_per_step": kda.kernel_plan(H, K, K, chunk, dtype).heads,
+            "loss": [float(got[0]), float(want[0])],
+            "grad_rel_err": [rel(a.astype(jnp.float32), b)
+                             for a, b in zip(got[1], want[1])]}),
+            flush=True)
 
 
 if __name__ == "__main__":
